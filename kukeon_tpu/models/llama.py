@@ -24,6 +24,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from kukeon_tpu.ops import dispatch
 from kukeon_tpu.ops.attention import gqa_attention
 from kukeon_tpu.ops.norms import rms_norm
 from kukeon_tpu.ops.rope import apply_rope
@@ -261,8 +262,8 @@ def quantize_np(w, axis: int):
     """Per-output-channel symmetric int8 on the host (numpy): w ~= q * s.
 
     The single source of truth for the numpy quantization recipe — host
-    loaders (hf_convert.load_params_quantized, init_quantized_params_host)
-    must match :func:`quantize_params`'s device recipe exactly, or
+    loaders (hf_convert.load_params_quantized, moe's host init) must match
+    :func:`quantize_params`'s device recipe exactly, or
     streamed-vs-quantized trees silently diverge.
     """
     import numpy as np
@@ -274,40 +275,70 @@ def quantize_np(w, axis: int):
     return {"q": q, "s": np.squeeze(s, axis=axis)}
 
 
-def init_quantized_params_host(cfg: LlamaConfig, seed: int = 0) -> Params:
-    """Random-init DIRECTLY in int8 on the host, leaf by leaf.
+def init_quantized_params(key: jax.Array, cfg: LlamaConfig,
+                          shardings: Any = None) -> Params:
+    """Random-init DIRECTLY in int8 on the device(s), leaf by leaf.
 
-    An 8B-class bf16 tree (~16 GB) cannot be materialized on one v5e chip
-    just to be quantized; building {"q", "s"} leaves in numpy keeps peak
-    memory at one leaf and ships only int8 + scales to the device."""
-    import numpy as np
-
+    An 8B-class bf16 tree (~16 GB) cannot be materialized on one 16 GB
+    chip just to be quantized, and drawing it on the host costs minutes
+    of single-threaded numpy before the chip is touched. Each leaf is
+    drawn and quantized by its own small jitted program — stacked layer
+    weights one layer at a time under ``lax.map``, so the f32 transient
+    is one layer of one matrix — and is born in its serving sharding
+    (``shardings``: the tree ``parallel.sharding.param_shardings`` gives
+    for this function's ``jax.eval_shape``), so nothing is staged through
+    one device or the host. The counter-based PRNG draws the same values
+    under any sharding: a 1-chip and a 4-chip cell serve the same
+    weights."""
     c = cfg
-    rng = np.random.default_rng(seed)
     L, H, I, V = c.num_layers, c.hidden_size, c.intermediate_size, c.vocab_size
-    ndtype = np.dtype(c.dtype)   # norms must match the activation dtype
+    keys = iter(jax.random.split(key, 9))
 
-    def q(shape, fan_in, axis):
-        w = rng.standard_normal(shape, np.float32) * (fan_in ** -0.5)
-        return quantize_np(w, axis)
+    def at(*path):
+        node = shardings
+        for k in path:
+            node = None if node is None else node[k]
+        return node
+
+    def q(path, shape, fan_in, axis):
+        """{"q","s"} for one [.., in, out] matrix; ``axis`` is the
+        contracted axis of the unstacked matrix."""
+
+        def one(k, shape2d):
+            w = jax.random.normal(k, shape2d, jnp.float32) * fan_in ** -0.5
+            qw, s = _int8_sym(w, axis)
+            return {"q": qw, "s": jnp.squeeze(s, axis=axis)}
+
+        if len(shape) == 3:
+            def fn(k):
+                return jax.lax.map(lambda kk: one(kk, shape[1:]),
+                                   jax.random.split(k, shape[0]))
+        else:
+            def fn(k):
+                return one(k, shape)
+        return jax.jit(fn, out_shardings=at(*path))(next(keys))
+
+    def ones(path, shape):
+        return jax.jit(lambda: jnp.ones(shape, c.dtype),
+                       out_shardings=at(*path))()
 
     params: Params = {
-        "embed": q((V, H), H, 1),
+        "embed": q(("embed",), (V, H), H, 1),
         "layers": {
-            "attn_norm": np.ones((L, H), ndtype),
-            "wq": q((L, H, c.q_dim), H, 1),
-            "wk": q((L, H, c.kv_dim), H, 1),
-            "wv": q((L, H, c.kv_dim), H, 1),
-            "wo": q((L, c.q_dim, H), c.q_dim, 1),
-            "mlp_norm": np.ones((L, H), ndtype),
-            "w_gate": q((L, H, I), H, 1),
-            "w_up": q((L, H, I), H, 1),
-            "w_down": q((L, I, H), I, 1),
+            "attn_norm": ones(("layers", "attn_norm"), (L, H)),
+            "wq": q(("layers", "wq"), (L, H, c.q_dim), H, 0),
+            "wk": q(("layers", "wk"), (L, H, c.kv_dim), H, 0),
+            "wv": q(("layers", "wv"), (L, H, c.kv_dim), H, 0),
+            "wo": q(("layers", "wo"), (L, c.q_dim, H), c.q_dim, 0),
+            "mlp_norm": ones(("layers", "mlp_norm"), (L, H)),
+            "w_gate": q(("layers", "w_gate"), (L, H, I), H, 0),
+            "w_up": q(("layers", "w_up"), (L, H, I), H, 0),
+            "w_down": q(("layers", "w_down"), (L, I, H), I, 0),
         },
-        "final_norm": np.ones((H,), ndtype),
+        "final_norm": ones(("final_norm",), (H,)),
     }
     if not c.tie_embeddings:
-        params["lm_head"] = q((H, V), H, 0)
+        params["lm_head"] = q(("lm_head",), (H, V), H, 0)
     return params
 
 
@@ -329,6 +360,7 @@ def _mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
             lead = h.shape[:-1]
             out = int8_matmul(h.reshape(-1, h.shape[-1]), w["q"], w["s"])
             return out.reshape(*lead, out.shape[-1])
+        dispatch.note("int8_matmul", "xla")
         return (h @ w["q"].astype(h.dtype)) * w["s"].astype(h.dtype)
     return h @ w
 

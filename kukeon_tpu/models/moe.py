@@ -170,9 +170,9 @@ def quantize_params(params: Params) -> Params:
 
 
 def init_quantized_params_host(cfg: MoEConfig, seed: int = 0) -> Params:
-    """Random-init DIRECTLY in int8 on the host, leaf by leaf (mirrors
-    llama.init_quantized_params_host: a mixtral-8x7b bf16 tree is ~93 GB —
-    it cannot be materialized on a 16 GB chip just to be quantized)."""
+    """Random-init DIRECTLY in int8 on the host, leaf by leaf: a
+    mixtral-8x7b bf16 tree is ~93 GB — it cannot be materialized on a
+    16 GB chip just to be quantized."""
     import numpy as np
 
     from kukeon_tpu.models.llama import quantize_np

@@ -23,7 +23,11 @@ from kukeon_tpu.obs.registry import (  # noqa: F401
     get_default,
     percentile_from_counts,
 )
-from kukeon_tpu.obs.expo import faults_collector, render  # noqa: F401
+from kukeon_tpu.obs.expo import (  # noqa: F401
+    faults_collector,
+    op_impl_collector,
+    render,
+)
 from kukeon_tpu.obs.trace import (  # noqa: F401
     PHASES,
     TRACEPARENT_HEADER,

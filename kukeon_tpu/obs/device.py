@@ -221,11 +221,6 @@ class ProfileSpool:
         try:
             import jax
 
-            if not hasattr(jax, "profiler") or not hasattr(
-                    jax.profiler, "start_trace"):
-                raise RuntimeError(
-                    "jax.profiler.start_trace is unavailable on this "
-                    "backend; no capture possible")
             os.makedirs(rec["path"], exist_ok=True)
             jax.profiler.start_trace(rec["path"])
             try:

@@ -140,3 +140,19 @@ def faults_collector():
         "Fault-injection fires by point (kukeon_tpu.faults).",
         [({"point": p}, float(v)) for p, v in sorted(points.items())],
     )
+
+
+def op_impl_collector():
+    """Scrape-time family for the kernel-path choices the dispatching ops
+    made while the programs of this process were traced
+    (``kukeon_tpu.ops.dispatch``): which of them run a Pallas kernel and
+    which the XLA path on this device."""
+    from kukeon_tpu.ops import dispatch
+
+    yield (
+        "kukeon_op_impl_traces_total", "counter",
+        "Implementation chosen by each dispatching op (pallas kernel or "
+        "xla), counted once per trace.",
+        [({"op": op, "impl": impl}, float(n))
+         for (op, impl), n in sorted(dispatch.counts().items())],
+    )

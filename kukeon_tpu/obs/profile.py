@@ -32,7 +32,6 @@ accelerator runtime.
 
 from __future__ import annotations
 
-import os
 import time
 from collections import deque
 from typing import Any, Iterable
@@ -54,55 +53,50 @@ PROGRAMS = (
     "decode_chunk_paged",
 )
 
-PEAK_FLOPS_ENV = "KUKEON_PEAK_FLOPS"
-PEAK_HBM_BPS_ENV = "KUKEON_PEAK_HBM_BPS"
+# device_kind, exactly as the installed runtime reports it (checked by
+# describing each topology with jax.experimental.topologies) ->
+# (peak dense bf16 FLOP/s, peak HBM bytes/s) of one device. Source: the
+# Google Cloud TPU documentation's system-architecture page of each
+# generation ("TPU v5e": 197 TFLOP/s, 819 GB/s; "TPU v5p": 459 TFLOP/s,
+# 2765 GB/s; "TPU v6e": 918 TFLOP/s, 1640 GB/s). Only generations where
+# one JAX device is one chip are listed. A TPU that is not here gets NO
+# utilization gauges (and the scrape says why) — never a made-up peak.
+PEAKS_BY_DEVICE_KIND: dict[str, tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+}
 
-# device_kind substring -> (peak FLOP/s, peak HBM bytes/s), bf16 dense.
-# Matched longest-substring-first so "TPU v5p" never hits the "v5" of a
-# litespec. Unknown backends (CPU smoke) fall back to a deliberately
-# generous default: MFU then reads LOW, never a fabricated 90%.
-_PEAK_SPECS: tuple[tuple[str, float, float], ...] = (
-    ("v6e", 918e12, 1.64e12),
-    ("v5p", 459e12, 2.76e12),
-    ("v5e", 197e12, 0.82e12),
-    ("v4", 275e12, 1.2e12),
-)
-_DEFAULT_PEAKS = (1e12, 100e9)
 
+def device_peaks() -> tuple[tuple[float, float] | None, str]:
+    """((peak FLOP/s, peak HBM bytes/s) | None, reason) for device 0.
 
-def device_peaks() -> tuple[float, float]:
-    """(peak FLOP/s, peak HBM bytes/s) for device 0 — env overrides
-    (``KUKEON_PEAK_FLOPS`` / ``KUKEON_PEAK_HBM_BPS``) beat the built-in
-    table, the table beats the conservative unknown-backend default."""
-    flops, bw = _DEFAULT_PEAKS
-    try:
-        import jax
+    None means "no utilization can be stated": on a non-TPU backend
+    (reason empty — the families are simply declared empty, as the HBM
+    families are), and on a TPU whose ``device_kind`` is not in
+    :data:`PEAKS_BY_DEVICE_KIND` (reason names the kind, and rides the
+    families' HELP text on the scrape)."""
+    import jax
 
-        kind = str(jax.devices()[0].device_kind).lower()
-        for sub, f, b in _PEAK_SPECS:
-            if sub in kind:
-                flops, bw = f, b
-                break
-    except Exception:  # noqa: BLE001 — no backend is not an error here
-        pass
-    try:
-        flops = float(os.environ.get(PEAK_FLOPS_ENV) or flops)
-        bw = float(os.environ.get(PEAK_HBM_BPS_ENV) or bw)
-    except ValueError:
-        pass
-    return max(flops, 1.0), max(bw, 1.0)
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        return None, ""
+    peaks = PEAKS_BY_DEVICE_KIND.get(d.device_kind)
+    if peaks is None:
+        return None, (f"no published peak for device_kind "
+                      f"{d.device_kind!r} in obs/profile.py "
+                      "PEAKS_BY_DEVICE_KIND")
+    return peaks, ""
 
 
 def cost_summary(compiled) -> tuple[float, float] | None:
     """(flops, bytes accessed) from a compiled executable's
-    ``cost_analysis()``; None when the backend reports nothing usable.
-    Handles both return shapes jax has shipped (dict and [dict])."""
+    ``cost_analysis()`` dict; None when the backend reports nothing
+    usable."""
     try:
         d = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — optional analysis, never a failure
         return None
-    if isinstance(d, (list, tuple)):
-        d = d[0] if d else None
     if not isinstance(d, dict):
         return None
     try:
@@ -285,12 +279,17 @@ class ProgramTimers:
 
     # --- derived views -----------------------------------------------------
 
-    def _utilization(self) -> dict[str, tuple[float, float]]:
-        """{program: (mfu, membw_util)} over settled dispatches, clamped
-        to [0, 1]: achieved = static per-dispatch cost x settled count /
-        measured busy seconds; peak from :func:`device_peaks`."""
-        peak_flops, peak_bw = self._peaks or device_peaks()
-        out = {}
+    def _utilization(self) -> tuple[dict[str, tuple[float, float]], str]:
+        """({program: (mfu, membw_util)}, why-empty) over settled
+        dispatches, clamped to [0, 1]: achieved = static per-dispatch cost
+        x settled count / measured busy seconds, over the device's
+        published peak. No published peak (:func:`device_peaks`) -> no
+        entries, with the reason."""
+        peaks, why = (self._peaks, "") if self._peaks else device_peaks()
+        out: dict[str, tuple[float, float]] = {}
+        if peaks is None:
+            return out, why
+        peak_flops, peak_bw = peaks
         with self._lock:
             for program, (flops, nbytes) in self._costs.items():
                 n = self._settled.get(program, 0)
@@ -301,35 +300,36 @@ class ProgramTimers:
                     min(1.0, (flops * n) / (busy * peak_flops)),
                     min(1.0, (nbytes * n) / (busy * peak_bw)),
                 )
-        return out
+        return out, why
 
     def _collect(self) -> Iterable[object]:
-        util = self._utilization()
+        util, why = self._utilization()
+        absent = f" ABSENT: {why}." if why else ""
         yield ("kukeon_program_mfu", "gauge",
                "Model FLOPs utilization per program: static FLOPs x "
                "settled dispatches / (measured busy seconds x device "
-               "peak FLOP/s), clamped to 1.",
+               "peak FLOP/s), clamped to 1." + absent,
                [({"program": p}, mfu) for p, (mfu, _bw) in
                 sorted(util.items())])
         yield ("kukeon_program_membw_util", "gauge",
                "HBM bandwidth utilization per program: bytes accessed x "
                "settled dispatches / (busy seconds x peak bytes/s), "
-               "clamped to 1.",
+               "clamped to 1." + absent,
                [({"program": p}, bw) for p, (_mfu, bw) in
                 sorted(util.items())])
 
-    def snapshot(self) -> dict[str, dict[str, float]]:
+    def snapshot(self) -> dict[str, dict[str, float | None]]:
         """Per-program roofline summary for bench artifacts and step
         records: dispatches, settled count, busy seconds, tokens, static
         cost, and derived MFU/bandwidth utilization."""
-        util = self._utilization()
-        out: dict[str, dict[str, float]] = {}
+        util, _why = self._utilization()
+        out: dict[str, dict[str, float | None]] = {}
         with self._lock:
             programs = (set(self._dispatches) | set(self._costs)
                         | set(self._tokens))
             for p in sorted(programs):
                 flops, nbytes = self._costs.get(p, (0.0, 0.0))
-                mfu, bw = util.get(p, (0.0, 0.0))
+                mfu, bw = util.get(p, (None, None))
                 out[p] = {
                     "dispatches": self._dispatches.get(p, 0),
                     "settled": self._settled.get(p, 0),
@@ -337,8 +337,8 @@ class ProgramTimers:
                     "tokens": self._tokens.get(p, 0),
                     "flops": flops,
                     "hbm_bytes": nbytes,
-                    "mfu": round(mfu, 6),
-                    "membw_util": round(bw, 6),
+                    "mfu": None if mfu is None else round(mfu, 6),
+                    "membw_util": None if bw is None else round(bw, 6),
                 }
         return out
 

@@ -16,6 +16,8 @@ bandwidth.
 import jax
 import jax.numpy as jnp
 
+from kukeon_tpu.ops import dispatch
+
 NEG_INF = -1e30
 
 
@@ -137,6 +139,7 @@ def decode_gqa_attention(
 
     Returns: [B, 1, H, D].
     """
+    dispatch.note("decode_gqa_attention", "xla")   # no kernel: one path
     B, _, H, D = q.shape
     S = cache_k.shape[1]
     KV = cache_k.shape[2]
@@ -247,6 +250,7 @@ def gqa_attention(
             and jax.default_backend() == "tpu"
         )
 
+    dispatch.note("gqa_attention", "pallas" if use_flash else "xla")
     if use_flash:
         k = repeat_kv(k, n_heads // n_kv)
         v = repeat_kv(v, n_heads // n_kv)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kukeon_tpu.ops import dispatch
+
 # VMEM budget for one weight block (~half of the ~16 MB/core VMEM stays
 # free for h/out/accumulators and double buffering).
 _BLOCK_BYTES = 4 * 1024 * 1024
@@ -67,11 +69,13 @@ def int8_matmul(h: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
         # activation block must stay far under VMEM; prefill is MXU-bound so
         # XLA's dequant-fused dot is the right tool there), and non-TPU
         # backends: plain XLA fallback.
+        dispatch.note("int8_matmul", "xla")
         w = q.astype(h.dtype)
         out = jax.lax.dot_general(
             h, w, (((1,), (1 if transpose else 0,)), ((), ())))
         return out * s.astype(h.dtype)
 
+    dispatch.note("int8_matmul", "pallas")
     # Pad B up to the bf16 sublane tile so the MXU operand is well-formed.
     Bp = max(16, ((B + 15) // 16) * 16)
     if Bp != B:
@@ -112,6 +116,8 @@ def int8_matmul_expert(x: jnp.ndarray, q: jnp.ndarray,
     E, C, K = x.shape
     N = q.shape[-1]
     if (K % 128) or (N % 128) or C > 64 or jax.default_backend() != "tpu":
+        dispatch.note("int8_matmul_expert", "xla")
         raw = jnp.einsum("eck,ekn->ecn", x, q.astype(x.dtype))
         return raw * s[:, None, :].astype(x.dtype)
+    dispatch.note("int8_matmul_expert", "pallas")
     return jnp.stack([int8_matmul(x[e], q[e], s[e]) for e in range(E)])

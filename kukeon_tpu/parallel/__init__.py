@@ -8,7 +8,6 @@ from kukeon_tpu.parallel.mesh import (  # noqa: F401
     auto_mesh_shape,
     make_mesh,
     serving_mesh,
-    set_mesh,
     training_mesh,
 )
 from kukeon_tpu.parallel.pipeline import (  # noqa: F401

@@ -58,64 +58,12 @@ def make_mesh(
     # Auto axis types: GSPMD propagates shardings from the annotations we set
     # at jit boundaries (jax 0.9 defaults to Explicit mode, which turns
     # with_sharding_constraint into an assert — not what this codebase wants).
-    # Older runtimes (<= 0.5) have no AxisType and are Auto-only; the kwarg
-    # must be omitted there, not passed as None.
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 6
     return jax.make_mesh(
         (pipe, data, fsdp, expert, seq, tensor),
         (AXIS_PIPE, AXIS_DATA, AXIS_FSDP, AXIS_EXPERT, AXIS_SEQ, AXIS_TENSOR),
         devices=devices,
-        **kwargs,
+        axis_types=(jax.sharding.AxisType.Auto,) * 6,
     )
-
-
-def set_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` as the ambient mesh.
-
-    jax >= 0.6 exposes ``jax.set_mesh``; on older runtimes the Mesh object
-    itself is the context manager. Every call site goes through this one
-    shim so the framework runs on both."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def ambient_mesh():
-    """The mesh activated by :func:`set_mesh` (abstract on jax >= 0.6,
-    physical on older runtimes — both carry the axis names shard_map
-    needs)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` (>= 0.6) / ``jax.experimental.shard_map`` (older),
-    one call-site-stable spelling.
-
-    ``axis_names`` (manual over only those axes) is the new partial-manual
-    spelling; old shard_map expresses the same thing inversely via
-    ``auto=<the other axes>``."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    # The old replication checker miscounts scan carries (its own error
-    # message says to disable it); correctness is covered by the real
-    # numeric tests, and the new-jax path above keeps full checking.
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, **kwargs)
 
 
 def serving_mesh(n_devices: int | None = None) -> Mesh:
@@ -162,18 +110,3 @@ def auto_mesh_shape(n_devices: int) -> dict[str, int]:
     tensor = max(d for d in range(1, min(8, n_devices) + 1)
                  if n_devices % d == 0)
     return {"data": n_devices // tensor, "tensor": tensor}
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (>= 0.6); older runtimes count via psum(1)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def pcast(x, axes, to="varying"):
-    """``jax.lax.pcast`` (>= 0.6 varying-type system); a no-op on older
-    runtimes, whose shard_map has no replication typing to satisfy."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x

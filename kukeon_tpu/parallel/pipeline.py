@@ -34,9 +34,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from kukeon_tpu.models import llama
 from kukeon_tpu.parallel.mesh import (
     AXIS_PIPE,
-    ambient_mesh,
-    pcast,
-    shard_map,
 )
 from kukeon_tpu.parallel import sharding as shd
 
@@ -77,7 +74,7 @@ def pipeline_forward(
     training/prefill layout; decode serving uses the tensor-parallel engine.
     """
     if mesh is None:
-        mesh = ambient_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
     P_ = mesh.shape.get(AXIS_PIPE, 1)
     c = cfg
     B, S = tokens.shape
@@ -111,9 +108,9 @@ def pipeline_forward(
         pstate = jnp.zeros((Bm, S), jnp.int32)
         out = jnp.zeros((M, Bm, S, H), c.dtype)
         # Mark device-dependent so the loop carry type is stable.
-        state = pcast(state, (AXIS_PIPE,), to="varying")
-        pstate = pcast(pstate, (AXIS_PIPE,), to="varying")
-        out = pcast(out, (AXIS_PIPE,), to="varying")
+        state = jax.lax.pcast(state, (AXIS_PIPE,), to="varying")
+        pstate = jax.lax.pcast(pstate, (AXIS_PIPE,), to="varying")
+        out = jax.lax.pcast(out, (AXIS_PIPE,), to="varying")
 
         def tick(t, carry):
             state, pstate, out = carry
@@ -149,7 +146,7 @@ def pipeline_forward(
         lambda _: P(AXIS_PIPE), params["layers"],
         is_leaf=lambda v: isinstance(v, (jnp.ndarray, jax.Array)) or hasattr(v, "shape"),
     )
-    out_m = shard_map(
+    out_m = jax.shard_map(
         stages,
         mesh=mesh,
         in_specs=(layer_in_specs, P(), P()),

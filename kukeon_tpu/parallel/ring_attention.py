@@ -30,10 +30,6 @@ from kukeon_tpu.parallel.mesh import (
     AXIS_FSDP,
     AXIS_SEQ,
     AXIS_TENSOR,
-    ambient_mesh,
-    axis_size,
-    pcast,
-    shard_map,
 )
 
 
@@ -67,7 +63,7 @@ def _block_update(o, m, l, q, k, v, q_pos, kv_pos, scale, n_rep):
 
 def _ring_attention_local(q, k, v, q_pos, kv_pos, axis_name: str, all_axes: tuple):
     """Per-device body; runs under shard_map over ``axis_name``."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     n_rep = q.shape[2] // k.shape[2]
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -76,7 +72,7 @@ def _ring_attention_local(q, k, v, q_pos, kv_pos, axis_name: str, all_axes: tupl
     # Fresh accumulators are device-invariant; mark them varying over every
     # manual axis so the fori_loop carry type stays fixed across iterations.
     def vary(x):
-        return pcast(x, all_axes, to="varying")
+        return jax.lax.pcast(x, all_axes, to="varying")
 
     o = vary(jnp.zeros((B, Sq, H, D), jnp.float32))
     m = vary(jnp.full((B, H, Sq), NEG_INF, jnp.float32))
@@ -118,7 +114,7 @@ def ring_attention(
     Returns: [B, S, NH, D], same sharding as q.
     """
     if mesh is None:
-        mesh = ambient_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
 
     mesh_axes = set(mesh.axis_names)
     batch_axes = tuple(a for a in (AXIS_DATA, AXIS_FSDP) if a in mesh_axes) or None
@@ -137,7 +133,7 @@ def ring_attention(
         axis_name=axis_name,
         all_axes=tuple(a for a in mesh.axis_names if a in used),
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, pos_spec, pos_spec),

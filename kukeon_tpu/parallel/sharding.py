@@ -106,10 +106,8 @@ def shard_params(params, mesh: Mesh, fsdp: bool = False, threads: int = 4,
     Quantized leaves ({"q": int8 matrix, "s": scale}) inherit the matrix
     spec for q; the scale shards with the matrix's surviving axes.
 
-    Transfers are issued from a small thread pool: on a direct PCIe link
-    this changes nothing measurable, but on a tunneled/remote chip the
-    per-transfer RPC latency dominates and concurrent streams pipeline it
-    (an 8B int8 tree is ~300 leaves; serial puts pay ~300 round trips)."""
+    Transfers are issued from a small thread pool, so the per-transfer
+    dispatch latency of the tree's leaves overlaps."""
     shardings = param_shardings(params, mesh, fsdp, specs=specs)
     flat_s, treedef = jax.tree.flatten(shardings)
     flat_p, _ = jax.tree.flatten(params)

@@ -36,15 +36,12 @@ from kukeon_tpu.parallel.mesh import (
     AXIS_FSDP,
     AXIS_SEQ,
     AXIS_TENSOR,
-    ambient_mesh,
-    axis_size,
-    shard_map,
 )
 
 
 def _ulysses_local(q, k, v, q_pos, kv_pos, axis_name: str):
     """Per-device body under shard_map: local arrays are [B, S/n, h, D]."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n or k.shape[2] % n:
         raise ValueError(
             f"ulysses needs seq axis ({n}) to divide the local head counts "
@@ -87,14 +84,14 @@ def ulysses_attention(
     over ``axis_name``; returns [B, S, NH, D] with q's sharding.
     """
     if mesh is None:
-        mesh = ambient_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
     mesh_axes = set(mesh.axis_names)
     batch_axes = tuple(a for a in (AXIS_DATA, AXIS_FSDP) if a in mesh_axes) or None
     head_axis = AXIS_TENSOR if AXIS_TENSOR in mesh_axes else None
 
     qkv_spec = P(batch_axes, axis_name, head_axis, None)
     pos_spec = P(batch_axes, axis_name)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(_ulysses_local, axis_name=axis_name),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, pos_spec, pos_spec),

@@ -235,8 +235,9 @@ class ModelSpec:
     # the cell's bridge IP, in-space agent cells reach it there, and the
     # space's default-deny egress governs its traffic (BASELINE config 4).
     # hostNetwork: true is the spec-visible opt-out for hosts whose TPU
-    # runtime plane needs host networking (multi-host pod slices, emulated
-    # chips behind a loopback tunnel) — it exempts the cell from the space
+    # runtime plane needs host networking (multi-host pod slices; sandboxed
+    # hosts where the fresh sysfs of a cell's own netns lacks the PCI
+    # devices libtpu enumerates) — it exempts the cell from the space
     # egress policy, so it must be an explicit manifest decision.
     host_network: bool = False
 

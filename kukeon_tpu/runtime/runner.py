@@ -453,8 +453,7 @@ class Runner:
             ctx = self._container_context(rec, spec)
             grant = slices.get(spec.name, [])
             if grant:
-                ctx.env.update(self.devices.visibility_env(grant))
-                ctx.devices = self.devices.device_nodes(grant)
+                self._grant_chips(ctx, grant)
             st = rec.status.container(spec.name) or model.ContainerStatus(name=spec.name)
             live = self.backend.container_state(ctx)
             if not live.running and spec.name not in parked:
@@ -472,6 +471,16 @@ class Runner:
         self.store.write_cell(rec)
         self._m_cell_starts.inc(cell=self._owner_key(rec))
         return rec
+
+    def _grant_chips(self, ctx: ContainerContext, grant: list[int]) -> None:
+        """Make a container's chip grant real: the env that restricts
+        libtpu to those chips, and the device nodes a namespaced cell's
+        /dev will hold. A process-backend container sees the host's whole
+        /dev, a namespaced one only its grant — which is what libtpu's
+        device numbering counts (TPUDeviceManager.visibility_env)."""
+        ctx.env.update(self.devices.visibility_env(
+            grant, None if self.backend.isolated else self.devices.chips))
+        ctx.devices = self.devices.device_nodes(grant)
 
     @staticmethod
     def _chip_slices(containers: list[t.ContainerSpec], chips: list[int]) -> dict[str, list[int]]:
@@ -871,8 +880,7 @@ class Runner:
             if grant:
                 # The cell's grant partition is deterministic by declaration
                 # order: the replica comes back on exactly its chips.
-                ctx.env.update(self.devices.visibility_env(grant))
-                ctx.devices = self.devices.device_nodes(grant)
+                self._grant_chips(ctx, grant)
             self.backend.start_container(ctx)
             live = self.backend.container_state(ctx)
             st = rec.status.container(spec.name)
@@ -935,8 +943,7 @@ class Runner:
                     grant = self._chip_slices(
                         containers, rec.status.tpu_chips).get(spec.name, [])
                     if grant:
-                        ctx.env.update(self.devices.visibility_env(grant))
-                        ctx.devices = self.devices.device_nodes(grant)
+                        self._grant_chips(ctx, grant)
                     if not self.backend.container_state(ctx).running:
                         self.backend.start_container(ctx)
                     live = self.backend.container_state(ctx)
@@ -1009,8 +1016,7 @@ class Runner:
             grant = self._chip_slices(containers,
                                       rec.status.tpu_chips).get(spec.name, [])
             if grant:
-                ctx.env.update(self.devices.visibility_env(grant))
-                ctx.devices = self.devices.device_nodes(grant)
+                self._grant_chips(ctx, grant)
             if not self.backend.container_state(ctx).running:
                 self.backend.start_container(ctx)
             live = self.backend.container_state(ctx)
@@ -1232,8 +1238,7 @@ class Runner:
                 grant = self._chip_slices(containers, rec.status.tpu_chips).get(spec.name, [])
                 if grant:
                     # Reuse the cell's grant (stable across restarts).
-                    ctx_full.env.update(self.devices.visibility_env(grant))
-                    ctx_full.devices = self.devices.device_nodes(grant)
+                    self._grant_chips(ctx_full, grant)
                 self.backend.start_container(ctx_full)
                 prev_exit = st.exit_code
                 live = self.backend.container_state(ctx_full)

@@ -22,7 +22,6 @@ from jax.sharding import Mesh
 
 from kukeon_tpu.models import bert
 from kukeon_tpu.parallel import sharding as shd
-from kukeon_tpu.parallel.mesh import set_mesh
 
 EMBED_BUCKETS = (16, 32, 64, 128, 256, 512)
 
@@ -66,7 +65,7 @@ class EmbeddingEngine:
             tokens = np.zeros((self.batch_size, b), np.int32)
             mask = np.zeros((self.batch_size, b), np.int32)
             mask[:, 0] = 1
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self._embed(self.params, jnp.asarray(tokens), jnp.asarray(mask))
 
     def embed_batch(self, prompts: list[np.ndarray]) -> np.ndarray:
@@ -95,7 +94,7 @@ class EmbeddingEngine:
             # live position so the bias row isn't all -inf.
             for row in range(len(idx), self.batch_size):
                 mask[row, 0] = 1
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 vecs = np.asarray(
                     self._embed(self.params, jnp.asarray(tokens), jnp.asarray(mask))
                 )

@@ -44,7 +44,7 @@ def main(argv=None) -> int:
     import jax
 
     from kukeon_tpu.models import llama, moe
-    from kukeon_tpu.parallel import make_mesh, set_mesh
+    from kukeon_tpu.parallel import make_mesh
     from kukeon_tpu.training import (
         TokenDataset,
         batches,
@@ -91,7 +91,7 @@ def main(argv=None) -> int:
         total_steps=max(args.steps, args.warmup_steps + 1),
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if is_moe:
             if sizes["pipe"] > 1:
                 print("error: pipeline parallelism is llama-only for now",

@@ -1,0 +1,175 @@
+"""Compile for the chip without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is described
+(`jax.experimental.topologies`), not attached: it refuses what the chip's
+compiler would refuse — a kernel tile that does not align, a program that
+does not fit 16 GB — and interpret-mode tests on the CPU cannot. These are
+the main path's kernels at the real widths and one whole decode program of
+the flagship cell; they guard every later PR at no chip time. A compile that
+passes is not a chip run: nothing executes, no number here is a device
+metric.
+
+The dispatchers ask `jax.default_backend()` and here that says "cpu"; the
+tests steer it themselves (monkeypatch), not through an option of the
+program.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else the compiler logs under /tmp
+# libtpu takes a one-process lock (/tmp/libtpu_lockfile) even to describe a
+# topology; compiling for a described chip opens no device, so sharing the
+# library with another such process is safe — without this a concurrent
+# rehearsal (or test worker) turns every test here into a skip.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding  # noqa: E402
+
+from kukeon_tpu.models import llama  # noqa: E402
+from kukeon_tpu.ops import flash_attention as fa  # noqa: E402
+from kukeon_tpu.ops import int8_matmul as i8  # noqa: E402
+
+# bytes_limit the attached v5e reports (memory_stats on the chip, PR 22).
+V5E_HBM_BYTES = 16909336064
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this host
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _for_the_chip(monkeypatch):
+    """Dispatch as on the chip, and keep the persistent compile cache out
+    of it: an executable compiled for a described chip is written to the
+    cache but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(dev, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(dev))
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "the Pallas kernel is not in the compiled program (XLA path taken)"
+
+
+# llama3-8b: hidden 4096, q 4096, kv 1024, ffn 14336, vocab 128256.
+# mixtral-8x7b shares those widths; its vocab is 32000.
+@pytest.mark.parametrize("k,n", [
+    (4096, 4096),      # wq / wo
+    (4096, 1024),      # wk / wv
+    (4096, 14336),     # w_gate / w_up
+    (14336, 4096),     # w_down
+    (4096, 128256),    # llama3-8b lm_head
+    (4096, 32000),     # mixtral-8x7b lm_head
+])
+def test_int8_matmul_compiles_for_v5e(v5e, k, n):
+    d = v5e.devices[0]
+    compiled = i8.int8_matmul.lower(
+        _on(d, (4, k), jnp.bfloat16), _on(d, (k, n), jnp.int8),
+        _on(d, (n,), jnp.float32)).compile()
+    _assert_kernel(compiled)
+
+
+def test_int8_matmul_transposed_compiles_for_v5e(v5e):
+    """The tied-embedding LM head orientation: q [N, K], vocab rows."""
+    d = v5e.devices[0]
+    compiled = i8.int8_matmul.lower(
+        _on(d, (4, 4096), jnp.bfloat16), _on(d, (128256, 4096), jnp.int8),
+        _on(d, (128256,), jnp.float32), transpose=True).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("k,n", [(4096, 14336), (14336, 4096)])
+def test_int8_matmul_expert_compiles_for_v5e(v5e, k, n):
+    """Mixtral's 8 expert stacks at decode-sized capacity."""
+    d = v5e.devices[0]
+    compiled = jax.jit(i8.int8_matmul_expert).lower(
+        _on(d, (8, 4, k), jnp.bfloat16), _on(d, (8, k, n), jnp.int8),
+        _on(d, (8, n), jnp.float32)).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("s", [1024, 8192])
+def test_flash_attention_compiles_for_v5e(v5e, s):
+    d = v5e.devices[0]
+    qkv = _on(d, (1, s, 32, 128), jnp.bfloat16)
+    pos = _on(d, (1, s), jnp.int32)
+    compiled = jax.jit(fa.flash_attention).lower(
+        qkv, qkv, qkv, pos, pos).compile()
+    _assert_kernel(compiled)
+
+
+def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e):
+    """The flagship cell's whole decode program — llama3-8b int8, 32 layers,
+    4 slots x 4096 rows of bf16 KV, 16 steps per chunk — built by the
+    engine itself from shapes alone, compiled for one described v5e: the
+    bytes it keeps resident (arguments + outputs - donated aliases +
+    temporaries + code) fit the chip's HBM. This is the program that holds
+    the most at once (weights + whole cache + the scan's copy of it)."""
+    from kukeon_tpu.parallel import make_mesh
+    from kukeon_tpu.serving import ServingEngine
+
+    class AbstractOnly:
+        """A checkpoint stream that only knows its shapes: the engine
+        builds shardings and abstract params from it and touches no
+        device (async_load's thread finds nothing to upload)."""
+
+        def __init__(self, tree):
+            self.abstract_params = tree
+
+        def __iter__(self):
+            return iter(())
+
+        def stat_snapshot(self):
+            return {}
+
+    cfg = llama.llama3_8b()
+    mesh = make_mesh(tensor=1, devices=v5e.devices[:1])
+    abstract = jax.eval_shape(
+        lambda k: llama.init_quantized_params(k, cfg), jax.random.key(0))
+    eng = ServingEngine(cfg, AbstractOnly(abstract), mesh, num_slots=4,
+                        max_seq_len=4096, async_load=True, kv_page_tokens=0)
+    repl = NamedSharding(mesh, PartitionSpec())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.set_mesh(mesh):
+        compiled = eng._decode_chunk.lower(
+            eng._abstract_params, eng._abstract_state(),
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=repl),
+            sds((4,), jnp.float32), sds((4,), jnp.int32),
+            sds((4,), jnp.float32), 16).compile()
+    m = compiled.memory_analysis()
+    resident = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes
+                + m.generated_code_size_in_bytes)
+    # int8 weights alone are ~8 GB; the program must hold them AND fit.
+    assert 8e9 < m.argument_size_in_bytes < V5E_HBM_BYTES
+    assert resident < V5E_HBM_BYTES, (
+        f"decode_chunk keeps {resident / 1e9:.2f} GB resident; the chip has "
+        f"{V5E_HBM_BYTES / 1e9:.2f} GB")

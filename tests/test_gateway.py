@@ -962,7 +962,7 @@ def _load_bench():
     return mod
 
 
-def test_bench_artifact_v7_and_backcompat(tmp_path):
+def test_bench_artifact_v8_and_backcompat(tmp_path):
     bench = _load_bench()
     serve = {"backend": "cpu", "n_chips": 2, "model": "tiny",
              "model_id": "tiny", "sessions": 4, "tok_per_s": 100.0,
@@ -976,7 +976,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                           "disagg": {"arms": {}},
                           "diurnal": {"peak_p95_s": 0.8, "failed": 0}})
     art = bench.read_artifact(str(out))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["replicas"] == 3
     assert art["kv_page_tokens"] == 16
     assert art["max_sessions"] == 9
@@ -986,14 +986,14 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
     assert art["diurnal"] == {"peak_p95_s": 0.8, "failed": 0}
     assert art["mesh"] == {"chips": 2, "tensor": 2, "kv_sharded": True}
 
-    # A v1 point (pre-gateway, single engine) reads back as v5: replicas=1,
+    # A v1 point (pre-gateway, single engine) reads back as v8: replicas=1,
     # legacy contiguous KV (kv_page_tokens=0), every session resident, no
     # handoff and no diurnal section (neither existed).
     v1 = tmp_path / "BENCH_r05.json"
     v1.write_text(json.dumps({"schema": "kukeon-bench/v1", "backend": "cpu",
                               "tok_per_s": 50.0, "sessions": 4}))
     art = bench.read_artifact(str(v1))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["replicas"] == 1
     assert art["tok_per_s"] == 50.0
     assert art["kv_page_tokens"] == 0
@@ -1012,7 +1012,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                               "replicas": 2,
                               "latency_s": {"ttft": {"p95": 0.4}}}))
     art = bench.read_artifact(str(v2))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["replicas"] == 2
     assert art["kv_page_tokens"] == 0
     assert art["max_sessions"] == 2
@@ -1025,7 +1025,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                               "replicas": 1, "kv_page_tokens": 16,
                               "max_sessions": 4}))
     art = bench.read_artifact(str(v3))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["kv_page_tokens"] == 16
     assert art["max_sessions"] == 4
     assert art["handoff_ms_p50"] is None
@@ -1040,7 +1040,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                               "handoff_ms_p50": 10.0,
                               "disagg": {"arms": {}}}))
     art = bench.read_artifact(str(v4))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["ttft_p95_s"] == 0.3
     assert art["handoff_ms_p50"] == 10.0
     assert art["disagg"] == {"arms": {}}
@@ -1056,7 +1056,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                               "diurnal": {"peak_p95_s": 0.8, "failed": 0},
                               "cold_start": {"p50_s": 30.0}}))
     art = bench.read_artifact(str(v5))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["diurnal"] == {"peak_p95_s": 0.8, "failed": 0}
     assert art["cold_start"] == {"p50_s": 30.0, "load_s": None}
     assert art["mesh"] is None
@@ -1071,7 +1071,7 @@ def test_bench_artifact_v7_and_backcompat(tmp_path):
                               "cold_start": {"p50_s": 30.0,
                                              "load_s": {"disk": 1.0}}}))
     art = bench.read_artifact(str(v6))
-    assert art["schema"] == "kukeon-bench/v7"
+    assert art["schema"] == "kukeon-bench/v8"
     assert art["mesh"] is None
     assert art["cold_start"] == {"p50_s": 30.0, "load_s": {"disk": 1.0}}
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from kukeon_tpu.models import moe
-from kukeon_tpu.parallel import make_mesh, set_mesh
+from kukeon_tpu.parallel import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -126,7 +126,7 @@ def test_expert_parallel_mesh_parity(tiny):
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         params, specs, is_leaf=lambda x: isinstance(x, P),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(
             lambda p, t, pos: moe.forward(p, cfg, t, pos)
         )(sharded, tokens, positions)
@@ -170,7 +170,7 @@ def test_moe_train_step_on_expert_mesh():
 
     cfg = moe.moe_tiny()
     mesh = make_mesh(expert=2, tensor=2, data=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         optimizer = make_optimizer(warmup_steps=1, total_steps=10)
         state, optimizer = create_moe_train_state(cfg, mesh, jax.random.key(0), optimizer)
         train_step, batch_sharding = make_moe_train_step(cfg, mesh, optimizer)

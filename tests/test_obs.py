@@ -518,9 +518,17 @@ def test_embedding_cell_stats_parity():
     dc = ServingCell("tiny", num_slots=1, max_seq_len=96, checkpoint=None,
                      dtype=None)
     try:
-        for key in ("ready", "draining", "uptimeSeconds", "unreadyReason"):
+        for key in ("ready", "draining", "uptimeSeconds", "unreadyReason",
+                    "devices", "platform", "deviceKind"):
             assert key in ec.stats(), key
             assert key in dc.stats(), key
+        # The device census is what JAX reports in the process that holds
+        # the device (chip_smoke.py's last line is read off these fields).
+        d0 = jax.devices()[0]
+        for s in (ec.stats(), dc.stats()):
+            assert s["platform"] == d0.platform == "cpu"
+            assert s["deviceKind"] == d0.device_kind
+            assert s["devices"] == [str(d) for d in jax.devices()]
         ec.mark_ready()
         s = ec.stats()
         assert s["ready"] is True and "unreadyReason" not in s

@@ -11,7 +11,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kukeon_tpu.models import llama
-from kukeon_tpu.parallel import make_mesh, set_mesh
+from kukeon_tpu.parallel import make_mesh
 from kukeon_tpu.parallel.pipeline import (
     make_pp_train_step,
     pipeline_forward,
@@ -44,7 +44,7 @@ def test_pipeline_matches_plain_forward(model4):
 
     mesh = make_mesh(pipe=4, data=2)
     sharded = _shard_pp(params, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda p, t, pos: pipeline_forward(
                 p, cfg, t, pos, mesh=mesh, num_microbatches=4
@@ -64,7 +64,7 @@ def test_pipeline_single_stage_degenerates(model4):
 
     mesh = make_mesh(pipe=1, data=8)
     sharded = _shard_pp(params, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda p, t, pos: pipeline_forward(
                 p, cfg, t, pos, mesh=mesh, num_microbatches=2
@@ -79,7 +79,7 @@ def test_pipeline_validations(model4):
     mesh = make_mesh(pipe=4, data=2)
     tokens = jnp.zeros((4, 8), jnp.int32)
     positions = jnp.zeros((4, 8), jnp.int32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="microbatches"):
             pipeline_forward(params, cfg, tokens, positions, mesh=mesh,
                              num_microbatches=3)
@@ -98,7 +98,7 @@ def test_pp_train_step_learns(model4):
 
     cfg, _ = model4
     mesh = make_mesh(pipe=4, data=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         optimizer = make_optimizer(learning_rate=1e-2, warmup_steps=1,
                                    total_steps=10)
         state, optimizer = create_train_state(

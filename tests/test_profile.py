@@ -4,8 +4,9 @@ contract, the engine-step flight recorder (ring bounds, concurrent
 ingest/readers, GET /v1/timeline), federation staleness, and the
 `kuke timeline` / `kuke profile layers` renderers.
 
-The acceptance spine: a flooded tiny engine exposes nonzero
-kukeon_program_mfu <= 1.0 for the programs that ran, `bench.py
+The acceptance spine: a flooded tiny engine counts its program work and
+exposes kukeon_program_mfu <= 1.0 exactly where the device's published
+peak is known, `bench.py
 --profile-layers`'s per-component FLOPs sum matches the whole-model
 reference within 5%, and /v1/timeline steps cross-link to trace ids the
 tracer resolves. The whole file must stay green under KUKEON_SANITIZE=1
@@ -148,11 +149,24 @@ def test_flight_recorder_concurrent_flood():
 # --- per-program timers: the engine flood ------------------------------------
 
 
-def test_engine_flood_exposes_nonzero_mfu_gauges():
-    """Acceptance: after precompile (static costs) + a request flood
-    (measured busy time), kukeon_program_mfu and
-    kukeon_program_membw_util are nonzero and <= 1.0 for the programs
-    that ran, and the dispatch/tokens counters line up with the work."""
+class _FakeDevice:
+    """Stands in for jax.devices()[0] at scrape time: what obs/profile's
+    peak table and obs/device's HBM collector read off a device."""
+
+    id = 0
+
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+    def memory_stats(self):
+        return None
+
+
+@pytest.fixture(scope="module")
+def flooded_engine():
+    """One tiny engine after precompile (static costs) + a request flood
+    (measured busy time) — shared by every peak-table case below."""
     eng = _tiny_engine()
     eng.precompile((8,))      # cost_analysis denominators land here
     eng.warmup(8)
@@ -161,24 +175,23 @@ def test_engine_flood_exposes_nonzero_mfu_gauges():
     while not all(r.done.is_set() for r in reqs):
         eng.step()
     eng.timers.settle()
+    return eng
 
+
+def test_engine_flood_counts_program_work(flooded_engine):
+    """After precompile + a flood the dispatch/tokens/cost counters line up
+    with the work, whatever the device's peak is."""
+    eng = flooded_engine
     snap = eng.timers.snapshot()
     for program in ("prefill", "decode_chunk"):
         assert snap[program]["dispatches"] >= 1
         assert snap[program]["settled"] >= 1
         assert snap[program]["busy_s"] > 0.0
         assert snap[program]["flops"] > 0.0          # CPU reports costs
-        assert 0.0 < snap[program]["mfu"] <= 1.0
-        assert 0.0 < snap[program]["membw_util"] <= 1.0
     # Decode counted batch*k token work; prefill counted the prompt rows.
     assert snap["decode_chunk"]["tokens"] >= 2 * 12
     assert snap["prefill"]["tokens"] >= 2 * len(PROMPT)
-
     fams = _parse_expo(render(eng.registry))
-    mfu = {l["program"]: float(v)
-           for _n, l, v in fams["kukeon_program_mfu"]["samples"]}
-    for program in ("prefill", "decode_chunk"):
-        assert 0.0 < mfu[program] <= 1.0
     # Histogram of settled wall times exists per program.
     assert any(l.get("program") == "decode_chunk"
                for _n, l, _v in fams["kukeon_program_seconds"]["samples"])
@@ -188,6 +201,64 @@ def test_engine_flood_exposes_nonzero_mfu_gauges():
     for key in ("seq", "t", "wall_s", "occupancy", "slots", "tokens",
                 "programs", "traces", "queue_depth"):
         assert key in step
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", "empty"),
+    ("tpu", "TPU v5 lite", "gauges"),
+    ("tpu", "TPU v9 imaginary", "absent-with-reason"),
+])
+def test_utilization_gauges_follow_the_peak_table(
+        flooded_engine, monkeypatch, platform, kind, want):
+    """MFU / bandwidth gauges exist only where the device's published peak
+    is known: computed on a listed TPU kind, declared-but-empty on the
+    CPU (like the HBM families), and ABSENT with the reason in the HELP
+    text on a TPU that is not in the table — never from a made-up peak."""
+    eng = flooded_engine
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    text = render(eng.registry)
+    fams = _parse_expo(text)
+    snap = eng.timers.snapshot()
+    for fam in ("kukeon_program_mfu", "kukeon_program_membw_util"):
+        assert fams[fam]["type"] == "gauge"            # always declared
+        values = {l["program"]: float(v)
+                  for _n, l, v in fams[fam]["samples"]}
+        if want == "gauges":
+            for program in ("prefill", "decode_chunk"):
+                assert 0.0 < values[program] <= 1.0
+            # (rounded to 6 digits: a tiny CPU run against a v5e peak is 0.0)
+            assert 0.0 <= snap["decode_chunk"]["mfu"] <= 1.0
+        else:
+            assert values == {}
+            assert snap["decode_chunk"]["mfu"] is None
+            assert snap["decode_chunk"]["membw_util"] is None
+        help_line = next(ln for ln in text.splitlines()
+                         if ln.startswith(f"# HELP {fam} "))
+        assert ("ABSENT" in help_line) == (want == "absent-with-reason")
+        if want == "absent-with-reason":
+            assert "TPU v9 imaginary" in help_line
+
+
+def test_peak_table_is_keyed_by_the_runtime_device_kind(monkeypatch):
+    """`TPU v5 lite` — what the installed runtime calls a v5e — resolves to
+    the published 197 TFLOP/s bf16 / 819 GB/s; a substring such as "v5e"
+    is not a key."""
+    from kukeon_tpu.obs import device_peaks
+    from kukeon_tpu.obs.profile import PEAKS_BY_DEVICE_KIND
+
+    assert PEAKS_BY_DEVICE_KIND["TPU v5 lite"] == (197e12, 819e9)
+    assert "v5e" not in PEAKS_BY_DEVICE_KIND
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
+    assert device_peaks() == ((197e12, 819e9), "")
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v5e")])
+    peaks, why = device_peaks()
+    assert peaks is None and "TPU v5e" in why
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("cpu", "cpu")])
+    assert device_peaks() == (None, "")
 
 
 # --- the per-layer cost profiler ---------------------------------------------

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kukeon_tpu.ops.attention import attention_mask, attention_reference, repeat_kv
-from kukeon_tpu.parallel import make_mesh, ring_attention, set_mesh
+from kukeon_tpu.parallel import make_mesh, ring_attention
 
 
 def test_ring_matches_reference():
@@ -23,7 +23,7 @@ def test_ring_matches_reference():
     )
 
     mesh = make_mesh(seq=8)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda *a: ring_attention(
                 a[0], a[1], a[2], q_positions=a[3], kv_positions=a[3], mesh=mesh
@@ -48,7 +48,7 @@ def test_ring_seq4_with_data_axis():
     )
 
     mesh = make_mesh(data=2, seq=4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda *a: ring_attention(
                 a[0], a[1], a[2], q_positions=a[3], kv_positions=a[3], mesh=mesh

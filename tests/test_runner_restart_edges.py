@@ -189,3 +189,27 @@ def test_crash_looping_model_cell_keeps_its_chip_grant(ctl):
     assert rec.status.tpu_chips == [0, 1]
     assert devices.allocated()[0] == "default/default/default/llm"
     assert devices.allocated()[2] == "default/default/default/other"
+
+
+@pytest.mark.parametrize("grant,visible,want_ids,want_bounds", [
+    # A process that sees the host's /dev: positions in the host's list.
+    ([1], [0, 1, 2, 3], "1", "1,1,1"),
+    ([2, 3], [0, 1, 2, 3], "2,3", "1,2,1"),
+    ([0, 1, 2, 3], [0, 1, 2, 3], "0,1,2,3", "2,2,1"),
+    # A namespaced cell: /dev holds only the grant, numbered from 0.
+    ([1], None, "0", "1,1,1"),
+    ([2, 3], None, "0,1", "1,2,1"),
+    # A host whose one chip is node /dev/vfio/2 (what the sealed one-chip
+    # machine hands out): that chip is libtpu's device 0, either way.
+    ([2], [2], "0", "1,1,1"),
+    # Not a rectangle of the 2x2 grid: visibility only.
+    ([0, 1, 2], [0, 1, 2, 3], "0,1,2", None),
+])
+def test_visibility_env_names_positions_and_2x2_bounds(
+        grant, visible, want_ids, want_bounds):
+    env = TPUDeviceManager.visibility_env(grant, visible)
+    assert env["TPU_VISIBLE_DEVICES"] == want_ids
+    assert env.get("TPU_CHIPS_PER_PROCESS_BOUNDS") == want_bounds
+    assert env.get("TPU_PROCESS_BOUNDS") == ("1,1,1" if want_bounds else None)
+    assert set(env) <= {"TPU_VISIBLE_DEVICES", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                        "TPU_PROCESS_BOUNDS"}

@@ -246,22 +246,95 @@ def test_watchdog_never_probes_an_idle_engine():
         wd.join(timeout=5)
 
 
+def test_stall_clock_runs_while_a_request_is_inside_prefill_dispatch():
+    """Between leaving the queue and taking its slot a request sits in
+    prefill dispatch — where a first-use compile (or a hung device call)
+    stalls the driver while queue depth and slot occupancy both read idle.
+    The watchdog's stall clock must see that request."""
+    eng = _tiny_engine()
+    seen: list[float] = []
+    real = eng._prefill
+
+    def slow_prefill(*a, **kw):
+        time.sleep(0.05)
+        seen.append(eng.stalled_s())
+        return real(*a, **kw)
+
+    eng._prefill = slow_prefill
+    assert eng.stalled_s() == 0.0            # idle: never stalled
+    r = eng.submit(PROMPT, SamplingParams(max_new_tokens=2))
+    while not r.done.is_set():
+        eng.step()
+    assert seen and seen[0] >= 0.05
+    assert eng.stalled_s() == 0.0
+
+
 @pytest.mark.faults
-def test_probe_reports_wedged_under_fault_injection():
-    """devices.probe_tpu_runtime's fault seam: the wedged verdict (and so
-    the whole watchdog->exit->restart chain) is reachable without a chip."""
-    from kukeon_tpu.runtime.devices import probe_tpu_runtime
+@pytest.mark.parametrize("probe_name", ["probe_tpu_runtime",
+                                        "probe_tpu_in_process"])
+def test_probe_reports_wedged_under_fault_injection(probe_name):
+    """The devices.probe_wedged fault seam, on both probes (the subprocess
+    form `kuke doctor` uses and the in-process form the cell's watchdog
+    uses): the wedged verdict (and so the whole watchdog->exit->restart
+    chain) is reachable without a chip."""
+    from kukeon_tpu.runtime import devices
 
     os.environ[faults.ENV] = "devices.probe_wedged:1"
-    status, detail = probe_tpu_runtime(timeout_s=5)
+    status, detail = getattr(devices, probe_name)(timeout_s=5)
     assert status == "wedged"
     assert "fault-injected" in detail
+
+
+def test_in_process_probe_answers_from_this_process(monkeypatch):
+    """The cell holds the chip, so the watchdog's question goes to THIS
+    process's own runtime client — a tiny transfer from a probe-owned
+    thread — and never to a second process (which could not open an
+    attached chip its parent holds)."""
+    import subprocess
+
+    from kukeon_tpu.runtime.devices import probe_tpu_in_process
+
+    def no_child(*a, **kw):
+        raise AssertionError("the in-process probe started a process")
+
+    monkeypatch.setattr(subprocess, "run", no_child)
+    monkeypatch.setattr(subprocess, "Popen", no_child)
+    status, detail = probe_tpu_in_process(timeout_s=30)
+    assert status == "ok", detail
+    assert "backend=cpu" in detail
+
+
+@pytest.mark.parametrize("behaviour,want", [("hangs", "wedged"),
+                                            ("raises", "unavailable")])
+def test_in_process_probe_verdicts(monkeypatch, behaviour, want):
+    """A transfer that never returns is the wedge (bounded wait, verdict
+    'wedged'); one that raises is 'unavailable' — the engine loop's own
+    error path owns that failure, the watchdog re-arms."""
+    from kukeon_tpu.runtime.devices import probe_tpu_in_process
+
+    release = threading.Event()
+
+    def device_put(x, *a, **kw):
+        if behaviour == "hangs":
+            release.wait(timeout=30)
+            return x
+        raise RuntimeError("device went away")
+
+    monkeypatch.setattr(jax, "device_put", device_put)
+    try:
+        t0 = time.monotonic()
+        status, detail = probe_tpu_in_process(timeout_s=0.2)
+        assert status == want, detail
+        assert time.monotonic() - t0 < 10        # the wait is bounded
+        assert ("did not finish" in detail) == (want == "wedged")
+    finally:
+        release.set()
 
 
 @pytest.mark.faults
 def test_watchdog_default_probe_uses_devices_seam():
     """EngineWatchdog with no probe override consults the real
-    probe_tpu_runtime — wired shut by the fault seam, no subprocess."""
+    probe_tpu_in_process — wired shut by the fault seam."""
     eng = _StalledEngine()
     eng.last_progress -= 10
     hits: list[str] = []
@@ -300,7 +373,7 @@ def test_wedged_cell_exits_nonzero_end_to_end(tmp_path):
         "KUKEON_WATCHDOG_PROBE_TIMEOUT_S": "5",
         "KUKEON_FAULTS": "devices.probe_wedged:1",
         # A fresh compilation cache: the stall under test IS the compile.
-        "KUKEON_JAX_CACHE_DIR": str(tmp_path / "jax-cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax-cache"),
     })
     log = open(tmp_path / "cell.log", "wb")
     proc = subprocess.Popen(
@@ -345,6 +418,65 @@ def test_wedged_cell_exits_nonzero_end_to_end(tmp_path):
         if proc.poll() is None:
             proc.kill()
         log.close()
+
+
+def test_compile_stall_past_watchdog_budget_is_not_killed(tmp_path):
+    """A real cell process with a tiny stall budget and NO fault armed: its
+    first request sits in jit compilation for seconds — a stall well past
+    the budget on a device this process holds. The watchdog probes
+    in-process, gets 'ok', re-arms, and the request is answered: slow is
+    not wedged."""
+    import socket as _socket
+    import subprocess
+    import sys
+    import urllib.request
+
+    s = _socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    env = dict(os.environ)
+    env.update({
+        "JAX_PLATFORMS": "cpu",
+        "KUKEON_WATCHDOG_S": "0.3",
+        # A fresh compilation cache: the stall under test IS the compile.
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax-cache"),
+    })
+    with open(tmp_path / "cell.log", "wb") as log:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "kukeon_tpu.runtime.serving_cell",
+             "--model", "tiny", "--port", str(port), "--no-warmup",
+             "--max-seq-len", "64", "--num-slots", "2"],
+            env=env, stdout=log, stderr=log,
+        )
+    base = f"http://127.0.0.1:{port}"
+    try:
+        deadline = time.monotonic() + 240
+        while True:
+            try:
+                urllib.request.urlopen(f"{base}/healthz", timeout=2).read()
+                break
+            except Exception:  # noqa: BLE001 — still booting
+                assert proc.poll() is None, "cell died before serving"
+                assert time.monotonic() < deadline, "cell never came up"
+                time.sleep(0.2)
+        out = json.loads(urllib.request.urlopen(urllib.request.Request(
+            f"{base}/v1/generate",
+            data=json.dumps({"prompt": "hi", "maxNewTokens": 8}).encode(),
+            headers={"Content-Type": "application/json"}),
+            timeout=240).read())
+        assert out["numTokens"] == 8
+        assert proc.poll() is None, "the watchdog killed a compiling cell"
+        text = urllib.request.urlopen(f"{base}/metrics",
+                                      timeout=10).read().decode()
+        ok = [ln for ln in text.splitlines() if ln.startswith(
+            'kukeon_watchdog_probes_total{verdict="ok"}')]
+        assert ok and float(ok[0].split()[-1]) >= 1, \
+            "the compile stall never reached the in-process probe"
+        assert "kukeon_watchdog_trips_total 0" in text
+    finally:
+        proc.kill()
+        proc.wait(timeout=30)
 
 
 # --- HTTP lifecycle ---------------------------------------------------------

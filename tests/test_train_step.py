@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from kukeon_tpu.models import llama
-from kukeon_tpu.parallel import make_mesh, set_mesh
+from kukeon_tpu.parallel import make_mesh
 from kukeon_tpu.training import create_train_state, make_train_step
 from kukeon_tpu.training.train_step import make_optimizer
 
@@ -29,7 +29,7 @@ def _fake_batch(key, cfg, B, S):
 def test_train_step_loss_decreases(mesh_kw):
     cfg = llama.llama_tiny()
     mesh = make_mesh(**mesh_kw)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         optimizer = make_optimizer(learning_rate=1e-2, warmup_steps=1, total_steps=100)
         state, optimizer = create_train_state(cfg, mesh, jax.random.key(0), optimizer)
         train_step, batch_sharding = make_train_step(cfg, mesh, optimizer)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from kukeon_tpu.models import llama
-from kukeon_tpu.parallel import make_mesh, set_mesh
+from kukeon_tpu.parallel import make_mesh
 from kukeon_tpu.training import (
     create_train_state,
     latest_step,
@@ -31,7 +31,7 @@ def test_save_restore_resume_identical(tmp_path):
     cfg = llama.llama_tiny()
     mesh = make_mesh(tensor=2, fsdp=2, data=2)
     root = str(tmp_path / "ckpts")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         opt = make_optimizer(warmup_steps=1, total_steps=10)
         state, opt = create_train_state(cfg, mesh, jax.random.key(0), opt)
         step_fn, bsh = make_train_step(cfg, mesh, opt)
@@ -45,7 +45,7 @@ def test_save_restore_resume_identical(tmp_path):
         ref_state, ref_loss = step_fn(state, tokens, targets, mask)
 
     # Resume in a "fresh job": new state tree on the same mesh, restored.
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fresh, opt2 = create_train_state(cfg, mesh, jax.random.key(9), opt)
         restored = restore_checkpoint(root, fresh)
         assert int(restored.step) == 1
@@ -66,14 +66,14 @@ def test_restore_onto_different_mesh(tmp_path):
     cfg = llama.llama_tiny()
     root = str(tmp_path / "ckpts")
     mesh_a = make_mesh(tensor=2, fsdp=2, data=2)
-    with set_mesh(mesh_a):
+    with jax.set_mesh(mesh_a):
         opt = make_optimizer(warmup_steps=1, total_steps=10)
         state, opt = create_train_state(cfg, mesh_a, jax.random.key(0), opt)
         save_checkpoint(root, state)
         want = [np.asarray(x) for x in jax.tree.leaves(state.params)]
 
     mesh_b = make_mesh(tensor=4, data=2)
-    with set_mesh(mesh_b):
+    with jax.set_mesh(mesh_b):
         fresh, _ = create_train_state(cfg, mesh_b, jax.random.key(7), opt)
         restored = restore_checkpoint(root, fresh)
         got = [np.asarray(x) for x in jax.tree.leaves(restored.params)]
@@ -102,7 +102,7 @@ def test_interrupted_save_preserves_previous_checkpoint(tmp_path):
     cfg = llama.llama_tiny()
     mesh = make_mesh(tensor=2, data=4)
     root = str(tmp_path / "ckpts")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         opt = make_optimizer(warmup_steps=1, total_steps=10)
         state, opt = create_train_state(cfg, mesh, jax.random.key(0), opt)
         save_checkpoint(root, state)                    # step 0: the survivor

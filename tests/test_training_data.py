@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from kukeon_tpu.models import llama
-from kukeon_tpu.parallel import make_mesh, set_mesh
+from kukeon_tpu.parallel import make_mesh
 from kukeon_tpu.training import (
     TokenDataset,
     batches,
@@ -83,7 +83,7 @@ def test_train_loop_with_resume_on_real_data(dataset, tmp_path):
             losses.append(float(loss))
         return state, losses
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         opt = make_optimizer(warmup_steps=1, total_steps=10)
         state, opt = create_train_state(cfg, mesh, jax.random.key(0), opt)
         step_fn, bsh = make_train_step(cfg, mesh, opt)
@@ -92,7 +92,7 @@ def test_train_loop_with_resume_on_real_data(dataset, tmp_path):
         _, l23_cont = run(2, state, 2, step_fn, bsh)
 
     # "Fresh job": new process state, restore, continue at step 2.
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fresh, opt2 = create_train_state(cfg, mesh, jax.random.key(5), opt)
         restored = restore_checkpoint(root, fresh)
         step_fn2, bsh2 = make_train_step(cfg, mesh, opt2)

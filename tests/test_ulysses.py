@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from kukeon_tpu.ops.attention import attention_mask, attention_reference, repeat_kv
-from kukeon_tpu.parallel import make_mesh, set_mesh, ulysses_attention
+from kukeon_tpu.parallel import make_mesh, ulysses_attention
 
 
 def _ref(q, k, v, positions):
@@ -26,7 +26,7 @@ def test_ulysses_matches_reference():
     ref = _ref(q, k, v, positions)
 
     mesh = make_mesh(seq=4, data=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda *a: ulysses_attention(
                 a[0], a[1], a[2], q_positions=a[3], kv_positions=a[3], mesh=mesh
@@ -47,7 +47,7 @@ def test_ulysses_composes_with_tensor_axis():
     ref = _ref(q, k, v, positions)
 
     mesh = make_mesh(seq=2, tensor=2, data=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda *a: ulysses_attention(
                 a[0], a[1], a[2], q_positions=a[3], kv_positions=a[3], mesh=mesh
@@ -65,7 +65,7 @@ def test_ulysses_head_divisibility_rejected():
     v = jnp.zeros((B, S, NKV, D), jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     mesh = make_mesh(seq=4, data=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="ring"):
             jax.jit(
                 lambda *a: ulysses_attention(
@@ -88,7 +88,7 @@ def test_train_step_with_ulysses_attention():
     losses = {}
     for impl, seq in (("ulysses", 2), ("ring", 2), ("auto", 1)):
         mesh = make_mesh(seq=seq, data=8 // seq // 2, tensor=2)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             opt = make_optimizer(warmup_steps=1, total_steps=10)
             state, opt = create_train_state(cfg, mesh, jax.random.key(0), opt)
             # use_ring_attention=False so we control attn_impl directly

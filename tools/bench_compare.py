@@ -14,9 +14,9 @@ latency, cold start, or HBM headroom:
 Exit codes: 0 = no regression past the threshold (or fewer than two
 comparable artifacts — early rounds logged raw run transcripts, not
 artifacts, and those are skipped, not errors), 1 = regression, 2 = usage.
-Wired into tools/check.sh as an informational step: a CPU-degraded round
-on a wedged TPU host (see ROADMAP "Perf/verify trajectory") is a fact to
-surface, not a reason to block unrelated work.
+Wired into tools/check.sh as an informational step. No BENCH_r*.json is
+committed (the record of chip runs is PERF_LEDGER.jsonl and PERF.md); on an
+empty directory this exits 0 with nothing to do.
 
 Zero dependencies on bench.py (which imports jax): the schema-upgrade
 shim here mirrors bench.read_artifact and is pinned against it by

@@ -44,8 +44,7 @@ EOF
 echo "check.sh: bench trajectory diff (informational)"
 python tools/bench_compare.py || \
     echo "check.sh: bench_compare reports a regression (informational —" \
-         "inspect the newest BENCH_r*.json; a CPU-degraded round on a" \
-         "wedged TPU host is a fact, not a gate)"
+         "inspect the newest BENCH_r*.json)"
 
 if python -c "import mypy" >/dev/null 2>&1; then
     echo "check.sh: mypy (strict modules)"
