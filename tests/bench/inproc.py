@@ -1,0 +1,36 @@
+"""The cell in the test's own process: what run.py's child does, without the
+look for a chip. Only tests use it."""
+
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import cell_main  # noqa: E402
+
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+
+
+class InProcessCell:
+    def __init__(self, spec: dict, seed: int):
+        self.host = cell_main.CellHost(spec["config"], seed, spec["pkg_dir"])
+        self.warm = min(spec["traffic"]["warmup"]["prefill"])
+
+    def start(self) -> dict:
+        import jax
+
+        d = jax.devices()[0]
+        info = self.host.boot(self.warm)
+        self.engine = self.host.cell.engine     # for a test to read counters
+        return {"device": {"platform": d.platform, "kind": d.device_kind,
+                           "count": 1}, **info}
+
+    def command(self, msg: dict) -> dict:
+        return json.loads(json.dumps(self.host.command(msg)))
+
+    def close(self) -> None:
+        if self.host.cell is not None:
+            self.host.stop_and_free()
