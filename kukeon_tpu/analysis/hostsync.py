@@ -46,8 +46,9 @@ SEAM_METHODS = ("_fetch", "_upload")
 # dispatches real chunks): the scope where a stray transfer costs a link
 # round trip per request or per chunk.
 HOT_PATH_METHODS = frozenset({
-    "submit", "step", "warmup", "generate", "_loop",
-    "_dispatch_prefill", "_dispatch_prefill_paged", "_dispatch_decode_chunk",
+    "submit", "step", "_step", "warmup", "generate", "_loop",
+    "_dispatch_prefill", "_dispatch_prefill_dense", "_dispatch_prefill_paged",
+    "_dispatch_decode_chunk",
     "_flush_inflight", "_emit", "_release_slot", "_preempt_slot",
     "_sampling_dev_arrays", "_bt_dev_array", "_ensure_decode_pages",
     "_prefix_lookup", "_prefix_store", "_prefix_lookup_paged",

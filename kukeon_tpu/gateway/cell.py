@@ -753,27 +753,27 @@ def make_gateway_handler(gw: GatewayCell):
                 msg = {"error": "deadline exceeded while queued at the "
                                 "gateway (all replicas shedding)",
                        "timedOut": True, "numTokens": 0}
+                gw.finish_span(span, "timeout")
                 if stream:
                     self._send_raw(200, (json.dumps(msg) + "\n").encode(),
                                    "application/x-ndjson")
                 else:
                     self._send(504, msg)
-                gw.finish_span(span, "timeout")
                 return
             if got[0] == "inline":
                 # The gateway answered from the export header (terminal
                 # first token) or passes a 400 through.
                 _tag, status, payload, ctype = got
-                self._send_raw(status, payload or b"{}", ctype)
                 gw.finish_span(span, "ok" if status < 400 else "error",
                                status=status)
+                self._send_raw(status, payload or b"{}", ctype)
                 return
             if got[0] == "shed":
                 _tag, status, payload, retry_after = got
                 secs = float(retry_after or GATEWAY_RETRY_AFTER_S)
+                gw.finish_span(span, "shed", status=status)
                 self._send_raw(status, payload or b"{}", "application/json",
                                {"Retry-After": str(max(1, math.ceil(secs)))})
-                gw.finish_span(span, "shed", status=status)
                 return
             _tag, rep, conn, resp = got
             try:
@@ -785,21 +785,27 @@ def make_gateway_handler(gw: GatewayCell):
                     ra = resp.getheader("Retry-After")
                     if ra:
                         headers["Retry-After"] = ra
-                    self._send_raw(
-                        resp.status, payload,
-                        resp.getheader("Content-Type") or "application/json",
-                        headers)
                     gw._m_requests.inc(
                         replica=rep.name,
                         outcome="ok" if resp.status < 400 else
                         f"status_{resp.status}")
+                    # The span is in the ring before the response's last
+                    # byte is out: a client that reads /v1/trace the moment
+                    # its answer arrives finds it (its outcome is the
+                    # replica's; a client gone by now changes nothing the
+                    # replica did).
                     gw.finish_span(
                         span, "ok" if resp.status < 400 else "error",
                         replica=rep.name, status=resp.status)
+                    self._send_raw(
+                        resp.status, payload,
+                        resp.getheader("Content-Type") or "application/json",
+                        headers)
             except OSError:
-                # Client went away; nothing to tell it, but the span still
-                # records the outcome (first finish wins — a stream error
-                # already finished it in-band).
+                # The replica's answer could not be read, or the client
+                # went away mid-stream; nothing to tell it, but the span
+                # still records the outcome (first finish wins — a stream
+                # error already finished it in-band).
                 gw.finish_span(span, "error", replica=rep.name,
                                detail="client disconnected")
             finally:

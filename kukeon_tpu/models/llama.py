@@ -193,6 +193,7 @@ def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return q, jnp.squeeze(s, axis=-1)
 
 
+@jax.named_scope("kv_insert")
 def _cache_insert(cache_kv: jnp.ndarray, new_kv: jnp.ndarray, offsets: jnp.ndarray) -> jnp.ndarray:
     """Insert [B, S, ...] at per-batch ``offsets`` into [B, S_max, ...].
 
@@ -365,6 +366,7 @@ def _mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
     return h @ w
 
 
+@jax.named_scope("embed")
 def _embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
     e = params["embed"]
     if _is_q(e):
@@ -373,6 +375,7 @@ def _embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
     return jnp.take(e, tokens, axis=0).astype(dtype)
 
 
+@jax.named_scope("lm_head")
 def _logits(params: Params, c: LlamaConfig, x: jnp.ndarray,
             pallas: bool = False) -> jnp.ndarray:
     if c.tie_embeddings:
@@ -393,6 +396,43 @@ def _logits(params: Params, c: LlamaConfig, x: jnp.ndarray,
 
 
 # --- Forward -----------------------------------------------------------------
+#
+# The pieces of one decoder block, each under a jax.named_scope: the scope
+# is metadata on the operations (the compiled code is the same), and it is
+# what a device trace can name a fused operation by after a refactor.
+
+def _qkv(x, w: dict, c: LlamaConfig, positions, pallas: bool = False):
+    """x [B, S, H] -> rotated q [B, S, NH, D] and k, v [B, S, KV, D]."""
+    B, S = x.shape[:2]
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
+    with jax.named_scope("qkv"):
+        q = _mm(h, w["wq"], pallas).reshape(B, S, c.num_heads, c.head_dim)
+        k = _mm(h, w["wk"], pallas).reshape(B, S, c.num_kv_heads, c.head_dim)
+        v = _mm(h, w["wv"], pallas).reshape(B, S, c.num_kv_heads, c.head_dim)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, positions, c.rope_theta)
+        k = apply_rope(k, positions, c.rope_theta)
+    return q, k, v
+
+
+def _wo(x, attn, w: dict, c: LlamaConfig, pallas: bool = False):
+    """The attention block's output projection and residual."""
+    B, S = x.shape[:2]
+    with jax.named_scope("wo"):
+        return x + _mm(attn.reshape(B, S, c.q_dim), w["wo"], pallas)
+
+
+def _mlp(x, w: dict, c: LlamaConfig, pallas: bool = False):
+    """The SwiGLU block and its residual."""
+    with jax.named_scope("mlp_norm"):
+        h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(
+            _mm(h, w["w_gate"], pallas).astype(jnp.float32)).astype(c.dtype)
+        up = _mm(h, w["w_up"], pallas)
+        return x + _mm(gate * up, w["w_down"], pallas)
+
 
 def transformer_block(
     x: jnp.ndarray,
@@ -406,21 +446,11 @@ def transformer_block(
     standalone for the pipeline-parallel path (parallel/pipeline.py), whose
     per-stage scan runs blocks outside forward's whole-model scan."""
     c = cfg
-    B, S = x.shape[:2]
-    h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-    q = _mm(h, w["wq"]).reshape(B, S, c.num_heads, c.head_dim)
-    k = _mm(h, w["wk"]).reshape(B, S, c.num_kv_heads, c.head_dim)
-    v = _mm(h, w["wv"]).reshape(B, S, c.num_kv_heads, c.head_dim)
-    q = apply_rope(q, positions, c.rope_theta)
-    k = apply_rope(k, positions, c.rope_theta)
+    q, k, v = _qkv(x, w, c, positions)
     attn = gqa_attention(
         q, k, v, q_positions=positions, kv_positions=positions, impl=attn_impl
     )
-    x = x + _mm(attn.reshape(B, S, c.q_dim), w["wo"])
-    h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
-    gate = jax.nn.silu(_mm(h, w["w_gate"]).astype(jnp.float32)).astype(c.dtype)
-    up = _mm(h, w["w_up"])
-    return x + _mm(gate * up, w["w_down"])
+    return _mlp(_wo(x, attn, w, c), w, c)
 
 
 def forward(
@@ -466,13 +496,7 @@ def forward(
 
     def layer_step(x, layer):
         w, layer_cache = layer
-        # Attention block.
-        h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-        q = _mm(h, w["wq"]).reshape(B, S, c.num_heads, c.head_dim)
-        k = _mm(h, w["wk"]).reshape(B, S, c.num_kv_heads, c.head_dim)
-        v = _mm(h, w["wv"]).reshape(B, S, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, positions, c.rope_theta)
-        k = apply_rope(k, positions, c.rope_theta)
+        q, k, v = _qkv(x, w, c, positions)
 
         if layer_cache is not None:
             ck, cv, cks, cvs = layer_cache
@@ -517,15 +541,7 @@ def forward(
             )
             new_layer_cache = None
 
-        attn = _mm(attn.reshape(B, S, c.q_dim), w["wo"])
-        x = x + attn
-
-        # MLP block (SwiGLU).
-        h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
-        gate = jax.nn.silu(_mm(h, w["w_gate"]).astype(jnp.float32)).astype(c.dtype)
-        up = _mm(h, w["w_up"])
-        x = x + _mm(gate * up, w["w_down"])
-        return x, new_layer_cache
+        return _mlp(_wo(x, attn, w, c), w, c), new_layer_cache
 
     layer_ws = params["layers"]
     if cache is not None:
@@ -574,22 +590,10 @@ def _decode_forward(
 
     def layer_step(x, layer):
         w, ck, cv, cks, cvs = layer
-        h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-        q = _mm(h, w["wq"], pl8).reshape(B, 1, c.num_heads, c.head_dim)
-        k = _mm(h, w["wk"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        v = _mm(h, w["wv"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, positions, c.rope_theta)
-        k = apply_rope(k, positions, c.rope_theta)
-
+        q, k, v = _qkv(x, w, c, positions, pl8)
         attn = decode_gqa_attention(q, k, v, ck, cv, offsets,
                                     k_scale=cks, v_scale=cvs)
-        x = x + _mm(attn.reshape(B, 1, c.q_dim), w["wo"], pl8)
-
-        h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
-        gate = jax.nn.silu(_mm(h, w["w_gate"], pl8).astype(jnp.float32)).astype(c.dtype)
-        up = _mm(h, w["w_up"], pl8)
-        x = x + _mm(gate * up, w["w_down"], pl8)
-        return x, (k, v)
+        return _mlp(_wo(x, attn, w, c, pl8), w, c, pl8), (k, v)
 
     x, (new_k, new_v) = jax.lax.scan(
         lambda carry, layer: layer_step(carry, layer),
@@ -598,20 +602,21 @@ def _decode_forward(
     )
     # new_k/new_v: [L, B, 1, KV, D] — one in-place slice write per slot
     # covering every layer at once (layers share the slot's offset).
-    k_upd, v_upd = cache.k, cache.v
-    ks_upd, vs_upd = cache.k_scale, cache.v_scale
-    if cache.quantized:
-        new_k, new_ks = quantize_kv(new_k)       # [L, B, 1, KV, D] / [L, B, 1, KV]
-        new_v, new_vs = quantize_kv(new_v)
-    for b in range(B):
-        start = (0, b, offsets[b], 0, 0)
-        k_upd = jax.lax.dynamic_update_slice(k_upd, new_k[:, b : b + 1], start)
-        v_upd = jax.lax.dynamic_update_slice(v_upd, new_v[:, b : b + 1], start)
+    with jax.named_scope("kv_insert"):
+        k_upd, v_upd = cache.k, cache.v
+        ks_upd, vs_upd = cache.k_scale, cache.v_scale
         if cache.quantized:
-            ks_upd = jax.lax.dynamic_update_slice(
-                ks_upd, new_ks[:, b : b + 1], start[:-1])
-            vs_upd = jax.lax.dynamic_update_slice(
-                vs_upd, new_vs[:, b : b + 1], start[:-1])
+            new_k, new_ks = quantize_kv(new_k)       # [L, B, 1, KV, D] / [L, B, 1, KV]
+            new_v, new_vs = quantize_kv(new_v)
+        for b in range(B):
+            start = (0, b, offsets[b], 0, 0)
+            k_upd = jax.lax.dynamic_update_slice(k_upd, new_k[:, b : b + 1], start)
+            v_upd = jax.lax.dynamic_update_slice(v_upd, new_v[:, b : b + 1], start)
+            if cache.quantized:
+                ks_upd = jax.lax.dynamic_update_slice(
+                    ks_upd, new_ks[:, b : b + 1], start[:-1])
+                vs_upd = jax.lax.dynamic_update_slice(
+                    vs_upd, new_vs[:, b : b + 1], start[:-1])
     new_cache = KVCache(k=k_upd, v=v_upd, lengths=cache.lengths + 1,
                         k_scale=ks_upd, v_scale=vs_upd)
 

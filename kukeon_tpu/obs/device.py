@@ -189,10 +189,14 @@ class ProfileSpool:
                 "On-demand profiler captures by outcome.",
                 labels=("outcome",))
 
-    def start(self, duration_ms: float) -> dict:
+    def start(self, duration_ms: float, python_tracer: bool = False) -> dict:
         """Begin a capture; returns its record immediately (the trace runs
         in the background for ``duration_ms``). Raises ProfileBusy while a
-        capture is in flight and ValueError on a bad duration."""
+        capture is in flight and ValueError on a bad duration.
+
+        The host side of a capture is the engine loop's own spans
+        (obs/spans.py). ``python_tracer`` adds the Python frames, which slow
+        the host they time: hundreds of thousands of events in 3 s."""
         from kukeon_tpu import faults
 
         duration_ms = float(duration_ms)
@@ -207,6 +211,7 @@ class ProfileSpool:
             "state": "running",
             "startedAt": time.time(),
             "durationMs": duration_ms,
+            "pythonTracer": python_tracer,
         }
         with self._lock:
             if self._active is not None:
@@ -222,7 +227,9 @@ class ProfileSpool:
             import jax
 
             os.makedirs(rec["path"], exist_ok=True)
-            jax.profiler.start_trace(rec["path"])
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = int(rec["pythonTracer"])
+            jax.profiler.start_trace(rec["path"], profiler_options=options)
             try:
                 time.sleep(rec["durationMs"] / 1000.0)
             finally:

@@ -108,6 +108,7 @@ def attention_grouped(
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def decode_gqa_attention(
     q: jnp.ndarray,
     k_new: jnp.ndarray,
@@ -181,6 +182,7 @@ def decode_gqa_attention(
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def gqa_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -13,15 +13,19 @@ the mesh over whatever devices JAX exposes and serves:
   GET  /v1/stats     -> slots/queue/throughput counters (a JSON view over
                         the same obs registry /metrics scrapes)
   GET  /metrics      -> Prometheus text exposition: engine latency
-                        histograms (TTFT/inter-token/e2e/queue-wait/
-                        prefill-by-bucket), shed/timeout/watchdog/fault
-                        counters, slot/queue gauges (kukeon_tpu/obs)
+                        histograms (TTFT/inter-token/e2e/queue-wait),
+                        the loop's seconds by phase, prefill tokens by
+                        kind, shed/timeout/watchdog/fault counters,
+                        slot/queue gauges (kukeon_tpu/obs)
   GET  /v1/trace?n=K -> newest K per-request trace spans (lifecycle events
                         + per-phase durations summing to e2e);
                         ?request_id=N pulls one request's span exactly
   POST /v1/profile   -> {"durationMs": N} starts a single-flight
                         jax.profiler capture into KUKEON_PROFILE_DIR
-                        (409 while one runs); GET /v1/profile lists captures
+                        (409 while one runs); the host side is the engine
+                        loop's own spans (obs/spans.py), Python frames only
+                        with "pythonTracer": true; GET /v1/profile lists
+                        captures
   POST /v1/generate  -> {"promptTokens": [...] | "prompt": "text",
                          "maxNewTokens": N, "temperature": T,
                          "deadlineS": D, ...}
@@ -68,6 +72,7 @@ from kukeon_tpu.obs import (
     device_memory_collector,
     expo,
 )
+from kukeon_tpu.obs import spans
 from kukeon_tpu.obs import trace as obs_trace
 from kukeon_tpu.serving.engine import DeadlineExceeded, RejectedError
 
@@ -768,13 +773,19 @@ class ServingCell(LifecycleMixin):
         inside the engine."""
         import queue as _q
 
-        prompt, sp, stops, prefix_id, deadline_s = self._parse_generate(req)
-        events: _q.Queue = _q.Queue()
-        t0 = time.monotonic()
-        r = self.engine.submit(prompt, sp,
-                               emit=lambda tok, done: events.put((tok, done)),
-                               prefix_id=prefix_id, deadline_s=deadline_s,
-                               trace_ctx=trace_ctx)
+        # Everything above the engine, on the profiler's clock: the body is
+        # parsed, the request is not yet queued.
+        with spans.span("cell.generate") as span:
+            prompt, sp, stops, prefix_id, deadline_s = \
+                self._parse_generate(req)
+            events: _q.Queue = _q.Queue()
+            t0 = time.monotonic()
+            r = self.engine.submit(
+                prompt, sp, emit=lambda tok, done: events.put((tok, done)),
+                prefix_id=prefix_id, deadline_s=deadline_s,
+                trace_ctx=trace_ctx)
+            if r.trace is not None:
+                span.set(request=r.trace.trace_id)
         yield from self._stream_events(r, events, stops, tokens=[],
                                        emitted="", t0=t0)
 
@@ -1468,7 +1479,9 @@ def make_handler(cell: ServingCell):
                             decode_batch=req.get("decodeBatch"))
                         self._send(200, prof)
                         return
-                    rec = profiler.start(float(req.get("durationMs", 1000)))
+                    rec = profiler.start(
+                        float(req.get("durationMs", 1000)),
+                        python_tracer=bool(req.get("pythonTracer", False)))
                     self._send(200, {"started": True, "capture": rec})
                 except ProfileBusy as e:
                     # Single-flight: one capture at a time (409 Conflict).

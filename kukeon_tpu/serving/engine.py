@@ -79,6 +79,7 @@ from kukeon_tpu.obs import (
     faults_collector,
     op_impl_collector,
 )
+from kukeon_tpu.obs.spans import LoopSpans
 from kukeon_tpu.parallel import sharding as shd
 from kukeon_tpu.serving.sampling import (
     SamplingParams,
@@ -222,6 +223,12 @@ class _InflightChunk:
     tokens: Any                              # device array [B, K]
     k: int
     slots: list[tuple[int, "Request"]]       # (slot, request) at dispatch time
+
+
+def _request_tag(req: "Request") -> str:
+    """A request's identifier on its spans: its trace id, the one /v1/trace
+    and /v1/timeline use; the engine's own id where it carries no trace."""
+    return req.trace.trace_id if req.trace is not None else str(req.id)
 
 
 def bucket_length(n: int, buckets: tuple[int, ...] = PREFILL_BUCKETS) -> int:
@@ -549,10 +556,18 @@ class ServingEngine:
         self._m_queue_wait = reg.histogram(
             "kukeon_engine_queue_wait_seconds",
             "Submit -> dequeued-for-a-slot wait.")
-        self._m_prefill = reg.histogram(
-            "kukeon_engine_prefill_seconds",
-            "Prefill dispatch latency by padded prompt bucket.",
-            labels=("bucket",))
+        self._m_prefill_tokens = reg.counter(
+            "kukeon_engine_prefill_tokens_total",
+            "Prompt tokens by prefill dispatch: real = run through the "
+            "model (the tail on a prefix hit), padded = the bucket they ran "
+            "in, cached = rows taken from the prefix store.",
+            labels=("kind",))
+        self._m_steps = reg.counter(
+            "kukeon_engine_steps_total",
+            "Engine-loop steps that did work.")
+        # The loop's spans (on the profiler's clock while /v1/profile
+        # captures) and its wall time by phase (obs/spans.py).
+        self.spans = LoopSpans(reg)
         self._m_ttft = reg.histogram(
             "kukeon_engine_ttft_seconds",
             "Submit -> first token emitted (time to first token).")
@@ -801,6 +816,7 @@ class ServingEngine:
                 out_v = jnp.pad(out_v, pad)
             return first, out_k, out_v
 
+        @jax.named_scope("kv_insert")
         def insert(state: DecodeState, kv_k, kv_v, length, slot, token):
             """Copy a prefill's KV block into ``slot`` and activate it.
 
@@ -888,6 +904,7 @@ class ServingEngine:
                      * vs[..., None].astype(jnp.float32)).astype(cfg.dtype)
             return k, v
 
+        @jax.named_scope("kv_insert")
         def insert_paged(state: DecodeState, kv_k, kv_v, length, page_ids,
                          slot, token):
             """Scatter a prefill's [L, 1, Sb, KV, D] block into the pool by
@@ -1525,7 +1542,8 @@ class ServingEngine:
                     # it — a lost notify only costs one timeout).
                     with self._work:
                         if self._running and self._idle_locked():
-                            self._work.wait(timeout=0.05)
+                            with self.spans.span("engine.idle_wait"):
+                                self._work.wait(timeout=0.05)
             except Exception as e:  # noqa: BLE001 — the engine thread must not die silently
                 import traceback
 
@@ -1765,6 +1783,10 @@ class ServingEngine:
 
         Returns True if any work was done.
         """
+        with self.spans.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         self._ensure_loaded()
         # Flight-recorder baselines: sync_stats / timer deltas over this
         # step become the step record's transfer counts and per-program
@@ -1779,63 +1801,68 @@ class ServingEngine:
         prefills = []
         exports = []
         free = list(self._free_slots())
-        while free:
-            req, resumed, swept = self._pop_waiting()
-            did_work = did_work or swept
-            if req is None:
-                break
-            if not resumed:
-                with self._lock:
-                    self._pending_n -= 1   # leaving the queue for a slot
-                self._m_queue_wait.observe(
-                    time.monotonic() - req.submitted_at)
-            if req.trace is not None:
-                req.trace.event("admitted")
-            if req.export:
-                # Prefill-only (KV handoff export): no slot, no pages —
-                # the loop's free list is untouched, so a prefill cell
-                # drains export bursts without decode-slot contention.
+        with self.spans.span("engine.admit", free=len(free),
+                             queued=self._pending_n + len(self._resume)):
+            while free:
+                req, resumed, swept = self._pop_waiting()
+                did_work = did_work or swept
+                if req is None:
+                    break
+                if not resumed:
+                    with self._lock:
+                        self._pending_n -= 1   # leaving the queue for a slot
+                    self._m_queue_wait.observe(
+                        time.monotonic() - req.submitted_at)
+                if req.trace is not None:
+                    req.trace.event("admitted")
+                if req.export:
+                    # Prefill-only (KV handoff export): no slot, no pages —
+                    # the loop's free list is untouched, so a prefill cell
+                    # drains export bursts without decode-slot contention.
+                    try:
+                        with self._prefill_span(req, -1) as span:
+                            exports.append(
+                                self._dispatch_prefill_export(req, span))
+                    except Exception as e:
+                        self._fail_request(req, e)
+                        raise
+                    did_work = True
+                    continue
+                slot = free.pop(0)
                 try:
-                    exports.append(self._dispatch_prefill_export(req))
+                    got = self._dispatch_prefill(req, slot)
+                    # Import seats emit host-side (the first token came with
+                    # the block) and return None — nothing to fetch later.
+                    if got is not None:
+                        prefills.append(got)
+                except PagePoolExhausted as e:
+                    # No pages for this prompt right now. If anything is in
+                    # flight, pages WILL free (requests finish, preemption,
+                    # prefix eviction) — park the request at the FRONT so it
+                    # retries next step ahead of everyone. If the engine is
+                    # otherwise idle, nothing will ever free pages: shed with
+                    # RejectedError + Retry-After rather than deadlocking.
+                    req.requeued = True
+                    if (self._active_requests() or prefills
+                            or self._inflight is not None):
+                        self._resume.appendleft(req)
+                    else:
+                        self._shed_kv_exhausted(req, e)
+                    did_work = True
+                    break
                 except Exception as e:
+                    # The request is out of the queue but not yet slotted: fail
+                    # it HERE or nobody ever wakes its waiter (_fail_all only
+                    # sees slots and the queue).
                     self._fail_request(req, e)
                     raise
                 did_work = True
-                continue
-            slot = free.pop(0)
-            try:
-                got = self._dispatch_prefill(req, slot)
-                # Import seats emit host-side (the first token came with
-                # the block) and return None — nothing to fetch later.
-                if got is not None:
-                    prefills.append(got)
-            except PagePoolExhausted as e:
-                # No pages for this prompt right now. If anything is in
-                # flight, pages WILL free (requests finish, preemption,
-                # prefix eviction) — park the request at the FRONT so it
-                # retries next step ahead of everyone. If the engine is
-                # otherwise idle, nothing will ever free pages: shed with
-                # RejectedError + Retry-After rather than deadlocking.
-                req.requeued = True
-                if (self._active_requests() or prefills
-                        or self._inflight is not None):
-                    self._resume.appendleft(req)
-                else:
-                    self._shed_kv_exhausted(req, e)
-                did_work = True
-                break
-            except Exception as e:
-                # The request is out of the queue but not yet slotted: fail
-                # it HERE or nobody ever wakes its waiter (_fail_all only
-                # sees slots and the queue).
-                self._fail_request(req, e)
-                raise
-            did_work = True
 
         new_inflight = None
         try:
             if self._active_requests():
-                new_inflight = self._dispatch_decode_chunk()
+                with self.spans.span("engine.decode_dispatch") as span:
+                    new_inflight = self._dispatch_decode_chunk(span)
                 did_work = True
 
             if prefills:
@@ -1843,10 +1870,12 @@ class ServingEngine:
                 # (per-request int() would pay one link round-trip each);
                 # the decode chunk dispatched above is already running
                 # behind it on the device.
-                with jax.set_mesh(self.mesh):
+                with self.spans.span("engine.fetch_first", n=len(prefills)), \
+                        jax.set_mesh(self.mesh):
                     firsts = self._fetch(jnp.stack([f for _, f in prefills]))
-                for (req, _), first in zip(prefills, firsts):
-                    self._emit(req, int(first))
+                with self.spans.span("engine.emit", tokens=len(prefills)):
+                    for (req, _), first in zip(prefills, firsts):
+                        self._emit(req, int(first))
         except Exception as e:
             # Dispatched-but-unfetched exports hold no slot and sit in no
             # queue, so _fail_all cannot find them — fail them HERE or
@@ -1867,6 +1896,7 @@ class ServingEngine:
             did_work = True
         self._inflight = new_inflight
         if did_work:
+            self._m_steps.inc()
             self._record_step(step_t0, fetches0, uploads0, busy0,
                               len(prefills), new_inflight)
             # Heartbeat writes stay under the admission lock everywhere
@@ -1890,8 +1920,10 @@ class ServingEngine:
             dt = busy - busy0.get(name, 0.0)
             if dt > 0.0:
                 programs[name] = round(dt, 6)
+        wall_s = time.monotonic() - step_t0
         self.recorder.record({
-            "wall_s": round(time.monotonic() - step_t0, 6),
+            "wall_s": round(wall_s, 6),
+            "host_s": self.spans.host_s(wall_s),
             "occupancy": len(seated),
             "slots": self.num_slots,
             "queue_depth": self._pending_n + len(self._resume),
@@ -2019,7 +2051,29 @@ class ServingEngine:
             self._pool.unref(e.pages)
         return self._pool.free >= need
 
-    def _dispatch_prefill_paged(self, req: Request, slot: int):
+    def _prefill_span(self, req: Request, slot: int):
+        """The span of one request's prefill dispatch (slot -1: an export,
+        which takes none); ``_prefill_dispatched`` gives it its counts."""
+        return self.spans.span("engine.prefill_dispatch", slot=slot,
+                               request=_request_tag(req))
+
+    def _prefill_dispatched(self, req: Request, span, cached: int,
+                            real: int, padded: int) -> None:
+        """What every prefill dispatch records once it is enqueued: the
+        tokens it ran through the model (``real``, the tail on a prefix
+        hit), the bucket they ran in (``padded``) and the rows it took from
+        the prefix store (``cached``), on its span and on the counters."""
+        self.timers.note_tokens("prefill", padded)
+        for kind, n in (("real", real), ("padded", padded),
+                        ("cached", cached)):
+            self._m_prefill_tokens.inc(n, kind=kind)
+        span.set(program="prefill_ext" if cached else "prefill",
+                 hit=int(cached > 0), cached=cached, real=real,
+                 padded=padded)
+        if req.trace is not None:
+            req.trace.event("prefill_dispatched")
+
+    def _dispatch_prefill_paged(self, req: Request, slot: int, span):
         """Paged admission: allocate the prompt's pages, prefill (suffix-
         only over gathered shared pages on a prefix hit), scatter the block
         into the pool by page index, and activate the slot.
@@ -2028,7 +2082,6 @@ class ServingEngine:
         its sequence — its KV was reclaimed, so the whole context re-
         prefills and generation continues where it stopped."""
         faults.maybe_fail("engine.prefill")
-        t0 = time.monotonic()
         seq = (req.prompt if not req.generated else
                np.concatenate([req.prompt,
                                np.asarray(req.generated, np.int32)]))
@@ -2114,10 +2167,7 @@ class ServingEngine:
             # diverges after the genuinely shared part.
             self._prefix_store_paged(req.prefix_id, seq, pages)
         req.slot = slot
-        self.timers.note_tokens("prefill", bucket)
-        self._m_prefill.observe(time.monotonic() - t0, bucket=str(bucket))
-        if req.trace is not None:
-            req.trace.event("prefill_dispatched")
+        self._prefill_dispatched(req, span, plen, n - plen, bucket)
         self._slot_req[slot] = req
         self._slot_len[slot] = n + 1
         self._sampling_dirty = True
@@ -2138,11 +2188,15 @@ class ServingEngine:
             # normal re-prefill path below (its imported block is stale by
             # then; local prefill of prompt+generated rebuilds it).
             return self._dispatch_import(req, slot)
-        if self.paged:
-            return self._dispatch_prefill_paged(req, slot)
+        with self._prefill_span(req, slot) as span:
+            if self.paged:
+                return self._dispatch_prefill_paged(req, slot, span)
+            return self._dispatch_prefill_dense(req, slot, span)
+
+    def _dispatch_prefill_dense(self, req: Request, slot: int, span):
+        """The contiguous-KV prefill + insert behind ``_dispatch_prefill``."""
         faults.maybe_fail("engine.prefill")
-        t0 = time.monotonic()
-        n = req.prompt.size
+        n = int(req.prompt.size)
         sp = req.sampling
         cached = self._prefix_lookup(req)
         with jax.set_mesh(self.mesh):
@@ -2174,12 +2228,8 @@ class ServingEngine:
                 self._prefix_store(req.prefix_id, req.prompt, kv_k, kv_v)
             self.state = self._insert(self.state, kv_k, kv_v, n, slot, first)
         req.slot = slot
-        # Dispatch latency by padded bucket (host-side dispatch + any
-        # compile; the device-side wait lands in the TTFT histogram).
-        self.timers.note_tokens("prefill", bucket)
-        self._m_prefill.observe(time.monotonic() - t0, bucket=str(bucket))
-        if req.trace is not None:
-            req.trace.event("prefill_dispatched")
+        plen = cached.length if cached is not None else 0
+        self._prefill_dispatched(req, span, plen, n - plen, bucket)
         self._slot_req[slot] = req
         self._slot_len[slot] = n + 1   # prompt + the first generated token's kv-to-be
         self._sampling_dirty = True
@@ -2187,7 +2237,7 @@ class ServingEngine:
 
     # --- disaggregated serving: KV handoff export / import -----------------
 
-    def _dispatch_prefill_export(self, req: Request):
+    def _dispatch_prefill_export(self, req: Request, span):
         """Prefill-only dispatch for a KV handoff export (disaggregated
         serving): run the prefill program, never seat a slot or touch the
         page pool — the caller fetches the dense KV block to host in
@@ -2196,7 +2246,6 @@ class ServingEngine:
         prefix cache still participates, so N agent sessions exporting one
         shared context prefill only its suffix."""
         faults.maybe_fail("engine.prefill")
-        t0 = time.monotonic()
         n = int(req.prompt.size)
         sp = req.sampling
         cached = None if self.paged else self._prefix_lookup(req)
@@ -2227,10 +2276,8 @@ class ServingEngine:
                 )
             if req.prefix_id is not None and not self.paged:
                 self._prefix_store(req.prefix_id, req.prompt, kv_k, kv_v)
-        self.timers.note_tokens("prefill", bucket)
-        self._m_prefill.observe(time.monotonic() - t0, bucket=str(bucket))
-        if req.trace is not None:
-            req.trace.event("prefill_dispatched")
+        plen = cached.length if cached is not None else 0
+        self._prefill_dispatched(req, span, plen, n - plen, bucket)
         return req, first, kv_k, kv_v, n
 
     def _finish_export(self, req: Request, first_dev, kv_k, kv_v, n: int):
@@ -2240,7 +2287,8 @@ class ServingEngine:
         request with the payload the serving cell serializes over
         ``/v1/kv/export``."""
         try:
-            with jax.set_mesh(self.mesh):
+            with self.spans.span("engine.fetch_first", n=1), \
+                    jax.set_mesh(self.mesh):
                 first = int(self._fetch(first_dev))
                 k_host = self._fetch(kv_k[:, :, :n])
                 v_host = self._fetch(kv_v[:, :, :n])
@@ -2496,13 +2544,16 @@ class ServingEngine:
                 self._bt[slot, base: base + len(got)] = got
                 self._bt_dirty = True
 
-    def _dispatch_decode_chunk(self) -> "_InflightChunk | None":
+    def _dispatch_decode_chunk(self, span) -> "_InflightChunk | None":
         faults.maybe_fail("engine.decode")
         k = self._chunk_size()
         if self.paged:
             self._ensure_decode_pages(k)
             if not self._active_requests():
                 return None      # pressure handling drained the batch
+        active = self._active_requests()
+        span.set(k=k, active=len(active),
+                 live_rows=sum(self._slot_len[slot] for slot, _req in active))
         temps_d, top_ks_d, top_ps_d = self._sampling_dev_arrays()
         with jax.set_mesh(self.mesh):
             self._key, k1 = jax.random.split(self._key)
@@ -2535,31 +2586,38 @@ class ServingEngine:
     def _flush_inflight(self):
         """Fetch + emit the previously dispatched chunk's token block."""
         chunk = self._inflight
-        toks = self._fetch(chunk.tokens)  # [B, K] — single transfer per chunk
-        for slot, req in chunk.slots:
-            if req.done.is_set():
-                continue   # finished meanwhile (overshoot chunk) — discard
-            base = self._slot_len[slot]
-            for t in range(chunk.k):
-                # Per-token length bookkeeping so a request finishing mid-chunk
-                # keeps every token generated before the limit.
-                self._slot_len[slot] = base + t + 1
-                self._emit(req, int(toks[slot, t]))
+        with self.spans.span("engine.fetch_chunk", k=chunk.k):
+            toks = self._fetch(chunk.tokens)  # [B, K] — single transfer per chunk
+        with self.spans.span("engine.emit") as span:
+            emitted0 = self._step_tokens
+            for slot, req in chunk.slots:
                 if req.done.is_set():
-                    break
-            else:
-                self._slot_len[slot] = base + chunk.k
+                    continue   # finished meanwhile (overshoot chunk) — discard
+                base = self._slot_len[slot]
+                for t in range(chunk.k):
+                    # Per-token length bookkeeping so a request finishing
+                    # mid-chunk keeps every token generated before the limit.
+                    self._slot_len[slot] = base + t + 1
+                    self._emit(req, int(toks[slot, t]))
+                    if req.done.is_set():
+                        break
+                else:
+                    self._slot_len[slot] = base + chunk.k
+            span.set(tokens=self._step_tokens - emitted0)
 
     def _emit(self, req: Request, token: int):
         now = time.monotonic()
         if not req.generated:
-            req.first_token_at = now
-            self._m_ttft.observe(
-                now - req.submitted_at,
-                exemplar=(req.trace.trace_id
-                          if req.trace is not None else None))
-            if req.trace is not None:
-                req.trace.event("first_token")
+            # The instant the time to first token ends inside the program.
+            with self.spans.span("engine.first_token",
+                                 request=_request_tag(req)):
+                req.first_token_at = now
+                self._m_ttft.observe(
+                    now - req.submitted_at,
+                    exemplar=(req.trace.trace_id
+                              if req.trace is not None else None))
+                if req.trace is not None:
+                    req.trace.event("first_token")
         elif req.last_token_at:
             self._m_itl.observe(now - req.last_token_at)
         req.last_token_at = now

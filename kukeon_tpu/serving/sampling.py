@@ -67,6 +67,7 @@ def sample(logits: jnp.ndarray, key: jax.Array, params: SamplingParams) -> jnp.n
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sample")
 def sample_per_slot(
     logits: jnp.ndarray,
     key: jax.Array,

@@ -487,6 +487,7 @@ def test_stream_through_gateway_from_real_cell_holds_back_split_utf8():
             self.error = None
             self.cancelled = False
             self.timed_out = False
+            self.trace = None
 
         def cancel(self):
             self.cancelled = True

@@ -321,7 +321,9 @@ def test_engine_metrics_families_after_traffic():
     fams = _parse_expo(text)
     for name, kind in (
         ("kukeon_engine_queue_wait_seconds", "histogram"),
-        ("kukeon_engine_prefill_seconds", "histogram"),
+        ("kukeon_engine_prefill_tokens_total", "counter"),
+        ("kukeon_engine_loop_seconds_total", "counter"),
+        ("kukeon_engine_steps_total", "counter"),
         ("kukeon_engine_ttft_seconds", "histogram"),
         ("kukeon_engine_inter_token_seconds", "histogram"),
         ("kukeon_engine_e2e_seconds", "histogram"),
@@ -337,9 +339,10 @@ def test_engine_metrics_families_after_traffic():
         ("kukeon_faults_fired_total", "counter"),
     ):
         assert fams.get(name, {}).get("type") == kind, name
-    # Prefill histogram is labelled by padded bucket; 8 tokens pad to 64.
-    pre = fams["kukeon_engine_prefill_seconds"]["samples"]
-    assert any(lab.get("bucket") == "64" for _n, lab, _v in pre)
+    # Prefill tokens by kind; the 8-token prompt padded to the 64 bucket.
+    pre = {lab["kind"]: float(v) for _n, lab, v
+           in fams["kukeon_engine_prefill_tokens_total"]["samples"]}
+    assert pre == {"real": len(PROMPT), "padded": 64.0, "cached": 0.0}
     # Transfer counters mirror the sync_stats seam exactly.
     hs = {lab["kind"]: float(v)
           for n, lab, v in fams["kukeon_engine_host_sync_total"]["samples"]}
@@ -447,7 +450,8 @@ def test_metrics_scrape_is_valid_while_flooded(obs_cell):
                      "kukeon_engine_inter_token_seconds",
                      "kukeon_engine_e2e_seconds",
                      "kukeon_engine_queue_wait_seconds",
-                     "kukeon_engine_prefill_seconds",
+                     "kukeon_engine_prefill_tokens_total",
+                     "kukeon_engine_loop_seconds_total",
                      "kukeon_engine_shed_total",
                      "kukeon_engine_slots_free",
                      "kukeon_engine_queue_depth",

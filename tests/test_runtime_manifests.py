@@ -226,6 +226,7 @@ def test_stream_deltas_survive_split_utf8_codepoint():
             self.error = None
             self.cancelled = False
             self.timed_out = False
+            self.trace = None
 
         def cancel(self):
             self.cancelled = True
