@@ -7,7 +7,9 @@ core, designed for TPU:
 
 - **Slot-based decode batch**: a fixed [B_slots] decode batch with a
   fixed-shape KV cache [L, B, S_max, KV, D]. Static shapes => one compiled
-  decode program; occupancy changes never recompile.
+  decode program; occupancy changes never recompile. The state HOLDS k/v as
+  [L, B, KV, S_max, D] (``_kv_major``): the layout the TPU compiler gives the
+  decode scan's carry, so no chunk transposes the cache into the scan and out.
 - **Paged KV cache (``kv_page_tokens > 0``)**: instead of reserving
   ``num_slots * S_max`` contiguous rows, HBM is owned as fixed-size pages
   ([L, P, page_tokens, KV, D], serving/kv_pages.py) with a per-slot block
@@ -92,6 +94,19 @@ PREFILL_BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096)
 _LOG = logging.getLogger("kukeon.serving.engine")
 
 
+def _kv_major(x: jnp.ndarray) -> jnp.ndarray:
+    """[L, B, S, KV, D] <-> [L, B, KV, S, D]; its own inverse.
+
+    The contiguous decode state holds k/v with KV heads major to rows, which
+    is the layout the TPU compiler gives the decode scan's carry: insert
+    writes a prefill's block swapped, decode_chunk swaps the axes on its way
+    into the scan and back out, and the compiler makes those two swaps
+    relabellings of the same bytes. A state held row-major makes every chunk
+    transpose K and V into the scan and back out of it: four whole-cache
+    copies a chunk whatever its length, and a cache and more of temporaries."""
+    return jnp.swapaxes(x, 2, 3)
+
+
 class _CounterMapView(Mapping):
     """Read-only dict view over a labelled registry counter.
 
@@ -138,7 +153,9 @@ class DeadlineExceeded(RuntimeError):
 class DecodeState:
     """Whole-engine decode state; lives sharded in HBM between steps."""
 
-    cache: llama.KVCache          # [L, B, S_max, KV, D] + lengths [B]
+    # Contiguous: k/v HELD [L, B, KV, S_max, D] (_kv_major: the decode scan's
+    # own layout), scales [L, B, S_max, KV]; paged: the pool [L, P, pt, KV, D].
+    cache: llama.KVCache          # + lengths [B]
     tokens: jnp.ndarray           # [B] int32 — last emitted token per slot
     active: jnp.ndarray           # [B] bool — slot currently generating
 
@@ -699,6 +716,12 @@ class ServingEngine:
         flags — is replicated, because the host block table / slot map is
         the source of truth and every chip must see all of it."""
         kv_sh, sc_sh = self._cache_shardings()
+        if not self.paged and len(kv_sh.spec):
+            # The contiguous state holds k/v KV-major (_kv_major): the spec's
+            # kv-head entry moves with its axis, from position 3 to 2.
+            layer, slot, row, head, dim = kv_sh.spec
+            kv_sh = NamedSharding(
+                self.mesh, PartitionSpec(layer, slot, head, row, dim))
         repl = NamedSharding(self.mesh, PartitionSpec())
         cache = llama.KVCache(
             k=kv_sh, v=kv_sh, lengths=repl,
@@ -707,28 +730,39 @@ class ServingEngine:
         )
         return DecodeState(cache=cache, tokens=repl, active=repl)
 
-    def _init_state(self) -> DecodeState:
+    def _cache_shapes(self) -> llama.KVCache:
+        """ShapeDtypeStructs of the cache as the state HOLDS it."""
         if self.paged:
             # Pool layout: page axis where the legacy cache has its slot
             # axis ([L, P, page_tokens, KV, D]); lengths stay per-SLOT [B]
             # (the pool has no per-page length — the block table says which
             # pages a slot's logical [0, S_max) range maps to). Page 0 is
             # the scratch page (kv_pages.SCRATCH_PAGE).
-            cache = llama.KVCache.create(
-                self.cfg, self.kv_pool_pages + 1, self.page_tokens,
-                quantized=self.kv_cache_int8,
+            shapes = jax.eval_shape(
+                lambda: llama.KVCache.create(
+                    self.cfg, self.kv_pool_pages + 1, self.page_tokens,
+                    quantized=self.kv_cache_int8,
+                )
             )
-            cache = llama.KVCache(
-                k=cache.k, v=cache.v,
-                lengths=jnp.zeros((self.num_slots,), jnp.int32),
-                k_scale=cache.k_scale, v_scale=cache.v_scale,
-            )
-        else:
-            cache = llama.KVCache.create(
+            return dataclasses.replace(
+                shapes,
+                lengths=jax.ShapeDtypeStruct((self.num_slots,), jnp.int32))
+        shapes = jax.eval_shape(
+            lambda: llama.KVCache.create(
                 self.cfg, self.num_slots, self.max_seq_len,
                 quantized=self.kv_cache_int8,
             )
-        kv_sharding, sc_sharding = self._cache_shardings()
+        )
+        return dataclasses.replace(
+            shapes, k=jax.eval_shape(_kv_major, shapes.k),
+            v=jax.eval_shape(_kv_major, shapes.v))
+
+    def _init_state(self) -> DecodeState:
+        # Zeros built in the held shape: one cache live at boot, never two.
+        cache = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self._cache_shapes())
+        sc_sharding = self._cache_shardings()[1]
+        kv_sharding = self._state_shardings().cache.k
         cache = llama.KVCache(
             k=jax.device_put(cache.k, kv_sharding),
             v=jax.device_put(cache.v, kv_sharding),
@@ -827,8 +861,10 @@ class ServingEngine:
             if state.cache.quantized:
                 kv_k, ks = llama.quantize_kv(kv_k)   # [L, 1, S, KV(, D)]
                 kv_v, vs = llama.quantize_kv(kv_v)
-            k = jax.lax.dynamic_update_slice(state.cache.k, kv_k, (0, slot, 0, 0, 0))
-            v = jax.lax.dynamic_update_slice(state.cache.v, kv_v, (0, slot, 0, 0, 0))
+            k = jax.lax.dynamic_update_slice(
+                state.cache.k, _kv_major(kv_k), (0, slot, 0, 0, 0))
+            v = jax.lax.dynamic_update_slice(
+                state.cache.v, _kv_major(kv_v), (0, slot, 0, 0, 0))
             cache = llama.KVCache(
                 k=k, v=v, lengths=state.cache.lengths.at[slot].set(length),
                 k_scale=(jax.lax.dynamic_update_slice(
@@ -872,8 +908,15 @@ class ServingEngine:
                 )
                 return (new_state, key), next_tokens
 
-            (state, _), toks = jax.lax.scan(body, (state, key), length=n_steps)
-            return state, toks.T  # [B, K]
+            def swapped(state):
+                # held KV-major <-> the row-major shapes fwd reads (_kv_major)
+                return dataclasses.replace(state, cache=dataclasses.replace(
+                    state.cache, k=_kv_major(state.cache.k),
+                    v=_kv_major(state.cache.v)))
+
+            (state, _), toks = jax.lax.scan(
+                body, (swapped(state), key), length=n_steps)
+            return swapped(state), toks.T  # [B, K]
 
         # --- paged variants (block-table gather/scatter) ------------------
         # The pool is [L, P, pt, KV, D]; a slot's logical [0, S_max) range
@@ -1238,26 +1281,9 @@ class ServingEngine:
 
     def _abstract_state(self) -> DecodeState:
         """ShapeDtypeStruct mirror of _init_state (no device bytes)."""
-        if self.paged:
-            shapes = jax.eval_shape(
-                lambda: llama.KVCache.create(
-                    self.cfg, self.kv_pool_pages + 1, self.page_tokens,
-                    quantized=self.kv_cache_int8,
-                )
-            )
-            shapes = llama.KVCache(
-                k=shapes.k, v=shapes.v,
-                lengths=jax.ShapeDtypeStruct((self.num_slots,), jnp.int32),
-                k_scale=shapes.k_scale, v_scale=shapes.v_scale,
-            )
-        else:
-            shapes = jax.eval_shape(
-                lambda: llama.KVCache.create(
-                    self.cfg, self.num_slots, self.max_seq_len,
-                    quantized=self.kv_cache_int8,
-                )
-            )
-        kv_sh, sc_sh = self._cache_shardings()
+        shapes = self._cache_shapes()
+        sc_sh = self._cache_shardings()[1]
+        kv_sh = self._state_shardings().cache.k
         repl = NamedSharding(self.mesh, PartitionSpec())
 
         def sds(x, sh):

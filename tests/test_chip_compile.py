@@ -122,13 +122,17 @@ def test_flash_attention_compiles_for_v5e(v5e, s):
     _assert_kernel(compiled)
 
 
-def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e):
+@pytest.mark.parametrize("k", [4, 16])
+def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e, k):
     """The flagship cell's whole decode program — llama3-8b int8, 32 layers,
-    4 slots x 4096 rows of bf16 KV, 16 steps per chunk — built by the
+    4 slots x 4096 rows of bf16 KV, 4 or 16 steps per chunk — built by the
     engine itself from shapes alone, compiled for one described v5e: the
     bytes it keeps resident (arguments + outputs - donated aliases +
     temporaries + code) fit the chip's HBM. This is the program that holds
-    the most at once (weights + whole cache + the scan's copy of it)."""
+    the most at once (weights + whole cache), and its temporaries stay under
+    half of the K + V cache: the state holds the cache in the scan carry's
+    own layout (engine._kv_major), so no chunk transposes it in and out;
+    a state held row-major costs a whole cache and more of temporaries."""
     from kukeon_tpu.parallel import make_mesh
     from kukeon_tpu.serving import ServingEngine
 
@@ -158,12 +162,13 @@ def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
     key = jax.eval_shape(lambda: jax.random.key(0))
+    state = eng._abstract_state()
     with jax.set_mesh(mesh):
         compiled = eng._decode_chunk.lower(
-            eng._abstract_params, eng._abstract_state(),
+            eng._abstract_params, state,
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=repl),
             sds((4,), jnp.float32), sds((4,), jnp.int32),
-            sds((4,), jnp.float32), 16).compile()
+            sds((4,), jnp.float32), k).compile()
     m = compiled.memory_analysis()
     resident = (m.argument_size_in_bytes + m.output_size_in_bytes
                 - m.alias_size_in_bytes + m.temp_size_in_bytes
@@ -173,3 +178,9 @@ def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e):
     assert resident < V5E_HBM_BYTES, (
         f"decode_chunk keeps {resident / 1e9:.2f} GB resident; the chip has "
         f"{V5E_HBM_BYTES / 1e9:.2f} GB")
+    kv_bytes = sum(x.size * x.dtype.itemsize
+                   for x in (state.cache.k, state.cache.v))
+    assert m.temp_size_in_bytes < kv_bytes / 2, (
+        f"decode_chunk (k={k}) holds {m.temp_size_in_bytes / 1e9:.2f} GB of "
+        f"temporaries beside a K + V cache of {kv_bytes / 1e9:.2f} GB: a "
+        "whole-cache copy is back in the program")
