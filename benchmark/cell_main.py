@@ -10,6 +10,11 @@ output and reads one command per line on its standard input:
                                            reference over the served sequences
   {"cmd": "exit"}
 
+Nothing here names a model family: the configuration file does
+(``"reference": "<family>"``), and ``launchers/<family>.py`` enters the
+configuration in the program's own registry before ``CellHost.boot`` builds the
+normal ``ServingCell`` from it.
+
 Nothing here falls back to the CPU: ``device_gate`` ends the process unless JAX
 reports a TPU with at least the chips the cell asks for. Tests drive ``CellHost``
 in their own process and never call the gate.
@@ -56,32 +61,13 @@ def device_gate(chips: int) -> dict:
     return found
 
 
-def llama_config(config: dict):
-    """The configuration file's published sizes as the program's config."""
-    import jax.numpy as jnp
-
-    from kukeon_tpu.models import llama
-
-    return llama.LlamaConfig(
-        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
-        intermediate_size=config["intermediate_size"],
-        num_layers=config["num_hidden_layers"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"], rope_theta=float(config["rope_theta"]),
-        rms_norm_eps=float(config["rms_norm_eps"]),
-        max_seq_len=config["max_position_embeddings"],
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=getattr(jnp, config["torch_dtype"]))
-
-
-def peak_bytes() -> int:
-    """Peak bytes in use on the fullest device (0 where JAX reports none)."""
+def device_bytes(stat: str) -> int:
+    """``peak_bytes_in_use`` or ``bytes_in_use`` of the fullest device (0
+    where JAX reports no memory statistics, as on the CPU)."""
     import jax
 
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.devices()]
-    return int(max(peaks))
+    return int(max((d.memory_stats() or {}).get(stat, 0)
+                   for d in jax.devices()))
 
 
 class CellHost:
@@ -90,9 +76,12 @@ class CellHost:
     def __init__(self, config: dict, seed: int, pkg_dir: str = plugins.HERE):
         self.config, self.seed, self.pkg_dir = config, int(seed), pkg_dir
         self.cell = self.server = self._thread = None
+        self._not_mine: list = []
 
     def boot(self, warm_prompt_len: int) -> dict:
         from http.server import ThreadingHTTPServer
+
+        import jax
 
         from kukeon_tpu.runtime import serving_cell as sc
 
@@ -101,13 +90,16 @@ class CellHost:
         if levers:
             raise SystemExit(f"benchmark: unset {levers}: a cell runs at the "
                              "levers its configuration file states")
+        # Arrays that were alive before this cell (none in run.py's child; a
+        # test process has other tests'): stop_and_free leaves them alone.
+        self._not_mine = jax.live_arrays()
         s = self.config["serving"]
-        name = self.config["name"]
-        cfg = llama_config(self.config)
-        sc.MODELS[name] = lambda: cfg
+        plugins.load("launchers", self.config["reference"],
+                     self.pkg_dir).register(self.config)
         cell = sc.ServingCell(
-            name, num_slots=s["num_slots"], max_seq_len=s["max_seq_len"],
-            checkpoint=None, dtype=s["dtype"], seed=self.seed,
+            self.config["name"], num_slots=s["num_slots"],
+            max_seq_len=s["max_seq_len"], checkpoint=None, dtype=s["dtype"],
+            seed=self.seed,
             kv_cache_int8=s["kv_cache_int8"], decode_chunk=s["decode_chunk"],
             kv_page_tokens=s["kv_page_tokens"], max_pending=s["max_pending"],
             deadline_s=s["deadline_s"], chips=s["chips"])
@@ -125,6 +117,10 @@ class CellHost:
         self._thread = threading.Thread(target=server.serve_forever,
                                         daemon=True, name="bench-http")
         self._thread.start()
+        # A lever that only some families' configs have is recorded where
+        # the program's config has it and left out where it has not.
+        pallas = ({"int8_pallas": eng.cfg.int8_pallas}
+                  if hasattr(eng.cfg, "int8_pallas") else {})
         return {
             "port": server.server_address[1],
             "boot_phases_s": {k: round(v, 3) for k, v in phases.items()},
@@ -132,7 +128,7 @@ class CellHost:
                 "prefill_buckets": list(eng.prefill_buckets),
                 "decode_chunk": eng.decode_chunk, "paged": eng.paged,
                 "kv_cache_int8": eng.kv_cache_int8, "slots": eng.num_slots,
-                "rows": eng.max_seq_len, "int8_pallas": eng.cfg.int8_pallas,
+                "rows": eng.max_seq_len, **pallas,
                 "kv_shard": eng.kv_shard, "mesh_chips": int(eng.mesh.size),
                 "prefix_entries": eng._prefix_cache_size,
                 "prefix_bytes": eng._prefix_cache_bytes,
@@ -140,24 +136,30 @@ class CellHost:
             },
         }
 
-    def stop_and_free(self) -> None:
-        """Stop serving and delete every array the program holds, so that the
-        reference has the device to itself."""
+    def stop_and_free(self) -> dict:
+        """Stop serving and delete every array that came to live on the device
+        since ``boot`` began, whoever holds it (weights, decode state, prefix
+        store, a family's own rings or tables), so that the reference has the
+        device to itself. Returns what was freed and the bytes still in use
+        on the fullest device afterwards."""
         import jax
 
-        eng = self.cell.engine
         self.server.shutdown()
         self.server.server_close()
         self._thread.join(timeout=30)
-        eng.stop()
-        held = [eng.params, eng.state,
-                [(e.kv_k, e.kv_v) for e in eng._prefix_cache.values()]]
-        eng._prefix_cache.clear()
-        for leaf in jax.tree.leaves(held):
-            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
-                leaf.delete()
+        self.cell.engine.stop()
+        kept = {id(a) for a in self._not_mine}
+        mine = [a for a in jax.live_arrays()
+                if id(a) not in kept and not a.is_deleted()]
+        n, freed = len(mine), sum(a.nbytes for a in mine)
+        for a in mine:
+            a.delete()
+        del mine
         self.cell = self.server = None
+        self._not_mine = []
         gc.collect()
+        return {"freed_arrays": n, "freed_bytes": int(freed),
+                "bytes_in_use_under_reference": device_bytes("bytes_in_use")}
 
     def check(self, requests: list[dict], pad_to: int,
               controls: tuple = ()) -> dict:
@@ -204,11 +206,11 @@ class CellHost:
     def command(self, msg: dict) -> dict:
         cmd = msg["cmd"]
         if cmd == "check":
-            peak = peak_bytes()
-            self.stop_and_free()
+            peak = device_bytes("peak_bytes_in_use")
+            freed = self.stop_and_free()
             out = self.check(msg["requests"], msg["pad_to"],
                              tuple(msg.get("controls", ())))
-            return {**out, "memory_peak_bytes": peak}
+            return {**out, **freed, "memory_peak_bytes": peak}
         raise ValueError(f"unknown command {cmd!r}")
 
 
@@ -224,15 +226,19 @@ def main(argv=None) -> int:
     os.environ["KUKEON_PROFILE_DIR"] = os.path.join(args.run_dir, "profiles")
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           os.path.join(REPO, ".jax_cache"))
-    device = device_gate(config["serving"]["chips"])
-    host = CellHost(config, args.seed,
-                    os.path.dirname(os.path.dirname(os.path.abspath(args.config))))
-    emit("ready", device=device, **host.boot(args.warm_prompt_len))
-    for line in sys.stdin:
-        msg = json.loads(line)
-        if msg["cmd"] == "exit":
-            break
-        emit("reply", cmd=msg["cmd"], **host.command(msg))
+    try:
+        device = device_gate(config["serving"]["chips"])
+        host = CellHost(config, args.seed, os.path.dirname(
+            os.path.dirname(os.path.abspath(args.config))))
+        emit("ready", device=device, **host.boot(args.warm_prompt_len))
+        for line in sys.stdin:
+            msg = json.loads(line)
+            if msg["cmd"] == "exit":
+                break
+            emit("reply", cmd=msg["cmd"], **host.command(msg))
+    except BaseException as e:      # told to the parent, then raised as it was
+        emit("failed", error=plugins.one_line(e, 400))
+        raise
     if host.cell is not None:
         host.stop_and_free()
     sys.stdout.flush()

@@ -48,7 +48,14 @@ def decode_steps(ctx: dict) -> float | None:
     """Decode steps the traced decode modules ran, from the trace alone: an
     instruction of the layer scan's body runs layers x steps times, so the
     most-run instruction of each decode program over the layers is its steps
-    (a chunk the capture cut in two counts the steps it caught)."""
+    (a chunk the capture cut in two counts the steps it caught).
+
+    Holds for a decode program that is ONE scan over all
+    ``num_hidden_layers`` equal layers (models/llama.py, models/moe.py). A
+    family whose decode program is not (a leading dense layer before a scan
+    over a period of four, say) brings readers of its own under new names,
+    and lists its cells in their ``workloads``: every metric read through
+    this function names its cells in BENCHMARK.json for that reason."""
     layers = ctx["config"]["num_hidden_layers"]
     most = 0
     for name, m in device0(ctx)["modules"].items():

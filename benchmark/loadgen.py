@@ -68,7 +68,10 @@ class OpenLoop:
     an offset from it (negative inside a ramp). Offering stops at
     ``t0 + seconds``; what is in flight then has ``drain_s`` to finish.
     ``at`` holds (offset, callable) pairs run on the dispatcher's clock, which
-    is how a traced run asks for its capture."""
+    is how a traced run asks for its capture. ``missed``, after ``run()``,
+    lists the (offset, callable) pairs that were due inside the window and
+    that the dispatcher never came to (a stalled host): the caller decides
+    which of them may still run, late."""
 
     def __init__(self, port: int, generator, t0: float, seconds: float,
                  drain_s: float, at: list | None = None):
@@ -80,6 +83,7 @@ class OpenLoop:
         self._cv = threading.Condition()
         self._threads: list[threading.Thread] = []
         self.records: list[dict] = []
+        self.missed: list = []
         self._inflight: dict = {}
         for r in generator.arrivals():
             self._push(r)
@@ -147,6 +151,10 @@ class OpenLoop:
                 self._inflight[item["id"]] = (item, due)
             t.start()
             self._threads.append(t)
+        with self._cv:
+            self.missed = [(due - self.t0, item["call"])
+                           for due, _n, item in sorted(self._heap)
+                           if "call" in item and due < self.end]
         for t in self._threads:
             t.join(max(0.0, self.deadline + 2.0 - time.monotonic()))
         with self._cv:

@@ -1,8 +1,9 @@
 """Finds the files that belong to one configuration, mix or metric by name.
 
-A generator, a per-layer reader, an operation count or a reference is one file
-``<kind>/<name>.py`` beside the configuration that uses it (``pkg_dir``, the
-directory that holds ``configs/`` and ``traffic/``), or else in this directory.
+A generator, a per-layer reader, an operation count, a reference or a launcher
+is one file ``<kind>/<name>.py`` beside the configuration that uses it
+(``pkg_dir``, the directory that holds ``configs/`` and ``traffic/``), or else
+in this directory.
 So a later PR adds a file and an entry of BENCHMARK.json, and edits nothing.
 """
 
@@ -16,6 +17,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
+
+
+def one_line(err: BaseException, most: int = 600) -> str:
+    """What ended a run, for a line of a record: a SystemExit's text, another
+    exception's type and message."""
+    what = str(err.code) if isinstance(err, SystemExit) \
+        else f"{type(err).__name__}: {err}"
+    return " ".join(what.split())[:most]
 
 
 def load(kind: str, name: str, pkg_dir: str = HERE):

@@ -19,6 +19,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from benchmark import plugins  # noqa: E402
+
 
 class AbstractOnly:
     """A checkpoint stream that only knows its shapes (as
@@ -41,29 +43,39 @@ def resident(compiled) -> int:
             + m.generated_code_size_in_bytes)
 
 
-def rehearse(config: dict, traffic: dict) -> list[tuple[str, float, float]]:
+def abstract_engine(config: dict, mesh, pkg_dir: str = plugins.HERE):
+    """The engine of a configuration over shapes alone: the family's launcher
+    (``launchers/<family>.py``) supplies the program's config, the abstract
+    parameter tree and what else its family needs; the levers are the
+    configuration file's, as in ``cell_main.CellHost.boot``."""
+    from kukeon_tpu.serving import ServingEngine
+
+    s = config["serving"]
+    family = plugins.load("launchers", config["reference"],
+                          pkg_dir).abstract(config)
+    cfg, tree = family.pop("cfg"), family.pop("params")
+    eng = ServingEngine(cfg, AbstractOnly(tree), mesh, **family,
+                        num_slots=s["num_slots"], max_seq_len=s["max_seq_len"],
+                        async_load=True, kv_page_tokens=s["kv_page_tokens"],
+                        kv_cache_int8=s["kv_cache_int8"],
+                        decode_chunk=s["decode_chunk"])
+    return cfg, eng
+
+
+def rehearse(config: dict, traffic: dict,
+             pkg_dir: str = plugins.HERE) -> list[tuple[str, float, float]]:
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from benchmark.cell_main import llama_config
-    from kukeon_tpu.models import llama
     from kukeon_tpu.parallel import make_mesh
-    from kukeon_tpu.serving import ServingEngine
 
     jax.config.update("jax_enable_compilation_cache", False)
     s = config["serving"]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     mesh = make_mesh(tensor=s["chips"], devices=topo.devices[:s["chips"]])
-    cfg = llama_config(config)
-    abstract = jax.eval_shape(
-        lambda k: llama.init_quantized_params(k, cfg), jax.random.key(0))
-    eng = ServingEngine(cfg, AbstractOnly(abstract), mesh,
-                        num_slots=s["num_slots"], max_seq_len=s["max_seq_len"],
-                        async_load=True, kv_page_tokens=s["kv_page_tokens"],
-                        kv_cache_int8=s["kv_cache_int8"],
-                        decode_chunk=s["decode_chunk"])
+    cfg, eng = abstract_engine(config, mesh, pkg_dir)
     repl = NamedSharding(mesh, PartitionSpec())
     kv_sh = eng._cache_shardings()[0]
     B = s["num_slots"]
@@ -114,4 +126,5 @@ if __name__ == "__main__":
     args = sys.argv[1:]
     for cfg_path, traffic_path in zip(args[::2], args[1::2]):
         with open(cfg_path) as f, open(traffic_path) as g:
-            rehearse(json.load(f), json.load(g))
+            rehearse(json.load(f), json.load(g), os.path.dirname(
+                os.path.dirname(os.path.abspath(cfg_path))))

@@ -24,6 +24,7 @@ import random
 import shutil
 import subprocess
 import sys
+import types
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -33,11 +34,16 @@ if REPO not in sys.path:
 from benchmark import loadgen, plugins, stats  # noqa: E402
 
 COMPILES = "kukeon_compiles_total"
+LATENESS_P50_MS = 5.0       # a generator later than this at the median starved
 SEED_MOD = 2147483629       # weights' key: any --seed folded into 31 bits
 
 
 def say(msg: str) -> None:
     print(msg, flush=True)
+
+
+def no_result_line(stage: str, err: BaseException) -> str:
+    return f"benchmark: no result: {stage}: {plugins.one_line(err)}"
 
 
 def load_json(path: str) -> dict:
@@ -82,16 +88,19 @@ class SubprocessCell:
 
     def _read(self, kind: str) -> dict:
         """Next BENCH record of ``kind``; other lines pass through."""
+        said = ""
         for line in self.proc.stdout:
             if line.startswith("BENCH "):
                 rec = json.loads(line[6:])
                 if rec["kind"] == kind:
                     return rec
+                if rec["kind"] == "failed":
+                    said = f" ({rec['error']})"
             else:
                 say("cell: " + line.rstrip())
         raise SystemExit(f"benchmark: the cell process ended (exit code "
-                         f"{self.proc.wait()}) before its {kind!r} record. "
-                         "No result.")
+                         f"{self.proc.wait()}) before its {kind!r} record"
+                         f"{said}. No result.")
 
     def start(self) -> dict:
         self.proc = subprocess.Popen(
@@ -189,14 +198,30 @@ def client_records(records: list[dict], censor_ms: float) -> list[dict]:
         out.append({**r, "ttft_ms": (tt[0] - r["due"]) * 1e3 if r["ok"]
                     else censor_ms,
                     "tpot_ms": stats.tpot_ms(tt[0], tt[-1], len(tt))
-                    if r["ok"] else None})
+                    if r["ok"] else None,
+                    "latency_ms": (tt[-1] - r["due"]) * 1e3 if r["ok"]
+                    else censor_ms})
     return out
 
 
 def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
           run_dir: str, t_start: float, controls: tuple = ()) -> dict:
-    """Everything after the device gate: boot, warm-up, window, check,
-    reduction. ``child`` has start(), command() and close()."""
+    """Everything after the device gate: boot, warm-up, window, capture,
+    check, reduction. ``child`` has start(), command() and close(). A run
+    that ends here without a result says at which stage, as one line on
+    standard output, and ends as it would have (same exception, same exit
+    code, every message that was there)."""
+    stage = types.SimpleNamespace(name="boot")      # where the run is
+    try:
+        return _drive(child, spec, seed, seconds, trace, run_dir, t_start,
+                      controls, stage)
+    except BaseException as e:         # SystemExit too; always re-raised
+        say(no_result_line(stage.name, e))
+        raise
+
+
+def _drive(child, spec, seed, seconds, trace, run_dir, t_start, controls,
+           stage) -> dict:
     config, traffic = spec["config"], spec["traffic"]
     wseed = seed % SEED_MOD
     info = child.start()
@@ -205,6 +230,7 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
         f"boot_s={json.dumps(info['boot_phases_s'])}")
     say(f"levers: {json.dumps(info['levers'])}")
 
+    stage.name = "warm-up"
     before_warm = scrape(port)
     warm = warm_up(port, traffic, config["vocab_size"], wseed,
                    config["serving"]["max_seq_len"])
@@ -213,41 +239,63 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
         f"{stats.delta(before_warm, after_warm, COMPILES):.0f}, "
         f"repeat identical: {warm['repeat_identical']}")
 
+    stage.name = "window"
     gen_mod = plugins.load("generators", traffic["generator"],
                            spec["pkg_dir"])
     gen = gen_mod.Generator(traffic["params"], seed, config["vocab_size"],
                             seconds)
     ramp_s = float(traffic["params"].get("ramp_s", 0.0))
     scrapes: dict = {}
+    scrape_late_ms: dict = {}
     capture: dict = {}
+    t0 = time.monotonic() + ramp_s + 0.25
 
-    def scrape_into(key):
-        return lambda: scrapes.__setitem__(key, scrape(port))
+    def scrape_into(key, offset):
+        def fn():
+            scrape_late_ms[key] = (time.monotonic() - t0 - offset) * 1e3
+            scrapes[key] = scrape(port)
+        return fn
 
     def start_capture():
-        capture["metrics_before"] = scrape(port)
-        capture["requested"] = time.monotonic()
-        status, body = loadgen.call(
-            port, "POST", "/v1/profile",
-            {"durationMs": capture["duration_s"] * 1e3})
-        body = json.loads(body or b"{}")
-        capture["status"], capture["rec"] = status, body.get("capture", body)
-        time.sleep(capture["duration_s"])
-        capture["metrics_after"] = scrape(port)
+        try:
+            capture["metrics_before"] = scrape(port)
+            capture["requested"] = time.monotonic()
+            status, body = loadgen.call(
+                port, "POST", "/v1/profile",
+                {"durationMs": capture["duration_s"] * 1e3})
+            body = json.loads(body or b"{}")
+            capture["status"] = status
+            capture["rec"] = body.get("capture", body)
+            time.sleep(capture["duration_s"])
+            capture["metrics_after"] = scrape(port)
+        except Exception as e:  # noqa: BLE001 (a thread's boundary)
+            # this thread's exception reaches nobody: keep it for the line
+            capture["error"] = f"{type(e).__name__}: {e}"
 
-    at = [(0.0, scrape_into("open")), (seconds - 0.05, scrape_into("close"))]
+    close_at = seconds - 0.05
+    window_scrapes = [(0.0, scrape_into("open", 0.0)),
+                      (close_at, scrape_into("close", close_at))]
+    at = list(window_scrapes)
     if trace:
         capture["duration_s"] = min(3.0, seconds / 3.0)
         capture["offset_s"] = min(5.0, seconds / 3.0)
         at.append((capture["offset_s"], start_capture))
-    t0 = time.monotonic() + ramp_s + 0.25
     loop = loadgen.OpenLoop(port, gen, t0, seconds, traffic["drain_s"], at)
     setup_s = t0 - t_start
     records = loop.run()
+    # A scrape that a stalled host kept the dispatcher from still runs, late,
+    # and the run says how late; a capture is never started after the window.
+    for _offset, fn in loop.missed:
+        if any(fn is scrape_fn for _o, scrape_fn in window_scrapes):
+            fn()
     final = scrape(port)
     if "open" not in scrapes or "close" not in scrapes:
         raise SystemExit("benchmark: the window's scrapes did not run. "
                          "No result.")
+    say("window scrapes: " + ", ".join(
+        f"{k} ran {scrape_late_ms[k]:.1f} ms late" for k in ("open", "close"))
+        + f" (close is due {seconds - close_at:.2f} s before the window's end;"
+        " later than that it counts work done after it)")
 
     censor_ms = (seconds + traffic["drain_s"]) * 1e3
     window = client_records([r for r in records if r["in_window"]], censor_ms)
@@ -262,11 +310,25 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
         say(f"failed request {r['id']}: status {r['status']} {r['error']}")
     compiles = stats.delta(after_warm, final, COMPILES)
 
+    if trace and "requested" not in capture and "error" not in capture:
+        stage.name = "capture"
+        raise SystemExit("benchmark: the capture was not started inside the "
+                         "window: the dispatcher came to it too late. "
+                         "No result.")
+    if trace and capture.get("requested", t0) + capture["duration_s"] \
+            > t0 + seconds:
+        stage.name = "capture"
+        raise SystemExit("benchmark: the capture began "
+                         f"{capture['requested'] - t0:.1f} s into the window "
+                         "and ran past its end. No result.")
     if trace and "metrics_after" not in capture:
-        raise SystemExit(f"benchmark: the capture did not finish: "
-                         f"{ {k: capture.get(k) for k in ('status', 'rec')} }"
-                         ". No result.")
+        stage.name = "capture"
+        raise SystemExit(
+            f"benchmark: the capture did not finish: "
+            f"{ {k: capture.get(k) for k in ('status', 'rec', 'error')} }"
+            ". No result.")
 
+    stage.name = "check"
     want = traffic["check"]
     sample = pick_sample(records, want["requests"], seed)
     check = child.command({
@@ -280,10 +342,19 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
         "repeat_identical": warm["repeat_identical"],
         "no_compile_in_window": compiles == 0,
         "every_answer_whole": not failed,
-        "lateness_p50_under_5ms": late["p50"] <= 5.0,
+        "lateness_p50_under_5ms": late["p50"] <= LATENESS_P50_MS,
         "reference": check is not None and all(
             check[k] <= limits[k] for k in limits),
     }
+    # Every number that `correct` compares, beside the limit it may not pass.
+    compared = {k: [check[k], limits[k]] for k in limits} if check else {}
+    compared.update({
+        "compiles_in_window": [int(compiles), 0],
+        "failed_requests": [len(failed), 0],
+        "lateness_p50_ms": [late["p50"], LATENESS_P50_MS],
+        "repeats_differing": [0 if warm["repeat_identical"] else 1, 0]})
+    compared = {k: {"value": v, "limit": lim}
+                for k, (v, lim) in compared.items()}
     if check is not None:
         say("check: " + ", ".join(
             f"{k} {check[k]:.5f} (limit {limits[k]})" for k in limits)
@@ -291,6 +362,9 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
             f"reference's best) of {check['requests']} requests in "
             f"{check['sequences']} sequences, logit std {check['logit_std']:.3f}, reference "
             f"{check['reference_s']} s")
+        say(f"freed for the reference: {check['freed_arrays']} arrays, "
+            f"{check['freed_bytes']} bytes; in use on the fullest device when "
+            f"it started: {check['bytes_in_use_under_reference']} bytes")
         for c in controls:
             say(f"control {c}: {json.dumps(check['control_' + c])}")
     say(f"compiles in the window: {compiles:.0f} (limit 0); failed requests: "
@@ -307,35 +381,45 @@ def drive(child, spec: dict, seed: int, seconds: float, trace: bool,
                               censor_ms)
     client["setup_s"] = setup_s
     say(f"client: {json.dumps(client)}")
-    if not trace:
+    if trace:
+        stage.name = "reduction"
+        reduced = reduce_trace(capture, run_dir)
+        used = reduced["devices"][:config["serving"]["chips"]]
+        device_out["busy_s"] = sum(d["busy_s"] for d in used) / len(used)
+        device_out["window_s"] = sum(d["window_s"] for d in used) / len(used)
+        cap0 = capture["requested"]
+        ctx = {
+            "trace": reduced, "capture": capture, "device": device,
+            "client": client,
+            "levers": info["levers"],
+            "metrics_open": scrapes["open"], "metrics_close": scrapes["close"],
+            "cell": spec["cell"], "config": config, "traffic": traffic,
+            "pkg_dir": spec["pkg_dir"],
+            "peaks": load_json(os.path.join(HERE, "peaks.json")),
+            "live": stats.mean_live(records, cap0,
+                                    cap0 + capture["duration_s"]),
+            "records": records, "window": (t0, t0 + seconds),
+        }
+        for m in spec["per_layer"]:
+            reader = plugins.load("layer_metrics", m["name"], spec["pkg_dir"])
+            value = reader.read(ctx)
+            if value is not None:
+                result["metrics"][m["name"]] = {"value": value,
+                                                "unit": m["unit"]}
+        result["breakdown"] = {"device_ops": used[0]["top_ops"][:10],
+                               "idle_gaps": used[0]["idle_gaps"][:10]}
+    else:
         for m in spec["end_to_end"]:
             result["metrics"][m["name"]] = {"value": client[m["name"]],
                                             "unit": m["unit"]}
-        return result
-
-    reduced = reduce_trace(capture, run_dir)
-    used = reduced["devices"][:config["serving"]["chips"]]
-    device_out["busy_s"] = sum(d["busy_s"] for d in used) / len(used)
-    device_out["window_s"] = sum(d["window_s"] for d in used) / len(used)
-    cap0 = capture["requested"]
-    ctx = {
-        "trace": reduced, "capture": capture, "device": device,
-        "client": client,
-        "levers": info["levers"],
-        "metrics_open": scrapes["open"], "metrics_close": scrapes["close"],
-        "cell": spec["cell"], "config": config, "traffic": traffic,
-        "pkg_dir": spec["pkg_dir"],
-        "peaks": load_json(os.path.join(HERE, "peaks.json")),
-        "live": stats.mean_live(records, cap0, cap0 + capture["duration_s"]),
-        "records": records, "window": (t0, t0 + seconds),
-    }
-    for m in spec["per_layer"]:
-        reader = plugins.load("layer_metrics", m["name"], spec["pkg_dir"])
-        value = reader.read(ctx)
-        if value is not None:
-            result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
-    result["breakdown"] = {"device_ops": used[0]["top_ops"][:10],
-                           "idle_gaps": used[0]["idle_gaps"][:10]}
+    # The checks by name, then every number compared beside its limit: the
+    # result's last key, and this run's last lines on standard error.
+    result["window_scrapes_late_ms"] = scrape_late_ms
+    result["checks"] = checks
+    result["compared"] = compared
+    for k, c in compared.items():
+        print(f"compared: {k} {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return result
 
 
@@ -347,9 +431,13 @@ def reduce_trace(capture: dict, run_dir: str) -> dict:
                          "No result.")
     out = os.path.join(run_dir, "reduction.json")
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    subprocess.run([sys.executable, os.path.join(HERE, "trace_reduce.py"),
-                    capture["rec"]["path"], out], check=True, env=env,
-                   cwd=REPO)
+    done = subprocess.run(
+        [sys.executable, os.path.join(HERE, "trace_reduce.py"),
+         capture["rec"]["path"], out], env=env, cwd=REPO)
+    if done.returncode != 0:
+        raise SystemExit(f"benchmark: trace_reduce.py exited with code "
+                         f"{done.returncode} over {capture['rec']['path']}. "
+                         "No result.")
     return load_json(out)
 
 

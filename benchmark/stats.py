@@ -33,14 +33,17 @@ def end_to_end(records: list[dict], seconds: float, limits: dict,
     """The client-side metrics over every request SENT in the window (which
     of them a cell reports under which heading is BENCHMARK.json's choice).
 
-    ``records`` carry ``ok``, ``ttft_ms`` and ``tpot_ms`` (None for a failed
-    request or a one-token answer). A failed, refused or undrained request
-    stays in both tails at ``censor_ms`` and misses the limits."""
+    ``records`` carry ``ok``, ``ttft_ms``, ``tpot_ms`` (None for a failed
+    request or a one-token answer) and ``latency_ms`` (due -> last token: the
+    whole answer, which is what a caller that acts on answers waits for). A
+    failed, refused or undrained request stays in every tail and mean at
+    ``censor_ms`` and misses the limits."""
     if not records:
         raise ValueError("no request was sent in the window")
     ttft = [r["ttft_ms"] if r["ok"] else censor_ms for r in records]
     tpot = [r["tpot_ms"] if r["ok"] else censor_ms for r in records
             if not r["ok"] or r["tpot_ms"] is not None]
+    latency = [r["latency_ms"] if r["ok"] else censor_ms for r in records]
     met = sum(1 for r in records if r["ok"]
               and r["ttft_ms"] <= limits["ttft_ms"]
               and (r["tpot_ms"] is None or r["tpot_ms"] <= limits["tpot_ms"]))
@@ -51,6 +54,7 @@ def end_to_end(records: list[dict], seconds: float, limits: dict,
         "tpot_p50_ms": percentile(tpot, 50),
         "tpot_p90_ms": percentile(tpot, 90),
         "tpot_mean_ms": sum(tpot) / len(tpot),
+        "latency_mean_ms": sum(latency) / len(latency),
         "slo_share": 100.0 * met / len(records),
         "out_tok_per_s": tokens_in_window / seconds,
     }

@@ -12,6 +12,18 @@ if REPO not in sys.path:
 from benchmark import cell_main  # noqa: E402
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+LATENESS = "lateness_p50_under_5ms"
+
+
+def sound(out: dict) -> bool:
+    """Every check that makes up ``correct`` but the generator's lateness: on
+    a CPU shared with other test workers the host IS late, which is no fault
+    of what these tests are about. That check has a test of its own, with a
+    generator that is late by construction."""
+    assert set(out["checks"]) == {
+        "reference", "repeat_identical", "every_answer_whole",
+        "no_compile_in_window", LATENESS}
+    return all(ok for name, ok in out["checks"].items() if name != LATENESS)
 
 
 class InProcessCell:

@@ -25,6 +25,10 @@ def test_run_fails_at_the_device_gate_on_the_cpu():
                           timeout=300, cwd=plugins.REPO)
     _no_result(proc)
     assert "JAX found platform 'cpu'" in proc.stderr
+    # ... and standard output says at which stage, with the child's own words
+    last = proc.stdout.strip().splitlines()[-1]
+    assert last.startswith("benchmark: no result: boot: ")
+    assert "exit code 1" in last and "JAX found platform 'cpu'" in last
 
 
 def test_run_has_no_option_that_skips_the_gate():

@@ -127,3 +127,35 @@ def test_files_under_paths_are_named_from_a_names_characters(bench):
             for f in files:
                 rel = os.path.relpath(os.path.join(root, f), plugins.REPO)
                 assert ok.match(rel), rel
+
+
+def _client_stats():
+    from benchmark import stats
+
+    rec = {"ok": True, "ttft_ms": 100.0, "tpot_ms": 20.0, "latency_ms": 500.0}
+    return stats.end_to_end([rec], 1.0, {"ttft_ms": 1000.0, "tpot_ms": 60.0},
+                            10, 9000.0)
+
+
+def test_every_cell_reports_setup_another_end_to_end_metric_and_a_layer(bench):
+    """What a cell reports end to end is a key of the client's arithmetic
+    (run.py looks it up there), beside setup_s."""
+    from benchmark import run
+
+    have = set(_client_stats()) | {"setup_s"}
+    for w in bench["workloads"]:
+        spec = run.load_cell(plugins.REPO, w["name"])
+        names = [m["name"] for m in spec["end_to_end"]]
+        assert "setup_s" in names and len(names) >= 2, w["name"]
+        assert set(names) <= have, (w["name"], set(names) - have)
+        assert spec["per_layer"], w["name"]
+
+
+@pytest.mark.parametrize("name", ["ttft_mean_ms", "tpot_mean_ms",
+                                  "out_tok_per_s"])
+def test_a_client_reader_hands_on_the_traced_windows_own_number(name):
+    """A metric held end to end in one cell and too unsteady for a bound in
+    another stands there per layer, under a name of its own."""
+    client = _client_stats()
+    reader = plugins.load("layer_metrics", "client_" + name)
+    assert reader.read({"client": client}) == client[name]

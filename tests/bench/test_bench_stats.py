@@ -23,8 +23,8 @@ def test_tpot_is_the_requests_mean_gap():
     assert stats.tpot_ms(1.0, 1.0, 1) is None
 
 
-def _rec(ok=True, ttft=100.0, tpot=20.0):
-    return {"ok": ok, "ttft_ms": ttft, "tpot_ms": tpot}
+def _rec(ok=True, ttft=100.0, tpot=20.0, latency=500.0):
+    return {"ok": ok, "ttft_ms": ttft, "tpot_ms": tpot, "latency_ms": latency}
 
 
 def test_slo_share_counts_failures_as_misses():
@@ -47,6 +47,7 @@ def test_a_failed_request_stays_in_both_tails():
     assert out["ttft_p90_ms"] == 9000.0 and out["tpot_p90_ms"] == 9000.0
     assert out["ttft_p50_ms"] == 100.0 and out["tpot_p50_ms"] == 20.0
     assert out["ttft_mean_ms"] == pytest.approx((4 * 100.0 + 9000.0) / 5)
+    assert out["latency_mean_ms"] == pytest.approx((4 * 500.0 + 9000.0) / 5)
     assert out["slo_share"] == pytest.approx(80.0)
 
 
