@@ -1,0 +1,96 @@
+"""An expert layer that is told which experts it holds.
+
+The router scores every token against ALL ``num_experts`` and takes its top
+k; this chip computes the part its ``experts_held = (first, count)`` give.
+Eight such shares (and the shared expert, once) sum to the uncut layer, which
+is what an expert-parallel deployment's combine adds up; on one chip the
+exchange is simply absent. No capacity and no dropped token: the (token,
+choice) pairs are sorted by expert and run through ``jax.lax.ragged_dot`` over
+the held stack, which on the TPU visits only the row tiles and the experts
+that have rows.
+
+  s   = sigmoid(float32(h) Wr)
+  sel = top_k(s + b)                         b: selection only
+  w   = s[sel] / (sum(s[sel]) + 1e-20) * route_scale       (``route_norm``)
+  y   = Shared(h) + sum_{e in sel, e held} w_e Expert_e(h)           (SwiGLU)
+
+Weights ``w``: ``router`` [H, E] float32, ``bias`` [E] float32, ``e_gate`` /
+``e_up`` [count, H, I], ``e_down`` [count, I, H], and the shared expert's
+``s_gate`` / ``s_up`` [H, Is], ``s_down`` [Is, H].
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from kukeon_tpu.models.llama import mm
+
+
+def route(h: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, k: int, *,
+          norm: bool = True, scale: float = 1.0,
+          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """h [N, H] -> (sel [N, k] int32, w [N, k] float32). The router runs in
+    float32 at the highest precision: a selection should not turn on the
+    activation dtype or on the TPU's default single bf16 pass."""
+    logits = jnp.dot(h.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(s + bias, k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * scale
+
+
+def swiglu(h: jnp.ndarray, w_gate, w_up, w_down) -> jnp.ndarray:
+    gate = jax.nn.silu(mm(h, w_gate).astype(jnp.float32)).astype(h.dtype)
+    return mm(gate * mm(h, w_up), w_down)
+
+
+def _routed(h, w: dict, local, held, wts):
+    """The held experts' part for h [N, H]: every (token, choice) pair sorted
+    by held expert (pairs that chose an expert held elsewhere last, in no
+    group), three ragged products over the held stack, weighted, and summed
+    back per token."""
+    N, K = local.shape
+    count = w["e_gate"].shape[0]
+    flat = jnp.where(held, local, count).reshape(N * K)
+    order = jnp.argsort(flat)                       # stable: by expert
+    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    xs = jnp.take(h, order // K, axis=0)            # [N*K, H]
+    gate = jax.nn.silu(jax.lax.ragged_dot(xs, w["e_gate"], sizes)
+                       .astype(jnp.float32)).astype(h.dtype)
+    up = jax.lax.ragged_dot(xs, w["e_up"], sizes)
+    y = jax.lax.ragged_dot(gate * up, w["e_down"], sizes)
+    # Rows past the last group belong to no expert; what a kernel leaves
+    # there is not defined, so they are selected out, not multiplied out.
+    in_group = (jnp.take(flat, order) < count)[:, None]
+    y = jnp.where(in_group, y * jnp.take(wts.reshape(N * K), order)[:, None]
+                  .astype(y.dtype), 0)
+    back = jnp.argsort(order)                       # pair -> its sorted row
+    return jnp.take(y, back, axis=0).reshape(N, K, -1).sum(axis=1)
+
+
+def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
+                 experts_held: tuple[int, int], route_norm: bool = True,
+                 route_scale: float = 1.0, counted: jnp.ndarray,
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """h [..., H] -> (y [..., H], held hits int32): the shared expert once
+    plus the held experts' share of the routed sum. ``counted`` [...] bool
+    marks the tokens whose choices count as hits (real prompt tokens, active
+    slots); every token is computed either way."""
+    lead, H = h.shape[:-1], h.shape[-1]
+    x = h.reshape(-1, H)
+    first, count = experts_held
+    with jax.named_scope("router"):
+        sel, wts = route(x, w["router"], w["bias"], experts_per_token,
+                         norm=route_norm, scale=route_scale)
+        local = sel - first
+        held = (local >= 0) & (local < count)
+    with jax.named_scope("expert_layer"):
+        y = _routed(x, w, local, held, wts)
+    with jax.named_scope("shared_expert"):
+        y = y + swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
+    mask = held & counted.reshape(-1, 1)
+    return y.reshape(*lead, H), jnp.sum(mask, dtype=jnp.int32)

@@ -194,7 +194,7 @@ def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 @jax.named_scope("kv_insert")
-def _cache_insert(cache_kv: jnp.ndarray, new_kv: jnp.ndarray, offsets: jnp.ndarray) -> jnp.ndarray:
+def cache_insert(cache_kv: jnp.ndarray, new_kv: jnp.ndarray, offsets: jnp.ndarray) -> jnp.ndarray:
     """Insert [B, S, ...] at per-batch ``offsets`` into [B, S_max, ...].
 
     Unrolled over the (small, static) batch: per-row dynamic_update_slice
@@ -347,7 +347,7 @@ def _is_q(w) -> bool:
     return isinstance(w, dict) and "q" in w
 
 
-def _mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
+def mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
     """h @ w for plain or quantized weights (dequant fused into the dot).
 
     ``pallas=True`` routes int8 weights through the Pallas kernel (decode
@@ -367,7 +367,7 @@ def _mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
 
 
 @jax.named_scope("embed")
-def _embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
+def embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
     e = params["embed"]
     if _is_q(e):
         rows = jnp.take(e["q"], tokens, axis=0).astype(dtype)
@@ -393,6 +393,11 @@ def _logits(params: Params, c: LlamaConfig, x: jnp.ndarray,
             return (raw * e["s"].astype(x.dtype)).astype(jnp.float32)
         return jnp.einsum("bsh,vh->bsv", x, e).astype(jnp.float32)
     return _mm(x, params["lm_head"], pallas).astype(jnp.float32)
+
+
+# The names this file's own forward and older callers use; ``mm``, ``embed``
+# and ``cache_insert`` are what the other model families import.
+_mm, _embed, _cache_insert = mm, embed, cache_insert
 
 
 # --- Forward -----------------------------------------------------------------

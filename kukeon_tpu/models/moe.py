@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from kukeon_tpu.models import llama
-from kukeon_tpu.models.llama import KVCache, _cache_insert, _embed, _mm
+from kukeon_tpu.models.llama import KVCache, cache_insert, embed, mm
 from kukeon_tpu.ops.attention import gqa_attention
 from kukeon_tpu.ops.norms import rms_norm
 from kukeon_tpu.ops.rope import apply_rope
@@ -61,7 +61,7 @@ class MoEConfig:
     dtype: Any = jnp.bfloat16
     router_z_coef: float = 1e-3
     load_balance_coef: float = 1e-2
-    # Route quantized decode matmuls (attention trunk via llama._mm, expert
+    # Route quantized decode matmuls (attention trunk via llama.mm, expert
     # stacks via ops.int8_matmul.int8_matmul_expert) through the Pallas
     # int8 kernel — same contract as LlamaConfig.int8_pallas, same engine
     # auto-routing, XLA fallback off-TPU. Prefill always keeps XLA's
@@ -137,7 +137,7 @@ def init_params(key: jax.Array, cfg: MoEConfig) -> Params:
 def quantize_params(params: Params) -> Params:
     """bf16 MoE pytree -> int8 ({"q", "s"} leaves for every dense matrix).
 
-    Attention/embed quantize exactly like the Llama tree (llama._mm
+    Attention/embed quantize exactly like the Llama tree (llama.mm
     consumes them); expert stacks [L, E, in, out] quantize per output
     channel along the contraction axis (s: [L, E, out], applied fused in
     the expert einsums). The router stays f32 — it is tiny and routing
@@ -330,14 +330,14 @@ def _decode_forward(
     def layer_step(x, layer):
         w, ck, cv = layer
         h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-        q = _mm(h, w["wq"], pl8).reshape(B, 1, c.num_heads, c.head_dim)
-        k = _mm(h, w["wk"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        v = _mm(h, w["wv"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
+        q = mm(h, w["wq"], pl8).reshape(B, 1, c.num_heads, c.head_dim)
+        k = mm(h, w["wk"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
+        v = mm(h, w["wv"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)
 
         attn = decode_gqa_attention(q, k, v, ck, cv, offsets)
-        x = x + _mm(attn.reshape(B, 1, c.q_dim), w["wo"], pl8)
+        x = x + mm(attn.reshape(B, 1, c.q_dim), w["wo"], pl8)
 
         h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
         y, _ = moe_block(h, w, c, inference=True, pallas=pl8)
@@ -382,7 +382,7 @@ def forward_with_aux(
     c = cfg
     B, S = tokens.shape
     inference = cache is not None
-    x = _embed(params, tokens, c.dtype)
+    x = embed(params, tokens, c.dtype)
 
     if cache is not None and S == 1 and attn_impl in ("auto", "reference"):
         logits, new_cache = _decode_forward(params, c, x, positions, cache, B)
@@ -395,16 +395,16 @@ def forward_with_aux(
         x, lb_sum, z_sum = carry
         w, layer_cache = layer
         h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-        q = _mm(h, w["wq"]).reshape(B, S, c.num_heads, c.head_dim)
-        k = _mm(h, w["wk"]).reshape(B, S, c.num_kv_heads, c.head_dim)
-        v = _mm(h, w["wv"]).reshape(B, S, c.num_kv_heads, c.head_dim)
+        q = mm(h, w["wq"]).reshape(B, S, c.num_heads, c.head_dim)
+        k = mm(h, w["wk"]).reshape(B, S, c.num_kv_heads, c.head_dim)
+        v = mm(h, w["wv"]).reshape(B, S, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)
 
         if layer_cache is not None:
             ck, cv = layer_cache
-            ck = _cache_insert(ck, k, offsets)
-            cv = _cache_insert(cv, v, offsets)
+            ck = cache_insert(ck, k, offsets)
+            cv = cache_insert(cv, v, offsets)
             kv_positions = jnp.broadcast_to(
                 jnp.arange(ck.shape[1], dtype=jnp.int32)[None, :], (B, ck.shape[1])
             )
@@ -421,7 +421,7 @@ def forward_with_aux(
             )
             new_layer_cache = None
 
-        x = x + _mm(attn.reshape(B, S, c.q_dim), w["wo"])
+        x = x + mm(attn.reshape(B, S, c.q_dim), w["wo"])
 
         h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
         y, aux = moe_block(h, w, c, inference=inference)

@@ -17,13 +17,17 @@ do a step's work; what it spends outside them is phase ``other``.
 | ``cell.generate`` | request | - (above the engine) |
 | ``engine.step`` | | ``other`` = its time less its children |
 | ``engine.admit`` | free, queued | ``admit`` |
-| ``engine.prefill_dispatch`` | request, slot, program, hit, cached, real, padded | - (inside ``admit``) |
-| ``engine.decode_dispatch`` | k, active, live_rows | ``decode_dispatch`` |
+| ``engine.prefill_dispatch`` | request, slot, program, hit, cached, real, padded[, <kind>_rows] | - (inside ``admit``) |
+| ``engine.decode_dispatch`` | k, active, live_rows[, <kind>_rows] | ``decode_dispatch`` |
 | ``engine.fetch_first`` | n | ``fetch_first`` |
 | ``engine.fetch_chunk`` | k | ``fetch_chunk`` |
 | ``engine.emit`` | tokens | ``emit`` |
 | ``engine.first_token`` | request | - (inside ``emit``) |
 | ``engine.idle_wait`` | | ``idle_wait`` |
+
+``<kind>_rows`` (``window_rows``, ``full_rows``) are on the spans of a family
+whose layers hold several kinds of state (``models/kv_kinds.py``): the rows the
+dispatched slots hold in one layer of each kind.
 
 ``request`` is the request's trace id (``req.trace.trace_id``), the identifier
 ``/v1/trace`` and ``/v1/timeline`` already use.

@@ -118,6 +118,7 @@ def decode_gqa_attention(
     lengths: jnp.ndarray,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    valid: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-token decode attention against a cache, append-free.
 
@@ -136,7 +137,10 @@ def decode_gqa_attention(
 
     Args:
       q: [B, 1, H, D]; k_new, v_new: [B, 1, KV, D] (always full precision);
-      cache_k, cache_v: [B, S, KV, D]; lengths: [B] valid cache slots.
+      cache_k, cache_v: [B, S, KV, D]; lengths: [B] valid cache slots;
+      valid: optional [B, S] bool, the rows to attend in place of
+        ``row < lengths`` (a ring that holds a window's rows: every written
+        row but the one the new token is about to take).
 
     Returns: [B, 1, H, D].
     """
@@ -158,7 +162,10 @@ def decode_gqa_attention(
     ) * scale
     if k_scale is not None:
         s_cache = s_cache * k_scale.transpose(0, 2, 1)[:, :, None, :]
-    valid = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
+    if valid is None:
+        valid = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
+    else:
+        valid = valid[:, None, None, :]
     s_cache = jnp.where(valid, s_cache, NEG_INF)
     s_self = jnp.einsum(
         "bkgd,bkd->bkg", qg, k_new.reshape(B, KV, D),

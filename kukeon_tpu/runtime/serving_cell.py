@@ -402,11 +402,14 @@ def _device_census() -> dict:
     }
 
 
+# A model's family is the type of the config it registers
+# (models/families.py). This set marked the second family before that and
+# decides nothing now; launchers written against it still add to it.
 MOE_MODELS = set()
 
 
 def _register_models():
-    from kukeon_tpu.models import bert, llama, moe
+    from kukeon_tpu.models import bert, llama, moe, window_moe
 
     MODELS.update({
         "tiny": llama.llama_tiny,
@@ -414,6 +417,7 @@ def _register_models():
         "llama3-8b": llama.llama3_8b,
         "mixtral-tiny": moe.moe_tiny,
         "mixtral-8x7b": moe.mixtral_8x7b,
+        "window-moe-tiny": window_moe.window_moe_tiny,
     })
     MOE_MODELS.update({"mixtral-tiny", "mixtral-8x7b"})
     EMBEDDING_MODELS.update({
@@ -444,7 +448,6 @@ class ServingCell(LifecycleMixin):
 
         enable_compilation_cache()
 
-        from kukeon_tpu.models import llama
         from kukeon_tpu.parallel import auto_mesh_shape, make_mesh, serving_mesh
         from kukeon_tpu.serving import ServingEngine
 
@@ -483,57 +486,29 @@ class ServingCell(LifecycleMixin):
             shape = auto_mesh_shape(n)
             mesh = make_mesh(data=shape["data"], tensor=shape["tensor"])
 
-        forward_fn = None
-        param_specs = None
-        if model in MOE_MODELS:
-            # MoE family: same engine, moe forward + expert-aware specs.
-            # int8-KV is a llama-decode-path feature the MoE forward doesn't
-            # have yet — fail loudly rather than serving garbage; an
-            # unspecified flag pins False so a tuning profile can never
-            # switch it on behind the guard.
-            if kv_cache_int8:
-                raise SystemExit(
-                    f"model {model!r} does not support --kv-cache-int8 yet"
-                )
+        # The family of the config says how to boot it, which forward the
+        # engine jits and what a slot's cache holds (models/families.py); a
+        # cell that asks a family for what it lacks ends here, loudly. An
+        # unspecified lever the family lacks is pinned off, so a tuning
+        # profile can never switch it on behind the guard.
+        from kukeon_tpu.models import families
+
+        family = families.of(cfg)
+        families.refuse(family, model, {
+            families.INT8_WEIGHTS: quantize,
+            families.INT8_KV: bool(kv_cache_int8),
+            families.PAGED: bool(kv_page_tokens),
+            families.MESH: mesh.size > 1,
+            families.CHECKPOINT: bool(checkpoint)})
+        if families.INT8_KV not in family.supports:
             kv_cache_int8 = False
-            from kukeon_tpu.models import hf_convert, moe
-            from kukeon_tpu.parallel import moe_specs_for_params
-
-            if checkpoint:
-                params, cfg = hf_convert.load_moe_params(
-                    checkpoint, dtype=cfg.dtype
-                )
-                if max_seq_len:
-                    cfg = dataclasses.replace(cfg, max_seq_len=max_seq_len)
-                if quantize:
-                    # Weights-only int8 (router/norms stay high precision);
-                    # dequant fuses into attention _mm and expert einsums.
-                    params = moe.quantize_params(params)
-            elif quantize:
-                # Random-init directly in int8 on the host: a mixtral-8x7b
-                # bf16 tree (~93 GB) cannot be materialized on-device just
-                # to be quantized.
-                params = moe.init_quantized_params_host(cfg, seed)
-            else:
-                params = moe.init_params(jax.random.key(seed), cfg)
-            forward_fn = moe.forward
-            param_specs = moe_specs_for_params(params)
-        elif checkpoint:
-            params, cfg = self._load_checkpoint(checkpoint, cfg, quantize)
-        elif quantize:
-            # Random-init directly in int8 on the device(s), every leaf
-            # born in its serving sharding: an 8B bf16 tree (~16 GB)
-            # cannot be materialized on a 16 GB chip just to be quantized
-            # (models/llama.py init_quantized_params).
-            from kukeon_tpu.parallel import sharding as shd
-
-            key = jax.random.key(seed)
-            abstract = jax.eval_shape(
-                lambda k: llama.init_quantized_params(k, cfg), key)
-            params = llama.init_quantized_params(
-                key, cfg, shd.param_shardings(abstract, mesh))
+        if families.PAGED not in family.supports:
+            kv_page_tokens = 0
+        if checkpoint:
+            params, cfg = family.load_checkpoint(
+                checkpoint, cfg, quantize, max_seq_len)
         else:
-            params = llama.init_params(jax.random.key(seed), cfg)
+            params = family.init_params(cfg, seed, quantize, mesh)
 
         self.model_name = model
         self.cfg = cfg
@@ -561,7 +536,6 @@ class ServingCell(LifecycleMixin):
             cfg, params, mesh, num_slots=num_slots,
             max_seq_len=max_seq_len or min(cfg.max_seq_len, 4096),
             kv_cache_int8=kv_cache_int8, async_load=True,
-            forward_fn=forward_fn, param_specs=param_specs,
             decode_chunk=decode_chunk, model_name=model,
             kv_page_tokens=kv_page_tokens,
             max_pending=max_pending, registry=registry,

@@ -64,7 +64,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kukeon_tpu import faults, sanitize
-from kukeon_tpu.models import llama
+from kukeon_tpu.models import families, kv_kinds, llama
 from kukeon_tpu.serving.kv_pages import (
     SCRATCH_PAGE,
     PageAllocator,
@@ -155,7 +155,9 @@ class DecodeState:
 
     # Contiguous: k/v HELD [L, B, KV, S_max, D] (_kv_major: the decode scan's
     # own layout), scales [L, B, S_max, KV]; paged: the pool [L, P, pt, KV, D].
-    cache: llama.KVCache          # + lengths [B]
+    # A family whose layers are of several kinds: kv_kinds.LayeredKV, one
+    # held stack a kind (a ring of a window's rows beside full stacks).
+    cache: "llama.KVCache | kv_kinds.LayeredKV"     # + lengths [B]
     tokens: jnp.ndarray           # [B] int32 — last emitted token per slot
     active: jnp.ndarray           # [B] bool — slot currently generating
 
@@ -301,12 +303,17 @@ class ServingEngine:
         kv_pool_pages: int | None = None,
         kv_shard: bool | None = None,
     ):
-        # Model pluggability: any forward with llama.forward's signature
+        # Model pluggability (models/families.py): a family is its config's
+        # type. Most serve through one forward with llama.forward's signature
         # ((params, cfg, tokens, positions, cache) -> (logits, cache')) and
-        # the shared KVCache layout serves through this engine —
-        # models/moe.py is the second family. ``param_specs`` supplies the
-        # matching PartitionSpec tree (default: the Llama specs).
-        self._forward = forward_fn or llama.forward
+        # the shared KVCache layout — models/moe.py is the second; one whose
+        # layers hold different kinds of state brings ``layered`` instead.
+        # ``forward_fn`` / ``param_specs`` override the record's (a caller
+        # that builds the engine from shapes passes them itself).
+        self.family = families.find(cfg)
+        self._layered = self.family.layered if self.family else None
+        self._forward = forward_fn or (
+            self.family.forward if self.family else None) or llama.forward
         self._param_specs = param_specs
         # Streamed checkpoint boot (models/checkpoints.CheckpointStream,
         # duck-typed on .abstract_params): the constructor sees only the
@@ -318,6 +325,9 @@ class ServingEngine:
                              else None)
         ptree = (self._ckpt_stream.abstract_params
                  if self._ckpt_stream is not None else params)
+        if (self._param_specs is None and self.family is not None
+                and self.family.param_specs is not None):
+            self._param_specs = self.family.param_specs(ptree)
         # Forwards that accept ``logit_positions`` let prefill compute the
         # LM head at ONE position instead of all S bucket rows — at 8B
         # shapes that removes a [S, 128k] f32 logits tensor (and its S×H×V
@@ -390,11 +400,12 @@ class ServingEngine:
             # a pallas-enabled cfg on a multi-chip mesh (per-layer weight
             # all-gathers), not just decline to set it.
             int8_pallas = (
-                (cfg.int8_pallas or env_wants)
+                (getattr(cfg, "int8_pallas", False) or env_wants)
                 and mesh is not None
                 and mesh.size == 1
             )
-        if cfg.int8_pallas != int8_pallas:
+        # (a family with no int8 path has no such lever on its config)
+        if getattr(cfg, "int8_pallas", int8_pallas) != int8_pallas:
             cfg = dataclasses.replace(cfg, int8_pallas=int8_pallas)
         self.cfg = cfg
         self.mesh = mesh
@@ -417,6 +428,17 @@ class ServingEngine:
         self.page_tokens = int(kv_page_tokens or 0)
         self.paged = self.page_tokens > 0
         self._pool: PageAllocator | None = None
+        # A layered family's kinds of per-layer state, as data; the dense
+        # path's one kind (every layer, S_max rows) only labels the gauge.
+        self._kinds: tuple[kv_kinds.CacheKind, ...] = (
+            self._layered.kinds(cfg, self.max_seq_len) if self._layered
+            else (kv_kinds.CacheKind("full", tuple(range(cfg.num_layers)),
+                                     self.max_seq_len),))
+        if self._layered and (self.paged or kv_cache_int8
+                              or (mesh is not None and mesh.size > 1)):
+            raise ValueError(
+                f"family {self.family.name!r} serves from a contiguous bf16 "
+                "cache on one chip: no paged KV, int8 KV or sharded mesh yet")
         if self.paged:
             pt = self.page_tokens
             if self.max_seq_len % pt:
@@ -582,6 +604,13 @@ class ServingEngine:
         self._m_steps = reg.counter(
             "kukeon_engine_steps_total",
             "Engine-loop steps that did work.")
+        # What a layered family's forwards sum on the device (an expert
+        # layer's routed choices and those that chose a held expert): they
+        # come back in the fetches of first tokens and chunk blocks.
+        self._counted = tuple(
+            reg.counter(name, "Summed on the device by the model's forwards "
+                        "over real prompt tokens and active slots.")
+            for name in (self._layered.counters if self._layered else ()))
         # The loop's spans (on the profiler's clock while /v1/profile
         # captures) and its wall time by phase (obs/spans.py).
         self.spans = LoopSpans(reg)
@@ -685,7 +714,10 @@ class ServingEngine:
         from collections import OrderedDict
 
         self._prefix_cache: "OrderedDict[str, _CachedPrefix]" = OrderedDict()
-        self._prefix_cache_size = max(0, prefix_cache_size)
+        # A layered family has no prefix store (a ring does not hold a
+        # prefix's rows): a prefixId is a counted miss and stores nothing.
+        self._prefix_cache_size = (0 if self._layered
+                                   else max(0, prefix_cache_size))
         self._prefix_cache_bytes = max(0, prefix_cache_bytes)
         self.prefix_hits = 0
         self.prefix_misses = 0
@@ -698,7 +730,7 @@ class ServingEngine:
         """(k/v sharding, scale sharding) for the decode cache."""
         spec = shd.kv_cache_spec()
         tensor_size = self.mesh.shape.get(shd.AXIS_TENSOR, 1)
-        if (self.kv_shard is False
+        if (self.kv_shard is False or self._layered
                 or self.cfg.num_kv_heads % max(tensor_size, 1)):
             # Replicate the cache when the tuner says so or when the KV
             # heads don't divide the tensor axis (correct, just more HBM)
@@ -716,13 +748,17 @@ class ServingEngine:
         flags — is replicated, because the host block table / slot map is
         the source of truth and every chip must see all of it."""
         kv_sh, sc_sh = self._cache_shardings()
+        repl = NamedSharding(self.mesh, PartitionSpec())
+        if self._layered:
+            return DecodeState(
+                cache=jax.tree.map(lambda _: repl, self._cache_shapes()),
+                tokens=repl, active=repl)
         if not self.paged and len(kv_sh.spec):
             # The contiguous state holds k/v KV-major (_kv_major): the spec's
             # kv-head entry moves with its axis, from position 3 to 2.
             layer, slot, row, head, dim = kv_sh.spec
             kv_sh = NamedSharding(
                 self.mesh, PartitionSpec(layer, slot, head, row, dim))
-        repl = NamedSharding(self.mesh, PartitionSpec())
         cache = llama.KVCache(
             k=kv_sh, v=kv_sh, lengths=repl,
             k_scale=sc_sh if self.kv_cache_int8 else None,
@@ -732,6 +768,10 @@ class ServingEngine:
 
     def _cache_shapes(self) -> llama.KVCache:
         """ShapeDtypeStructs of the cache as the state HOLDS it."""
+        if self._layered:
+            return kv_kinds.shapes(self._kinds, self.num_slots,
+                                   self.cfg.num_kv_heads, self.cfg.head_dim,
+                                   self.cfg.dtype)
         if self.paged:
             # Pool layout: page axis where the legacy cache has its slot
             # axis ([L, P, page_tokens, KV, D]); lengths stay per-SLOT [B]
@@ -761,6 +801,11 @@ class ServingEngine:
         # Zeros built in the held shape: one cache live at boot, never two.
         cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), self._cache_shapes())
+        if self._layered:
+            return DecodeState(
+                cache=jax.device_put(cache, self._state_shardings().cache),
+                tokens=jnp.zeros((self.num_slots,), jnp.int32),
+                active=jnp.zeros((self.num_slots,), bool))
         sc_sharding = self._cache_shardings()[1]
         kv_sharding = self._state_shardings().cache.k
         cache = llama.KVCache(
@@ -778,7 +823,80 @@ class ServingEngine:
             active=jnp.zeros((self.num_slots,), bool),
         )
 
+    def _build_layered_programs(self):
+        """prefill / insert / decode_chunk of a family whose layers hold
+        several kinds of state, under the names and call signatures of the
+        dense ones, so that every dispatch path, ``precompile`` and
+        ``warmup`` serve both. The family's forwards return device-summed
+        counters beside their logits; they ride in the array the host
+        already fetches: a prefill's ``first`` is [token, *counters], a
+        chunk's block [B + len(counters), K] (a counter's row a step)."""
+        cfg, kinds, model = self.cfg, self._kinds, self._layered
+
+        def prefill(params, tokens, length, key, temp, top_k, top_p):
+            last, kv_k, kv_v, counted = model.prefill(
+                params, cfg, tokens, length)
+            first = sample_per_slot(
+                last[None, :], key, temp[None], top_k[None], top_p[None])[0]
+            return jnp.concatenate([first[None], counted]), kv_k, kv_v
+
+        def insert(state: DecodeState, kv_k, kv_v, length, slot, token):
+            token = jnp.reshape(token, (-1,))[0]
+            return DecodeState(
+                cache=kv_kinds.insert(state.cache, kinds, kv_k, kv_v,
+                                      length, slot),
+                tokens=state.tokens.at[slot].set(token),
+                active=state.active.at[slot].set(True))
+
+        def decode_chunk_fn(params, state: DecodeState, key, temps, top_ks,
+                            top_ps, n_steps):
+            def body(carry, _):
+                state, key = carry
+                logits, new_k, new_v, counted = model.decode(
+                    params, cfg, state.tokens, state.cache, kinds,
+                    state.active)
+                # appends at the slots' lengths; inactive slots' lengths stay
+                cache = kv_kinds.append(state.cache, kinds, new_k, new_v,
+                                        state.active)
+                key, k1 = jax.random.split(key)
+                next_tokens = sample_per_slot(logits, k1, temps, top_ks,
+                                              top_ps)
+                next_tokens = jnp.where(state.active, next_tokens,
+                                        state.tokens)
+                return (DecodeState(cache=cache, tokens=next_tokens,
+                                    active=state.active), key), (
+                    next_tokens, counted)
+
+            def swapped(state):     # held KV-major <-> the row-major view
+                return dataclasses.replace(
+                    state, cache=kv_kinds.view(state.cache))
+
+            (state, _), (toks, counted) = jax.lax.scan(
+                body, (swapped(state), key), length=n_steps)
+            return swapped(state), jnp.concatenate([toks.T, counted.T])
+
+        ct, tm = self.compiles, self.timers
+        p_sh, st_sh = self._shardings, self._state_shardings()
+        repl = NamedSharding(self.mesh, PartitionSpec())
+        self._prefill = ct.wrap(jax.jit(
+            prefill,
+            in_shardings=(p_sh, repl, repl, repl, repl, repl, repl),
+            out_shardings=(repl, repl, repl),
+        ), "prefill", timer=tm.track("prefill"))
+        self._insert = ct.wrap(jax.jit(
+            insert, donate_argnums=(0,),
+            in_shardings=(st_sh, repl, repl, repl, repl, repl),
+            out_shardings=st_sh,
+        ), "insert", timer=tm.track("insert"))
+        self._decode_chunk = ct.wrap(jax.jit(
+            decode_chunk_fn, static_argnums=(6,), donate_argnums=(1,),
+            in_shardings=(p_sh, st_sh, repl, repl, repl, repl),
+            out_shardings=(st_sh, repl),
+        ), "decode", timer=tm.track("decode_chunk"))
+
     def _build_programs(self):
+        if self._layered:
+            return self._build_layered_programs()
         cfg = self.cfg
         fwd = self._forward
         last_pos_ok = self._fwd_logit_positions
@@ -1220,6 +1338,13 @@ class ServingEngine:
         yield ("kukeon_engine_decode_chunks_total", "counter",
                "Dispatched multi-step decode chunks.",
                [({}, float(s["chunks"]))])
+        lens = [n for n, req in zip(self._slot_len, self._slot_req)
+                if req is not None]
+        yield ("kukeon_engine_kv_rows", "gauge",
+               "Rows the seated slots hold in one layer of each kind (a "
+               "window layer's ring holds at most its window).",
+               [({"kind": kd.name}, float(sum(kd.live(n) for n in lens)))
+                for kd in self._kinds])
         # Streamed-checkpoint boot pipeline accounting: per-stage wall time
         # (stages OVERLAP — their sum exceeds the load's wall clock by
         # design) and bytes moved. All-zero on a non-streamed boot.
@@ -1282,13 +1407,19 @@ class ServingEngine:
     def _abstract_state(self) -> DecodeState:
         """ShapeDtypeStruct mirror of _init_state (no device bytes)."""
         shapes = self._cache_shapes()
-        sc_sh = self._cache_shardings()[1]
-        kv_sh = self._state_shardings().cache.k
         repl = NamedSharding(self.mesh, PartitionSpec())
 
         def sds(x, sh):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
 
+        B = self.num_slots
+        if self._layered:
+            return DecodeState(
+                cache=jax.tree.map(lambda x: sds(x, repl), shapes),
+                tokens=jax.ShapeDtypeStruct((B,), jnp.int32, sharding=repl),
+                active=jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=repl))
+        sc_sh = self._cache_shardings()[1]
+        kv_sh = self._state_shardings().cache.k
         cache = llama.KVCache(
             k=sds(shapes.k, kv_sh), v=sds(shapes.v, kv_sh),
             lengths=sds(shapes.lengths, repl),
@@ -1297,7 +1428,6 @@ class ServingEngine:
             v_scale=(sds(shapes.v_scale, sc_sh)
                      if shapes.v_scale is not None else None),
         )
-        B = self.num_slots
         return DecodeState(
             cache=cache,
             tokens=jax.ShapeDtypeStruct((B,), jnp.int32, sharding=repl),
@@ -1347,7 +1477,9 @@ class ServingEngine:
                     self.timers.note_cost("insert_paged", compiled)
                 else:
                     compiled = self._insert.lower(
-                        astate, kv, kv, L // 2, 0, jnp.int32(1),
+                        astate, kv, kv, L // 2, 0,
+                        jnp.zeros((1 + len(self._counted),), jnp.int32)
+                        if self._counted else jnp.int32(1),
                     ).compile()
                     self.timers.note_cost("insert", compiled)
             chunk_sizes = {1, 4}
@@ -1391,6 +1523,10 @@ class ServingEngine:
             )
         if export and kv_import is not None:
             raise ValueError("a request cannot both export and import KV")
+        if self._layered and (export or kv_import is not None):
+            raise ValueError(
+                f"family {self.family.name!r} has no KV handoff yet: its "
+                "window layers hold a ring, not the exported block's rows")
         if kv_import is not None and int(kv_import["length"]) != prompt.size:
             raise ValueError(
                 f"kv_import length {kv_import['length']} != prompt length "
@@ -1899,6 +2035,10 @@ class ServingEngine:
                 with self.spans.span("engine.fetch_first", n=len(prefills)), \
                         jax.set_mesh(self.mesh):
                     firsts = self._fetch(jnp.stack([f for _, f in prefills]))
+                if self._counted:
+                    # a row: [token, *device-summed counters]
+                    self._count_device_sums(firsts[:, 1:].sum(axis=0))
+                    firsts = firsts[:, 0]
                 with self.spans.span("engine.emit", tokens=len(prefills)):
                     for (req, _), first in zip(prefills, firsts):
                         self._emit(req, int(first))
@@ -2077,6 +2217,15 @@ class ServingEngine:
             self._pool.unref(e.pages)
         return self._pool.free >= need
 
+    def _rows_by_kind(self, lengths) -> dict[str, int]:
+        """``<kind>_rows``: the rows slots of these token counts hold in a
+        layer of each kind (a layered family's spans carry them; the dense
+        path's one kind is ``live_rows`` already)."""
+        if not self._layered:
+            return {}
+        return {f"{kd.name}_rows": sum(kd.live(n) for n in lengths)
+                for kd in self._kinds}
+
     def _prefill_span(self, req: Request, slot: int):
         """The span of one request's prefill dispatch (slot -1: an export,
         which takes none); ``_prefill_dispatched`` gives it its counts."""
@@ -2095,7 +2244,7 @@ class ServingEngine:
             self._m_prefill_tokens.inc(n, kind=kind)
         span.set(program="prefill_ext" if cached else "prefill",
                  hit=int(cached > 0), cached=cached, real=real,
-                 padded=padded)
+                 padded=padded, **self._rows_by_kind([cached + real]))
         if req.trace is not None:
             req.trace.event("prefill_dispatched")
 
@@ -2578,8 +2727,9 @@ class ServingEngine:
             if not self._active_requests():
                 return None      # pressure handling drained the batch
         active = self._active_requests()
-        span.set(k=k, active=len(active),
-                 live_rows=sum(self._slot_len[slot] for slot, _req in active))
+        lens = [self._slot_len[slot] for slot, _req in active]
+        span.set(k=k, active=len(active), live_rows=sum(lens),
+                 **self._rows_by_kind(lens))
         temps_d, top_ks_d, top_ps_d = self._sampling_dev_arrays()
         with jax.set_mesh(self.mesh):
             self._key, k1 = jax.random.split(self._key)
@@ -2614,6 +2764,8 @@ class ServingEngine:
         chunk = self._inflight
         with self.spans.span("engine.fetch_chunk", k=chunk.k):
             toks = self._fetch(chunk.tokens)  # [B, K] — single transfer per chunk
+        if self._counted:   # rows past the slots: a counter each, by step
+            self._count_device_sums(toks[self.num_slots:].sum(axis=1))
         with self.spans.span("engine.emit") as span:
             emitted0 = self._step_tokens
             for slot, req in chunk.slots:
@@ -2630,6 +2782,10 @@ class ServingEngine:
                 else:
                     self._slot_len[slot] = base + chunk.k
             span.set(tokens=self._step_tokens - emitted0)
+
+    def _count_device_sums(self, sums) -> None:
+        for counter, n in zip(self._counted, sums):
+            counter.inc(int(n))
 
     def _emit(self, req: Request, token: int):
         now = time.monotonic()

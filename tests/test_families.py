@@ -1,0 +1,123 @@
+"""models/families.py: one record a family, and the dense family's programs
+unchanged by the seam that lets a third family in."""
+
+from __future__ import annotations
+
+import hashlib
+
+import jax
+import numpy as np
+import pytest
+
+from kukeon_tpu.models import llama, moe
+from kukeon_tpu.parallel import make_mesh, moe_specs_for_params
+from kukeon_tpu.serving import ServingEngine
+
+# sha256 of each program's lowered text at the parent of PR 30 (commit
+# f58059e), taken by running THIS function against that tree. JAX's
+# compile-cache key is made of this text (locations stripped), so equal text
+# means the driver's machine finds the parent's executables again: set-up does
+# not move and no cell of the dense family can slow. A PR that changes a dense
+# program on purpose takes new hashes from its own tree.
+PARENT = {
+    "dense.prefill":
+        "d43f0d082864daf2b08f8c81884954f77f21a2c29940a4ecad3fe20849e0bcaa",
+    "dense.prefill_ext":
+        "8bc0cf81c13747e80bc25e189fb99ea05472698e9b76f745229b991a999a51b5",
+    "dense.insert":
+        "10856345330b648a26a4eed787f437a3c7c3a254284a22f36fe32a049a49b761",
+    "dense.decode_chunk":
+        "3e4312cb6790672b3c9e2713ef15de6d4d2653861b01c6e97782c3d3c556648f",
+    "dense.insert_paged":
+        "33a5e37d00fe4fe26ca25dddf825aff5b211225d39a4e051d65a4cc54faf8bde",
+    "dense.decode_chunk_paged":
+        "fdb5e034981d2ea5f6cd2e6dcf84f623930fee3347d99349bbc9014b65792bde",
+    "moe.prefill":
+        "90cc96c2f17fd443b1a5717fa0ecb43eef2d6214dbb9e62fadcff82c254aeb32",
+    "moe.decode_chunk":
+        "7ea03ef596f689b93005455a21cca5e512c712f3bf9134333c966890e712f937",
+}
+
+
+def _lowered(name: str, eng: ServingEngine) -> dict:
+    cfg, B = eng.cfg, eng.num_slots
+    eng._ensure_loaded()
+    key = jax.random.key(1)
+    f32, i32 = np.float32, np.int32
+    kv = np.zeros((cfg.num_layers, 1, 64, cfg.num_kv_heads, cfg.head_dim),
+                  np.dtype(cfg.dtype))
+    sample = (key, np.zeros(B, f32), np.zeros(B, i32), np.ones(B, f32))
+    tokens = np.zeros((1, 64), i32)
+    with jax.set_mesh(eng.mesh):
+        if eng.paged:
+            ids = np.zeros((64 // eng.page_tokens,), i32)
+            bt = np.zeros((B, eng.max_pages_per_slot), i32)
+            return {
+                name + ".insert_paged": eng._insert_paged.lower(
+                    eng.state, kv, kv, 5, ids, 0, i32(1)),
+                name + ".decode_chunk_paged": eng._decode_chunk_paged.lower(
+                    eng.params, eng.state, bt, *sample, 4)}
+        return {
+            name + ".prefill": eng._prefill.lower(
+                eng.params, tokens, 5, key, f32(0), i32(0), f32(1)),
+            name + ".prefill_ext": eng._prefill_ext.lower(
+                eng.params, kv, kv, 5, tokens, 3, key, f32(0), i32(0), f32(1)),
+            name + ".insert": eng._insert.lower(
+                eng.state, kv, kv, 5, 0, i32(1)),
+            name + ".decode_chunk": eng._decode_chunk.lower(
+                eng.params, eng.state, *sample, 4)}
+
+
+def lowered_texts() -> dict[str, str]:
+    """The engine's jitted programs for the two dense-cache families, lowered
+    at a tiny size: the dense family as the benchmark's cells boot it
+    (checkpoint-less weights-only int8, contiguous bf16 KV), its paged int8-KV
+    variants, and the Mixtral block."""
+    mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
+    shape = dict(num_slots=2, max_seq_len=128, decode_chunk=4)
+    cfg = llama.llama_tiny()
+    params = llama.init_quantized_params(jax.random.key(0), cfg)
+    mcfg = moe.moe_tiny()
+    mparams = moe.init_params(jax.random.key(0), mcfg)
+    out = {
+        **_lowered("dense", ServingEngine(cfg, params, mesh, **shape)),
+        **_lowered("dense", ServingEngine(
+            cfg, params, mesh, kv_page_tokens=16, kv_cache_int8=True,
+            **shape)),
+        **_lowered("moe", ServingEngine(
+            mcfg, mparams, mesh, forward_fn=moe.forward,
+            param_specs=moe_specs_for_params(mparams), **shape))}
+    return {k: out[k].as_text() for k in PARENT}
+
+
+def test_the_dense_families_programs_lower_to_the_parents_text():
+    got = {k: hashlib.sha256(v.encode()).hexdigest()
+           for k, v in lowered_texts().items()}
+    assert got == PARENT
+
+
+def test_a_family_is_its_configs_type_and_one_record():
+    from kukeon_tpu.models import families, window_moe
+
+    dense = families.of(llama.llama_tiny())
+    assert dense.name == "dense_gqa" and dense.layered is None
+    assert dense is families.of(llama.llama3_8b())
+    assert families.of(moe.moe_tiny()).name == "moe_softmax_topk"
+    layered = families.of(window_moe.window_moe_tiny())
+    assert layered.layered.counters == window_moe.COUNTERS
+    assert families.find(object()) is None
+    with pytest.raises(SystemExit, match="no model family"):
+        families.of(object())
+
+
+def test_the_engine_finds_the_family_of_a_config_by_itself():
+    """Callers that build the engine from a config alone (the benchmark's
+    compile rehearsal, the fixtures of a second family) pass no family."""
+    mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
+    mcfg = moe.moe_tiny()
+    eng = ServingEngine(mcfg, moe.init_params(jax.random.key(0), mcfg), mesh,
+                        num_slots=2, max_seq_len=64)
+    assert eng.family.name == "moe_softmax_topk"
+    assert eng._forward is moe.forward
+    assert [k.name for k in eng._kinds] == ["full"]
+    assert eng.generate([1, 2, 3]) is not None

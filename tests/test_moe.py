@@ -363,7 +363,7 @@ def test_quantized_moe_serving_cell():
 
 
 def test_int8_pallas_moe_decode_parity(tiny):
-    """MoE fused int8 decode (attention trunk via llama._mm, expert stacks
+    """MoE fused int8 decode (attention trunk via llama.mm, expert stacks
     via int8_matmul_expert) must match the dequant-in-einsum path
     numerically — the ISSUE 1 parity criterion for the MoE family."""
     import dataclasses
