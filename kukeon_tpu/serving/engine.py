@@ -604,6 +604,10 @@ class ServingEngine:
         self._m_steps = reg.counter(
             "kukeon_engine_steps_total",
             "Engine-loop steps that did work.")
+        self._m_chunks = reg.counter(
+            "kukeon_engine_decode_chunks_total",
+            "Dispatched decode chunks, by their length in steps.",
+            labels=("k",))
         # What a layered family's forwards sum on the device (an expert
         # layer's routed choices and those that chose a held expert): they
         # come back in the fetches of first tokens and chunk blocks.
@@ -1335,9 +1339,6 @@ class ServingEngine:
                "Wall time spent blocked in host<->device transfers.",
                [({"kind": "fetch"}, float(s["fetch_s"])),
                 ({"kind": "upload"}, float(s["upload_s"]))])
-        yield ("kukeon_engine_decode_chunks_total", "counter",
-               "Dispatched multi-step decode chunks.",
-               [({}, float(s["chunks"]))])
         lens = [n for n, req in zip(self._slot_len, self._slot_req)
                 if req is not None]
         yield ("kukeon_engine_kv_rows", "gauge",
@@ -1638,7 +1639,7 @@ class ServingEngine:
         while not req.done.is_set():
             self.step()
         # Every chunk size _chunk_size can produce: powers of 4 up to
-        # decode_chunk, plus the pending-queue clamp value.
+        # decode_chunk, plus the 4 it takes while a slot is free.
         chunk_sizes = {1, 4}
         size = 1
         while size * 4 <= self.decode_chunk:
@@ -1938,7 +1939,10 @@ class ServingEngine:
 
           1. dispatch prefill+insert for every free slot with a waiting
              request (device work queued, nothing fetched yet);
-          2. dispatch the next decode chunk for the active slots;
+          2. dispatch the next decode chunk for the active slots: 4 steps
+             while a slot is free (whoever arrives meanwhile is seated when
+             it ends, and step 3's tokens wait behind it on the device),
+             decode_chunk steps once every slot is seated (_chunk_size);
           3. fetch + emit the prefills' first tokens (overlaps 2's compute);
           4. fetch + emit the PREVIOUS chunk's tokens (double buffering —
              the block for the chunk dispatched in 2 lands next step).
@@ -2569,7 +2573,8 @@ class ServingEngine:
         return None
 
     def _chunk_size(self) -> int:
-        """Largest safe K, bounded by decode_chunk and cache capacity.
+        """Steps of the next decode chunk: decode_chunk with every slot
+        seated, 4 while one is free, never past the cache's capacity.
 
         A request's max_new_tokens budget deliberately does NOT bound K:
         overshooting a finishing request wastes a few decode steps but keeps
@@ -2577,13 +2582,14 @@ class ServingEngine:
         by the next insert, so the overshoot KV is never observed).
         """
         k = self.decode_chunk
-        # New requests should not wait for a long chunk to finish — but
-        # only when a free slot could actually seat one: with the batch
-        # full, the waiting request can't be admitted until someone
-        # finishes anyway, and short chunks would just multiply the
-        # per-chunk overhead (dispatch, and the paged layout's per-chunk
-        # gather/scatter) without buying any admission latency.
-        if (not self._pending.empty() or self._resume) and self._free_slots():
+        # While a slot is free a request that arrives during this chunk can
+        # be seated at its end, and a prefill dispatched this step gets its
+        # first token after it: keep the chunk short. The queue is no sign
+        # of that (step() has just emptied it into every free slot). With
+        # every slot seated nobody can be admitted until someone finishes,
+        # and short chunks would only multiply the per-chunk cost (dispatch,
+        # whole-weight copies, the paged layout's gather/scatter).
+        if self._free_slots():
             k = min(k, 4)
         # Capacity must count the un-flushed inflight chunk: the device cache
         # is already k_inflight steps ahead of the host's _slot_len.
@@ -2747,6 +2753,7 @@ class ServingEngine:
                     temps_d, top_ks_d, top_ps_d, k,
                 )
         self.sync_stats["chunks"] += 1
+        self._m_chunks.inc(k=k)
         self.timers.note_tokens(
             "decode_chunk_paged" if self.paged else "decode_chunk",
             len(self._active_requests()) * k)
