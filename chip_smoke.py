@@ -497,11 +497,6 @@ def phase_serve(procs: Procs, out_dir: str, model: str, rehearsal: bool):
         f"{({k: int(v) for k, v in probes.items()}) or 'none fired'}, trips "
         f"{int(fams.get('kukeon_watchdog_trips_total', [({}, 0)])[0][1])}; "
         "the cell was not killed")
-    mfu = by_label(fams, "kukeon_program_mfu", "program")
-    bw = by_label(fams, "kukeon_program_membw_util", "program")
-    say("live utilization gauges (host-settled wall clock, not a trace): "
-        f"mfu {({k: round(v, 4) for k, v in mfu.items()}) or 'absent'} "
-        f"membw {({k: round(v, 4) for k, v in bw.items()}) or 'absent'}")
     drain_to_exit(proc, addr, "cell-1", log_path)
     entries1 = cache_entries()
     say(f"compile cache: {entries1} entries after boot 1 "

@@ -38,10 +38,9 @@ A fourth guards the roofline instrumentation:
   jitted program wrapped in ``_build_programs`` must pass a ``timer=``
   keyword to ``CompileTracker.wrap`` (``timer=tm.track("<program>")``).
   A program wrapped without one dispatches invisibly to the per-program
-  wall-time/MFU gauges (``kukeon_program_seconds``,
-  ``kukeon_program_mfu``) — the flight recorder and the bench's
-  ``program_costs`` section would silently under-report where device
-  time goes.
+  counters (``kukeon_program_dispatch_total``,
+  ``kukeon_program_seconds``) — the flight recorder's step records and
+  the benchmark's window counts would silently under-report what ran.
 
 All rules are scoped to ``serving/engine.py``'s ``ServingEngine``: the
 pass reads ``_build_programs`` to learn which inner functions are jitted

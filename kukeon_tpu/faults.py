@@ -64,7 +64,6 @@ POINTS = (
     "checkpoint.stream",
     "devices.probe_wedged",
     "profile.capture",
-    "profile.layers",
 )
 
 

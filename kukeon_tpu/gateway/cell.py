@@ -69,7 +69,7 @@ SPILL_WAIT_TICK_S = 0.05
 
 class GatewayCell:
     """Routing + proxy brain behind the HTTP handler (handler-free so tests
-    and bench.py can drive it in-process)."""
+    can drive it in-process)."""
 
     def __init__(self, model: str, replica_urls: list[str], *,
                  registry: Registry | None = None,

@@ -1,7 +1,7 @@
 """Checkpoint tooling: synthesize HF-layout checkpoints and save/load the
 kukeon int8 quantized format.
 
-Two jobs, both in service of the flagship bench (BASELINE north star:
+Two jobs, both in service of serving a full-size model (BASELINE north star:
 Llama-3-8B serving on v5e):
 
 1. **Synthesis** — this environment has no network egress, so "load a real

@@ -5,8 +5,8 @@ Zero-dependency by design (the container bakes no prometheus_client): the
 registry is a few hundred lines of locked dicts, the exposition is the
 Prometheus text format 0.0.4 by hand, and traces are dataclasses in a ring
 buffer. Everything the serving engine, the cells, the runner, and the
-daemon report flows through here; ``bench.py`` scores itself from the same
-histograms a production scrape would read.
+daemon report flows through here; ``benchmark/run.py`` reads its counter
+metrics off the same ``/metrics`` a production scrape would read.
 
 Naming convention: ``kukeon_<subsystem>_<name>`` with ``_total`` for
 counters and ``_seconds`` for latency histograms — e.g.
@@ -46,13 +46,9 @@ from kukeon_tpu.obs.device import (  # noqa: F401
     device_memory_collector,
 )
 from kukeon_tpu.obs.profile import (  # noqa: F401
-    LAYER_PROFILE_SCHEMA,
     PROGRAMS,
     FlightRecorder,
     ProgramTimers,
-    cost_summary,
-    device_peaks,
-    profile_layers,
 )
 from kukeon_tpu.obs.slo import SloObjectives, SloTracker  # noqa: F401
 from kukeon_tpu.obs.tsdb import (  # noqa: F401

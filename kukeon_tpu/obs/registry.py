@@ -228,9 +228,9 @@ def percentile_from_counts(buckets: tuple[float, ...],
                            q: float) -> float | None:
     """q-quantile from per-bucket counts (finite buckets + overflow slot).
 
-    Module-level so callers holding a count DELTA (bench.py subtracts a
-    pre-measurement snapshot to keep warmup compiles out of the reported
-    percentiles) share the exact estimator the live histogram uses.
+    Module-level so callers holding a count DELTA (the TSDB's windowed
+    quantiles, the daemon's federated bucket counts) share the exact
+    estimator the live histogram uses.
 
     Edge contracts (unit-tested): an empty histogram returns the None
     sentinel — never a fabricated 0.0 that would read as "instant" on a

@@ -213,7 +213,7 @@ class ModelSpec:
     # numSlots * maxSeqLen contiguous rows per slot — mixed-length agent
     # traffic packs HBM page-granularly, with preemption + requeue under
     # pressure and refcounted prefix sharing. 0 forces the legacy
-    # contiguous layout; None defers to the persisted autotune profile.
+    # contiguous layout; None defers to the persisted tune file.
     kv_page_tokens: int | None = None
     # Admission control (serving resilience): bound on queued-not-yet-
     # slotted requests — past it the cell sheds with 429 + Retry-After

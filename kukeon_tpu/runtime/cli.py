@@ -1059,79 +1059,6 @@ def cmd_timeline(args):
     return 0 if steps else 1
 
 
-def _fmt_count(n) -> str:
-    if n is None:
-        return "-"
-    for unit in ("", "K", "M", "G", "T", "P"):
-        if abs(n) < 1000 or unit == "P":
-            return f"{n:.1f}{unit}" if unit else f"{n:.0f}"
-        n /= 1000.0
-    return f"{n:.1f}P"
-
-
-def render_layer_profile(key: str, prof: dict) -> str:
-    """One persisted per-layer cost profile (obs/profile.profile_layers,
-    written by `bench.py --profile-layers`) as a table: per component and
-    shape, the XLA cost-analysis FLOPs/bytes and measured wall time,
-    with the whole-model totals as the roofline reference. Pure; reads
-    no accelerator state."""
-    head = [f"{key}  ({prof.get('schema', '?')}"
-            + (f", profiled {prof['profiled_at']}"
-               if prof.get("profiled_at") else "") + ")"]
-    head.append(
-        f"  layers={prof.get('num_layers', '?')}"
-        f" prefill_len={prof.get('prefill_len', '?')}"
-        f" decode_batch={prof.get('decode_batch', '?')}"
-        f" model_flops={_fmt_count(prof.get('model_flops'))}"
-        f" model_bytes={_fmt_bytes(prof.get('model_bytes'))}")
-    if prof.get("errors"):
-        head.append(f"  {prof['errors']} component(s) failed to profile")
-    fmt = "  {:<10} {:<9} {:>10} {:>10} {:>10}"
-    lines = head + [fmt.format("COMPONENT", "SHAPE", "FLOPS", "BYTES",
-                               "WALL")]
-    for comp in prof.get("components", []):
-        name = comp.get("name", "?")
-        if comp.get("error"):
-            lines.append(fmt.format(name, "-", "-", "-", "-")
-                         + f"  ({comp['error']})")
-            continue
-        for shape in ("prefill", "decode"):
-            rec = comp.get(shape)
-            if not isinstance(rec, dict):
-                continue
-            wall = (f"{rec['wall_s'] * 1000:.2f}ms"
-                    if rec.get("wall_s") is not None else "-")
-            lines.append(fmt.format(
-                name, shape, _fmt_count(rec.get("flops")),
-                _fmt_bytes(rec.get("bytes")), wall))
-    return "\n".join(lines)
-
-
-def cmd_profile(args):
-    """Render persisted per-layer cost profiles. Reads the local profile
-    file (serving/tuning.py, next to the serving tune) only — no daemon,
-    no accelerator runtime — so it works anywhere the bench ran
-    `--profile-layers`. An optional key substring narrows the listing
-    (keys are ``model|backend|n_chips``)."""
-    from kukeon_tpu.serving import tuning
-
-    profs = tuning.load_layer_profiles()
-    if args.key:
-        profs = {k: v for k, v in profs.items() if args.key in k}
-    if args.json:
-        _print(profs, True)
-        return 0
-    if not profs:
-        print("no persisted layer profiles"
-              + (f" matching {args.key!r}" if args.key else "")
-              + f" in {tuning.layer_profile_path()}"
-              " (run bench.py --profile-layers)")
-        return 1
-    print("\n\n".join(render_layer_profile(k, v)
-                      for k, v in sorted(profs.items())))
-    return 0
-
-
 def cmd_scale(args):
     """The autoscaler's status verb: one row per autoscaled model cell —
     active target vs declared bounds, the latest queue-pressure and SLO
@@ -1527,12 +1454,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("-n", type=int, default=50, dest="n",
                     help="newest engine steps to fetch per cell")
 
-    sp = sub_add("profile")
-    sp.add_argument("profile_cmd", choices=["layers"])
-    sp.add_argument("key", nargs="?", default=None,
-                    help="profile key substring (keys are "
-                         "model|backend|n_chips)")
-
     sp = sub_add("rollout")
     sp.add_argument("name")
     sp.add_argument("--drain-timeout", type=float, default=60.0,
@@ -1617,7 +1538,6 @@ HANDLERS = {
     "scale": cmd_scale,
     "trace": cmd_trace,
     "timeline": cmd_timeline,
-    "profile": cmd_profile,
     "rollout": cmd_rollout,
     "doctor": cmd_doctor,
     "refresh": cmd_refresh,

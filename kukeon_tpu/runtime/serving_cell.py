@@ -324,10 +324,10 @@ def unpack_kv(body: bytes) -> tuple[dict, np.ndarray, np.ndarray]:
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 # One fixed path inside the checkout. The path is part of a cache entry's
-# key, so every process of this checkout — cells under the daemon, bench.py's
-# children, chip_smoke.py's two boots — must name the same directory or a
-# second boot never hits; a machine that is new on every run has no $HOME
-# worth caching in.
+# key, so every process of this checkout — cells under the daemon, the
+# benchmark's cell, chip_smoke.py's two boots — must name the same
+# directory or a second boot never hits; a machine that is new on every run
+# has no $HOME worth caching in.
 _CHECKOUT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
@@ -525,9 +525,9 @@ class ServingCell(LifecycleMixin):
         # async_load: the multi-GB weight transfer streams in the background
         # while warmup()'s precompile pass AOT-compiles the programs — cold
         # start pays max(transfer, compile) instead of their sum.
-        # model_name routes the engine to the persisted autotune profile
-        # (bench.py --autotune): levers the operator left unset
-        # (decode_chunk/kv_cache_int8 None) boot at the swept winner for
+        # model_name routes the engine to the persisted tune file
+        # (serving/tuning.py): levers the operator left unset
+        # (decode_chunk/kv_cache_int8 None) boot at the file's values for
         # this model+backend+chip-count.
         # One registry for the whole cell: engine metrics and cell
         # lifecycle gauges land in the same /metrics exposition.
@@ -638,8 +638,8 @@ class ServingCell(LifecycleMixin):
         ``kukeon_cold_start_phase_seconds{phase=}``, and drop a
         ``component="boot"`` span into the trace ring so ``kuke trace``
         can render the boot timeline like any request. Called once from
-        main() right before the cell goes ready; bench.py's cold-start
-        phase reads these gauges off the first /metrics scrape."""
+        main() right before the cell goes ready; chip_smoke.py reads these
+        gauges off the first /metrics scrape."""
         now = time.monotonic()
         m = self._boot_marks
         phases: dict[str, float] = {
@@ -1063,32 +1063,6 @@ class ServingCell(LifecycleMixin):
             **({"unreadyReason": unready_why} if unready_why else {}),
         }
 
-    def profile_layers(self, prefill_len: int | None = None,
-                       decode_batch: int | None = None) -> dict:
-        """Per-layer roofline profile of the live model
-        (obs/profile.profile_layers), persisted next to the serving tune
-        under the same ``model|backend|n_chips`` key. Degradation
-        contract: an armed ``profile.layers`` fault or a backend without
-        cost analysis yields recorded ``error`` entries in the returned
-        profile (and skips persistence) — it never crashes the cell."""
-        import jax
-
-        from kukeon_tpu.obs import profile as obs_profile
-        from kukeon_tpu.serving import tuning
-
-        eng = self.engine
-        eng._ensure_loaded()
-        prof = obs_profile.profile_layers(
-            eng.params, eng.cfg, eng.mesh,
-            prefill_len=prefill_len or min(64, eng.max_seq_len - 1),
-            decode_batch=decode_batch or eng.num_slots)
-        key_args = (self.model_name, jax.default_backend(),
-                    int(eng.mesh.size))
-        prof["key"] = tuning.profile_key(*key_args)
-        if not prof.get("errors"):
-            prof["path"] = tuning.save_layer_profile(*key_args, prof)
-        return prof
-
 
 @sanitize.guard_class
 class EmbeddingCell(LifecycleMixin):
@@ -1438,21 +1412,13 @@ def make_handler(cell: ServingCell):
                 try:
                     n = int(self.headers.get("Content-Length", 0))
                     req = json.loads(self.rfile.read(n) or b"{}")
-                    if req.get("layers"):
-                        # Per-layer roofline profile (synchronous — the
-                        # lowering loop runs in-request). Errors inside
-                        # the loop (including the armed profile.layers
-                        # fault) come back RECORDED in the profile body;
-                        # the cell keeps serving either way.
-                        if not hasattr(cell, "profile_layers"):
-                            self._send(404, {"error": "this cell has no "
-                                                      "layer profiler"})
-                            return
-                        prof = cell.profile_layers(
-                            prefill_len=req.get("prefillLen"),
-                            decode_batch=req.get("decodeBatch"))
-                        self._send(200, prof)
-                        return
+                    unknown = sorted(set(req) - {"durationMs", "pythonTracer"})
+                    if unknown:
+                        # A field this route does not know must not start
+                        # a capture the caller did not ask for.
+                        raise ValueError(
+                            f"unknown field(s) {unknown}: POST /v1/profile "
+                            "takes durationMs and pythonTracer")
                     rec = profiler.start(
                         float(req.get("durationMs", 1000)),
                         python_tracer=bool(req.get("pythonTracer", False)))
@@ -1616,7 +1582,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-seq-len", type=int, default=None)
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--dtype", default=None)
-    # None (flag absent) lets the persisted autotune profile decide; the
+    # None (flag absent) lets the persisted tune file decide; the
     # explicit flag always wins (serving/tuning.py).
     ap.add_argument("--kv-cache-int8", action="store_true", default=None)
     ap.add_argument("--decode-chunk", type=int, default=None)
@@ -1690,7 +1656,7 @@ def main(argv=None) -> int:
     if isinstance(cell, ServingCell):
         # Close out the cold-start trace: kukeon_cold_start_seconds (+ the
         # per-phase breakdown) lands on /metrics and the boot span joins
-        # the trace ring — bench.py's cold-start phase reads both.
+        # the trace ring — chip_smoke.py reads the gauges.
         cell.finish_boot()
     cell.mark_ready()
 

@@ -342,9 +342,10 @@ class ServingEngine:
             self._fwd_logit_positions = False
 
         # Tuning profile: levers not pinned by the caller fall back to the
-        # persisted autotune winner for this (model, backend, chip-count),
-        # then to defaults. bench.py --autotune writes the profile; a stale
-        # or missing one silently degrades to defaults (serving/tuning.py).
+        # persisted tune for this (model, backend, chip-count), then to
+        # defaults. An operator writes the file by hand (README "Tuning
+        # serving throughput"); a stale or missing one silently degrades to
+        # defaults (serving/tuning.py).
         self.tune: "Any | None" = None
         if model_name and (decode_chunk is None or kv_cache_int8 is None
                            or prefill_buckets is None
@@ -409,7 +410,7 @@ class ServingEngine:
             cfg = dataclasses.replace(cfg, int8_pallas=int8_pallas)
         self.cfg = cfg
         self.mesh = mesh
-        # KV-shard lever (autotune sweeps it): None = shard over the mesh's
+        # KV-shard lever: None = shard over the mesh's
         # tensor axis when the KV-head count divides it, False = replicate
         # the cache (more HBM, no gather in the attention dots), True =
         # shard — still subject to the divisibility fallback below.
@@ -1223,9 +1224,10 @@ class ServingEngine:
         kv_sh, sc_sh = self._cache_shardings()
         repl = NamedSharding(self.mesh, PartitionSpec())
         # Every wrap registers with BOTH seams: the coarse compile label
-        # (prefill|insert|decode — bench.py and the compile-flat tests
-        # consume that vocabulary, do not change it) and the per-program
-        # roofline timer (kukelint KUKE015 requires the timer= keyword).
+        # (prefill|insert|decode — the benchmark's compiles_in_window and
+        # the compile-flat tests consume that vocabulary, do not change it)
+        # and the per-program timer (kukelint KUKE015 requires the timer=
+        # keyword).
         self._prefill = ct.wrap(jax.jit(
             prefill,
             in_shardings=(p_sh, repl, repl, repl, repl, repl, repl),
@@ -1459,30 +1461,24 @@ class ServingEngine:
             })
             for L in buckets:
                 tokens = jax.ShapeDtypeStruct((1, L), jnp.int32)
-                compiled = self._prefill.lower(
+                self._prefill.lower(
                     aparams, tokens, L // 2, key,
                     jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
                 ).compile()
-                # Static roofline cost at the largest precompiled bucket
-                # (the per-dispatch cost the MFU gauges divide by; later
-                # iterations overwrite earlier, so the biggest L wins).
-                self.timers.note_cost("prefill", compiled)
                 kv_shape = (cfg.num_layers, 1, L, cfg.num_kv_heads, cfg.head_dim)
                 kv = jax.ShapeDtypeStruct(kv_shape, cfg.dtype)
                 if self.paged:
                     ids = jax.ShapeDtypeStruct((L // self.page_tokens,),
                                                jnp.int32)
-                    compiled = self._insert_paged.lower(
+                    self._insert_paged.lower(
                         astate, kv, kv, L // 2, ids, 0, jnp.int32(1),
                     ).compile()
-                    self.timers.note_cost("insert_paged", compiled)
                 else:
-                    compiled = self._insert.lower(
+                    self._insert.lower(
                         astate, kv, kv, L // 2, 0,
                         jnp.zeros((1 + len(self._counted),), jnp.int32)
                         if self._counted else jnp.int32(1),
                     ).compile()
-                    self.timers.note_cost("insert", compiled)
             chunk_sizes = {1, 4}
             size = 1
             while size * 4 <= self.decode_chunk:
@@ -1492,15 +1488,13 @@ class ServingEngine:
                 (B, self.max_pages_per_slot), jnp.int32)
             for k in sorted(chunk_sizes):
                 if self.paged:
-                    compiled = self._decode_chunk_paged.lower(
+                    self._decode_chunk_paged.lower(
                         aparams, astate, bt, key, temps, top_ks, top_ps, k,
                     ).compile()
-                    self.timers.note_cost("decode_chunk_paged", compiled)
                 else:
-                    compiled = self._decode_chunk.lower(
+                    self._decode_chunk.lower(
                         aparams, astate, key, temps, top_ks, top_ps, k,
                     ).compile()
-                    self.timers.note_cost("decode_chunk", compiled)
 
     # --- public API --------------------------------------------------------
 

@@ -1,18 +1,19 @@
-"""Persisted serving-tune profiles (the autotune → production seam).
+"""Persisted serving-tune profiles (the tune file → production seam).
 
-``bench.py --autotune`` sweeps the serving perf levers (decode-chunk size,
-int8 KV cache, prefill bucket ladder) on whatever backend is up and persists
-the winning configuration here; ``ServingEngine``/``ServingCell`` consult the
-profile at boot. A one-time sweep therefore permanently configures production
-serving — no operator has to re-derive the chunk size per model/chip-count.
+A tune file holds, per model and slice, values for the serving perf levers
+(decode-chunk size, int8 KV cache, prefill bucket ladder, paged KV, KV
+sharding); ``ServingEngine``/``ServingCell`` consult it at boot for every
+lever the operator left unset. Nothing in the tree writes the file: an
+operator who has measured a better setting (benchmark/sweep.py) writes it by
+hand or through :func:`save`.
 
 The profile file (default ``~/.kuke/serving_tune.json``, override with
 ``KUKEON_TUNE_PATH``) is a single JSON object keyed by
 ``model|backend|n_chips``: a profile tuned for llama3-8b on one TPU chip is
 never applied to a CPU smoke of the same model, a different model, or a
 different slice size — stale keys are simply ignored. This module is
-import-light on purpose (no jax): the bench orchestrator reads/writes
-profiles without touching any accelerator runtime.
+import-light on purpose (no jax): the CLI and the daemon can read profiles
+without touching any accelerator runtime.
 """
 
 from __future__ import annotations
@@ -128,64 +129,6 @@ def load(model: str | None, backend: str, n_chips: int,
         return ServingTune.from_dict(entry)
     except (KeyError, TypeError, ValueError):
         return None
-
-
-_LAYER_PROFILE_DEFAULT_PATH = os.path.join("~", ".kuke", "layer_profile.json")
-
-
-def layer_profile_path(path: str | None = None) -> str:
-    return os.path.expanduser(
-        path or os.environ.get("KUKEON_LAYER_PROFILE_PATH")
-        or _LAYER_PROFILE_DEFAULT_PATH
-    )
-
-
-def load_layer_profile(model: str | None, backend: str, n_chips: int,
-                       path: str | None = None) -> dict | None:
-    """The persisted per-layer cost profile (obs/profile.profile_layers)
-    for this exact (model, backend, chips) key, or None — same miss-not-
-    error contract as the serving tune next door."""
-    if not model:
-        return None
-    entry = _read_all(layer_profile_path(path)).get(
-        profile_key(model, backend, n_chips)
-    )
-    return entry if isinstance(entry, dict) else None
-
-
-def load_layer_profiles(path: str | None = None) -> dict[str, dict]:
-    """Every persisted layer profile, keyed ``model|backend|n_chips`` —
-    what `kuke profile layers` lists and substring-matches against."""
-    return {k: v for k, v in _read_all(layer_profile_path(path)).items()
-            if isinstance(v, dict)}
-
-
-def save_layer_profile(model: str, backend: str, n_chips: int,
-                       profile: dict, path: str | None = None) -> str:
-    """Merge one per-layer cost profile under its key; returns the path.
-    Same atomic read-modify-write as :func:`save` — the pipeline-split
-    planner reading this file mid-write must never see a torn JSON."""
-    p = layer_profile_path(path)
-    os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
-    entries = _read_all(p)
-    profile = dict(profile)
-    profile.setdefault(
-        "profiled_at", time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()))
-    entries[profile_key(model, backend, n_chips)] = profile
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(p) or ".",
-                               prefix=".layer_profile-")
-    try:
-        with os.fdopen(fd, "w") as f:
-            json.dump(entries, f, indent=2, sort_keys=True)
-            f.write("\n")
-        os.replace(tmp, p)
-    except BaseException:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        raise
-    return p
 
 
 def save(model: str, backend: str, n_chips: int, tune: ServingTune,

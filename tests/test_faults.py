@@ -22,28 +22,38 @@ def test_unarmed_is_a_noop():
     assert faults.stats == {}
 
 
-def test_unarmed_is_cheap_relative_to_armed_miss():
-    """The unset path must be a bare env lookup — meaningfully cheaper than
-    even an armed-but-different-point lookup (which pays the lock)."""
-    import timeit
+class _ForbiddenLock:
+    """Stands in for faults._lock: taking it is an error, and is counted."""
 
-    # Both paths share the os.environ lookup that dominates their cost, so
-    # the real gap is only ~10% — one scheduler hiccup can invert a single
-    # sample.  Take the min of several repeats and allow a bounded retry:
-    # the unarmed path is deterministically cheaper, so three consecutive
-    # inversions would mean the guard is broken, not the clock.
-    for _ in range(3):
-        unarmed = min(timeit.repeat(
-            lambda: faults.maybe_fail("p"), number=50000, repeat=3))
-        os.environ[faults.ENV] = "other.point:1"
-        try:
-            armed_miss = min(timeit.repeat(
-                lambda: faults.maybe_fail("p"), number=50000, repeat=3))
-        finally:
-            del os.environ[faults.ENV]
-        if unarmed < armed_miss:
-            break
-    assert unarmed < armed_miss
+    def __init__(self):
+        self.taken = 0
+
+    def acquire(self, *a, **kw):
+        self.taken += 1
+        raise AssertionError("the fault table's lock was taken")
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_unarmed_returns_before_the_lock_and_armed_miss_takes_it(monkeypatch):
+    """With KUKEON_FAULTS unset maybe_fail is a bare env lookup: it returns
+    without touching the table's lock. Armed for ANOTHER point it has to
+    read the table, so it does take the lock."""
+    lock = _ForbiddenLock()
+    monkeypatch.setattr(faults, "_lock", lock)
+    monkeypatch.delenv(faults.ENV, raising=False)
+    for _ in range(1000):
+        faults.maybe_fail("p")
+    assert lock.taken == 0
+    assert faults._cached_spec is None        # nothing was parsed either
+
+    monkeypatch.setenv(faults.ENV, "other.point:1")
+    with pytest.raises(AssertionError, match="lock was taken"):
+        faults.maybe_fail("p")
+    assert lock.taken == 1
 
 
 @pytest.mark.faults

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import http.client
 import json
-import os
 import socket
 import struct
 import threading
@@ -947,136 +946,3 @@ def test_cmd_rollout_prints_replica_progress(capsys, monkeypatch):
     out = capsys.readouterr().out
     assert "model-server-0" in out and "model-server-1" in out
     assert "rollout complete (2 replicas)" in out
-
-
-# --- bench artifact schema ---------------------------------------------------
-
-
-def _load_bench():
-    import importlib.util
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "kukeon_bench", os.path.join(root, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_artifact_v8_and_backcompat(tmp_path):
-    bench = _load_bench()
-    serve = {"backend": "cpu", "n_chips": 2, "model": "tiny",
-             "model_id": "tiny", "sessions": 4, "tok_per_s": 100.0,
-             "trials": [100.0], "replicas": 3,
-             "kv_page_tokens": 16, "max_sessions": 9,
-             "ttft_p95_s": 0.25,
-             "mesh": {"chips": 2, "tensor": 2, "kv_sharded": True}}
-    out = tmp_path / "BENCH_rXX.json"
-    bench.write_artifact(str(out), serve,
-                         {"vs_baseline": 0.5, "handoff_ms_p50": 12.5,
-                          "disagg": {"arms": {}},
-                          "diurnal": {"peak_p95_s": 0.8, "failed": 0}})
-    art = bench.read_artifact(str(out))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["replicas"] == 3
-    assert art["kv_page_tokens"] == 16
-    assert art["max_sessions"] == 9
-    assert art["ttft_p95_s"] == 0.25
-    assert art["handoff_ms_p50"] == 12.5
-    assert art["disagg"] == {"arms": {}}
-    assert art["diurnal"] == {"peak_p95_s": 0.8, "failed": 0}
-    assert art["mesh"] == {"chips": 2, "tensor": 2, "kv_sharded": True}
-
-    # A v1 point (pre-gateway, single engine) reads back as v8: replicas=1,
-    # legacy contiguous KV (kv_page_tokens=0), every session resident, no
-    # handoff and no diurnal section (neither existed).
-    v1 = tmp_path / "BENCH_r05.json"
-    v1.write_text(json.dumps({"schema": "kukeon-bench/v1", "backend": "cpu",
-                              "tok_per_s": 50.0, "sessions": 4}))
-    art = bench.read_artifact(str(v1))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["replicas"] == 1
-    assert art["tok_per_s"] == 50.0
-    assert art["kv_page_tokens"] == 0
-    assert art["max_sessions"] == 4
-    assert art["ttft_p95_s"] is None
-    assert art["handoff_ms_p50"] is None
-    assert art["disagg"] is None
-    assert art["diurnal"] is None
-    assert art["mesh"] is None
-
-    # A v2 point (pre-paged-KV) keeps its replicas and gains the later
-    # fields; its TTFT p95 lifts from the latency percentiles it recorded.
-    v2 = tmp_path / "BENCH_r06.json"
-    v2.write_text(json.dumps({"schema": "kukeon-bench/v2", "backend": "cpu",
-                              "tok_per_s": 60.0, "sessions": 2,
-                              "replicas": 2,
-                              "latency_s": {"ttft": {"p95": 0.4}}}))
-    art = bench.read_artifact(str(v2))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["replicas"] == 2
-    assert art["kv_page_tokens"] == 0
-    assert art["max_sessions"] == 2
-    assert art["ttft_p95_s"] == 0.4
-
-    # A v3 point (pre-disaggregation) gains the v4 and v5 fields.
-    v3 = tmp_path / "BENCH_r07.json"
-    v3.write_text(json.dumps({"schema": "kukeon-bench/v3", "backend": "cpu",
-                              "tok_per_s": 70.0, "sessions": 2,
-                              "replicas": 1, "kv_page_tokens": 16,
-                              "max_sessions": 4}))
-    art = bench.read_artifact(str(v3))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["kv_page_tokens"] == 16
-    assert art["max_sessions"] == 4
-    assert art["handoff_ms_p50"] is None
-    assert art["diurnal"] is None
-
-    # A v4 point (pre-autoscaling) gains only the diurnal section.
-    v4 = tmp_path / "BENCH_r08.json"
-    v4.write_text(json.dumps({"schema": "kukeon-bench/v4", "backend": "cpu",
-                              "tok_per_s": 80.0, "sessions": 2,
-                              "replicas": 2, "kv_page_tokens": 16,
-                              "max_sessions": 4, "ttft_p95_s": 0.3,
-                              "handoff_ms_p50": 10.0,
-                              "disagg": {"arms": {}}}))
-    art = bench.read_artifact(str(v4))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["ttft_p95_s"] == 0.3
-    assert art["handoff_ms_p50"] == 10.0
-    assert art["disagg"] == {"arms": {}}
-    assert art["diurnal"] is None
-
-    # A v5 point (pre-streamed-boot) gains only the cold-start load
-    # sub-phase ledger: explicit None — no disk/cast/upload existed.
-    v5 = tmp_path / "BENCH_r09.json"
-    v5.write_text(json.dumps({"schema": "kukeon-bench/v5", "backend": "cpu",
-                              "tok_per_s": 90.0, "sessions": 2,
-                              "replicas": 2, "kv_page_tokens": 16,
-                              "max_sessions": 4, "ttft_p95_s": 0.3,
-                              "diurnal": {"peak_p95_s": 0.8, "failed": 0},
-                              "cold_start": {"p50_s": 30.0}}))
-    art = bench.read_artifact(str(v5))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["diurnal"] == {"peak_p95_s": 0.8, "failed": 0}
-    assert art["cold_start"] == {"p50_s": 30.0, "load_s": None}
-    assert art["mesh"] is None
-
-    # A v6 point (pre-multi-chip) gains only the mesh section: explicit
-    # None — single-chip engines had no sharding layout to record.
-    v6 = tmp_path / "BENCH_r10.json"
-    v6.write_text(json.dumps({"schema": "kukeon-bench/v6", "backend": "cpu",
-                              "tok_per_s": 95.0, "sessions": 2,
-                              "replicas": 2, "kv_page_tokens": 16,
-                              "max_sessions": 4, "ttft_p95_s": 0.3,
-                              "cold_start": {"p50_s": 30.0,
-                                             "load_s": {"disk": 1.0}}}))
-    art = bench.read_artifact(str(v6))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["mesh"] is None
-    assert art["cold_start"] == {"p50_s": 30.0, "load_s": {"disk": 1.0}}
-
-    bad = tmp_path / "BENCH_bad.json"
-    bad.write_text(json.dumps({"schema": "nope/v9"}))
-    with pytest.raises(ValueError, match="schema"):
-        bench.read_artifact(str(bad))

@@ -1,23 +1,19 @@
-"""Roofline flight recorder (ISSUE 19): per-program timers and the MFU
-gauges they derive, the per-layer cost profiler and its FLOPs-sum
-contract, the engine-step flight recorder (ring bounds, concurrent
-ingest/readers, GET /v1/timeline), federation staleness, and the
-`kuke timeline` / `kuke profile layers` renderers.
+"""Program timers and the flight recorder: per-program dispatch / token /
+settled-seconds counters, the engine-step flight recorder (ring bounds,
+concurrent ingest/readers, GET /v1/timeline), federation staleness, and
+the `kuke timeline` renderer.
 
-The acceptance spine: a flooded tiny engine counts its program work and
-exposes kukeon_program_mfu <= 1.0 exactly where the device's published
-peak is known, `bench.py
---profile-layers`'s per-component FLOPs sum matches the whole-model
-reference within 5%, and /v1/timeline steps cross-link to trace ids the
-tracer resolves. The whole file must stay green under KUKEON_SANITIZE=1
-(check.yml runs it in both slices).
+The acceptance spine: a flooded tiny engine counts its program work under
+the names the benchmark reads (kukeon_program_dispatch_total,
+kukeon_program_tokens_total) and states no utilization, and /v1/timeline
+steps cross-link to trace ids the tracer resolves. The whole file must
+stay green under KUKEON_SANITIZE=1 (check.yml runs it in both slices).
 """
 
 from __future__ import annotations
 
 import http.client
 import json
-import os
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -26,12 +22,10 @@ import jax
 import numpy as np
 import pytest
 
-from kukeon_tpu import faults
 from kukeon_tpu.models import llama
 from kukeon_tpu.obs import (
     FlightRecorder,
     Registry,
-    profile_layers,
     render,
 )
 from kukeon_tpu.obs import federate as fed
@@ -149,26 +143,12 @@ def test_flight_recorder_concurrent_flood():
 # --- per-program timers: the engine flood ------------------------------------
 
 
-class _FakeDevice:
-    """Stands in for jax.devices()[0] at scrape time: what obs/profile's
-    peak table and obs/device's HBM collector read off a device."""
-
-    id = 0
-
-    def __init__(self, platform, device_kind):
-        self.platform = platform
-        self.device_kind = device_kind
-
-    def memory_stats(self):
-        return None
-
-
 @pytest.fixture(scope="module")
 def flooded_engine():
-    """One tiny engine after precompile (static costs) + a request flood
-    (measured busy time) — shared by every peak-table case below."""
+    """One tiny engine after precompile + a request flood (measured busy
+    time)."""
     eng = _tiny_engine()
-    eng.precompile((8,))      # cost_analysis denominators land here
+    eng.precompile((8,))
     eng.warmup(8)
     reqs = [eng.submit(PROMPT, SamplingParams(max_new_tokens=12))
             for _ in range(2)]
@@ -179,15 +159,14 @@ def flooded_engine():
 
 
 def test_engine_flood_counts_program_work(flooded_engine):
-    """After precompile + a flood the dispatch/tokens/cost counters line up
-    with the work, whatever the device's peak is."""
+    """After precompile + a flood the dispatch/tokens counters line up
+    with the work."""
     eng = flooded_engine
     snap = eng.timers.snapshot()
     for program in ("prefill", "decode_chunk"):
         assert snap[program]["dispatches"] >= 1
         assert snap[program]["settled"] >= 1
         assert snap[program]["busy_s"] > 0.0
-        assert snap[program]["flops"] > 0.0          # CPU reports costs
     # Decode counted batch*k token work; prefill counted the prompt rows.
     assert snap["decode_chunk"]["tokens"] >= 2 * 12
     assert snap["prefill"]["tokens"] >= 2 * len(PROMPT)
@@ -203,117 +182,37 @@ def test_engine_flood_counts_program_work(flooded_engine):
         assert key in step
 
 
-@pytest.mark.parametrize("platform,kind,want", [
-    ("cpu", "cpu", "empty"),
-    ("tpu", "TPU v5 lite", "gauges"),
-    ("tpu", "TPU v9 imaginary", "absent-with-reason"),
-])
-def test_utilization_gauges_follow_the_peak_table(
-        flooded_engine, monkeypatch, platform, kind, want):
-    """MFU / bandwidth gauges exist only where the device's published peak
-    is known: computed on a listed TPU kind, declared-but-empty on the
-    CPU (like the HBM families), and ABSENT with the reason in the HELP
-    text on a TPU that is not in the table — never from a made-up peak."""
+def test_scrape_counts_every_wrapped_program_and_states_no_utilization(
+        flooded_engine):
+    """The names benchmark/ reads off /metrics: every program the engine
+    wrapped with the timer seam that dispatched has a
+    kukeon_program_dispatch_total sample, every one that processed tokens
+    a kukeon_program_tokens_total sample, and no utilization or static-cost
+    family is on the scrape (a host-settled clock states none)."""
     eng = flooded_engine
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDevice(platform, kind)])
-    text = render(eng.registry)
-    fams = _parse_expo(text)
+    fams = _parse_expo(render(eng.registry))
+
+    def by_program(fam):
+        return {l["program"]: float(v) for _n, l, v in fams[fam]["samples"]}
+
+    dispatched = by_program("kukeon_program_dispatch_total")
+    tokens = by_program("kukeon_program_tokens_total")
+    wrapped = set(eng.timers._timers)
+    assert {"prefill", "insert", "decode_chunk"} <= wrapped
+    assert set(dispatched) <= wrapped and set(tokens) <= wrapped
     snap = eng.timers.snapshot()
-    for fam in ("kukeon_program_mfu", "kukeon_program_membw_util"):
-        assert fams[fam]["type"] == "gauge"            # always declared
-        values = {l["program"]: float(v)
-                  for _n, l, v in fams[fam]["samples"]}
-        if want == "gauges":
-            for program in ("prefill", "decode_chunk"):
-                assert 0.0 < values[program] <= 1.0
-            # (rounded to 6 digits: a tiny CPU run against a v5e peak is 0.0)
-            assert 0.0 <= snap["decode_chunk"]["mfu"] <= 1.0
-        else:
-            assert values == {}
-            assert snap["decode_chunk"]["mfu"] is None
-            assert snap["decode_chunk"]["membw_util"] is None
-        help_line = next(ln for ln in text.splitlines()
-                         if ln.startswith(f"# HELP {fam} "))
-        assert ("ABSENT" in help_line) == (want == "absent-with-reason")
-        if want == "absent-with-reason":
-            assert "TPU v9 imaginary" in help_line
+    for program in ("prefill", "insert", "decode_chunk"):
+        assert dispatched[program] == snap[program]["dispatches"] >= 1
+    for program in ("prefill", "decode_chunk"):
+        assert tokens[program] == snap[program]["tokens"] >= 1
+    for gone in ("kukeon_program_mfu", "kukeon_program_membw_util",
+                 "kukeon_program_flops", "kukeon_program_hbm_bytes"):
+        assert gone not in fams
+    assert set(snap["decode_chunk"]) == {"dispatches", "settled", "busy_s",
+                                         "tokens"}
 
 
-def test_peak_table_is_keyed_by_the_runtime_device_kind(monkeypatch):
-    """`TPU v5 lite` — what the installed runtime calls a v5e — resolves to
-    the published 197 TFLOP/s bf16 / 819 GB/s; a substring such as "v5e"
-    is not a key."""
-    from kukeon_tpu.obs import device_peaks
-    from kukeon_tpu.obs.profile import PEAKS_BY_DEVICE_KIND
-
-    assert PEAKS_BY_DEVICE_KIND["TPU v5 lite"] == (197e12, 819e9)
-    assert "v5e" not in PEAKS_BY_DEVICE_KIND
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
-    assert device_peaks() == ((197e12, 819e9), "")
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDevice("tpu", "TPU v5e")])
-    peaks, why = device_peaks()
-    assert peaks is None and "TPU v5e" in why
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDevice("cpu", "cpu")])
-    assert device_peaks() == (None, "")
-
-
-# --- the per-layer cost profiler ---------------------------------------------
-
-
-def test_profile_layers_flops_sum_matches_whole_model():
-    """Acceptance: per-component prefill FLOPs sum to the whole-model
-    reference within 5% (the scan-free lowering makes this structural,
-    not lucky), with one entry per component."""
-    cfg = llama.llama_tiny()
-    params = llama.init_params(jax.random.key(0), cfg)
-    prof = profile_layers(params, cfg, prefill_len=16, decode_batch=2,
-                          measure=False)
-    assert prof["schema"] == "kukeon-layer-profile/v1"
-    assert prof["errors"] == 0
-    names = [c["name"] for c in prof["components"]]
-    assert names == ["embed"] + [f"layer{i}" for i in
-                                 range(cfg.num_layers)] + ["head"]
-    assert prof["model_flops"] > 0
-    total = sum(c["prefill"]["flops"] for c in prof["components"])
-    assert abs(total - prof["model_flops"]) / prof["model_flops"] < 0.05
-    # Both shapes costed for every component.
-    for c in prof["components"]:
-        for shape in ("prefill", "decode"):
-            assert c[shape]["flops"] > 0
-            assert c[shape]["bytes"] > 0
-
-
-def test_profile_layers_measures_wall_time():
-    cfg = llama.llama_tiny()
-    params = llama.init_params(jax.random.key(0), cfg)
-    prof = profile_layers(params, cfg, prefill_len=8, decode_batch=1,
-                          measure=True, reps=1)
-    assert prof["errors"] == 0
-    assert all(c["prefill"]["wall_s"] >= 0 for c in prof["components"])
-
-
-def test_profile_layers_armed_fault_degrades_cleanly():
-    """Satellite: the profile.layers fault point. Armed at probability 1
-    every component records an error entry instead of raising — a
-    partial/empty profile, never a dead caller."""
-    cfg = llama.llama_tiny()
-    params = llama.init_params(jax.random.key(0), cfg)
-    os.environ[faults.ENV] = "profile.layers:1"
-    prof = profile_layers(params, cfg, prefill_len=8, decode_batch=1,
-                          measure=False)
-    # embed + layers + head each failed; the whole-model reference does
-    # not pass through the fault point, so it may still cost out.
-    assert prof["errors"] >= cfg.num_layers + 2
-    failed = [c for c in prof["components"] if c.get("error")]
-    assert len(failed) >= cfg.num_layers + 2
-    assert all("FaultInjected" in c["error"] for c in failed)
-
-
-# --- the live cell: /v1/timeline and POST /v1/profile {"layers": true} -------
+# --- the live cell: /v1/timeline and what POST /v1/profile refuses -----------
 
 
 @pytest.fixture(scope="module")
@@ -371,53 +270,37 @@ def test_timeline_endpoint_cross_links_to_traces(real_cell):
     assert status == 400
 
 
-def test_cell_layer_profile_over_http_persists(real_cell, monkeypatch,
-                                               tmp_path):
-    """POST /v1/profile {"layers": true} profiles the live model and
-    persists next to the serving tune; `kuke profile layers` renders the
-    stored profile without touching jax."""
-    from kukeon_tpu.runtime.cli import render_layer_profile
-    from kukeon_tpu.serving import tuning
-
+def test_profile_route_refuses_fields_it_does_not_know(real_cell, tmp_path,
+                                                       monkeypatch):
+    """POST /v1/profile with a field the route does not know ({"layers":
+    true} asked for a per-layer profile once) answers 400 with a message
+    and starts NO capture; a plain body still starts one."""
     cell, port = real_cell
-    store = tmp_path / "layer_profile.json"
-    monkeypatch.setenv("KUKEON_LAYER_PROFILE_PATH", str(store))
-    status, raw = _post(port, "/v1/profile",
-                        {"layers": True, "prefillLen": 8, "decodeBatch": 2})
-    assert status == 200
-    prof = json.loads(raw)
-    assert prof["errors"] == 0
-    assert prof["path"] == str(store)
-    assert "|" in prof["key"]
+    # The module's cell was built before the per-test spool isolation:
+    # give it a spool no other worker's captures (or pruning) can touch.
+    monkeypatch.setattr(cell.profiler, "base_dir", str(tmp_path / "spool"))
 
-    stored = tuning.load_layer_profiles()
-    assert prof["key"] in stored
-    assert stored[prof["key"]]["profiled_at"]
-    out = render_layer_profile(prof["key"], stored[prof["key"]])
-    assert "COMPONENT" in out and "layer0" in out and "prefill" in out
+    def names():
+        status, raw = _get(port, "/v1/profile")
+        assert status == 200
+        return {c["name"] for c in json.loads(raw)["captures"]}
 
-
-def test_cell_layer_profile_fault_recorded_not_fatal(real_cell):
-    """Satellite, the other fault branch: an armed profile.layers fault
-    during an HTTP-triggered profile comes back RECORDED in the body
-    (200, errors counted, nothing persisted) and the cell keeps
-    serving."""
-    cell, port = real_cell
-    os.environ[faults.ENV] = "profile.layers:1"
-    try:
-        status, raw = _post(port, "/v1/profile", {"layers": True,
-                                                  "prefillLen": 8,
-                                                  "decodeBatch": 1})
-    finally:
-        os.environ.pop(faults.ENV, None)
-        faults.reset()
-    assert status == 200
-    prof = json.loads(raw)
-    assert prof["errors"] > 0
-    assert "path" not in prof                    # partial -> not persisted
-    status, raw = _post(port, "/v1/generate",
-                        {"promptTokens": [1, 2, 3], "maxNewTokens": 2})
-    assert status == 200 and json.loads(raw)["numTokens"] == 2
+    assert names() == set()
+    status, raw = _post(port, "/v1/profile", {"layers": True,
+                                              "prefillLen": 8})
+    assert status == 400
+    err = json.loads(raw)["error"]
+    assert "layers" in err and "durationMs" in err
+    assert cell.profiler._active is None
+    assert names() == set()
+    status, raw = _post(port, "/v1/profile", {"durationMs": 50})
+    assert status == 200 and json.loads(raw)["started"]
+    started = json.loads(raw)["capture"]["name"]
+    deadline = time.monotonic() + 30
+    while cell.profiler._active is not None:
+        assert time.monotonic() < deadline, "capture never completed"
+        time.sleep(0.05)
+    assert names() == {started}
 
 
 # --- federation: fetch_timelines + scrape staleness --------------------------
@@ -519,27 +402,6 @@ def test_render_timeline_table():
     assert "no recorded engine steps" in render_timeline([])
 
 
-def test_render_layer_profile_marks_failed_components():
-    from kukeon_tpu.runtime.cli import render_layer_profile
-
-    prof = {"schema": "kukeon-layer-profile/v1", "num_layers": 2,
-            "prefill_len": 16, "decode_batch": 2, "model_flops": 1.2e7,
-            "model_bytes": 3.4e6, "errors": 1,
-            "components": [
-                {"name": "embed",
-                 "prefill": {"flops": 2144.0, "bytes": 268.0,
-                             "wall_s": 0.001},
-                 "decode": {"flops": 268.0, "bytes": 34.0}},
-                {"name": "layer0", "error": "FaultInjected: boom"},
-            ]}
-    out = render_layer_profile("tiny|cpu|1", prof)
-    assert "tiny|cpu|1" in out
-    assert "1 component(s) failed to profile" in out
-    assert "(FaultInjected: boom)" in out
-    assert "1.00ms" in out                         # measured wall column
-    assert "model_flops=12.0M" in out
-
-
 def test_render_top_dims_stale_rows(monkeypatch):
     """Satellite: a row whose last good scrape is older than 2 scrape
     intervals renders ANSI-dim; fresh rows render normally."""
@@ -560,36 +422,20 @@ def test_render_top_dims_stale_rows(monkeypatch):
     assert out.splitlines()[-1].startswith("\x1b[2m")
 
 
-# --- bench artifact v8 -------------------------------------------------------
+def test_cli_has_no_profile_verb_and_keeps_top_and_timeline(capsys):
+    """`kuke profile ...` is refused by the parser with the usage text
+    (its one sub-command rendered the per-layer profiles); `kuke top` and
+    `kuke timeline` still parse."""
+    from kukeon_tpu.runtime import cli
 
-
-def test_bench_compare_upgrades_v7_and_diffs_mfu(tmp_path):
-    """v7 artifacts upgrade in place (program_costs/mfu default None —
-    reported as n/a, never a regression) and an MFU drop past the
-    threshold flags with higher-is-better polarity."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare_v8", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools", "bench_compare.py"))
-    bc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bc)
-
-    old = tmp_path / "BENCH_r1.json"
-    old.write_text(json.dumps({"schema": "kukeon-bench/v7",
-                               "tok_per_s": 100.0}))
-    art = bc.read_artifact(str(old))
-    assert art["schema"] == "kukeon-bench/v8"
-    assert art["program_costs"] is None and art["mfu"] is None
-
-    new = dict(art, schema="kukeon-bench/v8", mfu=0.5,
-               program_costs={"decode_chunk": {"mfu": 0.5}})
-    prev = dict(art, mfu=0.9)
-    rows, regressed = bc.compare(prev, new, threshold_pct=10.0)
-    mfu_row = next(r for r in rows if r[0] == "MFU")
-    assert mfu_row[4] == "REGRESSION" and regressed
-    # Missing on one side: informational, never a regression.
-    rows, regressed = bc.compare(art, new, threshold_pct=10.0)
-    assert next(r for r in rows if r[0] == "MFU")[4] == "n/a"
-    assert not regressed
+    parser = cli.build_parser()
+    with pytest.raises(SystemExit) as exc:
+        parser.parse_args(["profile", "layers"])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert err.startswith("usage: kuke") and "invalid choice: 'profile'" in err
+    assert "profile" not in cli.HANDLERS
+    assert parser.parse_args(["top"]).cmd == "top"
+    args = parser.parse_args(["timeline", "ns/cell", "-n", "7"])
+    assert (args.cmd, args.cell, args.n) == ("timeline", "ns/cell", 7)
+    assert cli.HANDLERS["timeline"] is cli.cmd_timeline

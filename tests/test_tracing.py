@@ -704,7 +704,7 @@ def test_finish_boot_exports_phases_and_boot_span(real_cell):
     events = [e["event"] for e in boot[0]["events"]]
     assert {"boot_imports", "boot_init", "boot_compile",
             "boot_warmup"} <= set(events)
-    # bench.py's cold-start phase parses these off /metrics.
+    # chip_smoke.py parses these off /metrics.
     fams = fed.parse(expo.render(reg))
     got = {lab["phase"] for _n, lab, _v
            in fams["kukeon_cold_start_phase_seconds"].samples}

@@ -1,13 +1,10 @@
 """The in-daemon time-series store (obs/tsdb.py): selector/window parsing,
 counter-reset-aware rates, histogram-aware windowed percentiles against
 exact values, retention + series-cap bounds under flood, a sanitizer-armed
-concurrent ingest/query hammer, and the bench_compare trajectory diff."""
+concurrent ingest/query hammer."""
 
 from __future__ import annotations
 
-import importlib.util
-import json
-import os
 import threading
 import time
 
@@ -22,8 +19,6 @@ from kukeon_tpu.obs.tsdb import (
     parse_window,
     sparkline,
 )
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _fam(name: str, kind: str, *samples) -> dict:
@@ -306,73 +301,3 @@ def test_concurrent_ingest_query_hammer():
     assert not errors, errors
     st = db.stats()
     assert st["series"] > 0 and st["ingests"] > 0
-
-
-# --- bench_compare -----------------------------------------------------------
-
-
-def _load_bench_compare():
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare", os.path.join(REPO_ROOT, "tools",
-                                      "bench_compare.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _artifact(**over) -> dict:
-    base = {
-        "schema": "kukeon-bench/v3", "at": "2026-01-01T00:00:00Z",
-        "backend": "cpu", "n_chips": 1, "model": "tiny", "replicas": 1,
-        "sessions": 4, "tok_per_s": 1000.0, "trials": [1000.0],
-        "vs_baseline": None,
-        "latency_s": {"ttft": {"p50": 0.01, "p95": 0.05, "p99": 0.09},
-                      "e2e": {"p50": 0.1, "p95": 0.4, "p99": 0.6}},
-        "compiles": None, "peak_hbm_bytes": 1000000,
-        "kv_page_tokens": 16, "max_sessions": 4,
-        "cold_start": {"p50_s": 30.0}, "embedding": None, "mixed": None,
-    }
-    base.update(over)
-    return base
-
-
-def test_bench_compare_regression_table(tmp_path, capsys):
-    bc = _load_bench_compare()
-    for n, art in ((1, _artifact()),
-                   (2, _artifact(tok_per_s=850.0,
-                                 latency_s={"ttft": {"p95": 0.07},
-                                            "e2e": {"p95": 0.41}},
-                                 cold_start=None))):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(art))
-    rc = bc.main(["--dir", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REGRESSION" in out and "tok/s" in out
-    assert "ttft p95" in out and "+40.0%" in out
-    assert "cold start" in out and "n/a" in out     # missing on one side
-    # Looser threshold: the 15% tok/s drop passes at 40%.
-    assert bc.main(["--dir", str(tmp_path), "--threshold", "45"]) == 0
-
-
-def test_bench_compare_skips_non_artifacts(tmp_path, capsys):
-    bc = _load_bench_compare()
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"n": 1, "cmd": "x", "rc": 0}))   # early raw transcript
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(_artifact()))
-    assert bc.main(["--dir", str(tmp_path)]) == 0
-    assert "1 comparable artifact" in capsys.readouterr().out
-
-
-def test_bench_compare_schema_upgrade_matches_bench(tmp_path):
-    """The zero-dep loader in tools/bench_compare.py must upgrade a v1
-    artifact exactly like bench.read_artifact (pinned so they cannot
-    drift)."""
-    import bench
-    bc = _load_bench_compare()
-    v1 = _artifact()
-    v1["schema"] = "kukeon-bench/v1"
-    for k in ("replicas", "kv_page_tokens", "max_sessions"):
-        v1.pop(k)
-    path = tmp_path / "BENCH_r03.json"
-    path.write_text(json.dumps(v1))
-    assert bc.read_artifact(str(path)) == bench.read_artifact(str(path))

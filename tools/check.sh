@@ -17,7 +17,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "check.sh: compileall"
-python -m compileall -q kukeon_tpu tests bench.py
+python -m compileall -q kukeon_tpu tests
 
 echo "check.sh: kukelint (python -m kukeon_tpu.analysis)"
 python -m kukeon_tpu.analysis --strict-baseline
@@ -38,13 +38,8 @@ with open(default_contracts_path(), encoding="utf-8") as f:
 if have != want:
     sys.exit("analysis/guarded_by.json is stale — regenerate with "
              "`python -m kukeon_tpu.analysis --write-contracts`")
-print("guarded_by.json matches the tree")
+print("analysis/guarded_by.json matches the tree")
 EOF
-
-echo "check.sh: bench trajectory diff (informational)"
-python tools/bench_compare.py || \
-    echo "check.sh: bench_compare reports a regression (informational —" \
-         "inspect the newest BENCH_r*.json)"
 
 if python -c "import mypy" >/dev/null 2>&1; then
     echo "check.sh: mypy (strict modules)"
