@@ -7,7 +7,7 @@ row ``p mod rows``), a full layer needs all ``S_max``. A family states its
 kinds as data (``CacheKind``); this file is what the serving engine and the
 model do with them: the shapes, how a prefill's block ``[L, 1, S, KV, D]`` is
 inserted into a slot, how a decode step writes its new row, and which rows a
-decode step may attend to.
+decode step may attend to (``valid``: a count and one excluded row).
 
 The engine HOLDS every stack KV-major, ``[layers, B, KV, rows, D]`` (the layout
 the TPU compiler gives a decode scan's carry; ``serving/engine.py``), and
@@ -74,7 +74,7 @@ def _block_rows(kd: CacheKind, block: jnp.ndarray, length) -> jnp.ndarray:
     as [layers, 1, KV, rows', D] (held layout). A ring shorter than the block
     takes, for each of its rows, the LAST position below ``length`` that
     lands there; rows no position reaches hold whatever the gather brings
-    and are masked by ``valid``."""
+    and lie past the rows ``valid`` counts."""
     part = _of_kind(kd, block)
     if kd.ring and part.shape[2] > kd.rows:
         r = jnp.arange(kd.rows)
@@ -99,15 +99,20 @@ def insert(cache: LayeredKV, kinds, kv_k, kv_v, length, slot) -> LayeredKV:
         lengths=cache.lengths.at[slot].set(length))
 
 
-def valid(kd: CacheKind, lengths: jnp.ndarray) -> jnp.ndarray:
-    """[B, rows] bool: the rows a decode step at position ``lengths`` attends
-    to. A ring holds positions ``lengths - rows .. lengths - 1``; the oldest
-    of them has left the window and sits in the row the new token takes."""
-    r = jnp.arange(kd.rows)[None, :]
-    n = lengths[:, None]
+def valid(kd: CacheKind, lengths: jnp.ndarray):
+    """The rows a decode step at position ``lengths`` attends to, as numbers:
+    (rows to read [B], one row among them left out [B]), which the caller
+    hands to ``decode_gqa_attention`` as ``lengths`` and ``skip`` in place of
+    a ``[B, rows]`` mask, so that the read can stop at the last live row. A
+    full stack reads rows ``< lengths`` and leaves none out (None). A ring
+    holds positions ``lengths - rows .. lengths - 1``: it reads
+    ``min(lengths, rows)`` rows, and once it has wrapped the oldest of them
+    has left the window and sits in the row the new token takes, ``lengths %
+    rows`` (before the wrap that row is past the rows read and excludes
+    nothing)."""
     if kd.ring:
-        return (r < n) & (r != n % kd.rows)
-    return r < n
+        return jnp.minimum(lengths, kd.rows), lengths % kd.rows
+    return lengths, None
 
 
 @jax.named_scope("kv_insert")

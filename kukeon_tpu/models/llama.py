@@ -466,6 +466,7 @@ def forward(
     cache: KVCache | None = None,
     attn_impl: str = "auto",
     logit_positions: jnp.ndarray | None = None,
+    active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None]:
     """Run the decoder.
 
@@ -481,6 +482,8 @@ def forward(
         Prefill needs one next-token distribution, not S_bucket of them —
         at 8B shapes the full head is an S×H×128k matmul plus a [S, 128k]
         f32 tensor, bigger than the rest of the prefill combined.
+      active: optional [B] bool, single-token decode only: the slots whose
+        output the caller keeps. The others read no cache row.
 
     Returns:
       (logits [B, S, V] float32 — [B, 1, V] with ``logit_positions`` —
@@ -495,7 +498,7 @@ def forward(
     # through to the generic path instead of silently ignoring it.
     # (logit_positions is moot at S == 1: there is only one position.)
     if cache is not None and S == 1 and attn_impl in ("auto", "reference"):
-        return _decode_forward(params, c, x, positions, cache, B)
+        return _decode_forward(params, c, x, positions, cache, B, active)
 
     offsets = cache.lengths if cache is not None else None
 
@@ -576,6 +579,7 @@ def _decode_forward(
     positions: jnp.ndarray,
     cache: KVCache,
     B: int,
+    active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Single-token decode, HBM-optimal.
 
@@ -586,25 +590,27 @@ def _decode_forward(
     tiny per-layer new K/V, and the cache is updated once per step with
     per-slot in-place slice writes. Cache bytes stream through HBM exactly
     once per step — and for a quantized cache those bytes are int8, with
-    dequant fused into the attention dots.
+    dequant fused into the attention dots. A slot that is not ``active``
+    reads no row at all (its output is the caller's to drop).
     """
     from kukeon_tpu.ops.attention import decode_gqa_attention
 
     offsets = cache.lengths
+    reads = offsets if active is None else jnp.where(active, offsets, 0)
     pl8 = c.int8_pallas
 
     def layer_step(x, layer):
-        w, ck, cv, cks, cvs = layer
+        w, i = layer
         q, k, v = _qkv(x, w, c, positions, pl8)
-        attn = decode_gqa_attention(q, k, v, ck, cv, offsets,
-                                    k_scale=cks, v_scale=cvs)
+        attn = decode_gqa_attention(
+            q, k, v, cache.k, cache.v, i, reads, k_scale=cache.k_scale,
+            v_scale=cache.v_scale)
         return _mlp(_wo(x, attn, w, c, pl8), w, c, pl8), (k, v)
 
+    # The stacks are read at the layer's index, not scanned over: a kernel
+    # takes the whole stack as its operand and reads the layer in place.
     x, (new_k, new_v) = jax.lax.scan(
-        lambda carry, layer: layer_step(carry, layer),
-        x,
-        (params["layers"], cache.k, cache.v, cache.k_scale, cache.v_scale),
-    )
+        layer_step, x, (params["layers"], jnp.arange(cache.k.shape[0])))
     # new_k/new_v: [L, B, 1, KV, D] — one in-place slice write per slot
     # covering every layer at once (layers share the slot's offset).
     with jax.named_scope("kv_insert"):

@@ -312,6 +312,7 @@ def _decode_forward(
     positions: jnp.ndarray,
     cache: KVCache,
     B: int,
+    active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Single-token decode, HBM-optimal (mirrors llama._decode_forward: the
     layer scan reads the cache as a read-only input and emits only the tiny
@@ -325,10 +326,11 @@ def _decode_forward(
     from kukeon_tpu.ops.attention import decode_gqa_attention
 
     offsets = cache.lengths
+    reads = offsets if active is None else jnp.where(active, offsets, 0)
     pl8 = c.int8_pallas
 
     def layer_step(x, layer):
-        w, ck, cv = layer
+        w, i = layer
         h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
         q = mm(h, w["wq"], pl8).reshape(B, 1, c.num_heads, c.head_dim)
         k = mm(h, w["wk"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
@@ -336,7 +338,7 @@ def _decode_forward(
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)
 
-        attn = decode_gqa_attention(q, k, v, ck, cv, offsets)
+        attn = decode_gqa_attention(q, k, v, cache.k, cache.v, i, reads)
         x = x + mm(attn.reshape(B, 1, c.q_dim), w["wo"], pl8)
 
         h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
@@ -344,10 +346,7 @@ def _decode_forward(
         return x + y, (k, v)
 
     x, (new_k, new_v) = jax.lax.scan(
-        lambda carry, layer: layer_step(carry, (layer[0], layer[1], layer[2])),
-        x,
-        (params["layers"], cache.k, cache.v),
-    )
+        layer_step, x, (params["layers"], jnp.arange(cache.k.shape[0])))
     k_upd, v_upd = cache.k, cache.v
     for b in range(B):
         start = (0, b, offsets[b], 0, 0)
@@ -367,13 +366,15 @@ def forward_with_aux(
     cache: KVCache | None = None,
     attn_impl: str = "auto",
     logit_positions: jnp.ndarray | None = None,
+    active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None, dict]:
     """Run the MoE decoder; returns (logits, cache', aux-loss dict).
 
     Cache semantics identical to ``llama.forward`` (same KVCache layout, so
     the serving engine's insert/decode programs carry over unchanged);
     ``logit_positions`` [B] restricts the LM head to one position per
-    sequence exactly as in ``llama.forward`` (logits come back [B, 1, V]).
+    sequence exactly as in ``llama.forward`` (logits come back [B, 1, V]),
+    and ``active`` [B] keeps the other slots of a decode step off the cache.
 
     A cache marks the inference path: expert capacity switches to the
     no-drop/wide policy (see :func:`_capacity`) — serving must not silently
@@ -385,7 +386,8 @@ def forward_with_aux(
     x = embed(params, tokens, c.dtype)
 
     if cache is not None and S == 1 and attn_impl in ("auto", "reference"):
-        logits, new_cache = _decode_forward(params, c, x, positions, cache, B)
+        logits, new_cache = _decode_forward(params, c, x, positions, cache, B,
+                                            active)
         return logits, new_cache, {"load_balance": jnp.float32(0.0),
                                    "router_z": jnp.float32(0.0)}
 
@@ -458,9 +460,11 @@ def forward(
     cache: KVCache | None = None,
     attn_impl: str = "auto",
     logit_positions: jnp.ndarray | None = None,
+    active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None]:
     """Serving-signature forward (drop-in for ``llama.forward``)."""
     logits, new_cache, _ = forward_with_aux(
-        params, cfg, tokens, positions, cache, attn_impl, logit_positions
+        params, cfg, tokens, positions, cache, attn_impl, logit_positions,
+        active,
     )
     return logits, new_cache

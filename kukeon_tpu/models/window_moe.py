@@ -443,18 +443,19 @@ def decode(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
         for n, number in enumerate(kd.layers):
             index_of[number] = n
     index_of = jnp.asarray(index_of, jnp.int32)
-    masks = [kv_kinds.valid(kd, lengths) if kd.ring else None for kd in kinds]
+    # the rows each kind reads, as numbers; a slot that is not active reads
+    # none (its output is dropped: the engine keeps such a slot's token)
+    reads = [(jnp.where(active, count, 0), skip) for count, skip
+             in (kv_kinds.valid(kd, lengths) for kd in kinds)]
 
     def layer(x, w, layer_type, number):
         kind = kind_of[layer_type]
         q, k, v, gate = _qkvg(x, w, c, positions, rotary=layer_type == SLIDING)
-        ck = jax.lax.dynamic_index_in_dim(cache.k[kind], index_of[number],
-                                          keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cache.v[kind], index_of[number],
-                                          keepdims=False)
+        count, skip = reads[kind]
         with jax.named_scope(_scope(layer_type)):
-            attn = decode_gqa_attention(q, k, v, ck, cv, lengths,
-                                        valid=masks[kind])
+            attn = decode_gqa_attention(
+                q, k, v, cache.k[kind], cache.v[kind], index_of[number],
+                count, skip=skip)
         x, hits = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
         return x, k, v, hits
 

@@ -1,4 +1,4 @@
-"""Attention ops: XLA-fused reference path and Pallas flash dispatch.
+"""Attention ops: XLA-fused reference paths and the Pallas dispatch.
 
 Grouped-query attention (GQA) with a position-based mask, which uniformly
 covers:
@@ -7,10 +7,14 @@ covers:
     key_position <= query_position and slot < used length).
 
 The reference path is plain einsum + softmax: XLA fuses this well on TPU and
-keeps the matmuls on the MXU. The Pallas flash kernel
-(:mod:`kukeon_tpu.ops.flash_attention`) is used for long-sequence prefill and
-training on TPU, where materializing the [S, S] score matrix would blow HBM
-bandwidth.
+keeps the matmuls on the MXU. Two Pallas kernels take over where reading
+less is the point. The flash kernel (:mod:`kukeon_tpu.ops.flash_attention`)
+serves long-sequence prefill and training on TPU, where materializing the
+[S, S] score matrix would blow HBM bandwidth. The decode kernel
+(:mod:`kukeon_tpu.ops.decode_attention`) serves ``decode_gqa_attention`` on
+one TPU with a full-precision cache: a decode step is bound by the cache
+bytes it streams, the XLA body streams every row of every slot whatever is
+live, and the kernel fetches only the blocks that hold live rows.
 """
 
 import jax
@@ -108,6 +112,25 @@ def attention_grouped(
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+def decode_block_rows(heads: int, kv_heads: int, rows: int, head_dim: int,
+                      dtype, cache_dtype, devices: int) -> int | None:
+    """The rows of the blocks in which ``decode_gqa_attention`` reads a
+    cache of ``rows`` rows a slot, or None where its XLA body reads every
+    row. The kernel runs where the call can observe all of: a TPU; a
+    full-precision cache in the activations' dtype (an int8 cache and its
+    scales are the XLA body's); one device (GSPMD does not partition a
+    ``pallas_call``; a tensor axis needs its ``shard_map`` over the KV heads
+    first); shapes the kernel's blocks tile. The engine asks the same
+    question of its own shapes to count the rows a chunk reads."""
+    from kukeon_tpu.ops import decode_attention as da
+
+    if (jax.default_backend() != "tpu" or devices > 1
+            or jnp.dtype(cache_dtype) != jnp.dtype(dtype)
+            or not da.supports(heads, kv_heads, rows, head_dim, cache_dtype)):
+        return None
+    return da.block_rows(kv_heads, rows, head_dim, cache_dtype)
+
+
 @jax.named_scope("attention")
 def decode_gqa_attention(
     q: jnp.ndarray,
@@ -115,21 +138,30 @@ def decode_gqa_attention(
     v_new: jnp.ndarray,
     cache_k: jnp.ndarray,
     cache_v: jnp.ndarray,
+    layer: jnp.ndarray,
     lengths: jnp.ndarray,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
-    valid: jnp.ndarray | None = None,
+    skip: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-token decode attention against a cache, append-free.
 
     The new token's K/V are NOT written into the cache first (that write
     pattern forces a full-cache copy per layer inside a scan); instead the
-    cache contributes `lengths` masked slots and the current token
+    cache contributes its first ``lengths`` rows and the current token
     contributes one extra score, softmaxed together. The caller inserts the
     new K/V into the cache once per step, outside the layer scan.
 
+    Two bodies, chosen by ``decode_block_rows``. On one TPU with a
+    full-precision cache the Pallas kernel
+    (:mod:`kukeon_tpu.ops.decode_attention`) walks each slot's rows block by
+    block and stops at its last live block: a slot of ``lengths`` 0 (the
+    caller passes 0 where a slot is not active) moves no cache byte.
+    Everywhere else the XLA body below scores every row and masks; it is
+    also the kernel's reference in the tests.
+
     Quantized cache: cache_k/cache_v int8 with per-token per-head scales
-    k_scale/v_scale [B, S, KV]. Dequant is fused: the score dot runs on the
+    k_scale/v_scale [layers, B, S, KV]. Dequant is fused: the score dot runs on the
     int8 keys (convert folds into the einsum, so int8 is the HBM stream) and
     the per-token key scale multiplies the f32 scores; the value scale folds
     into the probabilities before the value dot. Exact same math as
@@ -137,14 +169,35 @@ def decode_gqa_attention(
 
     Args:
       q: [B, 1, H, D]; k_new, v_new: [B, 1, KV, D] (always full precision);
-      cache_k, cache_v: [B, S, KV, D]; lengths: [B] valid cache slots;
-      valid: optional [B, S] bool, the rows to attend in place of
-        ``row < lengths`` (a ring that holds a window's rows: every written
-        row but the one the new token is about to take).
+      cache_k, cache_v: the stack [layers, B, S, KV, D], read at index
+        ``layer`` (a traced scalar inside a scan over layers). The whole
+        stack and not the layer's slice, because the kernel reads a layer
+        of its operand in place where a slice would be copied out first;
+      lengths: [B] int32 rows to attend, ``row < lengths`` (at most S);
+      skip: optional [B] int32, one row below ``lengths`` left out (a ring
+        that holds a window's rows: the row the new token is about to take;
+        ``kv_kinds.valid``). A row at or past ``lengths`` excludes nothing.
 
     Returns: [B, 1, H, D].
     """
-    dispatch.note("decode_gqa_attention", "xla")   # no kernel: one path
+    if decode_block_rows(
+            q.shape[2], cache_k.shape[-2], cache_k.shape[-3], q.shape[3],
+            q.dtype, cache_k.dtype,
+            jax.sharding.get_abstract_mesh().size) is not None:
+        from kukeon_tpu.ops import decode_attention as da
+
+        dispatch.note("decode_gqa_attention", "pallas")
+        # The engine holds the stack [layers, B, KV, S, D] and the scan
+        # carries this function's view of it: swapping back is a relabelling
+        # of the held bytes, which the kernel's operand (default layout) is.
+        return da.decode_attention(
+            q, k_new, v_new, jnp.swapaxes(cache_k, 2, 3),
+            jnp.swapaxes(cache_v, 2, 3), lengths, skip, layer)
+    dispatch.note("decode_gqa_attention", "xla")
+    cache_k, cache_v, k_scale, v_scale = (
+        None if x is None else
+        jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+        for x in (cache_k, cache_v, k_scale, v_scale))
     B, _, H, D = q.shape
     S = cache_k.shape[1]
     KV = cache_k.shape[2]
@@ -162,11 +215,11 @@ def decode_gqa_attention(
     ) * scale
     if k_scale is not None:
         s_cache = s_cache * k_scale.transpose(0, 2, 1)[:, :, None, :]
-    if valid is None:
-        valid = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
-    else:
-        valid = valid[:, None, None, :]
-    s_cache = jnp.where(valid, s_cache, NEG_INF)
+    row = jnp.arange(S)[None, :]
+    valid = row < lengths[:, None]
+    if skip is not None:
+        valid = valid & (row != skip[:, None])
+    s_cache = jnp.where(valid[:, None, None, :], s_cache, NEG_INF)
     s_self = jnp.einsum(
         "bkgd,bkd->bkg", qg, k_new.reshape(B, KV, D),
         preferred_element_type=jnp.float32,

@@ -65,6 +65,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kukeon_tpu import faults, sanitize
 from kukeon_tpu.models import families, kv_kinds, llama
+from kukeon_tpu.ops.attention import decode_block_rows
 from kukeon_tpu.serving.kv_pages import (
     SCRATCH_PAGE,
     PageAllocator,
@@ -332,14 +333,16 @@ class ServingEngine:
         # LM head at ONE position instead of all S bucket rows — at 8B
         # shapes that removes a [S, 128k] f32 logits tensor (and its S×H×V
         # matmul) from every prefill, work that otherwise stalls decode.
+        # Those that accept ``active`` are told which slots a decode step
+        # serves, so that the others read no cache row.
         import inspect
 
         try:
-            self._fwd_logit_positions = (
-                "logit_positions" in inspect.signature(self._forward).parameters
-            )
+            accepts = inspect.signature(self._forward).parameters
         except (TypeError, ValueError):
-            self._fwd_logit_positions = False
+            accepts = ()
+        self._fwd_logit_positions = "logit_positions" in accepts
+        self._fwd_active = "active" in accepts
 
         # Tuning profile: levers not pinned by the caller fall back to the
         # persisted tune for this (model, backend, chip-count), then to
@@ -435,6 +438,15 @@ class ServingEngine:
             self._layered.kinds(cfg, self.max_seq_len) if self._layered
             else (kv_kinds.CacheKind("full", tuple(range(cfg.num_layers)),
                                      self.max_seq_len),))
+        # Of each kind, the rows of the blocks the decode attention reads in,
+        # or None where it reads every row: what
+        # kukeon_engine_decode_kv_rows_total counts from.
+        self._kv_blocks = tuple(
+            decode_block_rows(
+                cfg.num_heads, cfg.num_kv_heads, kd.rows, cfg.head_dim,
+                cfg.dtype, jnp.int8 if kv_cache_int8 else cfg.dtype,
+                mesh.size if mesh is not None else 1)
+            for kd in self._kinds)
         if self._layered and (self.paged or kv_cache_int8
                               or (mesh is not None and mesh.size > 1)):
             raise ValueError(
@@ -609,6 +621,13 @@ class ServingEngine:
             "kukeon_engine_decode_chunks_total",
             "Dispatched decode chunks, by their length in steps.",
             labels=("k",))
+        self._m_kv_rows = reg.counter(
+            "kukeon_engine_decode_kv_rows_total",
+            "Cache rows by dispatched decode chunk, over its steps and "
+            "every layer: held = what the slots hold, read = what the "
+            "attention fetches for the active slots (whole blocks of live "
+            "rows where the kernel runs, else all of held).",
+            labels=("what",))
         # What a layered family's forwards sum on the device (an expert
         # layer's routed choices and those that chose a held expert): they
         # come back in the fetches of first tokens and chunk blocks.
@@ -905,6 +924,13 @@ class ServingEngine:
         cfg = self.cfg
         fwd = self._forward
         last_pos_ok = self._fwd_logit_positions
+        tells_active = self._fwd_active
+
+        def served(state: DecodeState) -> dict:
+            """The decode step's word on which slots it serves: a slot that
+            is not active reads no cache row (decided here, inside the
+            jitted chunk, from the state's own flags)."""
+            return {"active": state.active} if tells_active else {}
 
         def last_logits(params, tokens, positions, cache, length):
             """(last-position logits [V], cache') — via the forward's
@@ -1016,7 +1042,8 @@ class ServingEngine:
                 lengths_before = state.cache.lengths
                 positions = lengths_before[:, None]
                 logits, cache = fwd(
-                    params, cfg, tokens, positions, state.cache
+                    params, cfg, tokens, positions, state.cache,
+                    **served(state)
                 )
                 # Inactive slots must not advance their cache length.
                 cache = dataclasses.replace(
@@ -1148,7 +1175,8 @@ class ServingEngine:
                 tokens = st.tokens[:, None]
                 lengths_before = st.cache.lengths
                 positions = lengths_before[:, None]
-                logits, cache = fwd(params, cfg, tokens, positions, st.cache)
+                logits, cache = fwd(params, cfg, tokens, positions, st.cache,
+                                    **served(st))
                 # Inactive slots must not advance their cache length.
                 cache = dataclasses.replace(
                     cache,
@@ -2224,6 +2252,19 @@ class ServingEngine:
         return {f"{kd.name}_rows": sum(kd.live(n) for n in lengths)
                 for kd in self._kinds}
 
+    def _decode_kv_rows(self, lengths) -> tuple[int, int]:
+        """(held, read): the cache rows of all layers that a decode step
+        over active slots of these token counts has before it, and those
+        its attention fetches: the kernel's whole blocks up to each slot's
+        last live row, or every held row where the XLA body runs."""
+        held = read = 0
+        for kd, block in zip(self._kinds, self._kv_blocks):
+            all_rows = len(kd.layers) * self.num_slots * kd.rows
+            held += all_rows
+            read += all_rows if block is None else len(kd.layers) * sum(
+                -(-kd.live(n) // block) * block for n in lengths)
+        return held, read
+
     def _prefill_span(self, req: Request, slot: int):
         """The span of one request's prefill dispatch (slot -1: an export,
         which takes none); ``_prefill_dispatched`` gives it its counts."""
@@ -2730,6 +2771,9 @@ class ServingEngine:
         lens = [self._slot_len[slot] for slot, _req in active]
         span.set(k=k, active=len(active), live_rows=sum(lens),
                  **self._rows_by_kind(lens))
+        held, read = self._decode_kv_rows(lens)
+        self._m_kv_rows.inc(k * held, what="held")
+        self._m_kv_rows.inc(k * read, what="read")
         temps_d, top_ks_d, top_ps_d = self._sampling_dev_arrays()
         with jax.set_mesh(self.mesh):
             self._key, k1 = jax.random.split(self._key)

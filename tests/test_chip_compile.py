@@ -4,8 +4,9 @@ The TPU compiler is installed here and compiles for a v5e that is described
 (`jax.experimental.topologies`), not attached: it refuses what the chip's
 compiler would refuse — a kernel tile that does not align, a program that
 does not fit 16 GB — and interpret-mode tests on the CPU cannot. These are
-the main path's kernels at the real widths and one whole decode program of
-the flagship cell; they guard every later PR at no chip time. A compile that
+the main path's kernels at the real widths and the whole decode programs of
+the flagship cell and of both benchmark configurations; they guard every
+later PR at no chip time. A compile that
 passes is not a chip run: nothing executes, no number here is a device
 metric.
 
@@ -31,6 +32,7 @@ import pytest  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding  # noqa: E402
 
 from kukeon_tpu.models import llama  # noqa: E402
+from kukeon_tpu.ops import decode_attention as da  # noqa: E402
 from kukeon_tpu.ops import flash_attention as fa  # noqa: E402
 from kukeon_tpu.ops import int8_matmul as i8  # noqa: E402
 
@@ -184,3 +186,106 @@ def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e, k):
         f"decode_chunk (k={k}) holds {m.temp_size_in_bytes / 1e9:.2f} GB of "
         f"temporaries beside a K + V cache of {kv_bytes / 1e9:.2f} GB: a "
         "whole-cache copy is back in the program")
+
+
+# Decode attention at the benchmark's shapes: the held stack [layers, B, KV,
+# rows, D], query heads a KV head, whether a row is excluded (a ring).
+@pytest.mark.parametrize("layers, slots, rows, groups, ring", [
+    (32, 8, 2048, 4, False),     # mistral-7b-v0.3: 8 slots x 2048 rows
+    (1, 32, 8192, 6, False),     # trinity share: the full layer
+    (4, 32, 4096, 6, True),      # trinity share: the four rings
+])
+def test_decode_attention_compiles_for_v5e(v5e, layers, slots, rows, groups,
+                                           ring):
+    d = v5e.devices[0]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    new = _on(d, (slots, 1, 8, 128), bf)
+    held = _on(d, (layers, slots, 8, rows, 128), bf)
+    compiled = jax.jit(
+        lambda q, kn, vn, k, v, count, skip, layer: da.decode_attention(
+            q, kn, vn, k, v, count, skip if ring else None, layer)
+    ).lower(_on(d, (slots, 1, 8 * groups, 128), bf), new, new, held, held,
+            _on(d, (slots,), i32), _on(d, (slots,), i32),
+            _on(d, (), i32)).compile()
+    _assert_kernel(compiled)
+    # the stacks are operands in place: nothing of a layer's size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def _abstract_cell(v5e, config_name):
+    """The engine of a benchmark configuration over shapes alone, as
+    ``benchmark/rehearse_compile.py`` builds it, and its decode chunk's
+    arguments but the chunk's length."""
+    import json
+
+    from benchmark import plugins, rehearse_compile as rc
+    from kukeon_tpu.parallel import make_mesh
+
+    with open(os.path.join(plugins.HERE, "configs", config_name + ".json")) as f:
+        config = json.load(f)
+    mesh = make_mesh(tensor=1, devices=v5e.devices[:1])
+    _cfg, eng = rc.abstract_engine(config, mesh)
+    repl = NamedSharding(mesh, PartitionSpec())
+    B = config["serving"]["num_slots"]
+    key = jax.eval_shape(lambda: jax.random.key(0))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    return mesh, eng, (eng._abstract_params, eng._abstract_state(),
+                       sds(key.shape, key.dtype), sds((B,), jnp.float32),
+                       sds((B,), jnp.int32), sds((B,), jnp.float32))
+
+
+def _cache_sized_values(text: str, cache_elements: int) -> list[str]:
+    """Instructions of a compiled program that MAKE a value of a layer of
+    the cache's size or more, in the cache's dtype: a copy, a transpose or a
+    fusion that materializes a slice of it. In-place updates, operands passed
+    through and the loop's own plumbing make nothing."""
+    import re
+
+    made = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
+                     line)
+        if not m or m.group(3) in (
+                "get-tuple-element", "parameter", "bitcast", "while", "tuple",
+                "dynamic-update-slice", "custom-call"):
+            continue
+        n = 1
+        for dim in m.group(2).split(","):
+            n *= int(dim)
+        if n >= cache_elements:
+            made.append(f"{m.group(3)} {m.group(1)} [{m.group(2)}]")
+    return made
+
+
+@pytest.mark.parametrize("config, k, gb", [
+    ("mistral-7b-v0.3-int8", 16, 10.09),
+    ("mistral-7b-v0.3-int8", 4, 10.09),
+    ("trinity-large-preview-ep8-bf16", 4, 12.12),
+])
+def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
+        v5e, config, k, gb):
+    """A benchmark cell's whole decode program, built by the engine from
+    shapes alone through the cell's launcher: the decode kernel is in it
+    (one call a layer kind the scan holds), nothing in it makes a value the
+    size of one layer's K or V of one kind (a Pallas operand takes its
+    default layout, so a kernel fed a slice or a transposed view of the
+    stack would bring a copy of it back), and what it keeps resident is what
+    it kept with the XLA body."""
+    from benchmark import rehearse_compile as rc
+    from kukeon_tpu.ops import dispatch
+
+    mesh, eng, args = _abstract_cell(v5e, config)
+    before = dispatch.counts().get(("decode_gqa_attention", "pallas"), 0)
+    with jax.set_mesh(mesh):
+        compiled = eng._decode_chunk.lower(*args, k).compile()
+    assert dispatch.counts()[("decode_gqa_attention", "pallas")] > before
+    text = compiled.as_text()
+    assert "decode_attention" in text and "tpu_custom_call" in text
+    held = args[1].cache.k           # one stack, or one a kind
+    smallest = min(x.size // x.shape[0]
+                   for x in (held if isinstance(held, tuple) else (held,)))
+    assert _cache_sized_values(text, smallest) == []
+    assert rc.resident(compiled) / 1e9 == pytest.approx(gb, rel=0.01)

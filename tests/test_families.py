@@ -18,7 +18,10 @@ from kukeon_tpu.serving import ServingEngine
 # compile-cache key is made of this text (locations stripped), so equal text
 # means the driver's machine finds the parent's executables again: set-up does
 # not move and no cell of the dense family can slow. A PR that changes a dense
-# program on purpose takes new hashes from its own tree.
+# program on purpose takes new hashes from its own tree: PR 33 took the three
+# decode chunks' (a step tells its attention which slots are active, and the
+# layer scan reads the held stack at the layer's index instead of scanning
+# over it); the prefills and inserts are still the text of f58059e.
 PARENT = {
     "dense.prefill":
         "d43f0d082864daf2b08f8c81884954f77f21a2c29940a4ecad3fe20849e0bcaa",
@@ -27,15 +30,15 @@ PARENT = {
     "dense.insert":
         "10856345330b648a26a4eed787f437a3c7c3a254284a22f36fe32a049a49b761",
     "dense.decode_chunk":
-        "3e4312cb6790672b3c9e2713ef15de6d4d2653861b01c6e97782c3d3c556648f",
+        "272b210a8b13e22bef10a4480cbb5784399a60b993ecfb92b63f09cb0533e67e",
     "dense.insert_paged":
         "33a5e37d00fe4fe26ca25dddf825aff5b211225d39a4e051d65a4cc54faf8bde",
     "dense.decode_chunk_paged":
-        "fdb5e034981d2ea5f6cd2e6dcf84f623930fee3347d99349bbc9014b65792bde",
+        "cde2cf61ef4c716ae7b28a6ef97e79321884c160c9b13b46b2ba180c707e20c6",
     "moe.prefill":
         "90cc96c2f17fd443b1a5717fa0ecb43eef2d6214dbb9e62fadcff82c254aeb32",
     "moe.decode_chunk":
-        "7ea03ef596f689b93005455a21cca5e512c712f3bf9134333c966890e712f937",
+        "88c4e173027326e1ecebf12a8a08734809b72b921968ecd918c1e0d7f766a11c",
 }
 
 
