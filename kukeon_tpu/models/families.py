@@ -10,12 +10,13 @@ slot's cache holds layer by layer:
   (contiguous or paged, bf16 or int8), written by ``forward`` itself; the
   prefix store, ``prefill_ext`` and the KV handoff work on that block.
 - ``layered`` set: the layers are of several kinds (``models/kv_kinds.py``:
-  a ring of a window's rows beside full stacks). The family states its kinds
-  and brings ``prefill`` and ``decode``; the engine owns insertion, the step's
-  write and the valid rows.
+  a ring of a window's rows beside full stacks; a state-space layer's state,
+  which has no rows at all). The family states its kinds and brings
+  ``prefill`` and ``decode``; the engine owns insertion, the step's write and
+  the valid rows.
 
-A third family is a record here and a module beside this one, not another set
-and another branch in the cell.
+A further family is a record here and a module beside this one, not another
+set and another branch in the cell.
 """
 
 from __future__ import annotations
@@ -32,10 +33,14 @@ class Layered:
     """The forwards of a family whose cache has several kinds of layers."""
 
     kinds: Callable[[Any, int], tuple]      # (cfg, max_seq_len) -> CacheKinds
+    # Each forward hands back ONE tree shaped as the cache's kinds state it
+    # (arrays by name: ``kv_kinds.names``), which the engine passes on whole.
     prefill: Callable       # (params, cfg, tokens [1, S], length) ->
-    #                         (logits [V], k, v [L, 1, S, KV, D], counters)
+    #                         (logits [V], block, counters); block: k, v
+    #                         [L, 1, S, KV, D] and the state at ``length``
     decode: Callable        # (params, cfg, tokens [B], view, kinds, active)
-    #                         -> (logits [B, V], k, v [L, B, 1, KV, D], counters)
+    #                         -> (logits [B, V], new, counters); new: k, v
+    #                         [L, B, 1, KV, D] and the state stacks, replaced
     counters: tuple[str, ...] = ()          # device-summed, by metric name
 
 
@@ -120,9 +125,19 @@ def _window_moe_init(cfg, seed, quantize, mesh):
         abstract, mesh, specs=window_moe.param_specs(abstract)))
 
 
+def _ssm_hybrid_init(cfg, seed, quantize, mesh):
+    from kukeon_tpu.models import ssm_hybrid
+    from kukeon_tpu.parallel import sharding as shd
+
+    key = jax.random.key(seed)
+    abstract = jax.eval_shape(lambda k: ssm_hybrid.init_params(k, cfg), key)
+    return ssm_hybrid.init_params(key, cfg, shd.param_shardings(
+        abstract, mesh, specs=ssm_hybrid.param_specs(abstract)))
+
+
 @functools.cache
 def _families() -> tuple[Family, ...]:
-    from kukeon_tpu.models import llama, moe, window_moe
+    from kukeon_tpu.models import llama, moe, ssm_hybrid, window_moe
 
     return (
         Family("dense_gqa", llama.LlamaConfig, _dense_init,
@@ -141,6 +156,11 @@ def _families() -> tuple[Family, ...]:
                    kinds=window_moe.WindowMoEConfig.cache_kinds,
                    prefill=window_moe.prefill, decode=window_moe.decode,
                    counters=window_moe.COUNTERS)),
+        Family("ssm_hybrid", ssm_hybrid.SsmHybridConfig, _ssm_hybrid_init,
+               param_specs=ssm_hybrid.param_specs,
+               layered=Layered(
+                   kinds=ssm_hybrid.SsmHybridConfig.cache_kinds,
+                   prefill=ssm_hybrid.prefill, decode=ssm_hybrid.decode)),
     )
 
 

@@ -1,19 +1,30 @@
 """A decode cache whose layers are of several kinds.
 
-``llama.KVCache`` is one stack: every layer holds ``S_max`` rows a slot. A
-model with window layers beside full ones holds two kinds of state: a window
-layer needs only its window's rows, kept as a ring (position ``p`` lives in
-row ``p mod rows``), a full layer needs all ``S_max``. A family states its
-kinds as data (``CacheKind``); this file is what the serving engine and the
-model do with them: the shapes, how a prefill's block ``[L, 1, S, KV, D]`` is
-inserted into a slot, how a decode step writes its new row, and which rows a
-decode step may attend to (``valid``: a count and one excluded row).
+``llama.KVCache`` is one stack: every layer holds ``S_max`` rows a slot. Other
+models hold other things for a slot, and a family states them as data
+(``CacheKind``):
 
-The engine HOLDS every stack KV-major, ``[layers, B, KV, rows, D]`` (the layout
-the TPU compiler gives a decode scan's carry; ``serving/engine.py``), and
-swaps to the row-major view ``[layers, B, rows, KV, D]`` on its way into a
+- **rows of K and V**: a full layer holds ``S_max`` rows, a window layer only
+  its window's, kept as a ring (position ``p`` lives in row ``p mod rows``);
+- **state**: named arrays WITHOUT a row axis, of a fixed size whatever the
+  slot's length (a state-space layer's convolution tail and scan state). A
+  prefill leaves the arrays of its last real token, ``insert`` copies them
+  into the slot, and a decode step REPLACES them: nothing is appended, no row
+  is valid or not, nothing wraps.
+
+This file is what the serving engine and the model do with both: the shapes,
+how what a prefill leaves behind (its ``block``: ``k`` and ``v`` ``[L, 1, S,
+KV, D]`` over the layers that hold rows, and each state array by its name) is
+inserted into a slot, how a decode step writes its new row or takes its new
+state, and which rows a decode step may attend to (``valid``: a count and one
+excluded row).
+
+The engine HOLDS every K / V stack KV-major, ``[layers, B, KV, rows, D]`` (the
+layout the TPU compiler gives a decode scan's carry; ``serving/engine.py``),
+and swaps to the row-major view ``[layers, B, rows, KV, D]`` on its way into a
 chunk's scan and back (``view``): ``append`` and the model's decode step work
-on the view, ``insert`` on the held arrays.
+on the view, ``insert`` on the held arrays. A state array has one layout, the
+one its kind states.
 """
 
 from __future__ import annotations
@@ -27,41 +38,73 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class CacheKind:
     name: str                   # the label of kukeon_engine_kv_rows{kind}
-    layers: tuple[int, ...]     # the layers of a prefill's block it holds
-    rows: int                   # rows a slot holds
+    layers: tuple[int, ...]     # the layers of a prefill's K / V block it holds
+    rows: int = 0               # rows of K and V a slot holds; 0: none
     ring: bool = False          # row = position mod rows (else = position)
+    # What a slot holds besides: (name, shape, dtype) of arrays without a row
+    # axis, the shape with None where the slots go.
+    state: tuple = ()
+
+    @property
+    def unit(self) -> str:
+        """What ``live`` counts: a span's ``<name>_<unit>``."""
+        return "rows" if self.rows else "slots"
 
     def live(self, length: int) -> int:
-        """Rows of a slot of ``length`` tokens that hold something."""
-        return min(length, self.rows)
+        """Rows of a slot of ``length`` tokens that hold something; of a
+        kind without rows the one state the slot holds."""
+        return min(length, self.rows) if self.rows else 1
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class LayeredKV:
-    k: tuple                    # per kind
-    v: tuple
+    held: tuple                 # per kind: its arrays by name (k, v; state)
     lengths: jnp.ndarray        # [B] tokens a slot holds (not rows)
+
+    @property
+    def k(self) -> tuple:
+        """The K stacks of the kinds that hold rows, in their order."""
+        return tuple(h["k"] for h in self.held if "k" in h)
+
+    @property
+    def v(self) -> tuple:
+        return tuple(h["v"] for h in self.held if "v" in h)
+
+
+def names(kinds) -> tuple[str, ...]:
+    """The arrays of a prefill's block, in the order of its leaves."""
+    rows = ("k", "v") if any(kd.rows for kd in kinds) else ()
+    return tuple(sorted(rows + tuple(
+        name for kd in kinds for name, _shape, _dtype in kd.state)))
 
 
 def shapes(kinds, batch: int, kv_heads: int, head_dim: int, dtype) -> LayeredKV:
     """ShapeDtypeStructs of the HELD cache."""
-    kv = tuple(jax.ShapeDtypeStruct(
-        (len(kd.layers), batch, kv_heads, kd.rows, head_dim), dtype)
-        for kd in kinds)
-    return LayeredKV(k=kv, v=kv,
+    def of(kd):
+        out = {}
+        if kd.rows:
+            out["k"] = out["v"] = jax.ShapeDtypeStruct(
+                (len(kd.layers), batch, kv_heads, kd.rows, head_dim), dtype)
+        for name, shape, dt in kd.state:
+            out[name] = jax.ShapeDtypeStruct(
+                tuple(batch if n is None else n for n in shape), dt)
+        return out
+
+    return LayeredKV(held=tuple(of(kd) for kd in kinds),
                      lengths=jax.ShapeDtypeStruct((batch,), jnp.int32))
 
 
 def view(cache: LayeredKV) -> LayeredKV:
-    """held <-> row-major view; its own inverse."""
-    return LayeredKV(k=tuple(jnp.swapaxes(x, 2, 3) for x in cache.k),
-                     v=tuple(jnp.swapaxes(x, 2, 3) for x in cache.v),
-                     lengths=cache.lengths)
+    """held <-> row-major view of K and V; its own inverse."""
+    return LayeredKV(
+        held=tuple({name: jnp.swapaxes(x, 2, 3) if name in ("k", "v") else x
+                    for name, x in h.items()} for h in cache.held),
+        lengths=cache.lengths)
 
 
 def _of_kind(kd: CacheKind, per_layer: jnp.ndarray) -> jnp.ndarray:
-    """The layers of ``kd`` out of an array whose axis 0 is the model's
+    """The layers of ``kd`` out of an array whose axis 0 is the block's
     layers: a slice where they are neighbours."""
     first, last = kd.layers[0], kd.layers[-1]
     if kd.layers == tuple(range(first, last + 1)):
@@ -86,16 +129,25 @@ def _block_rows(kd: CacheKind, block: jnp.ndarray, length) -> jnp.ndarray:
 
 
 @jax.named_scope("kv_insert")
-def insert(cache: LayeredKV, kinds, kv_k, kv_v, length, slot) -> LayeredKV:
-    """A prefill's block into ``slot`` of the held cache, kind by kind."""
-    at = (0, slot, 0, 0, 0)
+def insert(cache: LayeredKV, kinds, block: dict, length, slot) -> LayeredKV:
+    """What a prefill left behind into ``slot`` of the held cache, kind by
+    kind: the rows each kind keeps of ``k`` and ``v``, and a state array
+    whole (the prefill's has one slot where the held one has all)."""
+    def of(kd, held):
+        out = {}
+        for name in ("k", "v") if kd.rows else ():
+            out[name] = jax.lax.dynamic_update_slice(
+                held[name], _block_rows(kd, block[name], length),
+                (0, slot, 0, 0, 0))
+        for name, shape, _dtype in kd.state:
+            at = [0] * len(shape)
+            at[shape.index(None)] = slot
+            out[name] = jax.lax.dynamic_update_slice(
+                held[name], block[name].astype(held[name].dtype), at)
+        return out
+
     return LayeredKV(
-        k=tuple(jax.lax.dynamic_update_slice(
-            held, _block_rows(kd, kv_k, length), at)
-            for kd, held in zip(kinds, cache.k)),
-        v=tuple(jax.lax.dynamic_update_slice(
-            held, _block_rows(kd, kv_v, length), at)
-            for kd, held in zip(kinds, cache.v)),
+        held=tuple(of(kd, h) for kd, h in zip(kinds, cache.held)),
         lengths=cache.lengths.at[slot].set(length))
 
 
@@ -115,20 +167,40 @@ def valid(kd: CacheKind, lengths: jnp.ndarray):
     return lengths, None
 
 
+def keep(active: jnp.ndarray, new: jnp.ndarray, old: jnp.ndarray,
+         axis: int) -> jnp.ndarray:
+    """``new`` where a slot is ``active`` [B], else ``old``; the slots on
+    ``axis``. A model applies it to a LAYER's state before writing it back
+    into the stack, so that the select rides in the update's own pass and no
+    second array of the stack's size is made."""
+    shape = [1] * new.ndim
+    shape[axis] = active.shape[0]
+    return jnp.where(active.reshape(shape), new, old)
+
+
 @jax.named_scope("kv_insert")
-def append(cache: LayeredKV, kinds, new_k, new_v, active) -> LayeredKV:
-    """One decode step's rows [L, B, 1, KV, D] into the VIEW, one in-place
-    slice write a slot and kind (``llama.cache_insert`` says why a loop);
-    lengths advance where ``active``."""
+def append(cache: LayeredKV, kinds, new: dict, active) -> LayeredKV:
+    """One decode step's outcome into the VIEW. ``new["k"]``, ``new["v"]``
+    [L, B, 1, KV, D]: one in-place slice write a slot and kind
+    (``llama.cache_insert`` says why a loop). A state array comes back from
+    the step WHOLE, in the held shape, and takes the place of the one the
+    step read; where a slot is not ``active`` it already holds what it held
+    (``keep``). Lengths advance where ``active``."""
     B = cache.lengths.shape[0]
-    ks, vs = list(cache.k), list(cache.v)
-    for i, kd in enumerate(kinds):
-        nk, nv = _of_kind(kd, new_k), _of_kind(kd, new_v)
-        row = cache.lengths % kd.rows if kd.ring else cache.lengths
-        for b in range(B):
-            at = (0, b, row[b], 0, 0)
-            ks[i] = jax.lax.dynamic_update_slice(ks[i], nk[:, b:b + 1], at)
-            vs[i] = jax.lax.dynamic_update_slice(vs[i], nv[:, b:b + 1], at)
-    return LayeredKV(k=tuple(ks), v=tuple(vs),
-                     lengths=jnp.where(active, cache.lengths + 1,
-                                       cache.lengths))
+
+    def of(kd, held):
+        out = {name: new[name] for name, _shape, _dtype in kd.state}
+        if kd.rows:
+            row = cache.lengths % kd.rows if kd.ring else cache.lengths
+            rows = {name: _of_kind(kd, new[name]) for name in ("k", "v")}
+            out.update({name: held[name] for name in rows})
+            for b in range(B):
+                for name in rows:
+                    out[name] = jax.lax.dynamic_update_slice(
+                        out[name], rows[name][:, b:b + 1],
+                        (0, b, row[b], 0, 0))
+        return out
+
+    return LayeredKV(
+        held=tuple(of(kd, h) for kd, h in zip(kinds, cache.held)),
+        lengths=jnp.where(active, cache.lengths + 1, cache.lengths))

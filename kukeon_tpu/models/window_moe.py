@@ -26,7 +26,8 @@ depth costs no compile time.
 Two forwards, for the serving engine's per-kind cache (``models/kv_kinds.py``):
 ``prefill`` runs a whole prompt without a cache, attention in query blocks (a
 window layer's block reads only its band of keys, so an 8192-token prompt
-never builds an [S, S] score matrix), and returns every layer's K/V block;
+never builds an [S, S] score matrix), and returns every layer's K/V block
+(``{"k", "v"}``, the block ``kv_kinds.insert`` takes);
 ``decode`` runs one token a slot against the held stacks (a ring of
 ``sliding_window`` rows for window layers, ``S_max`` rows for full ones). Keys
 are stored rotated, so ring order does not matter.
@@ -47,7 +48,7 @@ import jax.numpy as jnp
 from kukeon_tpu.models import kv_kinds
 from kukeon_tpu.models.expert_layer import expert_layer, swiglu
 from kukeon_tpu.models.llama import embed, mm
-from kukeon_tpu.ops.attention import NEG_INF, decode_gqa_attention
+from kukeon_tpu.ops.attention import blocked_attention, decode_gqa_attention
 from kukeon_tpu.ops.norms import rms_norm
 from kukeon_tpu.ops.rope import apply_rope
 
@@ -314,34 +315,6 @@ def _mlp(x, w: dict, c: WindowMoEConfig, counted):
     return x + rms_norm(m, w["norm4"], c.rms_norm_eps), hits
 
 
-def blocked_attention(q, k, v, window: int | None, block: int):
-    """Causal GQA over one prompt in query blocks of ``block`` rows. A block
-    of a window layer slices the band of keys it can see; a full layer's
-    block, the keys up to its last row. q [B, S, NH, D]; k, v [B, S, KV, D]."""
-    B, S, NH, D = q.shape
-    KV = k.shape[2]
-    block = min(block, S)
-    if S % block:
-        raise ValueError(f"a prompt bucket of {S} rows is no multiple of the "
-                         f"query block {block}")
-    scale = D ** -0.5
-    outs = []
-    for q0 in range(0, S, block):
-        q1 = q0 + block
-        k0 = 0 if window is None else max(0, q0 - window)
-        qb = q[:, q0:q1].reshape(B, block, KV, NH // KV, D)
-        s = jnp.einsum("bqkgd,bTkd->bkgqT", qb, k[:, k0:q1],
-                       preferred_element_type=jnp.float32) * scale
-        back = (q0 + jnp.arange(block))[:, None] - (k0 + jnp.arange(q1 - k0))
-        see = back >= 0
-        if window is not None:
-            see &= back < window
-        p = jax.nn.softmax(jnp.where(see, s, NEG_INF), axis=-1)
-        o = jnp.einsum("bkgqT,bTkd->bqkgd", p.astype(v.dtype), v[:, k0:q1])
-        outs.append(o.reshape(B, block, NH, D))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-
-
 def _scope(layer_type: str) -> str:
     return "window_attention" if layer_type == SLIDING else "full_attention"
 
@@ -400,10 +373,10 @@ def _through_layers(params: Params, c: WindowMoEConfig, x, layer):
 
 
 def prefill(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
-            length) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+            length) -> tuple[jnp.ndarray, dict, jnp.ndarray]:
     """tokens [1, S] (``length`` of them real) -> (float32 logits [V] of the
-    last real position, K block, V block [L, 1, S, KV, D] with window layers'
-    keys rotated, COUNTERS)."""
+    last real position, the block ``{"k", "v"}`` [L, 1, S, KV, D] with window
+    layers' keys rotated, COUNTERS)."""
     c = cfg
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -422,14 +395,15 @@ def prefill(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
     x, ks, vs, hits = _through_layers(
         params, c, _embed_scaled(params, c, tokens), layer)
     last = jax.lax.dynamic_index_in_dim(x[0], length - 1, keepdims=True)
-    return _head(params, c, last)[0], ks, vs, _counters(c, counted, hits)
+    return (_head(params, c, last)[0], {"k": ks, "v": vs},
+            _counters(c, counted, hits))
 
 
 def decode(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
            cache: kv_kinds.LayeredKV, kinds, active: jnp.ndarray):
     """One token a slot against the VIEW of the held cache (``kv_kinds``):
     tokens [B] at positions ``cache.lengths`` -> (float32 logits [B, V], this
-    step's K and V rows [L, B, 1, KV, D], COUNTERS over the ``active``
+    step's rows ``{"k", "v"}`` [L, B, 1, KV, D], COUNTERS over the ``active``
     slots). The cache is read, never written: the caller appends."""
     c = cfg
     lengths = cache.lengths
@@ -454,11 +428,12 @@ def decode(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
         count, skip = reads[kind]
         with jax.named_scope(_scope(layer_type)):
             attn = decode_gqa_attention(
-                q, k, v, cache.k[kind], cache.v[kind], index_of[number],
-                count, skip=skip)
+                q, k, v, cache.held[kind]["k"], cache.held[kind]["v"],
+                index_of[number], count, skip=skip)
         x, hits = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
         return x, k, v, hits
 
     x, ks, vs, hits = _through_layers(
         params, c, _embed_scaled(params, c, tokens[:, None]), layer)
-    return _head(params, c, x)[:, 0], ks, vs, _counters(c, counted, hits)
+    return (_head(params, c, x)[:, 0], {"k": ks, "v": vs},
+            _counters(c, counted, hits))

@@ -17,8 +17,8 @@ do a step's work; what it spends outside them is phase ``other``.
 | ``cell.generate`` | request | - (above the engine) |
 | ``engine.step`` | | ``other`` = its time less its children |
 | ``engine.admit`` | free, queued | ``admit`` |
-| ``engine.prefill_dispatch`` | request, slot, program, hit, cached, real, padded[, <kind>_rows] | - (inside ``admit``) |
-| ``engine.decode_dispatch`` | k, active, live_rows[, <kind>_rows] | ``decode_dispatch`` |
+| ``engine.prefill_dispatch`` | request, slot, program, hit, cached, real, padded[, <kind>_rows, <kind>_slots] | - (inside ``admit``) |
+| ``engine.decode_dispatch`` | k, active, live_rows[, <kind>_rows, <kind>_slots] | ``decode_dispatch`` |
 | ``engine.fetch_first`` | n | ``fetch_first`` |
 | ``engine.fetch_chunk`` | k | ``fetch_chunk`` |
 | ``engine.emit`` | tokens | ``emit`` |
@@ -27,7 +27,9 @@ do a step's work; what it spends outside them is phase ``other``.
 
 ``<kind>_rows`` (``window_rows``, ``full_rows``) are on the spans of a family
 whose layers hold several kinds of state (``models/kv_kinds.py``): the rows the
-dispatched slots hold in one layer of each kind.
+dispatched slots hold in one layer of each kind. A kind that holds state
+without rows (a state-space layer's) gives ``<kind>_slots`` (``state_slots``):
+the slots whose state the dispatch touches.
 
 ``request`` is the request's trace id (``req.trace.trace_id``), the identifier
 ``/v1/trace`` and ``/v1/timeline`` already use.

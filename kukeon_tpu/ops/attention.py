@@ -112,6 +112,34 @@ def attention_grouped(
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+def blocked_attention(q, k, v, window: int | None, block: int):
+    """Causal GQA over one prompt in query blocks of ``block`` rows. A block
+    of a window layer slices the band of keys it can see; a full layer's
+    block, the keys up to its last row. q [B, S, NH, D]; k, v [B, S, KV, D]."""
+    B, S, NH, D = q.shape
+    KV = k.shape[2]
+    block = min(block, S)
+    if S % block:
+        raise ValueError(f"a prompt bucket of {S} rows is no multiple of the "
+                         f"query block {block}")
+    scale = D ** -0.5
+    outs = []
+    for q0 in range(0, S, block):
+        q1 = q0 + block
+        k0 = 0 if window is None else max(0, q0 - window)
+        qb = q[:, q0:q1].reshape(B, block, KV, NH // KV, D)
+        s = jnp.einsum("bqkgd,bTkd->bkgqT", qb, k[:, k0:q1],
+                       preferred_element_type=jnp.float32) * scale
+        back = (q0 + jnp.arange(block))[:, None] - (k0 + jnp.arange(q1 - k0))
+        see = back >= 0
+        if window is not None:
+            see &= back < window
+        p = jax.nn.softmax(jnp.where(see, s, NEG_INF), axis=-1)
+        o = jnp.einsum("bkgqT,bTkd->bqkgd", p.astype(v.dtype), v[:, k0:q1])
+        outs.append(o.reshape(B, block, NH, D))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 def decode_block_rows(heads: int, kv_heads: int, rows: int, head_dim: int,
                       dtype, cache_dtype, devices: int) -> int | None:
     """The rows of the blocks in which ``decode_gqa_attention`` reads a

@@ -409,7 +409,7 @@ MOE_MODELS = set()
 
 
 def _register_models():
-    from kukeon_tpu.models import bert, llama, moe, window_moe
+    from kukeon_tpu.models import bert, llama, moe, ssm_hybrid, window_moe
 
     MODELS.update({
         "tiny": llama.llama_tiny,
@@ -418,6 +418,7 @@ def _register_models():
         "mixtral-tiny": moe.moe_tiny,
         "mixtral-8x7b": moe.mixtral_8x7b,
         "window-moe-tiny": window_moe.window_moe_tiny,
+        "ssm-hybrid-tiny": ssm_hybrid.ssm_hybrid_tiny,
     })
     MOE_MODELS.update({"mixtral-tiny", "mixtral-8x7b"})
     EMBEDDING_MODELS.update({

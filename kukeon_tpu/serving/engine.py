@@ -156,8 +156,9 @@ class DecodeState:
 
     # Contiguous: k/v HELD [L, B, KV, S_max, D] (_kv_major: the decode scan's
     # own layout), scales [L, B, S_max, KV]; paged: the pool [L, P, pt, KV, D].
-    # A family whose layers are of several kinds: kv_kinds.LayeredKV, one
-    # held stack a kind (a ring of a window's rows beside full stacks).
+    # A family whose layers are of several kinds: kv_kinds.LayeredKV, what
+    # each kind holds by name (a ring of a window's rows beside full stacks;
+    # a state-space layer's state, which has no rows).
     cache: "llama.KVCache | kv_kinds.LayeredKV"     # + lengths [B]
     tokens: jnp.ndarray           # [B] int32 — last emitted token per slot
     active: jnp.ndarray           # [B] bool — slot currently generating
@@ -445,8 +446,11 @@ class ServingEngine:
             decode_block_rows(
                 cfg.num_heads, cfg.num_kv_heads, kd.rows, cfg.head_dim,
                 cfg.dtype, jnp.int8 if kv_cache_int8 else cfg.dtype,
-                mesh.size if mesh is not None else 1)
+                mesh.size if mesh is not None else 1) if kd.rows else None
             for kd in self._kinds)
+        # The kinds whose slots hold state without rows: what
+        # kukeon_engine_state_slot_steps_total counts from.
+        self._state_kinds = sum(1 for kd in self._kinds if kd.state)
         if self._layered and (self.paged or kv_cache_int8
                               or (mesh is not None and mesh.size > 1)):
             raise ValueError(
@@ -621,6 +625,13 @@ class ServingEngine:
             "kukeon_engine_decode_chunks_total",
             "Dispatched decode chunks, by their length in steps.",
             labels=("k",))
+        self._m_state_steps = reg.counter(
+            "kukeon_engine_state_slot_steps_total",
+            "Slot states (arrays without a row axis: a state-space layer's) "
+            "by dispatched decode chunk, over its steps and every kind that "
+            "holds one: held = what the program reads and writes, every "
+            "slot's; active = those of the slots that were decoding.",
+            labels=("what",))
         self._m_kv_rows = reg.counter(
             "kukeon_engine_decode_kv_rows_total",
             "Cache rows by dispatched decode chunk, over its steps and "
@@ -739,7 +750,8 @@ class ServingEngine:
 
         self._prefix_cache: "OrderedDict[str, _CachedPrefix]" = OrderedDict()
         # A layered family has no prefix store (a ring does not hold a
-        # prefix's rows): a prefixId is a counted miss and stores nothing.
+        # prefix's rows, a state is its prompt's END and no prefix of it):
+        # a prefixId is a counted miss and stores nothing.
         self._prefix_cache_size = (0 if self._layered
                                    else max(0, prefix_cache_size))
         self._prefix_cache_bytes = max(0, prefix_cache_bytes)
@@ -854,21 +866,29 @@ class ServingEngine:
         ``warmup`` serve both. The family's forwards return device-summed
         counters beside their logits; they ride in the array the host
         already fetches: a prefill's ``first`` is [token, *counters], a
-        chunk's block [B + len(counters), K] (a counter's row a step)."""
+        chunk's block [B + len(counters), K] (a counter's row a step).
+
+        What a prefill leaves behind is ONE tree, shaped as the family's
+        kinds state it (``kv_kinds.names``: K and V blocks, state arrays);
+        between the two programs it travels as its leaves, in that order, in
+        the place of the dense path's ``kv_k, kv_v``."""
         cfg, kinds, model = self.cfg, self._kinds, self._layered
+        names = kv_kinds.names(kinds)
 
         def prefill(params, tokens, length, key, temp, top_k, top_p):
-            last, kv_k, kv_v, counted = model.prefill(
-                params, cfg, tokens, length)
+            last, block, counted = model.prefill(params, cfg, tokens, length)
             first = sample_per_slot(
                 last[None, :], key, temp[None], top_k[None], top_p[None])[0]
-            return jnp.concatenate([first[None], counted]), kv_k, kv_v
+            if model.counters:
+                first = jnp.concatenate([first[None], counted])
+            return (first, *(block[name] for name in names))
 
-        def insert(state: DecodeState, kv_k, kv_v, length, slot, token):
+        def insert(state: DecodeState, *args):
+            *block, length, slot, token = args
             token = jnp.reshape(token, (-1,))[0]
             return DecodeState(
-                cache=kv_kinds.insert(state.cache, kinds, kv_k, kv_v,
-                                      length, slot),
+                cache=kv_kinds.insert(state.cache, kinds,
+                                      dict(zip(names, block)), length, slot),
                 tokens=state.tokens.at[slot].set(token),
                 active=state.active.at[slot].set(True))
 
@@ -876,12 +896,11 @@ class ServingEngine:
                             top_ps, n_steps):
             def body(carry, _):
                 state, key = carry
-                logits, new_k, new_v, counted = model.decode(
+                logits, new, counted = model.decode(
                     params, cfg, state.tokens, state.cache, kinds,
                     state.active)
                 # appends at the slots' lengths; inactive slots' lengths stay
-                cache = kv_kinds.append(state.cache, kinds, new_k, new_v,
-                                        state.active)
+                cache = kv_kinds.append(state.cache, kinds, new, state.active)
                 key, k1 = jax.random.split(key)
                 next_tokens = sample_per_slot(logits, k1, temps, top_ks,
                                               top_ps)
@@ -905,11 +924,11 @@ class ServingEngine:
         self._prefill = ct.wrap(jax.jit(
             prefill,
             in_shardings=(p_sh, repl, repl, repl, repl, repl, repl),
-            out_shardings=(repl, repl, repl),
+            out_shardings=(repl,) * (1 + len(names)),
         ), "prefill", timer=tm.track("prefill"))
         self._insert = ct.wrap(jax.jit(
             insert, donate_argnums=(0,),
-            in_shardings=(st_sh, repl, repl, repl, repl, repl),
+            in_shardings=(st_sh,) + (repl,) * (len(names) + 3),
             out_shardings=st_sh,
         ), "insert", timer=tm.track("insert"))
         self._decode_chunk = ct.wrap(jax.jit(
@@ -1373,7 +1392,8 @@ class ServingEngine:
                 if req is not None]
         yield ("kukeon_engine_kv_rows", "gauge",
                "Rows the seated slots hold in one layer of each kind (a "
-               "window layer's ring holds at most its window).",
+               "window layer's ring holds at most its window); of a kind "
+               "that holds state without rows, the slots that hold one.",
                [({"kind": kd.name}, float(sum(kd.live(n) for n in lens)))
                 for kd in self._kinds])
         # Streamed-checkpoint boot pipeline accounting: per-stage wall time
@@ -1489,12 +1509,18 @@ class ServingEngine:
             })
             for L in buckets:
                 tokens = jax.ShapeDtypeStruct((1, L), jnp.int32)
-                self._prefill.lower(
+                lowered = self._prefill.lower(
                     aparams, tokens, L // 2, key,
                     jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
-                ).compile()
+                )
+                lowered.compile()
                 kv_shape = (cfg.num_layers, 1, L, cfg.num_kv_heads, cfg.head_dim)
                 kv = jax.ShapeDtypeStruct(kv_shape, cfg.dtype)
+                # what the prefill hands insert: a layered family's block
+                # is its own (the leaves after the first token)
+                block = [jax.ShapeDtypeStruct(o.shape, o.dtype)
+                         for o in lowered.out_info[1:]
+                         ] if self._layered else [kv, kv]
                 if self.paged:
                     ids = jax.ShapeDtypeStruct((L // self.page_tokens,),
                                                jnp.int32)
@@ -1503,7 +1529,7 @@ class ServingEngine:
                     ).compile()
                 else:
                     self._insert.lower(
-                        astate, kv, kv, L // 2, 0,
+                        astate, *block, L // 2, 0,
                         jnp.zeros((1 + len(self._counted),), jnp.int32)
                         if self._counted else jnp.int32(1),
                     ).compile()
@@ -1549,7 +1575,8 @@ class ServingEngine:
         if self._layered and (export or kv_import is not None):
             raise ValueError(
                 f"family {self.family.name!r} has no KV handoff yet: its "
-                "window layers hold a ring, not the exported block's rows")
+                "layers hold a ring or a state, not the exported block's "
+                "rows")
         if kv_import is not None and int(kv_import["length"]) != prompt.size:
             raise ValueError(
                 f"kv_import length {kv_import['length']} != prompt length "
@@ -2245,11 +2272,13 @@ class ServingEngine:
 
     def _rows_by_kind(self, lengths) -> dict[str, int]:
         """``<kind>_rows``: the rows slots of these token counts hold in a
-        layer of each kind (a layered family's spans carry them; the dense
-        path's one kind is ``live_rows`` already)."""
+        layer of each kind, and ``<kind>_slots`` for a kind that holds state
+        without rows: the slots whose state the dispatch touches (a layered
+        family's spans carry them; the dense path's one kind is
+        ``live_rows`` already)."""
         if not self._layered:
             return {}
-        return {f"{kd.name}_rows": sum(kd.live(n) for n in lengths)
+        return {f"{kd.name}_{kd.unit}": sum(kd.live(n) for n in lengths)
                 for kd in self._kinds}
 
     def _decode_kv_rows(self, lengths) -> tuple[int, int]:
@@ -2421,7 +2450,7 @@ class ServingEngine:
                 bucket = min(self._bucket(tail.size), self.max_seq_len)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, : tail.size] = tail
-                first, kv_k, kv_v = self._prefill_ext(
+                first, *block = self._prefill_ext(
                     self.params, cached.kv_k, cached.kv_v, cached.length,
                     self._upload(tokens), tail.size, k1,
                     jnp.float32(sp.temperature), jnp.int32(sp.top_k),
@@ -2433,14 +2462,16 @@ class ServingEngine:
                 bucket = min(self._bucket(n), self.max_seq_len)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = req.prompt
-                first, kv_k, kv_v = self._prefill(
+                # what the prompt leaves behind: kv_k, kv_v; a layered
+                # family's block by its leaves
+                first, *block = self._prefill(
                     self.params, self._upload(tokens), n, k1,
                     jnp.float32(sp.temperature), jnp.int32(sp.top_k),
                     jnp.float32(sp.top_p),
                 )
-            if req.prefix_id is not None:
-                self._prefix_store(req.prefix_id, req.prompt, kv_k, kv_v)
-            self.state = self._insert(self.state, kv_k, kv_v, n, slot, first)
+            if req.prefix_id is not None and not self._layered:
+                self._prefix_store(req.prefix_id, req.prompt, *block)
+            self.state = self._insert(self.state, *block, n, slot, first)
         req.slot = slot
         plen = cached.length if cached is not None else 0
         self._prefill_dispatched(req, span, plen, n - plen, bucket)
@@ -2774,6 +2805,11 @@ class ServingEngine:
         held, read = self._decode_kv_rows(lens)
         self._m_kv_rows.inc(k * held, what="held")
         self._m_kv_rows.inc(k * read, what="read")
+        if self._state_kinds:
+            self._m_state_steps.inc(
+                k * self._state_kinds * self.num_slots, what="held")
+            self._m_state_steps.inc(
+                k * self._state_kinds * len(active), what="active")
         temps_d, top_ks_d, top_ps_d = self._sampling_dev_arrays()
         with jax.set_mesh(self.mesh):
             self._key, k1 = jax.random.split(self._key)

@@ -5,7 +5,7 @@ The TPU compiler is installed here and compiles for a v5e that is described
 compiler would refuse — a kernel tile that does not align, a program that
 does not fit 16 GB — and interpret-mode tests on the CPU cannot. These are
 the main path's kernels at the real widths and the whole decode programs of
-the flagship cell and of both benchmark configurations; they guard every
+the flagship cell and of the benchmark's configurations; they guard every
 later PR at no chip time. A compile that
 passes is not a chip run: nothing executes, no number here is a device
 metric.
@@ -35,6 +35,7 @@ from kukeon_tpu.models import llama  # noqa: E402
 from kukeon_tpu.ops import decode_attention as da  # noqa: E402
 from kukeon_tpu.ops import flash_attention as fa  # noqa: E402
 from kukeon_tpu.ops import int8_matmul as i8  # noqa: E402
+from kukeon_tpu.ops import selective_scan as ss  # noqa: E402
 
 # bytes_limit the attached v5e reports (memory_stats on the chip, PR 22).
 V5E_HBM_BYTES = 16909336064
@@ -190,26 +191,45 @@ def test_decode_chunk_at_8b_int8_fits_one_v5e(v5e, k):
 
 # Decode attention at the benchmark's shapes: the held stack [layers, B, KV,
 # rows, D], query heads a KV head, whether a row is excluded (a ring).
-@pytest.mark.parametrize("layers, slots, rows, groups, ring", [
-    (32, 8, 2048, 4, False),     # mistral-7b-v0.3: 8 slots x 2048 rows
-    (1, 32, 8192, 6, False),     # trinity share: the full layer
-    (4, 32, 4096, 6, True),      # trinity share: the four rings
+@pytest.mark.parametrize("layers, slots, rows, groups, ring, kv", [
+    (32, 8, 2048, 4, False, 8),     # mistral-7b-v0.3: 8 slots x 2048 rows
+    (1, 32, 8192, 6, False, 8),     # trinity share: the full layer
+    (4, 32, 4096, 6, True, 8),      # trinity share: the four rings
+    (2, 64, 4096, 20, False, 1),    # jamba2-3b: 20 query heads on ONE KV head
 ])
 def test_decode_attention_compiles_for_v5e(v5e, layers, slots, rows, groups,
-                                           ring):
+                                           ring, kv):
     d = v5e.devices[0]
     bf, i32 = jnp.bfloat16, jnp.int32
-    new = _on(d, (slots, 1, 8, 128), bf)
-    held = _on(d, (layers, slots, 8, rows, 128), bf)
+    new = _on(d, (slots, 1, kv, 128), bf)
+    held = _on(d, (layers, slots, kv, rows, 128), bf)
     compiled = jax.jit(
         lambda q, kn, vn, k, v, count, skip, layer: da.decode_attention(
             q, kn, vn, k, v, count, skip if ring else None, layer)
-    ).lower(_on(d, (slots, 1, 8 * groups, 128), bf), new, new, held, held,
+    ).lower(_on(d, (slots, 1, kv * groups, 128), bf), new, new, held, held,
             _on(d, (slots,), i32), _on(d, (slots,), i32),
             _on(d, (), i32)).compile()
     _assert_kernel(compiled)
     # the stacks are operands in place: nothing of a layer's size is made
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# The selective scan at jamba2-3b's widths (5120 channels, 16 states): the
+# smallest and the largest prompt bucket of its cell.
+@pytest.mark.parametrize("steps", [64, 2048])
+def test_selective_scan_compiles_for_v5e(v5e, steps):
+    d = v5e.devices[0]
+    f32 = jnp.float32
+    assert ss.kernel_chunk(steps, 5120, 1) == min(steps, ss.CHUNK)
+    by_time, by_state = _on(d, (steps, 5120), f32), _on(d, (steps, 16), f32)
+    compiled = ss.scan_kernel.lower(
+        by_time, by_time, by_time, by_state, by_state, _on(d, (16, 5120), f32),
+        _on(d, (5120,), f32), chunk=ss.kernel_chunk(steps, 5120, 1)).compile()
+    _assert_kernel(compiled)
+    assert "selective_scan" in compiled.as_text()
+    # HBM never sees an array of [steps, channels, states]
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < steps * 5120 * 16 * 4 / 4)
 
 
 def _abstract_cell(v5e, config_name):
@@ -237,20 +257,34 @@ def _abstract_cell(v5e, config_name):
                        sds((B,), jnp.int32), sds((B,), jnp.float32))
 
 
-def _cache_sized_values(text: str, cache_elements: int) -> list[str]:
+def _cache_sized_values(text: str, cache_elements: int,
+                        dtype: str = "bf16") -> list[str]:
     """Instructions of a compiled program that MAKE a value of a layer of
     the cache's size or more, in the cache's dtype: a copy, a transpose or a
-    fusion that materializes a slice of it. In-place updates, operands passed
+    fusion that materializes a slice of it. In-place updates (a
+    dynamic-update-slice, or a fusion whose root is one), operands passed
     through and the loop's own plumbing make nothing."""
     import re
 
+    roots, name = {}, None      # computation -> the operation at its root
+    for line in text.splitlines():
+        head = re.match(r"(%[\w.-]+) \(", line)
+        if head:
+            name = head.group(1)
+        root = re.match(r"\s*ROOT \S+ = \S+ ([\w-]+)\(", line)
+        if root and name:
+            roots[name] = root.group(1)
     made = []
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
-                     line)
+        m = re.match(r"\s*(?:ROOT )?(\S+) = " + dtype
+                     + r"\[([\d,]+)\]\S* ([\w-]+)\(", line)
         if not m or m.group(3) in (
                 "get-tuple-element", "parameter", "bitcast", "while", "tuple",
                 "dynamic-update-slice", "custom-call"):
+            continue
+        calls = re.search(r"calls=(%[\w.-]+)", line)
+        if (m.group(3) == "fusion" and calls
+                and roots.get(calls.group(1)) == "dynamic-update-slice"):
             continue
         n = 1
         for dim in m.group(2).split(","):
@@ -289,3 +323,43 @@ def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
                    for x in (held if isinstance(held, tuple) else (held,)))
     assert _cache_sized_values(text, smallest) == []
     assert rc.resident(compiled) / 1e9 == pytest.approx(gb, rel=0.01)
+
+
+@pytest.mark.parametrize("k", [4, 16])
+def test_the_state_space_cells_decode_chunk_holds_one_copy_of_each_state_stack(
+        v5e, k):
+    """jamba2-3b's whole decode program, built by the engine from shapes alone
+    through the cell's launcher: 26 mixers' scan state for 64 slots is 545 MB
+    of float32 and their convolution tails 51 MB. The chunk donates and
+    carries both, a layer's state is written back where it was read, and so
+    no instruction makes a second array of either stack's size and the
+    temporaries stay under half the scan state; the decode kernel reads the
+    two attention layers' rows (20 query heads on one KV head) in place."""
+    from benchmark import rehearse_compile as rc
+
+    mesh, eng, args = _abstract_cell(v5e, "ai21-jamba2-3b-bf16")
+    with jax.set_mesh(mesh):
+        compiled = eng._decode_chunk.lower(*args, k).compile()
+    text = compiled.as_text()
+    assert "decode_attention" in text and "tpu_custom_call" in text
+    state, rows = args[1].cache.held
+    assert state["ssm"].shape == (26, 64, 16, 5120)
+    assert state["conv"].shape == (26, 3, 64, 5120)
+    assert _cache_sized_values(text, state["ssm"].size, "f32") == []
+    # (the weights are bf16 too and larger than these stacks, and the
+    # compiler prefetches some of them whole once a chunk: tell the cache's
+    # own arrays by their dimensions, a stack's or one layer's)
+    def dims(v):
+        return sorted(int(n) for n in v[v.index("[") + 1:-1].split(",")
+                      if n != "1")
+
+    bf16 = _cache_sized_values(text, state["conv"].size)
+    for stack in (state["conv"].shape, rows["k"].shape):
+        ours = [sorted(n for n in shape if n != 1)
+                for shape in (stack, stack[1:])]
+        assert [v for v in bf16 if dims(v) in ours] == []
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < state["ssm"].size * 4 / 2
+    assert 6.0e9 < sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        args[0])) < 6.1e9
+    assert rc.resident(compiled) / 1e9 == pytest.approx(7.07, rel=0.01)

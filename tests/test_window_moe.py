@@ -83,11 +83,13 @@ def test_prefill_then_decode_through_the_ring_matches_the_full_forward(
                                [np.arange(n - 1, 40)], 64)[0]
     tokens = np.zeros((1, 32), np.int32)
     tokens[0, :n] = seq[:n]
-    last, kv_k, kv_v, counted = jax.jit(
+    last, block, counted = jax.jit(
         lambda p, t, m: wm.prefill(p, cfg, t, m))(params, tokens, n)
     assert np.abs(np.asarray(last) - want[0]).max() < 2e-4
-    assert kv_k.shape == (cfg.num_layers, 1, 32, cfg.num_kv_heads,
-                          cfg.head_dim)
+    assert sorted(block) == ["k", "v"] == list(kv_kinds.names(
+        cfg.cache_kinds(64)))
+    assert block["k"].shape == (cfg.num_layers, 1, 32, cfg.num_kv_heads,
+                                cfg.head_dim)
     # 19 real tokens x 8 expert layers x top-4; padding is not counted
     assert int(counted[0]) == n * 8 * 4 and 0 < int(counted[1]) < n * 8 * 4
 
@@ -97,16 +99,16 @@ def test_prefill_then_decode_through_the_ring_matches_the_full_forward(
     cache = jax.tree.map(
         lambda s: jnp.zeros(s.shape, s.dtype),
         kv_kinds.shapes(kinds, 2, cfg.num_kv_heads, cfg.head_dim, cfg.dtype))
-    cache = kv_kinds.insert(cache, kinds, kv_k, kv_v, n, 1)
+    cache = kv_kinds.insert(cache, kinds, block, n, 1)
     active = jnp.array([False, True])
 
     @jax.jit
     def step(cache, token):
         view = kv_kinds.view(cache)
-        logits, k, v, counted = wm.decode(params, cfg, token, view, kinds,
-                                          active)
+        logits, new, counted = wm.decode(params, cfg, token, view, kinds,
+                                         active)
         return logits, kv_kinds.view(
-            kv_kinds.append(view, kinds, k, v, active)), counted
+            kv_kinds.append(view, kinds, new, active)), counted
 
     for i in range(n, 40):
         logits, cache, counted = step(cache, jnp.array([0, seq[i]], jnp.int32))
