@@ -216,7 +216,10 @@ def cache_insert(cache_kv: jnp.ndarray, new_kv: jnp.ndarray, offsets: jnp.ndarra
 # Per-output-channel symmetric int8: w ≈ q * s with q int8, s f32[out].
 # Decode on TPU is HBM-bound (every weight byte streams once per step), so
 # halving weight bytes ≈ doubles decode throughput; XLA fuses the
-# convert(s8→bf16) into the dot, so int8 is what actually crosses HBM.
+# convert(s8→bf16) into the dot and reads one layer of the stack in place, so
+# int8 is what crosses HBM, once. That holds for all seven products of a
+# layer only while `_qkv` ends q's and k's at their flat results: a product
+# with the rotation fused into it has its weights transposed and copied first.
 # 8B-class weights (~8 GB int8) fit a single 16 GB v5e chip.
 
 
@@ -412,9 +415,23 @@ def _qkv(x, w: dict, c: LlamaConfig, positions, pallas: bool = False):
     with jax.named_scope("attn_norm"):
         h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
     with jax.named_scope("qkv"):
-        q = _mm(h, w["wq"], pallas).reshape(B, S, c.num_heads, c.head_dim)
-        k = _mm(h, w["wk"], pallas).reshape(B, S, c.num_kv_heads, c.head_dim)
-        v = _mm(h, w["wv"], pallas).reshape(B, S, c.num_kv_heads, c.head_dim)
+        # The three products END at their flat [B, S, out] results. Without
+        # the barrier the TPU compiler fuses the reshape to heads and the
+        # rotation's float32 halves into q's and k's products, and that form
+        # of a product wants its weight with the contracted axis minor: an
+        # int8 decode chunk then transposes the whole wq and wk stacks once a
+        # call (copy.105 / copy.104, 0.67 GB of temporaries) and copies one
+        # layer of each to VMEM every layer of every step, and a prefill
+        # slices and transposes a layer of each. Behind the barrier all seven
+        # products of a layer read their stacks in place
+        # (tests/test_chip_compile.py,
+        # test_a_dense_cells_programs_make_no_value_of_a_weights_size).
+        q, k, v = jax.lax.optimization_barrier((
+            _mm(h, w["wq"], pallas), _mm(h, w["wk"], pallas),
+            _mm(h, w["wv"], pallas)))
+        q = q.reshape(B, S, c.num_heads, c.head_dim)
+        k = k.reshape(B, S, c.num_kv_heads, c.head_dim)
+        v = v.reshape(B, S, c.num_kv_heads, c.head_dim)
     with jax.named_scope("rope"):
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)

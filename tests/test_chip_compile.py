@@ -18,6 +18,7 @@ program.
 from __future__ import annotations
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else the compiler logs under /tmp
 # libtpu takes a one-process lock (/tmp/libtpu_lockfile) even to describe a
@@ -260,29 +261,29 @@ def _abstract_cell(v5e, config_name):
 def _cache_sized_values(text: str, cache_elements: int,
                         dtype: str = "bf16") -> list[str]:
     """Instructions of a compiled program that MAKE a value of a layer of
-    the cache's size or more, in the cache's dtype: a copy, a transpose or a
-    fusion that materializes a slice of it. In-place updates (a
-    dynamic-update-slice, or a fusion whose root is one), operands passed
-    through and the loop's own plumbing make nothing."""
-    import re
-
-    roots, name = {}, None      # computation -> the operation at its root
+    the cache's size or more, in the cache's dtype, in HBM or in VMEM
+    (``S(1)``): a copy, a transpose or a fusion that materializes a slice of
+    it. In-place updates (a dynamic-update-slice, or a fusion whose root is
+    one), operands passed through and the loop's own plumbing make nothing,
+    and neither does an instruction INSIDE a fusion's ``calls=`` computation:
+    a slice fused into a product is read where it lies and never written."""
+    fused = set(re.findall(r" fusion\(.*calls=(%[\w.-]+)", text))
+    roots, found, inside = {}, [], None   # computation -> its root's operation
     for line in text.splitlines():
-        head = re.match(r"(%[\w.-]+) \(", line)
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) \(", line)
         if head:
-            name = head.group(1)
+            inside = head.group(1)
         root = re.match(r"\s*ROOT \S+ = \S+ ([\w-]+)\(", line)
-        if root and name:
-            roots[name] = root.group(1)
-    made = []
-    for line in text.splitlines():
+        if root and inside:
+            roots[inside] = root.group(1)
         m = re.match(r"\s*(?:ROOT )?(\S+) = " + dtype
                      + r"\[([\d,]+)\]\S* ([\w-]+)\(", line)
-        if not m or m.group(3) in (
+        if m and inside not in fused and m.group(3) not in (
                 "get-tuple-element", "parameter", "bitcast", "while", "tuple",
                 "dynamic-update-slice", "custom-call"):
-            continue
-        calls = re.search(r"calls=(%[\w.-]+)", line)
+            found.append((m, re.search(r"calls=(%[\w.-]+)", line)))
+    made = []
+    for m, calls in found:
         if (m.group(3) == "fusion" and calls
                 and roots.get(calls.group(1)) == "dynamic-update-slice"):
             continue
@@ -295,8 +296,8 @@ def _cache_sized_values(text: str, cache_elements: int,
 
 
 @pytest.mark.parametrize("config, k, gb", [
-    ("mistral-7b-v0.3-int8", 16, 10.09),
-    ("mistral-7b-v0.3-int8", 4, 10.09),
+    ("mistral-7b-v0.3-int8", 16, 9.41),
+    ("mistral-7b-v0.3-int8", 4, 9.41),
     ("trinity-large-preview-ep8-bf16", 4, 12.12),
 ])
 def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
@@ -323,6 +324,63 @@ def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
                    for x in (held if isinstance(held, tuple) else (held,)))
     assert _cache_sized_values(text, smallest) == []
     assert rc.resident(compiled) / 1e9 == pytest.approx(gb, rel=0.01)
+
+
+def _lower_dense_program(mesh, eng, args, kind: str, sizes: tuple):
+    """One of a dense cell's programs with the arguments
+    ``benchmark/rehearse_compile.py`` states for it: a decode chunk of
+    ``sizes[0]`` steps, a prefill of ``sizes[0]`` tokens, or a ``prefill_ext``
+    of ``sizes[1]`` tokens behind ``sizes[0]`` cached rows."""
+    cfg, repl = eng.cfg, NamedSharding(mesh, PartitionSpec())
+
+    def sds(shape, dtype, sh=repl):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def kv(rows):
+        return sds((cfg.num_layers, 1, rows, cfg.num_kv_heads, cfg.head_dim),
+                   cfg.dtype, eng._cache_shardings()[0])
+
+    params, _state, key = args[:3]
+    f32, i32 = sds((), jnp.float32), sds((), jnp.int32)
+    if kind == "decode_chunk":
+        return eng._decode_chunk.lower(*args, sizes[0])
+    tokens = sds((1, sizes[-1]), jnp.int32)
+    if kind == "prefill":
+        return eng._prefill.lower(params, tokens, i32, key, f32, i32, f32)
+    return eng._prefill_ext.lower(params, kv(sizes[0]), kv(sizes[0]), i32,
+                                  tokens, i32, key, f32, i32, f32)
+
+
+@pytest.mark.parametrize("kind, sizes", [
+    ("decode_chunk", (4,)), ("decode_chunk", (16,)),
+    ("prefill", (256,)), ("prefill", (2048,)),
+    ("prefill_ext", (1024, 256)),   # a turn of agent-sessions.json's warm-up
+], ids=lambda v: v if isinstance(v, str) else "+".join(map(str, v)))
+def test_a_dense_cells_programs_make_no_value_of_a_weights_size(
+        v5e, kind, sizes):
+    """Every quantized product of `mistral-7b-v0.3-int8` takes its stack and
+    the layer's index and reads one layer in place: no instruction of a
+    decode chunk or a prefill MAKES an int8 value of one layer of `wk`
+    (4096 x 1024 elements) or more, and a chunk's temporaries stay small.
+    `llama._qkv` ends its three products at their flat results for this:
+    with the rotation fused into q's and k's products the compiler wants
+    those weights transposed, and a chunk re-lays both stacks once a call
+    (`copy.105 s8[32,4096,4096]`, `copy.104 s8[32,4096,1024]`: 0.677 GB of
+    temporaries) and copies a layer of each to VMEM every layer of every
+    step (`constant_dynamic-slice_fusion.7 / .6`), and a prefill slices and
+    transposes a layer of each. The one int8 value a long prefill does make
+    is the prompt's own rows of the embedding table, `s8[tokens, hidden]`:
+    a lookup's result, not a weight."""
+    mesh, eng, args = _abstract_cell(v5e, "mistral-7b-v0.3-int8")
+    with jax.set_mesh(mesh):
+        compiled = _lower_dense_program(mesh, eng, args, kind, sizes).compile()
+    wk = args[0]["layers"]["wk"]["q"]
+    assert wk.dtype == jnp.int8 and wk.shape[1:] == (4096, 1024)
+    made = _cache_sized_values(compiled.as_text(), wk.size // wk.shape[0], "s8")
+    embedded = f"[{sizes[-1]},{eng.cfg.hidden_size}]"
+    assert [v for v in made if not v.endswith(embedded)] == []
+    if kind == "decode_chunk":
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 @pytest.mark.parametrize("k", [4, 16])
