@@ -11,6 +11,9 @@ that have rows.
 
   s   = sigmoid(float32(h) Wr)
   sel = top_k(s + b)                         b: selection only
+        (group-limited, ``groups`` > 1: the experts lie in ``groups`` equal
+        runs, a run scores the sum of its two best s + b, and only the
+        experts of the ``groups_kept`` best runs stand for selection)
   w   = s[sel] / (sum(s[sel]) + 1e-20) * route_scale       (``route_norm``)
   y   = Shared(h) + sum_{e in sel, e held} w_e Expert_e(h)           (SwiGLU)
 
@@ -27,16 +30,31 @@ import jax.numpy as jnp
 from kukeon_tpu.models.llama import mm
 
 
+def select(biased: jnp.ndarray, k: int, groups: int = 1,
+           groups_kept: int = 1) -> jnp.ndarray:
+    """The ``k`` experts of largest biased score [N, E], among the experts of
+    the ``groups_kept`` best runs where the experts lie in ``groups`` runs."""
+    if groups > 1:
+        N, E = biased.shape
+        by_group = biased.reshape(N, groups, E // groups)
+        best2, _ = jax.lax.top_k(by_group, 2)
+        _, kept = jax.lax.top_k(best2.sum(axis=-1), groups_kept)
+        stands = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+        biased = jnp.where(stands[:, :, None], by_group, -jnp.inf
+                           ).reshape(N, E)
+    return jax.lax.top_k(biased, k)[1]
+
+
 def route(h: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, k: int, *,
-          norm: bool = True, scale: float = 1.0,
-          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+          norm: bool = True, scale: float = 1.0, groups: int = 1,
+          groups_kept: int = 1) -> tuple[jnp.ndarray, jnp.ndarray]:
     """h [N, H] -> (sel [N, k] int32, w [N, k] float32). The router runs in
     float32 at the highest precision: a selection should not turn on the
     activation dtype or on the TPU's default single bf16 pass."""
     logits = jnp.dot(h.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, sel = jax.lax.top_k(s + bias, k)
+    sel = select(s + bias, k, groups, groups_kept)
     w = jnp.take_along_axis(s, sel, axis=-1)
     if norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -74,7 +92,8 @@ def _routed(h, w: dict, local, held, wts):
 
 def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
                  experts_held: tuple[int, int], route_norm: bool = True,
-                 route_scale: float = 1.0, counted: jnp.ndarray,
+                 route_scale: float = 1.0, groups: int = 1,
+                 groups_kept: int = 1, counted: jnp.ndarray,
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """h [..., H] -> (y [..., H], held hits int32): the shared expert once
     plus the held experts' share of the routed sum. ``counted`` [...] bool
@@ -85,7 +104,8 @@ def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
     first, count = experts_held
     with jax.named_scope("router"):
         sel, wts = route(x, w["router"], w["bias"], experts_per_token,
-                         norm=route_norm, scale=route_scale)
+                         norm=route_norm, scale=route_scale, groups=groups,
+                         groups_kept=groups_kept)
         local = sel - first
         held = (local >= 0) & (local < count)
     with jax.named_scope("expert_layer"):

@@ -11,7 +11,8 @@ slot's cache holds layer by layer:
   prefix store, ``prefill_ext`` and the KV handoff work on that block.
 - ``layered`` set: the layers are of several kinds (``models/kv_kinds.py``:
   a ring of a window's rows beside full stacks; a state-space layer's state,
-  which has no rows at all). The family states its kinds and brings
+  which has no rows at all; a latent row and an indexer's key with no head
+  axis). The family states its kinds and brings
   ``prefill`` and ``decode``; the engine owns insertion, the step's write and
   the valid rows.
 
@@ -37,10 +38,12 @@ class Layered:
     # (arrays by name: ``kv_kinds.names``), which the engine passes on whole.
     prefill: Callable       # (params, cfg, tokens [1, S], length) ->
     #                         (logits [V], block, counters); block: k, v
-    #                         [L, 1, S, KV, D] and the state at ``length``
+    #                         [L, 1, S, KV, D] (a named array's rows [L, 1, S,
+    #                         width]) and the state at ``length``
     decode: Callable        # (params, cfg, tokens [B], view, kinds, active)
     #                         -> (logits [B, V], new, counters); new: k, v
-    #                         [L, B, 1, KV, D] and the state stacks, replaced
+    #                         [L, B, 1, KV, D] (named rows [L, B, 1, width])
+    #                         and the state stacks, replaced
     counters: tuple[str, ...] = ()          # device-summed, by metric name
 
 
@@ -115,29 +118,24 @@ def _moe_specs(params):
     return moe_specs_for_params(params)
 
 
-def _window_moe_init(cfg, seed, quantize, mesh):
-    from kukeon_tpu.models import window_moe
-    from kukeon_tpu.parallel import sharding as shd
+def _drawn_init(model):
+    """The checkpoint-less boot of a family whose module draws its tree in
+    one jitted program (``init_params``) and lays it out by ``param_specs``."""
+    def init(cfg, seed, quantize, mesh):
+        from kukeon_tpu.parallel import sharding as shd
 
-    key = jax.random.key(seed)
-    abstract = jax.eval_shape(lambda k: window_moe.init_params(k, cfg), key)
-    return window_moe.init_params(key, cfg, shd.param_shardings(
-        abstract, mesh, specs=window_moe.param_specs(abstract)))
+        key = jax.random.key(seed)
+        abstract = jax.eval_shape(lambda k: model.init_params(k, cfg), key)
+        return model.init_params(key, cfg, shd.param_shardings(
+            abstract, mesh, specs=model.param_specs(abstract)))
 
-
-def _ssm_hybrid_init(cfg, seed, quantize, mesh):
-    from kukeon_tpu.models import ssm_hybrid
-    from kukeon_tpu.parallel import sharding as shd
-
-    key = jax.random.key(seed)
-    abstract = jax.eval_shape(lambda k: ssm_hybrid.init_params(k, cfg), key)
-    return ssm_hybrid.init_params(key, cfg, shd.param_shardings(
-        abstract, mesh, specs=ssm_hybrid.param_specs(abstract)))
+    return init
 
 
 @functools.cache
 def _families() -> tuple[Family, ...]:
-    from kukeon_tpu.models import llama, moe, ssm_hybrid, window_moe
+    from kukeon_tpu.models import (llama, moe, sparse_latent_moe, ssm_hybrid,
+                                   window_moe)
 
     return (
         Family("dense_gqa", llama.LlamaConfig, _dense_init,
@@ -150,17 +148,27 @@ def _families() -> tuple[Family, ...]:
                load_checkpoint=_moe_load,
                supports=frozenset({INT8_WEIGHTS, PAGED, PREFIX, MESH,
                                    CHECKPOINT})),
-        Family("window_moe", window_moe.WindowMoEConfig, _window_moe_init,
+        Family("window_moe", window_moe.WindowMoEConfig,
+               _drawn_init(window_moe),
                param_specs=window_moe.param_specs,
                layered=Layered(
                    kinds=window_moe.WindowMoEConfig.cache_kinds,
                    prefill=window_moe.prefill, decode=window_moe.decode,
                    counters=window_moe.COUNTERS)),
-        Family("ssm_hybrid", ssm_hybrid.SsmHybridConfig, _ssm_hybrid_init,
+        Family("ssm_hybrid", ssm_hybrid.SsmHybridConfig,
+               _drawn_init(ssm_hybrid),
                param_specs=ssm_hybrid.param_specs,
                layered=Layered(
                    kinds=ssm_hybrid.SsmHybridConfig.cache_kinds,
                    prefill=ssm_hybrid.prefill, decode=ssm_hybrid.decode)),
+        Family("sparse_latent_moe", sparse_latent_moe.SparseLatentMoEConfig,
+               _drawn_init(sparse_latent_moe),
+               param_specs=sparse_latent_moe.param_specs,
+               layered=Layered(
+                   kinds=sparse_latent_moe.SparseLatentMoEConfig.cache_kinds,
+                   prefill=sparse_latent_moe.prefill,
+                   decode=sparse_latent_moe.decode,
+                   counters=sparse_latent_moe.COUNTERS)),
     )
 
 

@@ -6,6 +6,11 @@ models hold other things for a slot, and a family states them as data
 
 - **rows of K and V**: a full layer holds ``S_max`` rows, a window layer only
   its window's, kept as a ring (position ``p`` lives in row ``p mod rows``);
+- **rows of named arrays** (``arrays``): a row that is no K and V over KV
+  heads but arrays of their own widths with NO head axis, side by side for
+  every layer (a latent attention's compressed row and its indexer's key),
+  ``[layers, B, rows, width]`` held and viewed alike; a decode step may read
+  only the ``select`` best of the live rows;
 - **state**: named arrays WITHOUT a row axis, of a fixed size whatever the
   slot's length (a state-space layer's convolution tail and scan state). A
   prefill leaves the arrays of its last real token, ``insert`` copies them
@@ -44,6 +49,11 @@ class CacheKind:
     # What a slot holds besides: (name, shape, dtype) of arrays without a row
     # axis, the shape with None where the slots go.
     state: tuple = ()
+    # What a row is made of where it is no K and V: (name, width) of arrays
+    # [layers, B, rows, width], and how many of a slot's live rows a decode
+    # step reads whole at most (0: every one).
+    arrays: tuple = ()
+    select: int = 0
 
     @property
     def unit(self) -> str:
@@ -54,6 +64,22 @@ class CacheKind:
         """Rows of a slot of ``length`` tokens that hold something; of a
         kind without rows the one state the slot holds."""
         return min(length, self.rows) if self.rows else 1
+
+    def row_names(self) -> tuple[str, ...]:
+        """The arrays a row is made of."""
+        if not self.rows:
+            return ()
+        return tuple(name for name, _w in self.arrays) or ("k", "v")
+
+    def read(self, length: int) -> float:
+        """Rows' worth of bytes a decode step reads of a slot of ``length``
+        tokens where the rows are named arrays: the first array of every
+        live row (what the selection scores) and the others of the selected
+        rows only."""
+        widths = [w for _name, w in self.arrays]
+        live = self.live(length)
+        chosen = min(live, self.select) if self.select else live
+        return (live * widths[0] + chosen * sum(widths[1:])) / sum(widths)
 
 
 @jax.tree_util.register_dataclass
@@ -74,18 +100,21 @@ class LayeredKV:
 
 def names(kinds) -> tuple[str, ...]:
     """The arrays of a prefill's block, in the order of its leaves."""
-    rows = ("k", "v") if any(kd.rows for kd in kinds) else ()
-    return tuple(sorted(rows + tuple(
-        name for kd in kinds for name, _shape, _dtype in kd.state)))
+    return tuple(sorted({name for kd in kinds for name in kd.row_names()}
+                        | {name for kd in kinds
+                           for name, _shape, _dtype in kd.state}))
 
 
 def shapes(kinds, batch: int, kv_heads: int, head_dim: int, dtype) -> LayeredKV:
     """ShapeDtypeStructs of the HELD cache."""
     def of(kd):
         out = {}
-        if kd.rows:
+        if kd.rows and not kd.arrays:
             out["k"] = out["v"] = jax.ShapeDtypeStruct(
                 (len(kd.layers), batch, kv_heads, kd.rows, head_dim), dtype)
+        for name, width in kd.arrays:
+            out[name] = jax.ShapeDtypeStruct(
+                (len(kd.layers), batch, kd.rows, width), dtype)
         for name, shape, dt in kd.state:
             out[name] = jax.ShapeDtypeStruct(
                 tuple(batch if n is None else n for n in shape), dt)
@@ -96,7 +125,8 @@ def shapes(kinds, batch: int, kv_heads: int, head_dim: int, dtype) -> LayeredKV:
 
 
 def view(cache: LayeredKV) -> LayeredKV:
-    """held <-> row-major view of K and V; its own inverse."""
+    """held <-> row-major view of K and V; its own inverse. Rows of named
+    arrays have no head axis to swap: held and viewed alike."""
     return LayeredKV(
         held=tuple({name: jnp.swapaxes(x, 2, 3) if name in ("k", "v") else x
                     for name, x in h.items()} for h in cache.held),
@@ -114,11 +144,14 @@ def _of_kind(kd: CacheKind, per_layer: jnp.ndarray) -> jnp.ndarray:
 
 def _block_rows(kd: CacheKind, block: jnp.ndarray, length) -> jnp.ndarray:
     """The rows of a prefill's block [L, 1, S, KV, D] that this kind keeps,
-    as [layers, 1, KV, rows', D] (held layout). A ring shorter than the block
+    as [layers, 1, KV, rows', D] (held layout; of a named array [L, 1, S,
+    width], as it is). A ring shorter than the block
     takes, for each of its rows, the LAST position below ``length`` that
     lands there; rows no position reaches hold whatever the gather brings
     and lie past the rows ``valid`` counts."""
     part = _of_kind(kd, block)
+    if kd.arrays:       # [layers, 1, S, width]: the held layout already
+        return part[:, :, :kd.rows]
     if kd.ring and part.shape[2] > kd.rows:
         r = jnp.arange(kd.rows)
         pos = r + kd.rows * ((length - 1 - r) // kd.rows)
@@ -135,10 +168,10 @@ def insert(cache: LayeredKV, kinds, block: dict, length, slot) -> LayeredKV:
     whole (the prefill's has one slot where the held one has all)."""
     def of(kd, held):
         out = {}
-        for name in ("k", "v") if kd.rows else ():
+        for name in kd.row_names():
+            rows = _block_rows(kd, block[name], length)
             out[name] = jax.lax.dynamic_update_slice(
-                held[name], _block_rows(kd, block[name], length),
-                (0, slot, 0, 0, 0))
+                held[name], rows, (0, slot) + (0,) * (rows.ndim - 2))
         for name, shape, _dtype in kd.state:
             at = [0] * len(shape)
             at[shape.index(None)] = slot
@@ -181,7 +214,8 @@ def keep(active: jnp.ndarray, new: jnp.ndarray, old: jnp.ndarray,
 @jax.named_scope("kv_insert")
 def append(cache: LayeredKV, kinds, new: dict, active) -> LayeredKV:
     """One decode step's outcome into the VIEW. ``new["k"]``, ``new["v"]``
-    [L, B, 1, KV, D]: one in-place slice write a slot and kind
+    [L, B, 1, KV, D] (a named array's [L, B, 1, width]): one in-place slice
+    write a slot and kind
     (``llama.cache_insert`` says why a loop). A state array comes back from
     the step WHOLE, in the held shape, and takes the place of the one the
     step read; where a slot is not ``active`` it already holds what it held
@@ -192,13 +226,13 @@ def append(cache: LayeredKV, kinds, new: dict, active) -> LayeredKV:
         out = {name: new[name] for name, _shape, _dtype in kd.state}
         if kd.rows:
             row = cache.lengths % kd.rows if kd.ring else cache.lengths
-            rows = {name: _of_kind(kd, new[name]) for name in ("k", "v")}
+            rows = {name: _of_kind(kd, new[name]) for name in kd.row_names()}
             out.update({name: held[name] for name in rows})
             for b in range(B):
-                for name in rows:
+                for name, x in rows.items():
                     out[name] = jax.lax.dynamic_update_slice(
-                        out[name], rows[name][:, b:b + 1],
-                        (0, b, row[b], 0, 0))
+                        out[name], x[:, b:b + 1],
+                        (0, b, row[b]) + (0,) * (x.ndim - 3))
         return out
 
     return LayeredKV(

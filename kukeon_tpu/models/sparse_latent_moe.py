@@ -1,0 +1,633 @@
+"""Latent attention that selects its rows, over an expert layer with
+group-limited routing (the ``deepseek_v32`` block: DeepSeek-V3.2-Exp),
+functional JAX.
+
+What the other families have none of:
+
+- **a latent cache**: a layer caches ONE row a token and no K or V a head:
+  ``[c | kr]``, the normed ``kv_lora_rank`` latent and the rotated
+  ``qk_rope_head_dim`` part every head shares, and beside it the indexer's key
+  ``kI`` (``models/kv_kinds.py``: rows of named arrays, no head axis);
+- **an indexer that selects**: ``I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s))``
+  over ``index_n_heads`` small heads, and the attention of token ``t`` sees the
+  ``index_topk`` positions of largest ``I`` only (``ops/sparse_attention.py``);
+- **two forms of one attention**: ``prefill`` expands the latent into each
+  head's ``k_nope`` and ``v`` (``Wkv_b``) and runs masked flash attention, the
+  selection as a mask over causal scores; ``decode`` absorbs ``Wkv_b`` into the
+  query and the output (``q_nope Wkv_b^K`` against ``c``, the mix of latents
+  through ``Wkv_b^V``) and attends over the GATHERED rows of the selection,
+  one shared latent head for all ``num_heads``. Both are the equations of
+  ``benchmark/reference/sparse_latent_moe.py``; neither drops a term;
+- **YaRN** frequencies for both rotations and its factor on the softmax scale
+  (``ops/rope.py``); the attention's rotated pairs are neighbours
+  (interleaved), the indexer's are split halves, as the published code has it;
+- **group-limited routing** (``models/expert_layer.py``: the experts in
+  ``n_group`` runs, ``topk_group`` kept), a selection bias whose load is the
+  same for every seed (``_bias``), plain pre-norm residuals, an unscaled
+  embedding.
+
+Layout: ``params["layers"]`` is a list, the leading dense layers and then the
+expert layers, each with its own leaves, and a forward walks it unrolled: a
+held stack of experts sliced out of a scan's stacked weights is COPIED on its
+way into the ragged product (a custom call), 1.4 GB a layer of every decode
+step at the published widths, where a layer's own leaf is read in place. A
+prefill works a layer through in pieces so that a 32768-row prompt never holds
+more than a piece's temporaries: attention by chunks of ``ATTN_CHUNK`` queries
+(the mask of a chunk is ``[chunk, S]`` int8) and, inside a chunk, by groups of
+``HEAD_GROUP`` heads (a group's expanded K and V are recomputed a chunk, 4% of
+the attention's operations at 32768 rows); the MLP by runs of ``MLP_ROWS`` rows (the expert
+layer sorts every (token, choice) pair of its rows).
+
+Not here, and refused at boot rather than served wrongly
+(``models/families.py``): int8 weights or KV, paged KV, a prefix store, a mesh
+of more than one chip, the multi-token-prediction module, training.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from kukeon_tpu.models import kv_kinds
+from kukeon_tpu.models.expert_layer import expert_layer, select, swiglu
+from kukeon_tpu.models.llama import embed, mm
+from kukeon_tpu.ops import rope
+from kukeon_tpu.ops import sparse_attention as sa
+from kukeon_tpu.ops.norms import rms_norm
+
+Params = dict[str, Any]
+# Device-summed counters a forward returns beside its logits, in this order:
+# the routers' choices and those that chose a held expert (as window_moe's),
+# the token x expert-layer pairs those choices were made for, and of the decode
+# steps the latent rows attended and the rows that were live for them.
+COUNTERS = ("kukeon_moe_routed_total", "kukeon_moe_held_hits_total",
+            "kukeon_moe_routed_tokens_total",
+            "kukeon_sparse_rows_selected_total",
+            "kukeon_sparse_rows_live_total")
+ATTN_CHUNK = 4096       # queries a prefill attends at once (a bucket's, if fewer)
+HEAD_GROUP = 16         # heads whose K and V a prefill expands at once
+MLP_ROWS = 2048         # rows a prefill takes through an MLP at once
+LN_EPS = 1e-6           # the indexer's LayerNorm
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseLatentMoEConfig:
+    vocab_size: int = 129280
+    hidden_size: int = 7168
+    intermediate_size: int = 18432          # the dense layers' SwiGLU
+    moe_intermediate_size: int = 2048       # one expert's, and the shared one's
+    num_layers: int = 61
+    num_dense_layers: int = 3
+    num_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    num_experts: int = 256                  # the router's width
+    experts_per_token: int = 8
+    experts_held: tuple[int, int] = (0, 256)    # (first, count) on this chip
+    n_group: int = 8
+    topk_group: int = 4
+    route_scale: float = 2.5
+    route_norm: bool = True
+    rope_theta: float = 10_000.0
+    rope_factor: float = 40.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 163840
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        first, count = self.experts_held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} of "
+                             f"{self.num_experts}")
+        if (self.num_experts % self.n_group or self.num_heads % self.head_group
+                or not 0 < self.num_dense_layers < self.num_layers):
+            raise ValueError(
+                f"{self.num_experts} experts in {self.n_group} groups, "
+                f"{self.num_heads} heads, {self.num_dense_layers} dense of "
+                f"{self.num_layers} layers")
+
+    # What the engine asks every config for; this family's cache has neither.
+    @property
+    def num_kv_heads(self) -> int:
+        return self.num_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def head_group(self) -> int:
+        return min(HEAD_GROUP, self.num_heads)
+
+    @property
+    def latent_width(self) -> int:
+        """The cached row [c | kr | 0 ...]: whole lanes. The chip's tiling
+        holds 576 values a row as 640 whatever the program says; said here,
+        the row is what the gather reads and nothing relays it out."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim)
+                 // sa.LANES) * sa.LANES
+
+    @property
+    def yarn(self) -> bool:
+        """The published code stretches the rotation whenever the served
+        context passes the trained one."""
+        return self.max_seq_len > self.rope_original_max
+
+    @property
+    def softmax_scale(self) -> float:
+        m = rope.yarn_mscale(self.rope_factor, self.rope_mscale) \
+            if self.yarn else 1.0
+        return self.head_dim ** -0.5 * m * m
+
+    def inv_freq(self) -> jnp.ndarray:
+        if not self.yarn:
+            return rope.rope_frequencies(self.qk_rope_head_dim,
+                                         self.rope_theta)
+        return rope.yarn_frequencies(
+            self.qk_rope_head_dim, self.rope_theta, self.rope_factor,
+            self.rope_original_max, self.rope_beta_fast, self.rope_beta_slow)
+
+    def cache_kinds(self, max_seq_len: int) -> tuple[kv_kinds.CacheKind, ...]:
+        """Every layer holds, for every position, the latent row and the
+        indexer's key; a decode step scores the keys of the live rows and
+        reads ``index_topk`` latent rows."""
+        return (kv_kinds.CacheKind(
+            "latent", tuple(range(self.num_layers)), max_seq_len,
+            arrays=(("kidx", self.index_head_dim),
+                    ("ckv", self.latent_width)),
+            select=self.index_topk),)
+
+
+def deepseek_v32_exp() -> SparseLatentMoEConfig:
+    """deepseek-ai/DeepSeek-V3.2-Exp as published (671B-A37B)."""
+    return SparseLatentMoEConfig()
+
+
+def sparse_latent_moe_tiny() -> SparseLatentMoEConfig:
+    """Test size: one dense layer and two expert layers, a selection of 8 rows
+    (every test prompt is longer), this chip holding 4 of 16 experts in 4
+    groups of which 2 are kept."""
+    return SparseLatentMoEConfig(
+        vocab_size=384, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=48, num_layers=3, num_dense_layers=1,
+        num_heads=4, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_n_heads=16, index_head_dim=16,
+        index_topk=8, num_experts=16, experts_per_token=4,
+        experts_held=(4, 4), n_group=4, topk_group=2, rope_original_max=16,
+        max_seq_len=64, dtype=jnp.float32)
+
+
+# --- Init --------------------------------------------------------------------
+#
+# The weights ARE their recipe, as in ``models/window_moe.py``: a leaf is a
+# seeded Gaussian under a key folded from (seed, leaf name, layer, expert), and
+# ``benchmark/reference/sparse_latent_moe.py`` draws the same values without
+# importing this file (tests/bench pins the two).
+
+# ``wkv_b`` is drawn as published, [R, heads x (k_nope | v)], and HELD as its
+# two halves a head, ``wkv_bk`` [NH, Dn, R] and ``wkv_bv`` [NH, R, Dv]: the
+# operands of the absorbed decode's two batched products, read in place.
+LEAVES = ("embed", "lm_head", "final_norm", "norm1", "norm2", "wq_a",
+          "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo", "wi_q", "wi_k",
+          "wi_k_gain", "wi_k_shift", "wi_w", "w_gate", "w_up", "w_down",
+          "router", "bias", "s_gate", "s_up", "s_down", "e_gate", "e_up",
+          "e_down")
+GAIN_STD = 0.1
+SHIFT_STD = 0.1
+BIAS_SAMPLES = 1 << 16
+BIAS_STEPS = 32
+BIAS_STEP = 0.02
+
+
+def _leaf_key(key, name: str, layer=None, expert=None):
+    key = jax.random.fold_in(key, LEAVES.index(name))
+    if layer is not None:
+        key = jax.random.fold_in(key, layer)
+    if expert is not None:
+        key = jax.random.fold_in(key, expert)
+    return key
+
+
+def _matrix(key, shape, fan_in, dtype):
+    return (jax.random.normal(key, shape, jnp.float32)
+            * fan_in ** -0.5).astype(dtype)
+
+
+def _gain(key, shape, dtype):
+    return (1.0 + GAIN_STD * jax.random.normal(key, shape, jnp.float32)
+            ).astype(dtype)
+
+
+def _bias(key, router, gain, c: SparseLatentMoEConfig) -> jnp.ndarray:
+    """``e_score_correction_bias``: what the published training moves until
+    the experts' loads are even, FITTED to this seed's router as training
+    fits it, so that a seed cannot change how much work a chip that holds a
+    block of experts is given (left at zero a Gaussian router's own draw
+    moves a block of 16's load by 3.5% between seeds). The logits of a normed
+    token whose direction is isotropic are N(0, A^T A) with A = the norm's
+    gain x the router; over ``BIAS_SAMPLES`` such draws the bias takes
+    ``BIAS_STEPS`` steps of the training's rule (down where an expert's load
+    is over even, up where under), each the size of its relative excess."""
+    a = gain.astype(jnp.float32)[:, None] * router
+    cov = jnp.dot(a.T, a, precision=jax.lax.Precision.HIGHEST)
+    logits = jnp.dot(
+        jax.random.normal(key, (BIAS_SAMPLES, c.num_experts), jnp.float32),
+        jnp.linalg.cholesky(cov).T, precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    even = BIAS_SAMPLES * c.experts_per_token / c.num_experts
+
+    def step(_, b):
+        sel = select(s + b, c.experts_per_token, c.n_group, c.topk_group)
+        load = jnp.zeros((c.num_experts,), jnp.float32).at[
+            sel.reshape(-1)].add(1.0)
+        return b - BIAS_STEP * (load / even - 1.0)
+
+    return jax.lax.fori_loop(0, BIAS_STEPS, step,
+                             jnp.zeros((c.num_experts,), jnp.float32))
+
+
+def _layer_leaves(c: SparseLatentMoEConfig, dense: bool) -> dict:
+    """name -> (kind, shape, fan_in) of one layer's leaves."""
+    H, Im, Q, R = (c.hidden_size, c.moe_intermediate_size, c.q_lora_rank,
+                   c.kv_lora_rank)
+    NH, Dk, Dv = c.num_heads, c.head_dim, c.v_head_dim
+    Hi, Di = c.index_n_heads, c.index_head_dim
+    out = {
+        "norm1": ("gain", (H,), 0), "norm2": ("gain", (H,), 0),
+        "wq_a": ("matrix", (H, Q), H), "q_norm": ("gain", (Q,), 0),
+        "wq_b": ("matrix", (Q, NH * Dk), Q),
+        "wkv_a": ("matrix", (H, R + c.qk_rope_head_dim), H),
+        "kv_norm": ("gain", (R,), 0),
+        # a head's columns of wkv_b: its k_nope, then its v
+        "wkv_bk": ("up_k", (R, NH * (c.qk_nope_head_dim + Dv)), R),
+        "wkv_bv": ("up_v", (R, NH * (c.qk_nope_head_dim + Dv)), R),
+        "wo": ("matrix", (NH * Dv, H), NH * Dv),
+        "wi_q": ("matrix", (Q, Hi * Di), Q), "wi_k": ("matrix", (H, Di), H),
+        "wi_k_gain": ("gain", (Di,), 0), "wi_k_shift": ("shift", (Di,), 0),
+        "wi_w": ("matrix", (H, Hi), H)}
+    if dense:
+        I = c.intermediate_size
+        out.update({"w_gate": ("matrix", (H, I), H),
+                    "w_up": ("matrix", (H, I), H),
+                    "w_down": ("matrix", (I, H), I)})
+    else:
+        out.update({
+            "router": ("router", (H, c.num_experts), H),
+            "bias": ("bias", (c.num_experts,), 0),
+            "s_gate": ("matrix", (H, Im), H), "s_up": ("matrix", (H, Im), H),
+            "s_down": ("matrix", (Im, H), Im),
+            "e_gate": ("experts", (H, Im), H), "e_up": ("experts", (H, Im), H),
+            "e_down": ("experts", (Im, H), Im)})
+    return out
+
+
+def _draw(key, c, name, kind, shape, fan_in, layer):
+    if kind in ("up_k", "up_v"):
+        both = _matrix(_leaf_key(key, "wkv_b", layer), shape, fan_in, c.dtype
+                       ).reshape(shape[0], c.num_heads, -1)
+        n = c.qk_nope_head_dim
+        return (jnp.transpose(both[..., :n], (1, 2, 0)) if kind == "up_k"
+                else jnp.transpose(both[..., n:], (1, 0, 2)))
+    k = _leaf_key(key, name, layer)
+    if kind == "gain":
+        return _gain(k, shape, c.dtype)
+    if kind == "shift":
+        return (SHIFT_STD * jax.random.normal(k, shape, jnp.float32)
+                ).astype(c.dtype)
+    if kind == "router":
+        return _matrix(k, shape, fan_in, jnp.float32)
+    if kind == "bias":      # fitted to the layer's own router and norm
+        spec = _layer_leaves(c, False)
+        return _bias(k, _draw(key, c, "router", *spec["router"], layer),
+                     _draw(key, c, "norm2", *spec["norm2"], layer), c)
+    if kind == "experts":
+        first, count = c.experts_held
+        return jax.lax.map(
+            lambda e: _matrix(_leaf_key(key, name, layer, e), shape, fan_in,
+                              c.dtype), first + jnp.arange(count))
+    return _matrix(k, shape, fan_in, c.dtype)
+
+
+def _draw_params(key: jax.Array, c: SparseLatentMoEConfig) -> Params:
+    H, V, Ld = c.hidden_size, c.vocab_size, c.num_dense_layers
+    return {
+        # rows of unit variance, as a trained stream is its token's own vector
+        # before anything else: at fan_in ** -0.5 the first attention's mean
+        # over the selected rows, which a sequence's tokens share, outweighs
+        # the token (0.022 against 0.012 a value at the published widths),
+        # every router then sees one direction a sequence, and a seed's
+        # prompts decide which experts work
+        "embed": _matrix(_leaf_key(key, "embed"), (V, H), 1, c.dtype),
+        "lm_head": _matrix(_leaf_key(key, "lm_head"), (H, V), H, c.dtype),
+        "final_norm": _gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
+        "layers": [
+            {name: _draw(key, c, name, *spec, i)
+             for name, spec in _layer_leaves(c, i < Ld).items()}
+            for i in range(c.num_layers)],
+    }
+
+
+def init_params(key: jax.Array, cfg: SparseLatentMoEConfig,
+                shardings: Any = None) -> Params:
+    """Checkpoint-less init on the device in ONE jitted program that takes
+    the key as its argument (``window_moe.init_params`` says why)."""
+    return jax.jit(lambda k: _draw_params(k, cfg),
+                   out_shardings=shardings)(key)
+
+
+def param_specs(params: Params):
+    """Everything whole on the one chip."""
+    from jax.sharding import PartitionSpec
+
+    return jax.tree.map(lambda _: PartitionSpec(), params)
+
+
+# --- The block ---------------------------------------------------------------
+
+def _rotate_first(x, positions, c, interleaved: bool):
+    """x [..., S, heads, D] with its first ``qk_rope_head_dim`` rotated."""
+    r = c.qk_rope_head_dim
+    return jnp.concatenate(
+        [rope.rotate(x[..., :r], positions, c.inv_freq(), interleaved),
+         x[..., r:]], axis=-1)
+
+
+def _latents(h, w: dict, c: SparseLatentMoEConfig, positions):
+    """The normed input h [.., S, H] at ``positions`` [.., S] -> (cq [.., S,
+    Q], the latent row [c | kr] [.., S, R + Dr] that is cached)."""
+    R = c.kv_lora_rank
+    # A product ENDS at its flat result wherever a reshape to heads or a
+    # rotation follows (``llama._qkv`` says why: fused into the product they
+    # make the compiler transpose and copy the weight first).
+    cq, kv = jax.lax.optimization_barrier(
+        (mm(h, w["wq_a"]), mm(h, w["wkv_a"])))
+    cq = rms_norm(cq, w["q_norm"], c.rms_norm_eps)
+    kr = rope.rotate(kv[..., None, R:], positions, c.inv_freq(),
+                     interleaved=True)[..., 0, :]
+    pad = jnp.zeros((*kv.shape[:-1], c.latent_width - kv.shape[-1]), kv.dtype)
+    return cq, jnp.concatenate(
+        [rms_norm(kv[..., :R], w["kv_norm"], c.rms_norm_eps), kr, pad],
+        axis=-1)
+
+
+@jax.named_scope("indexer")
+def _index_key(h, w: dict, c: SparseLatentMoEConfig, positions):
+    """The normed input -> kI [.., S, Di], the cached index row: a LayerNorm
+    of its projection, the first ``qk_rope_head_dim`` rotated in split
+    halves."""
+    k = mm(h, w["wi_k"]).astype(jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + LN_EPS)
+    k = (k * w["wi_k_gain"].astype(jnp.float32)
+         + w["wi_k_shift"].astype(jnp.float32)).astype(h.dtype)
+    return _rotate_first(k[..., None, :], positions, c, False)[..., 0, :]
+
+
+@jax.named_scope("indexer")
+def _index_query(cq, wts, w: dict, c: SparseLatentMoEConfig, positions):
+    """The query latent and ``weights_proj`` of the normed input (``wts``
+    [.., S, Hi]) -> (qI [.., S, Hi, Di], rotated as the key is, and the
+    heads' weights [.., S, Hi] float32)."""
+    Hi, Di = c.index_n_heads, c.index_head_dim
+    qi = jax.lax.optimization_barrier(mm(cq, w["wi_q"]))
+    qi = qi.reshape(*cq.shape[:-1], Hi, Di)
+    wts = wts.astype(jnp.float32) * (Hi ** -0.5 * Di ** -0.5)
+    return _rotate_first(qi, positions, c, False), wts
+
+
+def _queries(cq, wq_b, c: SparseLatentMoEConfig, positions, heads: int):
+    """cq [.., S, Q] through ``heads`` heads' columns of Wq_b -> [.., S,
+    heads, Dk], the rotated part (the last ``qk_rope_head_dim``, neighbours
+    paired) rotated."""
+    q = jax.lax.optimization_barrier(mm(cq, wq_b)).reshape(
+        *cq.shape[:-1], heads, c.head_dim)
+    n = c.qk_nope_head_dim
+    return jnp.concatenate(
+        [q[..., :n], rope.rotate(q[..., n:], positions, c.inv_freq(),
+                                 interleaved=True)], axis=-1)
+
+
+def _pieces(S: int, rows: int) -> int:
+    if S % min(rows, S):
+        raise ValueError(f"a prompt bucket of {S} rows is no multiple of the "
+                         f"{rows} rows a prefill works through at once")
+    return S // min(rows, S)
+
+
+def _in_place(fn, x, rows: int):
+    """x [S, ...] with ``fn`` applied to runs of ``rows`` rows, one after
+    another, each run written back where it was read: one buffer of x's size
+    however many runs. ``fn(piece, first row) -> (piece', extra)``; the
+    extras are summed."""
+    n = _pieces(x.shape[0], rows)
+    rows = x.shape[0] // n
+
+    def one(i, carry):
+        x, extra = carry
+        first = i * rows
+        # behind a barrier, or the compiler hoists a norm's float32 copy of
+        # ALL rows out of the loop
+        piece, e = fn(jax.lax.optimization_barrier(
+            jax.lax.dynamic_slice_in_dim(x, first, rows)), first)
+        return (jax.lax.dynamic_update_slice_in_dim(x, piece, first, 0),
+                extra + e)
+
+    return jax.lax.fori_loop(0, n, one, (x, jnp.int32(0)))
+
+
+def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig):
+    """x [S, H] of one prompt -> (x + Attn(N1 x), the latent rows [S, W], the
+    index keys [S, Di]). Two passes over the rows in chunks, so that nothing
+    of x's size is made: the first leaves what every later query needs of
+    every row (the query latent, the cached rows, the indexer's weights); the
+    second attends a chunk of queries against them and adds to x in place."""
+    S = x.shape[0]
+    NH, G, R = c.num_heads, c.head_group, c.kv_lora_rank
+    Dv, Dr = c.v_head_dim, c.qk_rope_head_dim
+    n = _pieces(S, ATTN_CHUNK)
+    rows = S // n
+
+    def project(a):
+        piece, first = a
+        at = first + jnp.arange(rows)
+        h = rms_norm(jax.lax.optimization_barrier(piece), w["norm1"],
+                     c.rms_norm_eps)
+        cq, row = _latents(h, w, c, at)
+        return cq, row, _index_key(h, w, c, at), mm(h, w["wi_w"])
+
+    cq, row, ki, wts = jax.tree.map(
+        lambda a: a.reshape(S, *a.shape[2:]),
+        jax.lax.map(project, (x.reshape(n, rows, -1),
+                              jnp.arange(n, dtype=jnp.int32) * rows)))
+    # a group of heads' columns at a time, the groups on a leading axis
+    wq_b = jnp.swapaxes(w["wq_b"].reshape(-1, NH // G, G * c.head_dim), 0, 1)
+    wk = w["wkv_bk"].reshape(NH // G, G, -1, R)
+    wv = w["wkv_bv"].reshape(NH // G, G, R, Dv)
+    latent, kr = row[:, :R], row[:, R:R + Dr]
+
+    def chunk(xq, first):
+        def part(a):
+            return jax.lax.dynamic_slice_in_dim(a, first, rows, axis=0)
+
+        at = first + jnp.arange(rows)
+        qi, wi = _index_query(part(cq), part(wts), w, c, at)
+        with jax.named_scope("sparse_select"):
+            mask = sa.select_rows(qi, wi, ki, first, topk=c.index_topk)
+
+        def group(_, ws):
+            q = _queries(part(cq), ws[0], c, at, G)
+            # the expanded form: each head's k_nope and v out of the latent
+            k = jnp.concatenate(
+                [jnp.einsum("sr,hdr->hsd", latent, ws[1]),
+                 jnp.broadcast_to(kr[None], (G, S, Dr))], axis=-1)
+            v = jnp.einsum("sr,hrd->hsd", latent, ws[2])
+            with jax.named_scope("latent_attention"):
+                o = sa.masked_attention(jnp.swapaxes(q, 0, 1), k, v, mask,
+                                        first, scale=c.softmax_scale)
+            return None, jnp.swapaxes(o, 0, 1)          # [rows, G, Dv]
+
+        _, o = jax.lax.scan(group, None, (wq_b, wk, wv))
+        o = jnp.moveaxis(o, 0, 1).reshape(rows, NH * Dv)
+        return xq + mm(o, w["wo"]), jnp.int32(0)
+
+    x, _ = _in_place(chunk, x, ATTN_CHUNK)
+    return x, row, ki
+
+
+def _mlp(x, w: dict, c: SparseLatentMoEConfig, counted):
+    """The dense SwiGLU or the expert layer, by the leaves the layer has, for
+    x [N, H]; returns (x', held hits)."""
+    h = rms_norm(x, w["norm2"], c.rms_norm_eps)
+    if "router" not in w:
+        return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), jnp.int32(0)
+    m, hits = expert_layer(
+        h, w, experts_per_token=c.experts_per_token,
+        experts_held=c.experts_held, route_norm=c.route_norm,
+        route_scale=c.route_scale, groups=c.n_group,
+        groups_kept=c.topk_group, counted=counted)
+    return x + m, hits
+
+
+def _head(params, c: SparseLatentMoEConfig, x):
+    with jax.named_scope("lm_head"):
+        return mm(rms_norm(x, params["final_norm"], c.rms_norm_eps),
+                  params["lm_head"]).astype(jnp.float32)
+
+
+def _through_layers(params: Params, x, layer):
+    """x through the layers, one after another. ``layer(x, w, number) ->
+    (x', latent row, index key, sums)``; ``number`` is the layer's place in
+    the model. Returns (x, the rows and the keys stacked over the layers, the
+    sums added up)."""
+    rows, keys, sums = [], [], 0
+    for number, w in enumerate(params["layers"]):
+        x, row, key, s = layer(x, w, number)
+        sums = sums + s
+        rows.append(row)
+        keys.append(key)
+    return x, jnp.stack(rows), jnp.stack(keys), sums
+
+
+def _counters(c: SparseLatentMoEConfig, counted, hits, selected=0, live=0):
+    tokens = jnp.sum(counted, dtype=jnp.int32) * (
+        c.num_layers - c.num_dense_layers)
+    return jnp.stack([tokens * c.experts_per_token, hits, tokens,
+                      jnp.int32(selected), jnp.int32(live)])
+
+
+def prefill(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
+            length) -> tuple[jnp.ndarray, dict, jnp.ndarray]:
+    """tokens [1, S] (``length`` of them real) -> (float32 logits [V] of the
+    last real position, the block ``{"ckv", "kidx"}`` [L, 1, S, width],
+    COUNTERS)."""
+    c = cfg
+    S = tokens.shape[1]
+    counted = jnp.arange(S) < length
+
+    def layer(x, w, _number):
+        x, row, key = _prefill_attention(x, w, c)
+
+        def mlp(piece, first):
+            return _mlp(piece, w, c, jax.lax.dynamic_slice_in_dim(
+                counted, first, piece.shape[0]))
+
+        x, hits = _in_place(mlp, x, MLP_ROWS)
+        return x, row, key, hits
+
+    x, rows, keys, hits = _through_layers(
+        params, embed(params, tokens, c.dtype)[0], layer)
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, keepdims=True)
+    return (_head(params, c, last)[0],
+            {"ckv": rows[:, None], "kidx": keys[:, None]},
+            _counters(c, counted, hits))
+
+
+def decode(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
+           cache: kv_kinds.LayeredKV, kinds, active: jnp.ndarray):
+    """One token a slot against the VIEW of the held cache (``kv_kinds``):
+    tokens [B] at positions ``cache.lengths`` -> (float32 logits [B, V], this
+    step's rows ``{"ckv", "kidx"}`` [L, B, 1, width], COUNTERS over the
+    ``active`` slots). The cache is read, never written: the caller appends,
+    and the step's own row takes part in its selection and its attention
+    without having been written."""
+    c = cfg
+    B = tokens.shape[0]
+    NH, R, Dn, Dv = (c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim,
+                     c.v_head_dim)
+    W = c.latent_width
+    lengths = cache.lengths
+    positions = lengths[:, None]
+    held = cache.held[0]
+    # a slot that is not active reads nothing (its output is dropped: the
+    # engine keeps such a slot's token)
+    reads = jnp.where(active, lengths, 0)
+
+    def layer(x, w, number):
+        h = rms_norm(x, w["norm1"], c.rms_norm_eps)
+        cq, row = _latents(h, w, c, positions)
+        ki = _index_key(h, w, c, positions)[:, 0]
+        qi, wts = _index_query(cq, mm(h, w["wi_w"]), w, c, positions)
+        qi, wts = qi[:, 0], wts[:, 0]
+        with jax.named_scope("indexer"):
+            scores = sa.decode_index_scores(qi, wts, held["kidx"], number,
+                                            reads)
+            own = jnp.einsum(
+                "bh,bh->b", wts, jnp.maximum(jnp.einsum(
+                    "bhd,bd->bh", qi, ki,
+                    preferred_element_type=jnp.float32), 0.0))
+        idx, chosen = sa.select_decode(scores, own, c.index_topk)
+        q = _queries(cq, w["wq_b"], c, positions, NH)[:, 0]     # [B, NH, Dk]
+        # the absorbed query: each head's q_nope through its Wkv_b^K, then
+        # the rotated part, against a latent row [c | kr | 0]
+        q = jnp.concatenate(
+            [jnp.einsum("bhd,hdr->bhr", q[..., :Dn], w["wkv_bk"]),
+             q[..., Dn:],
+             jnp.zeros((B, NH, W - R - c.qk_rope_head_dim), q.dtype)], axis=-1)
+        mix = sa.gathered_attention(
+            q, row[:, 0], held["ckv"], number, idx, chosen,
+            scale=c.softmax_scale, value_dim=R)
+        o = jnp.einsum("bhr,hrd->bhd", mix, w["wkv_bv"])
+        x = x + mm(o.reshape(B, 1, NH * Dv), w["wo"])
+        x, hits = _mlp(x[:, 0], w, c, active)
+        picked = jnp.sum(chosen & active[:, None], dtype=jnp.int32)
+        return x[:, None], row, ki[:, None], jnp.stack([hits, picked])
+
+    x, rows, keys, sums = _through_layers(
+        params, embed(params, tokens[:, None], c.dtype), layer)
+    live = jnp.sum(jnp.where(active, lengths + 1, 0),
+                   dtype=jnp.int32) * c.num_layers
+    return (_head(params, c, x)[:, 0], {"ckv": rows, "kidx": keys},
+            _counters(c, active, sums[0], sums[1], live))

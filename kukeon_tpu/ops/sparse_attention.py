@@ -1,0 +1,414 @@
+"""Attention that selects its rows: a small indexer scores every earlier
+position for a query, the ``topk`` best are kept, and the attention proper sees
+only those (DeepSeek sparse attention over a latent cache).
+
+    I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s)),   s <= t
+    S_t     = the min(topk, t + 1) positions of largest I(t, s)
+
+Four ops, each a Pallas TPU kernel where ``kernels_run`` says so and the XLA
+body below it everywhere else (the CPU, shapes the tiles do not divide); the
+XLA body is also the kernel's reference in the tests.
+
+- ``select_rows`` (prefill): the index scores of a block of queries against
+  every key up to the block's last row, never ``[S, S]`` whole: a block's
+  scores live in VMEM, the ``topk``-th largest of each row is found there by a
+  descent over the bits of the float's order, and what leaves is the mask
+  ``s in S_t`` as int8, laid out in key tiles ``[S / T, Q, T]``.
+- ``masked_attention`` (prefill): flash attention in the expanded form under
+  that mask AND causality, for a group of heads that share it: the mask tile
+  is fetched once a (query block, key tile) and every head of the group runs
+  under it; key tiles past a block's last row are neither fetched nor run.
+- ``decode_index_scores``: one query a slot against the slot's LIVE index
+  keys, read in place from the held stack; tiles past a slot's length move no
+  byte.
+- ``gathered_attention`` (decode): the absorbed form over ONE shared latent
+  head: the selected rows are gathered from the held stack (the step's own row
+  among them without having been written first) and attended; rows that were
+  not selected are never read.
+
+A selection is a discontinuity: two positions whose index scores lie within
+rounding of each other at the ``topk`` boundary may swap.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from kukeon_tpu.ops import dispatch
+
+NEG_INF = -1e30
+LANES = 128
+KEY_TILE = 512          # keys of one tile of the prefill kernels and the mask
+SELECT_ROWS = 128       # queries a step of the selection kernel
+FLASH_ROWS = 512        # queries a step of the attention kernel
+DECODE_TILE = 2048      # index keys a step of the decode kernel
+INT_MIN = -2 ** 31
+
+
+def key_tile(keys: int) -> int:
+    return min(KEY_TILE, keys)
+
+
+def kernels_run(queries: int, keys: int, head_dim: int) -> bool:
+    """Whether the prefill kernels cover a chunk of ``queries`` rows against
+    ``keys`` positions (dispatcher guard): one TPU, whole tiles."""
+    return (jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1
+            and keys % KEY_TILE == 0 and queries % FLASH_ROWS == 0
+            and head_dim % LANES == 0)
+
+
+def decode_kernel_runs(rows: int, head_dim: int) -> bool:
+    return (jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1
+            and rows % DECODE_TILE == 0 and head_dim % LANES == 0)
+
+
+def _order(x):
+    """float32 -> int32 whose signed order is the floats' order."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+# --- select_rows ---------------------------------------------------------------
+
+def _select_kernel(row0_ref, q_ref, w_ref, k_ref, mask_ref, sc_ref, *,
+                   topk, heads, dim):
+    nk, bq, bk = sc_ref.shape
+    first = row0_ref[0] + pl.program_id(0) * bq
+    # the key tiles that hold a position some row of this block may see
+    tiles = jnp.minimum((first + bq + bk - 1) // bk, nk)
+    rows = first + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+
+    def score(t, _):
+        k = k_ref[t]                                            # [bk, D]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                q_ref[:, h * dim:(h + 1) * dim], k,
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            acc = acc + w_ref[:, h:h + 1] * jnp.maximum(s, 0.0)
+        acc = jnp.where(t * bk + cols <= rows, acc, -jnp.inf)
+        sc_ref[t] = _order(acc)
+        return 0
+
+    jax.lax.fori_loop(0, tiles, score, 0)
+
+    def count(at):          # rows' entries >= at [bq, 1], over the tiles
+        def tile(t, c):
+            ge = (sc_ref[t] >= at).astype(jnp.int32)
+            for lane in range(0, bk, LANES):
+                c = c + ge[:, lane:lane + LANES]
+            return c
+
+        c = jax.lax.fori_loop(0, tiles, tile,
+                              jnp.zeros((bq, min(bk, LANES)), jnp.int32))
+        return jnp.sum(c, axis=1, keepdims=True)
+
+    # The topk-th largest entry of each row, bit by bit from the sign down:
+    # the largest ``at`` that at least topk entries reach. A row with fewer
+    # entries than topk ends at or under the order of -inf: everything it
+    # may see is selected.
+    at = jnp.where(count(jnp.zeros((bq, 1), jnp.int32)) >= topk,
+                   jnp.int32(0), jnp.int32(INT_MIN))
+
+    def descend(i, at):
+        cand = at | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(cand) >= topk, cand, at)
+
+    at = jax.lax.fori_loop(0, 31, descend, at)
+
+    def write(t, _):
+        keep = (sc_ref[t] >= at) & (t * bk + cols <= rows)
+        mask_ref[t] = keep.astype(jnp.int32).astype(mask_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tiles, write, 0)
+
+    def blank(t, _):
+        mask_ref[t] = jnp.zeros((bq, bk), mask_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(tiles, nk, blank, 0)
+
+
+def _select_xla(q, w, k, row0, topk):
+    Q, S = q.shape[0], k.shape[0]
+    s = jnp.einsum("qhd,kd->qhk", q, k, preferred_element_type=jnp.float32)
+    score = jnp.einsum("qhk,qh->qk", jnp.maximum(s, 0.0), w)
+    see = jnp.arange(S)[None, :] <= (row0 + jnp.arange(Q))[:, None]
+    score = jnp.where(see, score, -jnp.inf)
+    kth = jax.lax.top_k(score, min(topk, S))[0][:, -1:]
+    T = key_tile(S)
+    keep = (score >= kth) & see
+    return keep.astype(jnp.int8).reshape(Q, S // T, T).transpose(1, 0, 2)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def select_rows(q, w, k, row0, *, topk: int, interpret: bool | None = None):
+    """The mask ``s in S_t`` of a chunk of queries. q [Q, Hh, D] the indexer's
+    queries (rotated), w [Q, Hh] float32 their weights, k [S, D] every index
+    key of the prompt; the chunk's first row is position ``row0`` (traced).
+    Returns int8 [S / T, Q, T] (T = ``key_tile(S)``): tile, query, key of the
+    tile; 1 where the key is at or before the query and among its ``topk``
+    best."""
+    Q, Hh, D = q.shape
+    S = k.shape[0]
+    if interpret is None:
+        if not kernels_run(Q, S, D):
+            dispatch.note("select_rows", "xla")
+            return _select_xla(q, w, k, row0, topk)
+        interpret = False
+    dispatch.note("select_rows", "pallas")
+    bk, bq = key_tile(S), min(SELECT_ROWS, Q)
+    nk = S // bk
+    held = (nk * bq * bk * 4 + 2 * nk * bq * bk + 2 * S * D * k.dtype.itemsize
+            + 2 * bq * Hh * (D * q.dtype.itemsize + 4 * LANES))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, heads=Hh, dim=D),
+        out_shape=jax.ShapeDtypeStruct((nk, Q, bk), jnp.int8),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(Q // bq,),
+            in_specs=[
+                pl.BlockSpec((bq, Hh * D), lambda i, r: (i, 0)),
+                pl.BlockSpec((bq, Hh), lambda i, r: (i, 0)),
+                pl.BlockSpec((nk, bk, D), lambda i, r: (0, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((nk, bq, bk), lambda i, r: (0, i, 0)),
+            scratch_shapes=[pltpu.VMEM((nk, bq, bk), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=int(held * 1.25) + (8 << 20)),
+        name="sparse_select_rows",
+        interpret=interpret,
+    )(jnp.reshape(row0, (1,)).astype(jnp.int32), q.reshape(Q, Hh * D),
+      w.astype(jnp.float32), k.reshape(nk, bk, D))
+
+
+# --- masked_attention ----------------------------------------------------------
+
+def _last_tile(row0, i, bq, bk):
+    """The last key tile a query block may see."""
+    return (row0 + (i + 1) * bq - 1) // bk
+
+
+def _attend_kernel(row0_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale):
+    G, bq, _ = q_ref.shape
+    bk = k_ref.shape[1]
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j <= _last_tile(row0_ref[0], i, bq, bk))
+    def _compute():
+        keep = mask_ref[...].astype(jnp.int32) != 0             # [bq, bk]
+
+        def head(h, _):
+            s = jax.lax.dot_general(
+                q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[h][:, :1]
+            l_prev = l_scr[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # a row that has met no selected key yet holds NEG_INF: it adds
+            # weights of 1 here, and its first real key's ``corr`` of 0 wipes
+            # them; every row selects at least one key
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[h]
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return 0
+
+        jax.lax.fori_loop(0, G, head, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        def head(h, _):
+            l = jnp.maximum(l_scr[h][:, :1], 1e-30)
+            o_ref[h] = (acc_scr[h] / l).astype(o_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, G, head, 0)
+
+
+def _attend_xla(q, k, v, mask, scale):
+    nk, Q, T = mask.shape
+    keep = mask.transpose(1, 0, 2).reshape(Q, nk * T) != 0
+    s = jnp.einsum("gqd,gkd->gqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(keep[None], s, NEG_INF), axis=-1)
+    return jnp.einsum("gqk,gkd->gqd", p.astype(v.dtype), v).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def masked_attention(q, k, v, mask, row0, *, scale: float,
+                     interpret: bool | None = None):
+    """softmax(q k^T * scale) v over the keys the mask keeps, for a group of
+    heads that share it. q [G, Q, Dk], k [G, S, Dk], v [G, S, Dv]; ``mask``
+    as ``select_rows`` gives it (causality inside); ``row0`` the position of
+    the chunk's first query. Returns [G, Q, Dv] in q's dtype."""
+    G, Q, Dk = q.shape
+    S, Dv = k.shape[1], v.shape[2]
+    if interpret is None:
+        if not kernels_run(Q, S, Dv):
+            dispatch.note("masked_attention", "xla")
+            return _attend_xla(q, k, v, mask, scale)
+        interpret = False
+    dispatch.note("masked_attention", "pallas")
+    bk, bq = key_tile(S), min(FLASH_ROWS, Q)
+    nk = S // bk
+
+    def seen(i, j, r):
+        return jnp.minimum(j, _last_tile(r[0], i, bq, bk))
+
+    item = q.dtype.itemsize
+    held = (2 * G * (bq * (Dk + Dv) + bk * (Dk + Dv)) * item + 2 * bq * bk
+            + G * bq * (2 * LANES + Dv) * 4 + 4 * bq * bk * 4)
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((G, Q, Dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(Q // bq, nk),
+            in_specs=[
+                pl.BlockSpec((G, bq, Dk), lambda i, j, r: (0, i, 0)),
+                pl.BlockSpec((G, bk, Dk),
+                             lambda i, j, r: (0, seen(i, j, r), 0)),
+                pl.BlockSpec((G, bk, Dv),
+                             lambda i, j, r: (0, seen(i, j, r), 0)),
+                pl.BlockSpec((None, bq, bk),
+                             lambda i, j, r: (seen(i, j, r), i, 0)),
+            ],
+            out_specs=pl.BlockSpec((G, bq, Dv), lambda i, j, r: (0, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, bq, LANES), jnp.float32),    # running max
+                pltpu.VMEM((G, bq, LANES), jnp.float32),    # running sum
+                pltpu.VMEM((G, bq, Dv), jnp.float32),       # accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(held * 1.25) + (8 << 20)),
+        name="sparse_masked_attention",
+        interpret=interpret,
+    )(jnp.reshape(row0, (1,)).astype(jnp.int32), q, k, v, mask)
+
+
+# --- decode --------------------------------------------------------------------
+
+def _decode_scores_kernel(layer_ref, len_ref, q_ref, w_ref, k_ref, o_ref):
+    del layer_ref
+    bk = k_ref.shape[0]
+    b, j = pl.program_id(0), pl.program_id(1)
+    n = len_ref[b]
+
+    @pl.when(j * bk < n)
+    def _live():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [Hh, bk]
+        s = jnp.sum(w_ref[...] * jnp.maximum(s, 0.0), axis=0, keepdims=True)
+        row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        o_ref[...] = jnp.where(row < n, s, -jnp.inf)
+
+    @pl.when(j * bk >= n)
+    def _past():
+        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_index_scores(q, w, keys, layer, lengths, *,
+                        interpret: bool | None = None):
+    """I(t, s) of one query a slot against the rows ``s < lengths`` of the
+    slot's index keys. q [B, Hh, D], w [B, Hh] float32, ``keys`` the held
+    stack [layers, B, rows, D] read at ``layer`` (traced; the stack is the
+    operand so that a layer is read in place). Returns float32 [B, rows],
+    -inf at and past ``lengths``."""
+    B, Hh, D = q.shape
+    rows = keys.shape[2]
+    if interpret is None:
+        if not decode_kernel_runs(rows, D):
+            dispatch.note("decode_index_scores", "xla")
+            k = jax.lax.dynamic_index_in_dim(keys, layer, keepdims=False)
+            s = jnp.einsum("bhd,bkd->bhk", q, k,
+                           preferred_element_type=jnp.float32)
+            s = jnp.einsum("bhk,bh->bk", jnp.maximum(s, 0.0), w)
+            return jnp.where(jnp.arange(rows)[None] < lengths[:, None], s,
+                             -jnp.inf)
+        interpret = False
+    dispatch.note("decode_index_scores", "pallas")
+    bk = min(DECODE_TILE, rows)
+
+    def live(b, j, layer, n):       # the tile read: never past the last live
+        return jnp.minimum(j, jnp.maximum((n[b] + bk - 1) // bk - 1, 0))
+
+    out = pl.pallas_call(
+        _decode_scores_kernel,
+        out_shape=jax.ShapeDtypeStruct((B, 1, rows), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, rows // bk),
+            in_specs=[
+                pl.BlockSpec((None, Hh, D), lambda b, j, la, n: (b, 0, 0)),
+                pl.BlockSpec((None, Hh, 1), lambda b, j, la, n: (b, 0, 0)),
+                pl.BlockSpec((None, None, bk, D),
+                             lambda b, j, la, n: (la[0], b,
+                                                  live(b, j, la, n), 0)),
+            ],
+            out_specs=pl.BlockSpec((None, 1, bk),
+                                   lambda b, j, la, n: (b, 0, j))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="sparse_decode_index_scores",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths.astype(jnp.int32),
+      q, w.astype(jnp.float32)[..., None], keys)
+    return out[:, 0]
+
+
+@jax.named_scope("sparse_select")
+def select_decode(scores, own, topk: int):
+    """The rows a decode step attends to: the ``topk`` best of a slot's cache
+    rows and the step's own position together. scores [B, rows] (-inf where
+    no row lives), own [B] the step's score of itself. Returns (idx [B, k]
+    int32 into the cache rows, ``rows`` standing for the step's own row;
+    chosen [B, k] bool, False where fewer than k positions exist)."""
+    rows = scores.shape[1]
+    best, idx = jax.lax.top_k(
+        jnp.concatenate([scores, own[:, None]], axis=1), min(topk, rows + 1))
+    return idx.astype(jnp.int32), best > -jnp.inf
+
+
+@jax.named_scope("latent_attention")
+def gathered_attention(q, new, latents, layer, idx, chosen, *, scale: float,
+                       value_dim: int):
+    """The absorbed form over the selected rows. q [B, NH, W] (each head's
+    query against a latent row: ``W`` wide, the ``value_dim`` latent values
+    first, the rotated part after), new [B, W] the step's own row,
+    ``latents`` the held stack [layers, B, rows, W] read at ``layer``, idx /
+    chosen as ``select_decode`` gives them. Returns float32-accumulated
+    [B, NH, value_dim] in q's dtype: the heads' mixes of latent values, which
+    the caller takes through the value half of the up-projection."""
+    B, rows = latents.shape[1], latents.shape[2]
+    own = idx >= rows
+    got = latents[layer, jnp.arange(B)[:, None], jnp.minimum(idx, rows - 1)]
+    got = jnp.where(own[..., None], new[:, None, :], got)       # [B, k, W]
+    s = jnp.einsum("bhw,bkw->bhk", q, got,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(chosen[:, None, :], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkv->bhv", p.astype(got.dtype),
+                      got[..., :value_dim]).astype(q.dtype)

@@ -256,11 +256,13 @@ def bucket_length(n: int, buckets: tuple[int, ...] = PREFILL_BUCKETS) -> int:
     for b in buckets:
         if n <= b:
             return b
-    # Beyond the largest bucket: round up to a multiple of it (rare path;
-    # still a bounded compile cache because lengths are multiples of the
-    # largest bucket).
+    # Beyond the largest bucket: its next doubling (8192, 16384, 32768 ...
+    # past 4096), so that a long context costs a program an octave and a
+    # warm-up that names the doublings has compiled every one of them.
     last = buckets[-1]
-    return ((n + last - 1) // last) * last
+    while last < n:
+        last *= 2
+    return last
 
 
 @sanitize.guard_class
@@ -446,7 +448,8 @@ class ServingEngine:
             decode_block_rows(
                 cfg.num_heads, cfg.num_kv_heads, kd.rows, cfg.head_dim,
                 cfg.dtype, jnp.int8 if kv_cache_int8 else cfg.dtype,
-                mesh.size if mesh is not None else 1) if kd.rows else None
+                mesh.size if mesh is not None else 1)
+            if kd.rows and not kd.arrays else None
             for kd in self._kinds)
         # The kinds whose slots hold state without rows: what
         # kukeon_engine_state_slot_steps_total counts from.
@@ -2290,8 +2293,12 @@ class ServingEngine:
         for kd, block in zip(self._kinds, self._kv_blocks):
             all_rows = len(kd.layers) * self.num_slots * kd.rows
             held += all_rows
-            read += all_rows if block is None else len(kd.layers) * sum(
-                -(-kd.live(n) // block) * block for n in lengths)
+            if kd.arrays:   # rows of named arrays: what the kind says it reads
+                read += len(kd.layers) * round(sum(
+                    kd.read(n) for n in lengths))
+            else:
+                read += all_rows if block is None else len(kd.layers) * sum(
+                    -(-kd.live(n) // block) * block for n in lengths)
         return held, read
 
     def _prefill_span(self, req: Request, slot: int):

@@ -421,3 +421,93 @@ def test_the_state_space_cells_decode_chunk_holds_one_copy_of_each_state_stack(
     assert 6.0e9 < sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
         args[0])) < 6.1e9
     assert rc.resident(compiled) / 1e9 == pytest.approx(7.07, rel=0.01)
+
+
+# The selecting attention's kernels (ops/sparse_attention.py) at the
+# deepseek-v3.2-exp share's widths: 64 index heads of 128, a chunk of 4096
+# queries against the smallest and the largest prompt bucket, a group of 16
+# heads of 192 / 128 under one mask, and a decode step's 16 queries against
+# the held stack of index keys.
+@pytest.mark.parametrize("keys", [4096, 32768])
+def test_sparse_prefill_kernels_compile_for_v5e(v5e, keys):
+    from kukeon_tpu.ops import sparse_attention as sa
+
+    d = v5e.devices[0]
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    Q = 4096
+    assert sa.kernels_run(Q, keys, 128)
+    select = jax.jit(lambda q, w, k, r: sa.select_rows(
+        q, w, k, r, topk=2048)).lower(
+        _on(d, (Q, 64, 128), bf), _on(d, (Q, 64), f32),
+        _on(d, (keys, 128), bf), _on(d, (), i32)).compile()
+    _assert_kernel(select)
+    assert "sparse_select_rows" in select.as_text()
+    # the scores never reach HBM: what is made is the int8 mask's size
+    assert select.memory_analysis().temp_size_in_bytes < Q * keys
+    attend = jax.jit(lambda q, k, v, m, r: sa.masked_attention(
+        q, k, v, m, r, scale=0.1)).lower(
+        _on(d, (16, Q, 192), bf), _on(d, (16, keys, 192), bf),
+        _on(d, (16, keys, 128), bf), _on(d, (keys // 512, Q, 512), jnp.int8),
+        _on(d, (), i32)).compile()
+    _assert_kernel(attend)
+    assert "sparse_masked_attention" in attend.as_text()
+
+
+def test_sparse_decode_index_scores_compile_for_v5e(v5e):
+    from kukeon_tpu.ops import sparse_attention as sa
+
+    d = v5e.devices[0]
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    compiled = jax.jit(sa.decode_index_scores).lower(
+        _on(d, (16, 64, 128), bf), _on(d, (16, 64), f32),
+        _on(d, (5, 16, 32768, 128), bf), _on(d, (), i32),
+        _on(d, (16,), i32)).compile()
+    _assert_kernel(compiled)
+    # the stack is an operand in place: nothing of a layer's size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("program, size, temp_gb", [
+    ("decode_chunk", 4, 0.1), ("prefill", 32768, 2.4)])
+def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
+        v5e, program, size, temp_gb):
+    """deepseek-v3.2-exp-ep16-bf16's decode chunk and its largest prefill,
+    built by the engine from shapes alone through the cell's launcher: 9.29 GB
+    of weights and 4.03 GB of cache (16 slots x 32768 rows x five layers of
+    640 + 128 values) stay resident, so a program's temporaries have to fit
+    what is left of the chip; the decode chunk runs the index kernel and makes
+    no value of a cache layer's size (the gather reads the selected rows out
+    of the held stack), the prefill runs the selection and the masked
+    attention and never a [S, S] array of scores."""
+    from benchmark import rehearse_compile as rc
+
+    mesh, eng, args = _abstract_cell(v5e, "deepseek-v3.2-exp-ep16-bf16")
+    repl = NamedSharding(mesh, PartitionSpec())
+    held, = args[1].cache.held
+    assert held["ckv"].shape == (5, 16, 32768, 640)
+    assert held["kidx"].shape == (5, 16, 32768, 128)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(args[0]))
+    cache = sum(x.size * x.dtype.itemsize for x in held.values())
+    assert 9.25e9 < weights < 9.30e9 and 4.0e9 < cache < 4.05e9
+    with jax.set_mesh(mesh):
+        if program == "decode_chunk":
+            compiled = eng._decode_chunk.lower(*args, size).compile()
+        else:
+            scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)  # noqa: E731
+            compiled = eng._prefill.lower(
+                args[0], jax.ShapeDtypeStruct((1, size), jnp.int32,
+                                              sharding=repl),
+                scalar(jnp.int32), args[2], scalar(jnp.float32),
+                scalar(jnp.int32), scalar(jnp.float32)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < temp_gb * 1e9
+    if program == "decode_chunk":
+        assert "sparse_decode_index_scores" in text
+        assert _cache_sized_values(text, held["kidx"].size // 5) == []
+        assert rc.resident(compiled) < V5E_HBM_BYTES
+    else:
+        assert "sparse_select_rows" in text
+        assert "sparse_masked_attention" in text
+        # beside the cache, which a prefill does not take as an argument
+        assert rc.resident(compiled) + cache < V5E_HBM_BYTES
