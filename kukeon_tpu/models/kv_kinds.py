@@ -9,8 +9,9 @@ models hold other things for a slot, and a family states them as data
 - **rows of named arrays** (``arrays``): a row that is no K and V over KV
   heads but arrays of their own widths with NO head axis, side by side for
   every layer (a latent attention's compressed row and its indexer's key),
-  ``[layers, B, rows, width]`` held and viewed alike; a decode step may read
-  only the ``select`` best of the live rows;
+  ``[layers, B, rows, width]`` held and viewed alike; a decode step may
+  attend only the ``select`` best of the live rows, which it finds by reading
+  them all;
 - **state**: named arrays WITHOUT a row axis, of a fixed size whatever the
   slot's length (a state-space layer's convolution tail and scan state). A
   prefill leaves the arrays of its last real token, ``insert`` copies them
@@ -51,7 +52,9 @@ class CacheKind:
     state: tuple = ()
     # What a row is made of where it is no K and V: (name, width) of arrays
     # [layers, B, rows, width], and how many of a slot's live rows a decode
-    # step reads whole at most (0: every one).
+    # step ATTENDS at most (0: every one). It fetches every live row whatever
+    # it attends (``read``): at rows / select = 16 a stream of whole blocks
+    # costs less than a gather by row.
     arrays: tuple = ()
     select: int = 0
 
@@ -71,15 +74,12 @@ class CacheKind:
             return ()
         return tuple(name for name, _w in self.arrays) or ("k", "v")
 
-    def read(self, length: int) -> float:
-        """Rows' worth of bytes a decode step reads of a slot of ``length``
-        tokens where the rows are named arrays: the first array of every
-        live row (what the selection scores) and the others of the selected
-        rows only."""
-        widths = [w for _name, w in self.arrays]
-        live = self.live(length)
-        chosen = min(live, self.select) if self.select else live
-        return (live * widths[0] + chosen * sum(widths[1:])) / sum(widths)
+    def read(self, length: int) -> int:
+        """Rows a decode step fetches of a slot of ``length`` tokens where
+        the rows are named arrays: every array of every live row (the
+        selection scores the first, the attention streams the others in
+        place under its threshold)."""
+        return self.live(length)
 
 
 @jax.tree_util.register_dataclass

@@ -15,9 +15,10 @@ What the other families have none of:
   head's ``k_nope`` and ``v`` (``Wkv_b``) and runs masked flash attention, the
   selection as a mask over causal scores; ``decode`` absorbs ``Wkv_b`` into the
   query and the output (``q_nope Wkv_b^K`` against ``c``, the mix of latents
-  through ``Wkv_b^V``) and attends over the GATHERED rows of the selection,
-  one shared latent head for all ``num_heads``. Both are the equations of
-  ``benchmark/reference/sparse_latent_moe.py``; neither drops a term;
+  through ``Wkv_b^V``) and attends the slot's live rows IN PLACE under the
+  selection's threshold, one shared latent head for all ``num_heads``. Both
+  are the equations of ``benchmark/reference/sparse_latent_moe.py``; neither
+  drops a term;
 - **YaRN** frequencies for both rotations and its factor on the softmax scale
   (``ops/rope.py``); the attention's rotated pairs are neighbours
   (interleaved), the indexer's are split halves, as the published code has it;
@@ -136,7 +137,8 @@ class SparseLatentMoEConfig:
     def latent_width(self) -> int:
         """The cached row [c | kr | 0 ...]: whole lanes. The chip's tiling
         holds 576 values a row as 640 whatever the program says; said here,
-        the row is what the gather reads and nothing relays it out."""
+        the row is what the decode step's copies read and nothing relays it
+        out."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim)
                  // sa.LANES) * sa.LANES
 
@@ -163,7 +165,7 @@ class SparseLatentMoEConfig:
     def cache_kinds(self, max_seq_len: int) -> tuple[kv_kinds.CacheKind, ...]:
         """Every layer holds, for every position, the latent row and the
         indexer's key; a decode step scores the keys of the live rows and
-        reads ``index_topk`` latent rows."""
+        attends the ``index_topk`` best latent rows among them."""
         return (kv_kinds.CacheKind(
             "latent", tuple(range(self.num_layers)), max_seq_len,
             arrays=(("kidx", self.index_head_dim),
@@ -608,7 +610,6 @@ def decode(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
                 "bh,bh->b", wts, jnp.maximum(jnp.einsum(
                     "bhd,bd->bh", qi, ki,
                     preferred_element_type=jnp.float32), 0.0))
-        idx, chosen = sa.select_decode(scores, own, c.index_topk)
         q = _queries(cq, w["wq_b"], c, positions, NH)[:, 0]     # [B, NH, Dk]
         # the absorbed query: each head's q_nope through its Wkv_b^K, then
         # the rotated part, against a latent row [c | kr | 0]
@@ -616,13 +617,13 @@ def decode(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
             [jnp.einsum("bhd,hdr->bhr", q[..., :Dn], w["wkv_bk"]),
              q[..., Dn:],
              jnp.zeros((B, NH, W - R - c.qk_rope_head_dim), q.dtype)], axis=-1)
-        mix = sa.gathered_attention(
-            q, row[:, 0], held["ckv"], number, idx, chosen,
-            scale=c.softmax_scale, value_dim=R)
+        mix, kept = sa.decode_attention(
+            q, row[:, 0], own, scores, held["ckv"], number, reads,
+            topk=c.index_topk, scale=c.softmax_scale, value_dim=R)
         o = jnp.einsum("bhr,hrd->bhd", mix, w["wkv_bv"])
         x = x + mm(o.reshape(B, 1, NH * Dv), w["wo"])
         x, hits = _mlp(x[:, 0], w, c, active)
-        picked = jnp.sum(chosen & active[:, None], dtype=jnp.int32)
+        picked = jnp.sum(jnp.where(active, kept, 0), dtype=jnp.int32)
         return x[:, None], row, ki[:, None], jnp.stack([hits, picked])
 
     x, rows, keys, sums = _through_layers(

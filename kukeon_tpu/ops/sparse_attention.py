@@ -21,13 +21,18 @@ XLA body is also the kernel's reference in the tests.
 - ``decode_index_scores``: one query a slot against the slot's LIVE index
   keys, read in place from the held stack; tiles past a slot's length move no
   byte.
-- ``gathered_attention`` (decode): the absorbed form over ONE shared latent
-  head: the selected rows are gathered from the held stack (the step's own row
-  among them without having been written first) and attended; rows that were
-  not selected are never read.
+- ``decode_attention``: the ``topk``-th largest of a slot's live index scores
+  and the step's own, found by the same descent, then the absorbed form over
+  ONE shared latent head: the slot's live rows are streamed in place from the
+  held stack, a block at a time, and attended under ``score >= topk-th`` (the
+  step's own row among them without having been written first). No sort, no
+  index list, no gathered copy; a block past a slot's last live row moves no
+  byte, and a slot of length 0 is not walked.
 
-A selection is a discontinuity: two positions whose index scores lie within
-rounding of each other at the ``topk`` boundary may swap.
+One selection rule for both halves: everything at or above the ``topk``-th
+score, never a row at or past the length; rows that tie with the ``topk``-th
+are all kept. A selection is a discontinuity: two positions whose index scores
+lie within rounding of each other at the ``topk`` boundary may swap.
 """
 
 from __future__ import annotations
@@ -46,7 +51,9 @@ LANES = 128
 KEY_TILE = 512          # keys of one tile of the prefill kernels and the mask
 SELECT_ROWS = 128       # queries a step of the selection kernel
 FLASH_ROWS = 512        # queries a step of the attention kernel
-DECODE_TILE = 2048      # index keys a step of the decode kernel
+DECODE_TILE = 2048      # cache rows a step of either decode kernel
+DECODE_DEPTH = 3        # blocks the decode attention's walk keeps in flight
+SUBLANES = 8            # a slot's scores lie [SUBLANES, rows / SUBLANES] in VMEM
 INT_MIN = -2 ** 31
 
 
@@ -73,6 +80,22 @@ def _order(x):
     """float32 -> int32 whose signed order is the floats' order."""
     b = jax.lax.bitcast_convert_type(x, jnp.int32)
     return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _kth_largest(count, topk: int, shape):
+    """The order of the ``topk``-th largest entry, bit by bit from the sign
+    down: the largest ``at`` that at least ``topk`` entries reach.
+    ``count(at)`` says how many entries are >= ``at`` (``shape`` int32 both).
+    Fewer entries than ``topk`` end at or under the order of -inf: everything
+    there is is selected."""
+    at = jnp.where(count(jnp.zeros(shape, jnp.int32)) >= topk,
+                   jnp.int32(0), jnp.int32(INT_MIN))
+
+    def descend(i, at):
+        cand = at | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(cand) >= topk, cand, at)
+
+    return jax.lax.fori_loop(0, 31, descend, at)
 
 
 # --- select_rows ---------------------------------------------------------------
@@ -111,18 +134,7 @@ def _select_kernel(row0_ref, q_ref, w_ref, k_ref, mask_ref, sc_ref, *,
                               jnp.zeros((bq, min(bk, LANES)), jnp.int32))
         return jnp.sum(c, axis=1, keepdims=True)
 
-    # The topk-th largest entry of each row, bit by bit from the sign down:
-    # the largest ``at`` that at least topk entries reach. A row with fewer
-    # entries than topk ends at or under the order of -inf: everything it
-    # may see is selected.
-    at = jnp.where(count(jnp.zeros((bq, 1), jnp.int32)) >= topk,
-                   jnp.int32(0), jnp.int32(INT_MIN))
-
-    def descend(i, at):
-        cand = at | jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(count(cand) >= topk, cand, at)
-
-    at = jax.lax.fori_loop(0, 31, descend, at)
+    at = _kth_largest(count, topk, (bq, 1))
 
     def write(t, _):
         keep = (sc_ref[t] >= at) & (t * bk + cols <= rows)
@@ -380,35 +392,194 @@ def decode_index_scores(q, w, keys, layer, lengths, *,
     return out[:, 0]
 
 
-@jax.named_scope("sparse_select")
-def select_decode(scores, own, topk: int):
-    """The rows a decode step attends to: the ``topk`` best of a slot's cache
-    rows and the step's own position together. scores [B, rows] (-inf where
-    no row lives), own [B] the step's score of itself. Returns (idx [B, k]
-    int32 into the cache rows, ``rows`` standing for the step's own row;
-    chosen [B, k] bool, False where fewer than k positions exist)."""
+def _decode_attend_kernel(layer_ref, len_ref, own_ref, q_ref, new_ref, sc_ref,
+                          lat_hbm, o_ref, kept_ref, buf, sem, m_scr, l_scr,
+                          acc_scr, *, topk, scale):
+    slots = q_ref.shape[0]
+    R = o_ref.shape[2]
+    depth, bk, _ = buf.shape
+    sub, width = sc_ref.shape[1:]
+    per = width // bk                   # blocks a sublane of the scores
+    layer = layer_ref[0]
+    f32 = jnp.float32
+
+    # A slot that is not walked attends to its own row alone.
+    o_ref[...] = jnp.broadcast_to(new_ref[:, :, :R], o_ref.shape)
+    kept_ref[...] = jnp.ones_like(kept_ref)
+
+    def total(flags):                   # [sub, width] bool -> [1, 1] int32
+        return jnp.sum(jnp.sum(flags.astype(jnp.int32), axis=1, keepdims=True),
+                       axis=0, keepdims=True)
+
+    def walk(b, n):
+        blocks = (n + bk - 1) // bk
+
+        def copy(j):
+            return pltpu.make_async_copy(
+                lat_hbm.at[layer, b, pl.ds(pl.multiple_of(j * bk, bk), bk), :],
+                buf.at[j % depth], sem.at[j % depth])
+
+        def start(j):
+            @pl.when(j < blocks)
+            def _start():
+                copy(j).start()
+
+        for j in range(depth - 1):      # in flight under the descent
+            start(j)
+        own = own_ref[b]
+        kth = _kth_largest(
+            lambda at: total(sc_ref[b] >= at) + (own >= at).astype(jnp.int32),
+            topk, (1, 1))
+        position = (
+            jax.lax.broadcasted_iota(jnp.int32, (sub, width), 0) * width
+            + jax.lax.broadcasted_iota(jnp.int32, (sub, width), 1))
+        own_kept = own >= kth                                   # [1, 1]
+        kept = (total((sc_ref[b] >= kth) & (position < n))
+                + own_kept.astype(jnp.int32))
+        kept_ref[b] = jnp.broadcast_to(kept, kept_ref.shape[1:])
+
+        # The step's own row starts the running softmax where it is kept.
+        q = q_ref[b]                                            # [NH, W]
+        new = new_ref[b].astype(f32)                            # [1, W]
+        s_own = jnp.sum(q.astype(f32) * new, axis=1, keepdims=True) * scale
+        m_scr[...] = jnp.broadcast_to(
+            jnp.where(own_kept, s_own, NEG_INF), m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(
+            jnp.where(own_kept, 1.0, 0.0).astype(f32), l_scr.shape)
+        acc_scr[...] = jnp.broadcast_to(
+            jnp.where(own_kept, new[:, :R], 0.0), acc_scr.shape)
+
+        def block(j, _):
+            start(j + depth - 1)
+            copy(j).wait()
+            rows = buf[j % depth]                               # [bk, W]
+            order = sc_ref[b, pl.ds(j // per, 1),
+                           pl.ds(pl.multiple_of((j % per) * bk, bk), bk)]
+            row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            keep = (order >= kth) & (row < n)                   # [1, bk]
+            s = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale             # [NH, bk]
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[:, :1]
+            l_prev = l_scr[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # a slot whose own row fell out holds NEG_INF until its first
+            # kept row: the weights of 1 it adds till then are wiped by that
+            # row's ``corr`` of 0; every walked slot keeps at least one row
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :R], (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+            return 0
+
+        jax.lax.fori_loop(0, blocks, block, 0)
+        o_ref[b] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+    def slot(b, _):
+        n = len_ref[b]
+
+        @pl.when(n > 0)
+        def _live():
+            walk(b, n)
+
+        return 0
+
+    jax.lax.fori_loop(0, slots, slot, 0)
+
+
+def _decode_attend_xla(q, new, own, scores, latents, layer, lengths, topk,
+                       scale, value_dim):
     rows = scores.shape[1]
-    best, idx = jax.lax.top_k(
-        jnp.concatenate([scores, own[:, None]], axis=1), min(topk, rows + 1))
-    return idx.astype(jnp.int32), best > -jnp.inf
+    rows_of = jax.lax.dynamic_index_in_dim(latents, layer, keepdims=False)
+    live = jnp.arange(rows)[None] < lengths[:, None]
+    both = jnp.concatenate([jnp.where(live, scores, -jnp.inf), own[:, None]],
+                           axis=1)
+    kth = jax.lax.top_k(both, min(topk, rows + 1))[0][:, -1:]
+    keep = (both >= kth) & jnp.pad(live, ((0, 0), (0, 1)),
+                                   constant_values=True)        # [B, rows + 1]
+    s = jnp.concatenate(
+        [jnp.einsum("bhw,bkw->bhk", q, rows_of,
+                    preferred_element_type=jnp.float32),
+         jnp.einsum("bhw,bw->bh", q, new,
+                    preferred_element_type=jnp.float32)[..., None]],
+        axis=-1) * scale
+    p = jax.nn.softmax(jnp.where(keep[:, None, :], s, NEG_INF), axis=-1)
+    p = p.astype(rows_of.dtype)
+    mix = (jnp.einsum("bhk,bkv->bhv", p[..., :rows], rows_of[..., :value_dim],
+                      preferred_element_type=jnp.float32)
+           + p[..., rows:].astype(jnp.float32)
+           * new[:, None, :value_dim].astype(jnp.float32))
+    return mix.astype(q.dtype), jnp.sum(keep, axis=1, dtype=jnp.int32)
 
 
 @jax.named_scope("latent_attention")
-def gathered_attention(q, new, latents, layer, idx, chosen, *, scale: float,
-                       value_dim: int):
-    """The absorbed form over the selected rows. q [B, NH, W] (each head's
-    query against a latent row: ``W`` wide, the ``value_dim`` latent values
-    first, the rotated part after), new [B, W] the step's own row,
-    ``latents`` the held stack [layers, B, rows, W] read at ``layer``, idx /
-    chosen as ``select_decode`` gives them. Returns float32-accumulated
-    [B, NH, value_dim] in q's dtype: the heads' mixes of latent values, which
-    the caller takes through the value half of the up-projection."""
-    B, rows = latents.shape[1], latents.shape[2]
-    own = idx >= rows
-    got = latents[layer, jnp.arange(B)[:, None], jnp.minimum(idx, rows - 1)]
-    got = jnp.where(own[..., None], new[:, None, :], got)       # [B, k, W]
-    s = jnp.einsum("bhw,bkw->bhk", q, got,
-                   preferred_element_type=jnp.float32) * scale
-    p = jax.nn.softmax(jnp.where(chosen[:, None, :], s, NEG_INF), axis=-1)
-    return jnp.einsum("bhk,bkv->bhv", p.astype(got.dtype),
-                      got[..., :value_dim]).astype(q.dtype)
+@functools.partial(jax.jit, static_argnames=("topk", "scale", "value_dim",
+                                             "block", "interpret"))
+def decode_attention(q, new, own, scores, latents, layer, lengths, *,
+                     topk: int, scale: float, value_dim: int,
+                     block: int | None = None, interpret: bool | None = None):
+    """One decode step's selection and its attention, the absorbed form over
+    the rows the selection keeps. q [B, NH, W] (each head's query against a
+    latent row: ``W`` wide, the ``value_dim`` latent values first, the rotated
+    part after), new [B, W] the step's own row and own [B] its index score,
+    scores [B, rows] the index scores of the cache rows, ``latents`` the held
+    stack [layers, B, rows, W] read at ``layer`` (traced; the stack is the
+    operand so that a layer is read in place), lengths [B] the rows that live
+    (0: the slot is not walked and attends to its own row alone).
+
+    A slot keeps what reaches the ``topk``-th largest of its live scores and
+    ``own`` together, ties with it included, and never a row at or past its
+    length. Returns ([B, NH, value_dim] in q's dtype, float32-accumulated: the
+    heads' mixes of latent values, which the caller takes through the value
+    half of the up-projection; int32 [B] the rows each slot kept, its own
+    among them)."""
+    B, NH, W = q.shape
+    rows = latents.shape[2]
+    lengths = lengths.astype(jnp.int32)
+    width = rows // SUBLANES
+    bk = block or min(DECODE_TILE, width)
+    if interpret is None:
+        if not (decode_kernel_runs(rows, W) and value_dim % LANES == 0
+                and width % bk == 0):
+            dispatch.note("decode_attention", "xla")
+            return _decode_attend_xla(q, new, own, scores, latents, layer,
+                                      lengths, topk, scale, value_dim)
+        interpret = False
+    dispatch.note("decode_attention", "pallas")
+    # the scores as the descent wants them: in the floats' order, -inf where
+    # no row lives, a slot's over whole vector registers
+    live = jnp.arange(rows)[None] < lengths[:, None]
+    order = _order(jnp.where(live, scores, -jnp.inf)).reshape(
+        B, SUBLANES, width)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    item = latents.dtype.itemsize
+    held = (B * NH * (W + value_dim) * item + B * rows * 4
+            + DECODE_DEPTH * bk * W * item
+            + NH * (2 * LANES + value_dim + 3 * bk) * 4)
+    out, kept = pl.pallas_call(
+        functools.partial(_decode_attend_kernel, topk=topk, scale=scale),
+        out_shape=(jax.ShapeDtypeStruct((B, NH, value_dim), q.dtype),
+                   jax.ShapeDtypeStruct((B, 1, LANES), jnp.int32)),
+        in_specs=[smem] * 3 + [vmem] * 3
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(vmem, vmem),
+        scratch_shapes=[
+            pltpu.VMEM((DECODE_DEPTH, bk, W), latents.dtype),
+            pltpu.SemaphoreType.DMA((DECODE_DEPTH,)),
+            pltpu.VMEM((NH, LANES), jnp.float32),           # running max
+            pltpu.VMEM((NH, LANES), jnp.float32),           # running sum
+            pltpu.VMEM((NH, value_dim), jnp.float32),       # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(held * 1.5) + (8 << 20)),
+        name="sparse_decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths, _order(own),
+      q, new[:, None, :], order, latents)
+    return out, kept[:, 0, 0]

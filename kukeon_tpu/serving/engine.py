@@ -2294,8 +2294,7 @@ class ServingEngine:
             all_rows = len(kd.layers) * self.num_slots * kd.rows
             held += all_rows
             if kd.arrays:   # rows of named arrays: what the kind says it reads
-                read += len(kd.layers) * round(sum(
-                    kd.read(n) for n in lengths))
+                read += len(kd.layers) * sum(kd.read(n) for n in lengths)
             else:
                 read += all_rows if block is None else len(kd.layers) * sum(
                     -(-kd.live(n) // block) * block for n in lengths)

@@ -427,7 +427,8 @@ def test_the_state_space_cells_decode_chunk_holds_one_copy_of_each_state_stack(
 # deepseek-v3.2-exp share's widths: 64 index heads of 128, a chunk of 4096
 # queries against the smallest and the largest prompt bucket, a group of 16
 # heads of 192 / 128 under one mask, and a decode step's 16 queries against
-# the held stack of index keys.
+# the held stack of index keys, then its 128 absorbed heads against the held
+# stack of latent rows.
 @pytest.mark.parametrize("keys", [4096, 32768])
 def test_sparse_prefill_kernels_compile_for_v5e(v5e, keys):
     from kukeon_tpu.ops import sparse_attention as sa
@@ -467,18 +468,44 @@ def test_sparse_decode_index_scores_compile_for_v5e(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
+def test_sparse_decode_attention_compiles_for_v5e(v5e):
+    from kukeon_tpu.ops import sparse_attention as sa
+
+    d = v5e.devices[0]
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    from kukeon_tpu.ops import dispatch
+
+    assert sa.decode_kernel_runs(32768, 640)
+    before = dispatch.counts().get(("decode_attention", "pallas"), 0)
+    compiled = jax.jit(lambda *a: sa.decode_attention(
+        *a, topk=2048, scale=0.1, value_dim=512)).lower(
+        _on(d, (16, 128, 640), bf), _on(d, (16, 640), bf), _on(d, (16,), f32),
+        _on(d, (16, 32768), f32), _on(d, (5, 16, 32768, 640), bf),
+        _on(d, (), i32), _on(d, (16,), i32)).compile()
+    _assert_kernel(compiled)
+    assert dispatch.counts()[("decode_attention", "pallas")] > before
+    text = compiled.as_text()
+    assert "sparse_decode_attention" in text and " sort(" not in text
+    # the stack is an operand in place; what is made beside it is the scores
+    # in the floats' order (2 MB), never a gathered copy of the selection
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 @pytest.mark.parametrize("program, size, temp_gb", [
-    ("decode_chunk", 4, 0.1), ("prefill", 32768, 2.4)])
+    ("decode_chunk", 1, 0.1), ("decode_chunk", 4, 0.1),
+    ("decode_chunk", 16, 0.1), ("prefill", 32768, 2.4)])
 def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
         v5e, program, size, temp_gb):
     """deepseek-v3.2-exp-ep16-bf16's decode chunk and its largest prefill,
     built by the engine from shapes alone through the cell's launcher: 9.29 GB
     of weights and 4.03 GB of cache (16 slots x 32768 rows x five layers of
     640 + 128 values) stay resident, so a program's temporaries have to fit
-    what is left of the chip; the decode chunk runs the index kernel and makes
-    no value of a cache layer's size (the gather reads the selected rows out
-    of the held stack), the prefill runs the selection and the masked
-    attention and never a [S, S] array of scores."""
+    what is left of the chip; a decode chunk of each length the cell warms
+    runs the index kernel and the selecting attention's, sorts no scores,
+    gathers no copy of the selection (16 slots x 2048 rows x 640) and makes no
+    value of a cache layer's size (both kernels read the held stacks in
+    place), the prefill runs the selection and the masked attention and never
+    a [S, S] array of scores."""
     from benchmark import rehearse_compile as rc
 
     mesh, eng, args = _abstract_cell(v5e, "deepseek-v3.2-exp-ep16-bf16")
@@ -504,6 +531,11 @@ def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
     assert m.temp_size_in_bytes < temp_gb * 1e9
     if program == "decode_chunk":
         assert "sparse_decode_index_scores" in text
+        assert "sparse_decode_attention" in text
+        # the only sort left is the sampler's, over the vocabulary
+        assert not re.search(r"\[16,3276[89]\]\S* sort\(", text)
+        assert "[16,32769]" not in text
+        assert not re.search(r"bf16\[(16,2048|32768),640\]", text)
         assert _cache_sized_values(text, held["kidx"].size // 5) == []
         assert rc.resident(compiled) < V5E_HBM_BYTES
     else:

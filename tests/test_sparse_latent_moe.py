@@ -207,9 +207,8 @@ def test_a_row_of_named_arrays_is_inserted_appended_and_read_by_its_widths():
     assert kd.unit == "rows" and kd.row_names() == ("kidx", "ckv")
     assert kv_kinds.names(kinds) == ("ckv", "kidx")
     assert (kd.live(3), kd.live(40)) == (3, 16)
-    # every live key, the latent of the 5 selected: in rows of 4 + 12 values
-    assert kd.read(3) == pytest.approx(3.0)
-    assert kd.read(10) == pytest.approx((10 * 4 + 5 * 12) / 16)
+    # every array of every live row is fetched; 5 of them are attended
+    assert (kd.read(3), kd.read(10), kd.read(40)) == (3, 10, 16)
     shapes = kv_kinds.shapes(kinds, 3, 2, 8, jnp.float32)
     assert {k: v.shape for k, v in shapes.held[0].items()} == {
         "kidx": (2, 3, 16, 4), "ckv": (2, 3, 16, 12)}
@@ -326,11 +325,32 @@ def test_the_masked_prefill_is_attention_over_a_gather_of_the_selected_rows():
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+def _attend_loop(q, new, own, scores, latents, lengths, topk, scale, R):
+    """``sa.decode_attention`` a slot at a time, in numpy: the live scores and
+    the step's own sorted, everything at or above the topk-th kept, a softmax
+    over exactly those rows' products."""
+    out = np.zeros((*q.shape[:2], R), np.float32)
+    kept = []
+    for b in range(q.shape[0]):
+        n = int(lengths[b])
+        both = np.append(np.asarray(scores[b, :n], np.float32), float(own[b]))
+        kth = np.sort(both)[::-1][min(topk, n + 1) - 1]
+        named = np.nonzero(both >= kth)[0]
+        rows = np.stack([np.asarray(new[b] if r == n else latents[b, r],
+                                    np.float32) for r in named])
+        s = np.asarray(q[b], np.float32) @ rows.T * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b] = p / p.sum(-1, keepdims=True) @ rows[:, :R]
+        kept.append(len(named))
+    return out, kept
+
+
 def test_the_decode_indexer_scores_the_live_rows_and_the_step_joins_them():
     """``decode_index_scores`` reads a layer of the held stack up to each
-    slot's length (XLA body and kernel alike); ``select_decode`` ranks the
-    step's own position with them; ``gathered_attention`` attends exactly
-    those rows, the step's own among them without having been written."""
+    slot's length (XLA body and kernel alike); ``decode_attention`` ranks the
+    step's own position with them and attends exactly the rows at or above
+    the topk-th score, the step's own among them without having been
+    written."""
     B, Hh, D, rows, W, R = 3, 4, 16, 64, 32, 24
     ks = jax.random.split(jax.random.key(6), 6)
     q = jax.random.normal(ks[0], (B, Hh, D))
@@ -347,25 +367,63 @@ def test_the_decode_indexer_scores_the_live_rows_and_the_step_joins_them():
                                      interpret=interpret)
         np.testing.assert_allclose(got, want, atol=1e-5)
     own = jnp.array([0.5, -1.0, 1e9])       # slot 2's own row is its best
-    idx, chosen = sa.select_decode(jnp.asarray(want), own, 8)
-    assert chosen.sum(-1).tolist() == [1, 6, 8]
-    assert idx[0, 0] == rows and idx[2, 0] == rows
-    assert set(np.asarray(idx[1])[np.asarray(chosen[1])]) == {0, 1, 2, 3, 4,
-                                                              rows}
-    # the absorbed attention over the gathered rows, against the rows named
     latents = jax.random.normal(ks[3], (2, B, rows, W))
     new = jax.random.normal(ks[4], (B, W))
     qa = jax.random.normal(ks[5], (B, 5, W))
-    got = sa.gathered_attention(qa, new, latents, jnp.int32(1), idx, chosen,
-                                scale=0.3, value_dim=R)
-    for b in range(B):
-        named = np.asarray(idx[b])[np.asarray(chosen[b])]
-        got_rows = np.stack([np.asarray(new[b]) if r == rows
-                             else np.asarray(latents[1, b, r]) for r in named])
-        s = np.asarray(qa[b]) @ got_rows.T * 0.3
-        p = np.exp(s - s.max(-1, keepdims=True))
-        np.testing.assert_allclose(
-            got[b], p / p.sum(-1, keepdims=True) @ got_rows[:, :R], atol=2e-5)
+    mix, kept = _attend_loop(qa, new, own, want, latents[1], lengths, 8, 0.3,
+                             R)
+    assert kept == [1, 6, 8]
+    for interpret in (None, True):
+        got, n = sa.decode_attention(
+            qa, new, own, jnp.asarray(want), latents, jnp.int32(1), lengths,
+            topk=8, scale=0.3, value_dim=R, interpret=interpret)
+        assert n.tolist() == kept
+        np.testing.assert_allclose(got, mix, atol=2e-5)
+    # a slot that holds nothing attends to its own row alone
+    np.testing.assert_allclose(got[0], np.broadcast_to(new[0, :R], (5, R)),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("case, length, own, kept", [
+    ("not active", 0, 0.0, 1),
+    ("fewer live rows than topk", 5, 0.0, 6),
+    ("own row makes topk", 7, 0.0, 8),
+    ("own row the best", 50, 99.0, 8),
+    ("own row the worst", 50, -99.0, 8),
+    ("a length inside a block", 21, 0.25, 8),
+    ("a tie at the boundary", 40, 0.0, 10),
+    ("every row lives", 64, 0.0, 8),
+])
+def test_the_selecting_decode_is_the_loop_written_out(case, length, own, kept):
+    """``decode_attention``: the kernel (interpret mode, blocks of 8 rows so
+    that a slot's rows span several and end inside one) against its XLA body
+    against ``_attend_loop``. One rule: everything at or above the topk-th of
+    the live scores and the step's own, never a row at or past the length;
+    rows that tie with the topk-th are all kept."""
+    B, NH, rows, W, R, topk = 2, 5, 64, 32, 24, 8
+    ks = jax.random.split(jax.random.key(length), 5)
+    q = jax.random.normal(ks[0], (B, NH, W))
+    new = jax.random.normal(ks[1], (B, W))
+    latents = jax.random.normal(ks[2], (3, B, rows, W))
+    scores = np.array(jax.random.normal(ks[3], (B, rows)))
+    if case == "a tie at the boundary":
+        # three live rows share the 8th place of slot 1; so does a row past
+        # the length, which no rule may keep
+        order = np.argsort(scores[1, :length])[::-1]
+        scores[1, order[7:10]] = scores[1, order[7]]
+        scores[1, length + 3] = scores[1, order[7]]
+    # slot 0 stands beside the case: a live slot with a score of its own
+    lengths = jnp.array([33, length])
+    owns = jnp.array([0.1, own], jnp.float32)
+    mix, n = _attend_loop(q, new, owns, scores, latents[2], lengths, topk, 0.2,
+                          R)
+    assert n == [8, kept]
+    for interpret in (None, True):
+        got, count = sa.decode_attention(
+            q, new, owns, jnp.asarray(scores), latents, jnp.int32(2), lengths,
+            topk=topk, scale=0.2, value_dim=R, interpret=interpret)
+        assert count.tolist() == n
+        np.testing.assert_allclose(got, mix, atol=2e-5)
 
 
 def test_yarn_keeps_fast_pairs_and_stretches_slow_ones():
