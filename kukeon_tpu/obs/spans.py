@@ -17,10 +17,10 @@ do a step's work; what it spends outside them is phase ``other``.
 | ``cell.generate`` | request | - (above the engine) |
 | ``engine.step`` | | ``other`` = its time less its children |
 | ``engine.admit`` | free, queued | ``admit`` |
-| ``engine.prefill_dispatch`` | request, slot, program, hit, cached, real, padded[, <kind>_rows, <kind>_slots] | - (inside ``admit``) |
+| ``engine.prefill_dispatch`` | request, slot, decoding, program, hit, cached, real, padded[, <kind>_rows, <kind>_slots] | - (inside ``admit``) |
 | ``engine.decode_dispatch`` | k, active, live_rows[, <kind>_rows, <kind>_slots] | ``decode_dispatch`` |
 | ``engine.fetch_first`` | n | ``fetch_first`` |
-| ``engine.fetch_chunk`` | k | ``fetch_chunk`` |
+| ``engine.fetch_chunk`` | k, active | ``fetch_chunk`` |
 | ``engine.emit`` | tokens | ``emit`` |
 | ``engine.first_token`` | request | - (inside ``emit``) |
 | ``engine.idle_wait`` | | ``idle_wait`` |
@@ -30,6 +30,12 @@ whose layers hold several kinds of state (``models/kv_kinds.py``): the rows the
 dispatched slots hold in one layer of each kind. A kind that holds state
 without rows (a state-space layer's) gives ``<kind>_slots`` (``state_slots``):
 the slots whose state the dispatch touches.
+
+``decoding`` is the slots that were seated, each with a first token, when
+the step that dispatched the prefill began to admit: their next chunk runs
+behind it on the device. ``active`` is the slots the fetched chunk stepped.
+With the device's module events they say how much of the slot-time spent
+decoding went to other requests' prompts.
 
 ``request`` is the request's trace id (``req.trace.trace_id``), the identifier
 ``/v1/trace`` and ``/v1/timeline`` already use.

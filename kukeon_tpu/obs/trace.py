@@ -6,11 +6,19 @@ A request's life inside one engine is a chain of monotonic timestamps::
     submitted -> admitted -> prefill_dispatched -> first_token -> finished
 
 and the exported span derives phase durations from CONSECUTIVE event
-pairs, so the phases partition the request's wall time exactly:
-``queued + prefill + decode == e2e`` (the acceptance tolerance exists only
-for float rounding). Requests that die early (shed at submit, deadline
-expiry while queued, cancel) simply stop the chain where they stopped —
-their later phases read 0 and the recorded outcome names why.
+pairs (:meth:`Span.phases_s`), so the phases partition the request's wall
+time exactly: ``queued + prefill_dispatch + prefill_wait + decode == e2e``
+(the acceptance tolerance exists only for float rounding). Requests that
+die early (shed at submit, deadline expiry while queued, cancel) simply
+stop the chain where they stopped — their later phases read 0 and the
+recorded outcome names why.
+
+The ring holds the newest 512 spans. Over any window of ``/metrics`` the
+same waits are the ``_sum`` / ``_count`` of the histograms the engine
+observes beside the chain's events: ``kukeon_engine_queue_wait_seconds``
+(``admitted``), ``kukeon_engine_ttft_seconds`` (``first_token``),
+``kukeon_engine_inter_token_seconds`` (each token of ``decode``) and
+``kukeon_engine_e2e_seconds`` (``finished``).
 
 **Distributed context.** Request identity used to be an engine-local
 integer, so a request flowing gateway -> replica -> engine (retried onto a
@@ -199,15 +207,23 @@ class Span:
     def e2e_s(self) -> float:
         return self.events[-1][1] - self.events[0][1]
 
-    def to_dict(self) -> dict:
-        first = self.events[0][1]
-        last = self.events[-1][1]
+    def phases_s(self) -> dict[str, float]:
+        """Seconds by phase, from consecutive events: what ``phasesS``
+        rounds. An event outside ``_PHASE_OF`` (``preempted``,
+        ``kv_exported``) names its own gap; a phase entered twice (a
+        preempted request queues again) sums."""
         phases: dict[str, float] = {}
         alias = self.component == "engine"
         for ev, nxt in zip(self.events, self.events[1:]):
             name = ev[0]
             phase = _PHASE_OF.get(name, name) if alias else name
             phases[phase] = phases.get(phase, 0.0) + (nxt[1] - ev[1])
+        return phases
+
+    def to_dict(self) -> dict:
+        first = self.events[0][1]
+        last = self.events[-1][1]
+        phases = self.phases_s()
         out_events = []
         for ev in self.events:
             d = {"event": ev[0], "atS": round(ev[1] - first, 6)}

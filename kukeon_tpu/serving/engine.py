@@ -557,6 +557,9 @@ class ServingEngine:
         self._slot_req: list[Request | None] = [None] * num_slots
         self._slot_len: list[int] = [0] * num_slots    # host-side cache lengths
         self._inflight: _InflightChunk | None = None
+        # Slots that were seated when this step's admit began (each holds a
+        # first token): the `decoding` of its engine.prefill_dispatch spans.
+        self._decoding = 0
         # Device-resident sampling arrays, re-uploaded only when the slot
         # composition changes (each host->device upload costs a link RT).
         # The dirty flag is set exactly where composition changes (slot
@@ -2019,6 +2022,7 @@ class ServingEngine:
         prefills = []
         exports = []
         free = list(self._free_slots())
+        self._decoding = self.num_slots - len(free)
         with self.spans.span("engine.admit", free=len(free),
                              queued=self._pending_n + len(self._resume)):
             while free:
@@ -2304,7 +2308,8 @@ class ServingEngine:
         """The span of one request's prefill dispatch (slot -1: an export,
         which takes none); ``_prefill_dispatched`` gives it its counts."""
         return self.spans.span("engine.prefill_dispatch", slot=slot,
-                               request=_request_tag(req))
+                               request=_request_tag(req),
+                               decoding=self._decoding)
 
     def _prefill_dispatched(self, req: Request, span, cached: int,
                             real: int, padded: int) -> None:
@@ -2849,7 +2854,8 @@ class ServingEngine:
     def _flush_inflight(self):
         """Fetch + emit the previously dispatched chunk's token block."""
         chunk = self._inflight
-        with self.spans.span("engine.fetch_chunk", k=chunk.k):
+        with self.spans.span("engine.fetch_chunk", k=chunk.k,
+                             active=len(chunk.slots)):
             toks = self._fetch(chunk.tokens)  # [B, K] — single transfer per chunk
         if self._counted:   # rows past the slots: a counter each, by step
             self._count_device_sums(toks[self.num_slots:].sum(axis=1))
