@@ -205,7 +205,10 @@ def keep(active: jnp.ndarray, new: jnp.ndarray, old: jnp.ndarray,
     """``new`` where a slot is ``active`` [B], else ``old``; the slots on
     ``axis``. A model applies it to a LAYER's state before writing it back
     into the stack, so that the select rides in the update's own pass and no
-    second array of the stack's size is made."""
+    second array of the stack's size is made. For a state too large to pass
+    whole every step (``ssm_hybrid``'s scan state) the model leaves an idle
+    slot's untouched instead (``ops/selective_scan.py`` ``update_held``) and
+    keeps this for the small one (its convolution's tail)."""
     shape = [1] * new.ndim
     shape[axis] = active.shape[0]
     return jnp.where(active.reshape(shape), new, old)
@@ -219,7 +222,8 @@ def append(cache: LayeredKV, kinds, new: dict, active) -> LayeredKV:
     (``llama.cache_insert`` says why a loop). A state array comes back from
     the step WHOLE, in the held shape, and takes the place of the one the
     step read; where a slot is not ``active`` it already holds what it held
-    (``keep``). Lengths advance where ``active``."""
+    (the step selected it back with ``keep``, or never touched it). Lengths
+    advance where ``active``."""
     B = cache.lengths.shape[0]
 
     def of(kd, held):

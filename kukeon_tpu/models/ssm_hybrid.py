@@ -30,7 +30,10 @@ Two forwards for the serving engine (``models/families.py`` ``Layered``):
 attention layers' K / V block and every mixer's state AT ``length`` (the
 convolution's tail is read at ``length``, the scan stands still past it);
 ``decode`` runs one token a slot against the held cache and returns the new
-rows and the state stacks with each active slot's state replaced in place.
+rows and the state stacks with each active slot's state replaced in place
+(the scan state by ``ops/selective_scan.py``'s ``update_held``: on a TPU a
+kernel that walks the ACTIVE slots of the held stack, so an idle slot costs a
+step nothing).
 
 Not here, and refused at boot rather than served wrongly
 (``models/families.py``): int8 weights or KV, paged KV, a prefix store (it
@@ -333,18 +336,22 @@ def _mixer_prefill(x, w: dict, c: SsmHybridConfig, length):
 
 
 @jax.named_scope("mamba_mixer")
-def _mixer_decode(x, w: dict, c: SsmHybridConfig, tail, state, active):
-    """x [B, H], one token a slot; tail [d_conv - 1, B, I]; state [B, N, I]
-    -> (x', tail', state'), the slot's own where it is not ``active``."""
+def _mixer_decode(x, w: dict, c: SsmHybridConfig, tail, ssm, i,
+                  walk: ss.Walk):
+    """x [B, H], one token a slot; tail [d_conv - 1, B, I]; ``ssm`` the held
+    stack of scan states [mixers, B, N, I], of which this is mixer ``i``
+    -> (x', tail', the stack). Where a slot is not among ``walk``'s active
+    ones the tail is its own and its scan state is not touched
+    (``ss.update_held``)."""
     h = rms_norm(x, w["norm1"], c.rms_norm_eps)
     u, z = jnp.split(mm(h, w["w_in"]), 2, axis=-1)
     taps = jnp.concatenate([tail, u[None].astype(tail.dtype)])
     cc = _conv(taps, w, x.dtype)
     d, b, cm = _scan_inputs(cc, w, c)
-    y, new = ss.state_update(state, cc, d, z, b, cm, -jnp.exp(w["a_log"]),
-                             w["d_skip"])
-    return (x + mm(y, w["w_out"]), kv_kinds.keep(active, taps[1:], tail, 1),
-            kv_kinds.keep(active, new, state, 0))
+    y, ssm = ss.update_held(ssm, i, walk, cc, d, z, b, cm,
+                            -jnp.exp(w["a_log"]), w["d_skip"])
+    return (x + mm(y, w["w_out"]),
+            kv_kinds.keep(walk.active, taps[1:], tail, 1), ssm)
 
 
 def _qkv(x, w: dict, c: SsmHybridConfig):
@@ -425,23 +432,25 @@ def decode(params: Params, cfg: SsmHybridConfig, tokens: jnp.ndarray,
     """One token a slot against the VIEW of the held cache: tokens [B] at
     positions ``cache.lengths`` -> (float32 logits [B, V], what
     ``kv_kinds.append`` takes: this step's rows ``k``, ``v`` [attention
-    layers, B, 1, KV, D] and the state stacks ``conv`` and ``ssm`` whole,
-    each active slot's state replaced; no counters). The stacks ride in the
-    scans' carry and a layer's state is written back where it was read, so
-    the step makes no second array of a stack's size."""
+    layers, B, 1, KV, D] and the state stacks ``conv`` and ``ssm`` whole:
+    each active slot's tail replaced, each active slot's scan state moved one
+    step where it lies, an idle slot's untouched; no counters). The stacks
+    ride in the scans' carry, a layer's tail is written back where it was
+    read and the scan states are updated in the stack itself, so the step
+    makes no second array of a stack's size."""
     c = cfg
     state_of = next(h for kd, h in zip(kinds, cache.held) if kd.state)
     rows_of = next(h for kd, h in zip(kinds, cache.held) if kd.rows)
     # a slot that is not active reads no row (its output is dropped)
     count = jnp.where(active, cache.lengths, 0)
+    walk = ss.live_slots(active)    # once a step, not once a mixer
 
     def mixer(carry, w, i):
         x, conv, ssm = carry
-        x, tail, state = _mixer_decode(
+        x, tail, ssm = _mixer_decode(
             x, w, c, jax.lax.dynamic_index_in_dim(conv, i, keepdims=False),
-            jax.lax.dynamic_index_in_dim(ssm, i, keepdims=False), active)
+            ssm, i, walk)
         conv = jax.lax.dynamic_update_index_in_dim(conv, tail, i, 0)
-        ssm = jax.lax.dynamic_update_index_in_dim(ssm, state, i, 0)
         return (_mlp(x, w, c), conv, ssm), ()
 
     def attn(carry, w, i):

@@ -10,7 +10,7 @@ N]``, ``A`` ``[N, I]`` (negative), ``D`` ``[I]``. The state is held STATE-major,
 ``[N, I]`` with the channels on the lanes: an ``[I, 16]`` array would pad its
 16 to a tile's 128 lanes and take eight times its bytes on a TPU.
 
-Two entry points. ``selective_scan`` runs the ``S`` tokens of one prompt from a
+Three entry points. ``selective_scan`` runs the ``S`` tokens of one prompt from a
 zero state and returns every ``y`` and the state after token ``length - 1``
 (past ``length`` the step size is masked to 0, so ``exp(0) = 1`` keeps the
 state and the input term adds nothing: a prompt right-padded to its bucket
@@ -20,13 +20,19 @@ fill whole tiles, a Pallas kernel that walks time in chunks with the state of
 1024 channels in registers, so that HBM sees ``c``, ``d``, ``z``, ``y`` once
 and ``B``, ``C`` once a channel block and never an ``[S, I, N]`` array;
 elsewhere a ``lax.scan`` over chunks of time steps that computes the same.
-``state_update`` is one step for ``B`` slots (a decode step): plain
-``jax.numpy`` that XLA fuses into one pass over the held state.
+``state_update`` is one step for ``B`` slots: plain ``jax.numpy`` that XLA
+fuses into one pass over a layer's state. ``update_held`` is a decode step's:
+one step in the HELD stack ``[mixers, B, N, I]`` for the slots that decode.
+On one TPU a second Pallas kernel walks the ACTIVE slots only, each slot's
+``[N, I]`` copied in, replaced and copied back where it lay, so that an idle
+slot moves no byte; elsewhere ``state_update`` over the layer's slice with
+the idle slots' old state selected back in the write.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -187,3 +193,165 @@ def state_update(h, c, d, z, b, cm, a, dskip):
     h, y = _step(h, c.astype(f32), d, z.astype(f32), b.astype(f32),
                  cm.astype(f32), a, dskip)
     return y.astype(c.dtype), h
+
+
+# --- A decode step's update of the HELD stack --------------------------------
+
+UPDATE_LANES = 512  # channels of a slot's state the kernel computes at once
+UPDATE_DEPTH = 4    # buffers of one slot's state [N, I]: one computed, two on
+                    # their way in, one on its way out (three cost a walk 40%
+                    # more time, six or eight gain nothing: PERF.md, PR 41)
+
+
+def update_kernel_runs(channels: int, states: int, devices: int) -> bool:
+    """Whether a decode step's update is the Pallas body (else
+    ``state_update`` on the layer's slice): a TPU, one device, channels in
+    whole blocks as the prefill's kernel asks, states in whole sublanes."""
+    return (jax.default_backend() == "tpu" and devices == 1
+            and channels % BLOCK == 0 and states % SUBLANES == 0)
+
+
+class Walk(NamedTuple):
+    """The slots a decode step updates, made once a step (``live_slots``)
+    and read by every mixer of it."""
+    active: jax.Array   # [B] bool
+    slots: jax.Array    # [B] int32: the active slots' numbers, ascending,
+                        # then zeros
+    live: jax.Array     # [1] int32: how many they are
+
+
+def live_slots(active) -> Walk:
+    slots = jnp.nonzero(active, size=active.shape[0], fill_value=0)[0]
+    return Walk(active, slots.astype(jnp.int32),
+                jnp.sum(active, dtype=jnp.int32).reshape(1))
+
+
+def _update_kernel(layer_ref, slots_ref, live_ref, c_ref, d_ref, z_ref,
+                   bt_ref, cmt_ref, a_ref, dskip_ref, _held, y_ref, h_hbm,
+                   buf, sem, *, depth, lanes):
+    """No grid: ONE loop over the ``live_ref[0]`` active slots, in scalars.
+    c, d, z, y [B, I] and a [N, I], dskip [1, I] in VMEM; bt, cmt [N, B] (a
+    slot's B and C as a column over the states); ``h_hbm`` the held stack
+    [mixers, B, N, I] in HBM, the output that IS the operand ``_held``. A
+    slot's state comes into ``buf[t % depth]``, is replaced there and goes
+    back to where it came from, with the next two slots' on their way in and
+    the last one's on its way out."""
+    layer, live = layer_ref[0], live_ref[0]
+    channels = y_ref.shape[1]
+
+    def into(t):        # HBM -> buf
+        return pltpu.make_async_copy(h_hbm.at[layer, slots_ref[t]],
+                                     buf.at[t % depth], sem.at[0, t % depth])
+
+    def back(t):        # buf -> HBM
+        return pltpu.make_async_copy(buf.at[t % depth],
+                                     h_hbm.at[layer, slots_ref[t]],
+                                     sem.at[1, t % depth])
+
+    for t in range(depth - 2):
+        @pl.when(t < live)
+        def _first():
+            into(t).start()
+
+    # a slot that is not on the walk: nothing undefined may leave the call
+    y_ref[...] = jnp.zeros_like(y_ref)
+
+    def step(t, _):
+        slot, k = slots_ref[t], t % depth
+        into(t).wait()
+
+        @pl.when(t >= 2)    # buf[(t - 2) % depth] is the next to fill
+        def _written():
+            back(t - 2).wait()
+
+        @pl.when(t + depth - 2 < live)
+        def _next():
+            into(t + depth - 2).start()
+
+        row = pl.ds(slot, 1)
+        mine = jax.lax.broadcasted_iota(jnp.int32, bt_ref.shape, 1) == slot
+        b = jnp.sum(jnp.where(mine, bt_ref[...], 0.0), axis=1, keepdims=True)
+        cm = jnp.sum(jnp.where(mine, cmt_ref[...], 0.0), axis=1,
+                     keepdims=True)                                 # [N, 1]
+        for j in range(channels // lanes):
+            at = pl.ds(j * lanes, lanes)
+            c, d, z = c_ref[row, at], d_ref[row, at], z_ref[row, at]
+            h = jnp.exp(d * a_ref[:, at]) * buf[k, :, at] + (d * c) * b
+            buf[k, :, at] = h
+            y = jnp.sum(h * cm, axis=0, keepdims=True) + dskip_ref[:, at] * c
+            y_ref[row, at] = _gate(y, z)
+        back(t).start()
+        return 0
+
+    jax.lax.fori_loop(0, live, step, 0)
+    for t in (live - 2, live - 1):
+        @pl.when(t >= 0)
+        def _last():
+            back(t).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def update_kernel(held, layer, slots, live, c, d, z, b, cm, a, dskip, *,
+                  interpret: bool = False):
+    """The Pallas body of ``update_held``: float32 operands; ``held`` the
+    stack [mixers, B, N, I], which stays in HBM whole (a layer sliced out of
+    it would be copied on its way into the call) and is aliased to the
+    result, so a scan that carries it still carries one array; ``layer`` its
+    mixer (traced); ``slots``, ``live`` a ``Walk``'s.
+    Returns (y [B, I] float32, zero where a slot is not on the walk; the
+    stack). Jitted, so the two runs of mixers of a decode program lower it
+    once (``ops/decode_attention.py`` says what a call site costs a boot)."""
+    _, B, N, I = held.shape
+    lanes = min(UPDATE_LANES, I)
+    f32 = jnp.float32
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_vmem = 4 * (4 * B * I + (1 + UPDATE_DEPTH) * N * I + I
+                   + 2 * N * max(B, LANES))
+    return pl.pallas_call(
+        functools.partial(_update_kernel, depth=UPDATE_DEPTH, lanes=lanes),
+        out_shape=[jax.ShapeDtypeStruct((B, I), f32),
+                   jax.ShapeDtypeStruct(held.shape, held.dtype)],
+        in_specs=[smem] * 3 + [vmem] * 7 + [hbm],
+        out_specs=[vmem, hbm],
+        scratch_shapes=[pltpu.VMEM((UPDATE_DEPTH, N, I), f32),
+                        pltpu.SemaphoreType.DMA((2, UPDATE_DEPTH))],
+        input_output_aliases={10: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(in_vmem * 1.5) + (4 << 20)),
+        name="ssm_state_update",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, live, c, d, z,
+      b.T, cm.T, a, dskip.reshape(1, I), held)
+
+
+@jax.named_scope("selective_scan")
+def update_held(held, layer, walk: Walk, c, d, z, b, cm, a, dskip):
+    """One token a slot, in the HELD stack: ``held`` [mixers, B, N, I]
+    float32, of which mixer ``layer``'s state moves one step where a slot is
+    active and stays, bit for bit, where it is not; ``walk`` is
+    ``live_slots(active)``, made once a step and not once a mixer; c, z [B,
+    I]; d [B, I] float32; b, cm [B, N]. Returns (y [B, I] in c's dtype, the
+    stack). On one TPU a kernel walks the active slots only, at every
+    occupancy: an idle slot moves no byte (its y is zero), and with every slot
+    active the walk still moves the state once where XLA passes over it twice
+    (the update, then the sum over the states for y: PERF.md section 5,
+    PR 41). Elsewhere ``state_update`` computes every slot of the layer's
+    slice and an idle slot's old state rides back in the write (its y is its
+    own, dropped downstream like its token)."""
+    f32 = jnp.float32
+    if update_kernel_runs(held.shape[3], held.shape[2],
+                          jax.sharding.get_abstract_mesh().size):
+        dispatch.note("state_update", "pallas")
+        y, held = update_kernel(
+            held, layer, walk.slots, walk.live, c.astype(f32), d,
+            z.astype(f32), b.astype(f32), cm.astype(f32), a, dskip)
+        return y.astype(c.dtype), held
+    dispatch.note("state_update", "xla")
+    h = jax.lax.dynamic_index_in_dim(held, layer, keepdims=False)
+    y, new = state_update(h, c, d, z, b, cm, a, dskip)
+    # the select rides in the update's own pass: no second array of the
+    # stack's size is made
+    new = jnp.where(walk.active[:, None, None], new, h)
+    return y, jax.lax.dynamic_update_index_in_dim(held, new, layer, 0)

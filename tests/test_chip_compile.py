@@ -233,6 +233,28 @@ def test_selective_scan_compiles_for_v5e(v5e, steps):
             < steps * 5120 * 16 * 4 / 4)
 
 
+# A decode step's update of the held scan states at jamba2-3b's sizes: 26
+# mixers x 64 slots x [16, 5120] float32, the stack an operand in place.
+def test_state_update_kernel_compiles_for_v5e(v5e):
+    d = v5e.devices[0]
+    f32, i32 = jnp.float32, jnp.int32
+    assert ss.update_kernel_runs(5120, 16, 1)
+    assert not ss.update_kernel_runs(5120, 16, 4)   # GSPMD partitions no kernel
+    assert not ss.update_kernel_runs(128, 8, 1)     # the tiny preset's channels
+    by_slot, by_state = _on(d, (64, 5120), f32), _on(d, (64, 16), f32)
+    compiled = jax.jit(ss.update_kernel, donate_argnums=0).lower(
+        _on(d, (26, 64, 16, 5120), f32), _on(d, (), i32), _on(d, (64,), i32),
+        _on(d, (1,), i32), by_slot, by_slot, by_slot, by_state, by_state,
+        _on(d, (16, 5120), f32), _on(d, (5120,), f32)).compile()
+    _assert_kernel(compiled)
+    assert "ssm_state_update" in compiled.as_text()
+    # the stack goes in and comes out as ONE array: donated, aliased through
+    # the call, and nothing of its size (or of one mixer's) is made beside it
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 26 * 64 * 16 * 5120 * 4
+    assert m.temp_size_in_bytes < 64 * 16 * 5120 * 4 / 4
+
+
 def _abstract_cell(v5e, config_name):
     """The engine of a benchmark configuration over shapes alone, as
     ``benchmark/rehearse_compile.py`` builds it, and its decode chunk's
@@ -392,14 +414,23 @@ def test_the_state_space_cells_decode_chunk_holds_one_copy_of_each_state_stack(
     carries both, a layer's state is written back where it was read, and so
     no instruction makes a second array of either stack's size and the
     temporaries stay under half the scan state; the decode kernel reads the
-    two attention layers' rows (20 query heads on one KV head) in place."""
+    two attention layers' rows (20 query heads on one KV head) in place.
+    Since PR 41 the scan states are updated by the kernel of
+    ``selective_scan.update_held`` in the stack itself (one call in each run
+    of mixers): no fusion writes the stack any more."""
     from benchmark import rehearse_compile as rc
+    from kukeon_tpu.ops import dispatch
 
     mesh, eng, args = _abstract_cell(v5e, "ai21-jamba2-3b-bf16")
+    before = dispatch.counts().get(("state_update", "pallas"), 0)
     with jax.set_mesh(mesh):
         compiled = eng._decode_chunk.lower(*args, k).compile()
+    assert dispatch.counts()[("state_update", "pallas")] == before + 2
     text = compiled.as_text()
     assert "decode_attention" in text and "tpu_custom_call" in text
+    assert len(re.findall(r" = \(.*f32\[26,64,16,5120\]\S*\) custom-call\(",
+                          text)) == 2 and "ssm_state_update" in text
+    assert not re.search(r" = f32\[26,64,16,5120\]\S* fusion\(", text)
     state, rows = args[1].cache.held
     assert state["ssm"].shape == (26, 64, 16, 5120)
     assert state["conv"].shape == (26, 3, 64, 5120)
