@@ -17,7 +17,14 @@ that have rows.
   w   = s[sel] / (sum(s[sel]) + 1e-20) * route_scale       (``route_norm``)
   y   = Shared(h) + sum_{e in sel, e held} w_e Expert_e(h)           (SwiGLU)
 
-Weights ``w``: ``router`` [H, E] float32, ``bias`` [E] float32, ``e_gate`` /
+``scoring="softmax_selected"`` is the other router (granitemoehybrid's): no
+sigmoid, no bias, no scale; the top k of the LOGITS, weighted by a softmax
+over those k logits alone:
+
+  l = float32(h) Wr;   sel = top_k(l);   w = softmax(l[sel])
+
+Weights ``w``: ``router`` [H, E] float32, ``bias`` [E] float32 (the sigmoid
+router's; the other has none), ``e_gate`` /
 ``e_up`` [count, H, I], ``e_down`` [count, I, H], and the shared expert's
 ``s_gate`` / ``s_up`` [H, Is], ``s_down`` [Is, H].
 """
@@ -45,14 +52,21 @@ def select(biased: jnp.ndarray, k: int, groups: int = 1,
     return jax.lax.top_k(biased, k)[1]
 
 
-def route(h: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, k: int, *,
-          norm: bool = True, scale: float = 1.0, groups: int = 1,
-          groups_kept: int = 1) -> tuple[jnp.ndarray, jnp.ndarray]:
+SIGMOID, SOFTMAX_SELECTED = "sigmoid", "softmax_selected"
+
+
+def route(h: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray | None,
+          k: int, *, norm: bool = True, scale: float = 1.0, groups: int = 1,
+          groups_kept: int = 1, scoring: str = SIGMOID,
+          ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """h [N, H] -> (sel [N, k] int32, w [N, k] float32). The router runs in
     float32 at the highest precision: a selection should not turn on the
     activation dtype or on the TPU's default single bf16 pass."""
     logits = jnp.dot(h.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == SOFTMAX_SELECTED:
+        top, sel = jax.lax.top_k(logits, k)
+        return sel.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
     s = jax.nn.sigmoid(logits)
     sel = select(s + bias, k, groups, groups_kept)
     w = jnp.take_along_axis(s, sel, axis=-1)
@@ -93,7 +107,8 @@ def _routed(h, w: dict, local, held, wts):
 def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
                  experts_held: tuple[int, int], route_norm: bool = True,
                  route_scale: float = 1.0, groups: int = 1,
-                 groups_kept: int = 1, counted: jnp.ndarray,
+                 groups_kept: int = 1, scoring: str = SIGMOID,
+                 counted: jnp.ndarray,
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """h [..., H] -> (y [..., H], held hits int32): the shared expert once
     plus the held experts' share of the routed sum. ``counted`` [...] bool
@@ -103,9 +118,9 @@ def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
     x = h.reshape(-1, H)
     first, count = experts_held
     with jax.named_scope("router"):
-        sel, wts = route(x, w["router"], w["bias"], experts_per_token,
+        sel, wts = route(x, w["router"], w.get("bias"), experts_per_token,
                          norm=route_norm, scale=route_scale, groups=groups,
-                         groups_kept=groups_kept)
+                         groups_kept=groups_kept, scoring=scoring)
         local = sel - first
         held = (local >= 0) & (local < count)
     with jax.named_scope("expert_layer"):

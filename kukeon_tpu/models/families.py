@@ -11,8 +11,8 @@ slot's cache holds layer by layer:
   prefix store, ``prefill_ext`` and the KV handoff work on that block.
 - ``layered`` set: the layers are of several kinds (``models/kv_kinds.py``:
   a ring of a window's rows beside full stacks; a state-space layer's state,
-  which has no rows at all; a latent row and an indexer's key with no head
-  axis). The family states its kinds and brings
+  which has no rows at all, Mamba-1's or Mamba-2's; a latent row and an
+  indexer's key with no head axis). The family states its kinds and brings
   ``prefill`` and ``decode``; the engine owns insertion, the step's write and
   the valid rows.
 
@@ -135,7 +135,7 @@ def _drawn_init(model):
 @functools.cache
 def _families() -> tuple[Family, ...]:
     from kukeon_tpu.models import (llama, moe, sparse_latent_moe, ssm_hybrid,
-                                   window_moe)
+                                   ssm_moe, window_moe)
 
     return (
         Family("dense_gqa", llama.LlamaConfig, _dense_init,
@@ -169,6 +169,12 @@ def _families() -> tuple[Family, ...]:
                    prefill=sparse_latent_moe.prefill,
                    decode=sparse_latent_moe.decode,
                    counters=sparse_latent_moe.COUNTERS)),
+        Family("ssm_moe", ssm_moe.SsmMoEConfig, _drawn_init(ssm_moe),
+               param_specs=ssm_moe.param_specs,
+               layered=Layered(
+                   kinds=ssm_moe.SsmMoEConfig.cache_kinds,
+                   prefill=ssm_moe.prefill, decode=ssm_moe.decode,
+                   counters=ssm_moe.COUNTERS)),
     )
 
 

@@ -177,17 +177,17 @@ def _leaf_key(key, name: str, layer=None):
     return key if layer is None else jax.random.fold_in(key, layer)
 
 
-def _matrix(key, shape, fan_in, dtype, scale=1.0):
+def matrix(key, shape, fan_in, dtype, scale=1.0):
     return (jax.random.normal(key, shape, jnp.float32)
             * (fan_in ** -0.5 * scale)).astype(dtype)
 
 
-def _gain(key, shape, dtype):
+def gain(key, shape, dtype):
     return (1.0 + GAIN_STD * jax.random.normal(key, shape, jnp.float32)
             ).astype(dtype)
 
 
-def _dt_bias(key, shape):
+def dt_bias(key, shape):
     """softplus^-1 of a log-uniform step size, float32."""
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
                  * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
@@ -223,17 +223,17 @@ def _layer_leaves(c: SsmHybridConfig, mixer: bool) -> dict:
 def _draw(key, c: SsmHybridConfig, name, kind, shape, fan_in, layer):
     k = _leaf_key(key, name, layer)
     if kind == "gain":
-        return _gain(k, shape, c.dtype)
+        return gain(k, shape, c.dtype)
     if kind == "taps":
-        return _matrix(k, shape, fan_in, c.dtype).T
+        return matrix(k, shape, fan_in, c.dtype).T
     if kind == "conv_bias":
         return (CONV_BIAS_STD * jax.random.normal(k, shape, jnp.float32)
                 ).astype(c.dtype)
     if kind == "dt":
-        return _matrix(k, shape, fan_in, c.dtype, DT_SCALE)
+        return matrix(k, shape, fan_in, c.dtype, DT_SCALE)
     if kind == "dt_bias":
-        return _dt_bias(k, shape)
-    return _matrix(k, shape, fan_in, c.dtype)
+        return dt_bias(k, shape)
+    return matrix(k, shape, fan_in, c.dtype)
 
 
 def _draw_params(key: jax.Array, c: SsmHybridConfig) -> Params:
@@ -255,9 +255,9 @@ def _draw_params(key: jax.Array, c: SsmHybridConfig) -> Params:
         (M, N, I))
     mamba["d_skip"] = jnp.ones((M, I), jnp.float32)
     return {
-        "embed": _matrix(_leaf_key(key, "embed"), (c.vocab_size, H), H,
-                         c.dtype),
-        "final_norm": _gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
+        "embed": matrix(_leaf_key(key, "embed"), (c.vocab_size, H), H,
+                        c.dtype),
+        "final_norm": gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
         "mamba": mamba, "attn": stack(False)}
 
 
@@ -308,7 +308,7 @@ def _scan_inputs(cc, w: dict, c: SsmHybridConfig):
     return d, b, cm
 
 
-def _conv(taps, w: dict, dtype):
+def conv_tap(taps, w: dict, dtype):
     """The causal convolution at its newest tap: ``taps`` the d_conv inputs
     [d_conv, .., I], oldest first."""
     f32 = jnp.float32
@@ -326,7 +326,7 @@ def _mixer_prefill(x, w: dict, c: SsmHybridConfig, length):
     h = rms_norm(x, w["norm1"], c.rms_norm_eps)
     u, z = jnp.split(mm(h, w["w_in"]), 2, axis=-1)
     padded = jnp.pad(u, ((K - 1, 0), (0, 0)))       # u_t = 0 for t < 0
-    cc = _conv([padded[j:j + S] for j in range(K)], w, x.dtype)
+    cc = conv_tap([padded[j:j + S] for j in range(K)], w, x.dtype)
     # row t of ``padded`` is u at t - (K - 1): the K - 1 inputs before length
     tail = jax.lax.dynamic_slice_in_dim(padded, length, K - 1, axis=0)
     d, b, cm = _scan_inputs(cc, w, c)
@@ -346,7 +346,7 @@ def _mixer_decode(x, w: dict, c: SsmHybridConfig, tail, ssm, i,
     h = rms_norm(x, w["norm1"], c.rms_norm_eps)
     u, z = jnp.split(mm(h, w["w_in"]), 2, axis=-1)
     taps = jnp.concatenate([tail, u[None].astype(tail.dtype)])
-    cc = _conv(taps, w, x.dtype)
+    cc = conv_tap(taps, w, x.dtype)
     d, b, cm = _scan_inputs(cc, w, c)
     y, ssm = ss.update_held(ssm, i, walk, cc, d, z, b, cm,
                             -jnp.exp(w["a_log"]), w["d_skip"])
@@ -354,7 +354,7 @@ def _mixer_decode(x, w: dict, c: SsmHybridConfig, tail, ssm, i,
             kv_kinds.keep(walk.active, taps[1:], tail, 1), ssm)
 
 
-def _qkv(x, w: dict, c: SsmHybridConfig):
+def qkv(x, w: dict, c: SsmHybridConfig):
     """x [B, S, H] -> q [B, S, NH, D], k, v [B, S, KV, D]; no rotary."""
     B, S = x.shape[:2]
     h = rms_norm(x, w["norm1"], c.rms_norm_eps)
@@ -413,7 +413,7 @@ def prefill(params: Params, cfg: SsmHybridConfig, tokens: jnp.ndarray, length):
         return _mlp(x, w, c)[None], (tail, state)
 
     def attn(x, w, _i):
-        q, k, v = _qkv(x, w, c)
+        q, k, v = qkv(x, w, c)
         with jax.named_scope("full_attention"):
             a = blocked_attention(q, k, v, None, PREFILL_BLOCK)
         x = x + mm(a.reshape(*x.shape[:2], c.q_dim), w["wo"])
@@ -455,7 +455,7 @@ def decode(params: Params, cfg: SsmHybridConfig, tokens: jnp.ndarray,
 
     def attn(carry, w, i):
         x, conv, ssm = carry
-        q, k, v = _qkv(x[:, None], w, c)
+        q, k, v = qkv(x[:, None], w, c)
         with jax.named_scope("full_attention"):
             a = decode_gqa_attention(q, k, v, rows_of["k"], rows_of["v"], i,
                                      count)

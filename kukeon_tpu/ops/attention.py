@@ -112,17 +112,20 @@ def attention_grouped(
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
-def blocked_attention(q, k, v, window: int | None, block: int):
+def blocked_attention(q, k, v, window: int | None, block: int,
+                      scale: float | None = None):
     """Causal GQA over one prompt in query blocks of ``block`` rows. A block
     of a window layer slices the band of keys it can see; a full layer's
-    block, the keys up to its last row. q [B, S, NH, D]; k, v [B, S, KV, D]."""
+    block, the keys up to its last row. q [B, S, NH, D]; k, v [B, S, KV, D].
+    ``scale`` multiplies the float32 scores; None: ``D ** -0.5``."""
     B, S, NH, D = q.shape
     KV = k.shape[2]
     block = min(block, S)
     if S % block:
         raise ValueError(f"a prompt bucket of {S} rows is no multiple of the "
                          f"query block {block}")
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     outs = []
     for q0 in range(0, S, block):
         q1 = q0 + block
@@ -171,6 +174,7 @@ def decode_gqa_attention(
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     skip: jnp.ndarray | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Single-token decode attention against a cache, append-free.
 
@@ -205,6 +209,8 @@ def decode_gqa_attention(
       skip: optional [B] int32, one row below ``lengths`` left out (a ring
         that holds a window's rows: the row the new token is about to take;
         ``kv_kinds.valid``). A row at or past ``lengths`` excludes nothing.
+      scale: what multiplies the float32 scores, in either body; None:
+        ``D ** -0.5`` (a model whose published multiplier is another says so).
 
     Returns: [B, 1, H, D].
     """
@@ -220,7 +226,7 @@ def decode_gqa_attention(
         # of the held bytes, which the kernel's operand (default layout) is.
         return da.decode_attention(
             q, k_new, v_new, jnp.swapaxes(cache_k, 2, 3),
-            jnp.swapaxes(cache_v, 2, 3), lengths, skip, layer)
+            jnp.swapaxes(cache_v, 2, 3), lengths, skip, layer, scale=scale)
     dispatch.note("decode_gqa_attention", "xla")
     cache_k, cache_v, k_scale, v_scale = (
         None if x is None else
@@ -230,7 +236,8 @@ def decode_gqa_attention(
     S = cache_k.shape[1]
     KV = cache_k.shape[2]
     G = H // KV
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    scale = (1.0 / jnp.sqrt(D).astype(jnp.float32) if scale is None
+             else jnp.float32(scale))
     dt = q.dtype
 
     qg = q.reshape(B, KV, G, D)

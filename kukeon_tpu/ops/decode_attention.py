@@ -169,16 +169,19 @@ def _kernel(*refs, scale, rows, depth, ring, groups):
                        (jnp.int32(0), cursors))
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "depth", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "depth", "interpret", "scale"))
 def decode_attention(q, k_new, v_new, cache_k, cache_v, count, skip=None,
                      layer=0, *, rows: int | None = None,
-                     depth: int = _DEPTH, interpret: bool = False):
+                     depth: int = _DEPTH, interpret: bool = False,
+                     scale: float | None = None):
     """q [B, 1, H, D]; k_new, v_new [B, 1, KV, D]; cache_k, cache_v the
     stacks in the HELD layout [layers, B, KV, S, D], read at ``layer`` (the
     whole stack is the operand and stays in HBM: a layer sliced out of it
     would be copied on its way into the call); count [B] int32, the rows to
     read of each slot (0: none; at most S); skip [B] one row left out, or
-    None. Returns [B, 1, H, D] in q's dtype.
+    None; ``scale`` what multiplies the scores (None: ``D ** -0.5``).
+    Returns [B, 1, H, D] in q's dtype.
 
     Nothing here but the kernel runs once a layer: the walk over the live
     blocks is the kernel's own, in scalars, from ``count``. Jitted so that
@@ -212,7 +215,9 @@ def decode_attention(q, k_new, v_new, cache_k, cache_v, count, skip=None,
             + 2 * B * KV * Gp * D * item              # new rows in, output out
             + 2 * KV * Gp * (2 * LANES + D) * 4)      # m, l, acc
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / (D ** 0.5), rows=rows,
+        functools.partial(_kernel,
+                          scale=1.0 / (D ** 0.5) if scale is None else scale,
+                          rows=rows,
                           depth=depth, ring=skip is not None, groups=G),
         out_shape=jax.ShapeDtypeStruct((B, KV, Gp, D), q.dtype),
         in_specs=[smem] * len(scalars) + [vmem] + [hbm] * 2,

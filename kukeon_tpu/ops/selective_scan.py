@@ -27,6 +27,11 @@ On one TPU a second Pallas kernel walks the ACTIVE slots only, each slot's
 ``[N, I]`` copied in, replaced and copied back where it lay, so that an idle
 slot moves no byte; elsewhere ``state_update`` over the layer's slice with
 the idle slots' old state selected back in the write.
+
+``state_update`` and ``update_held`` also take ``A`` as ``[1, I]``: a decay
+that does not turn on the state's index (a Mamba-2 mixer's, one scalar a head,
+repeated over the head's channels), whose exponential is then one row and not
+``N`` of them. The prefill of such a mixer is ``ops/ssd_scan.py``'s.
 """
 
 from __future__ import annotations
@@ -198,6 +203,9 @@ def state_update(h, c, d, z, b, cm, a, dskip):
 # --- A decode step's update of the HELD stack --------------------------------
 
 UPDATE_LANES = 512  # channels of a slot's state the kernel computes at once
+UPDATE_TILE = 32768     # ... and at most this many state elements (16 states:
+                        # 512 channels; 128 states: 256, since a dynamic row
+                        # of 128 lanes does not lower), or registers spill
 UPDATE_DEPTH = 4    # buffers of one slot's state [N, I]: one computed, two on
                     # their way in, one on its way out (three cost a walk 40%
                     # more time, six or eight gain nothing: PERF.md, PR 41)
@@ -230,12 +238,12 @@ def _update_kernel(layer_ref, slots_ref, live_ref, c_ref, d_ref, z_ref,
                    bt_ref, cmt_ref, a_ref, dskip_ref, _held, y_ref, h_hbm,
                    buf, sem, *, depth, lanes):
     """No grid: ONE loop over the ``live_ref[0]`` active slots, in scalars.
-    c, d, z, y [B, I] and a [N, I], dskip [1, I] in VMEM; bt, cmt [N, B] (a
-    slot's B and C as a column over the states); ``h_hbm`` the held stack
-    [mixers, B, N, I] in HBM, the output that IS the operand ``_held``. A
-    slot's state comes into ``buf[t % depth]``, is replaced there and goes
-    back to where it came from, with the next two slots' on their way in and
-    the last one's on its way out."""
+    c, d, z, y [B, I] and a [N, I] (or [1, I]), dskip [1, I] in VMEM; bt,
+    cmt [N, B] (a slot's B and C as a column over the states); ``h_hbm`` the
+    held stack [mixers, B, N, I] in HBM, the output that IS the operand
+    ``_held``. A slot's state comes into ``buf[t % depth]``, is replaced there
+    and goes back to where it came from, with the next two slots' on their
+    way in and the last one's on its way out."""
     layer, live = layer_ref[0], live_ref[0]
     channels = y_ref.shape[1]
 
@@ -297,17 +305,17 @@ def update_kernel(held, layer, slots, live, c, d, z, b, cm, a, dskip, *,
     stack [mixers, B, N, I], which stays in HBM whole (a layer sliced out of
     it would be copied on its way into the call) and is aliased to the
     result, so a scan that carries it still carries one array; ``layer`` its
-    mixer (traced); ``slots``, ``live`` a ``Walk``'s.
+    mixer (traced); ``slots``, ``live`` a ``Walk``'s; ``a`` [N, I] or [1, I].
     Returns (y [B, I] float32, zero where a slot is not on the walk; the
     stack). Jitted, so the two runs of mixers of a decode program lower it
     once (``ops/decode_attention.py`` says what a call site costs a boot)."""
     _, B, N, I = held.shape
-    lanes = min(UPDATE_LANES, I)
+    lanes = min(I, max(2 * LANES, min(UPDATE_LANES, UPDATE_TILE // N)))
     f32 = jnp.float32
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_vmem = 4 * (4 * B * I + (1 + UPDATE_DEPTH) * N * I + I
+    in_vmem = 4 * (4 * B * I + (a.shape[0] + UPDATE_DEPTH * N) * I + I
                    + 2 * N * max(B, LANES))
     return pl.pallas_call(
         functools.partial(_update_kernel, depth=UPDATE_DEPTH, lanes=lanes),

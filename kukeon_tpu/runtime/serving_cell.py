@@ -410,7 +410,7 @@ MOE_MODELS = set()
 
 def _register_models():
     from kukeon_tpu.models import (bert, llama, moe, sparse_latent_moe,
-                                   ssm_hybrid, window_moe)
+                                   ssm_hybrid, ssm_moe, window_moe)
 
     MODELS.update({
         "tiny": llama.llama_tiny,
@@ -421,6 +421,7 @@ def _register_models():
         "window-moe-tiny": window_moe.window_moe_tiny,
         "ssm-hybrid-tiny": ssm_hybrid.ssm_hybrid_tiny,
         "sparse-latent-moe-tiny": sparse_latent_moe.sparse_latent_moe_tiny,
+        "ssm-moe-tiny": ssm_moe.ssm_moe_tiny,
     })
     MOE_MODELS.update({"mixtral-tiny", "mixtral-8x7b"})
     EMBEDDING_MODELS.update({

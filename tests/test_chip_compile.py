@@ -37,6 +37,7 @@ from kukeon_tpu.ops import decode_attention as da  # noqa: E402
 from kukeon_tpu.ops import flash_attention as fa  # noqa: E402
 from kukeon_tpu.ops import int8_matmul as i8  # noqa: E402
 from kukeon_tpu.ops import selective_scan as ss  # noqa: E402
+from kukeon_tpu.ops import ssd_scan as sd  # noqa: E402
 
 # bytes_limit the attached v5e reports (memory_stats on the chip, PR 22).
 V5E_HBM_BYTES = 16909336064
@@ -574,3 +575,47 @@ def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
         assert "sparse_masked_attention" in text
         # beside the cache, which a prefill does not take as an argument
         assert rc.resident(compiled) + cache < V5E_HBM_BYTES
+
+
+# The chunked state-space scan at granite-4.0-h-small's widths (128 heads of
+# 64 channels, 128 states, chunks of 256): the smallest and the largest prompt
+# bucket of its cell, and the one bucket of the engine's default ones that is
+# a single chunk of 128 (64 rows are no lane tile: the XLA body's).
+@pytest.mark.parametrize("steps", [128, 512, 8192])
+def test_ssd_scan_compiles_for_v5e(v5e, steps):
+    d = v5e.devices[0]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    chunk = min(256, steps)
+    assert sd.kernel_runs(steps, 128, 64, 128, chunk, 1)
+    assert not sd.kernel_runs(steps, 128, 64, 128, chunk, 4)    # GSPMD
+    assert not sd.kernel_runs(64, 128, 64, 128, 64, 1)      # no lane tile
+    assert not sd.kernel_runs(32, 4, 8, 16, 8, 1)           # the tiny preset
+    by_state = _on(d, (steps, 128), bf16)
+    compiled = sd.scan_kernel.lower(
+        _on(d, (steps, 8192), bf16), _on(d, (steps, 128), f32), by_state,
+        by_state, _on(d, (128,), f32), _on(d, (128,), f32), heads=128,
+        chunk=chunk).compile()
+    _assert_kernel(compiled)
+    assert "ssd_scan" in compiled.as_text()
+    # HBM never sees an array of [steps, heads, 64, 128] (34 GB in float32 at
+    # 8192 steps), nor a chunk's [heads, 256, 256] decay matrices (33.5 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# A decode step's update of the held scan states at granite-4.0-h-small's
+# sizes: 9 mixers x 32 slots x [128, 8192] float32 (4 MiB a slot and mixer),
+# the decay ONE row (a head's scalar over its channels), the stack in place.
+def test_state_update_kernel_compiles_with_a_decay_of_one_row_for_v5e(v5e):
+    d = v5e.devices[0]
+    f32, i32 = jnp.float32, jnp.int32
+    assert ss.update_kernel_runs(8192, 128, 1)
+    by_slot, by_state = _on(d, (32, 8192), f32), _on(d, (32, 128), f32)
+    compiled = jax.jit(ss.update_kernel, donate_argnums=0).lower(
+        _on(d, (9, 32, 128, 8192), f32), _on(d, (), i32), _on(d, (32,), i32),
+        _on(d, (1,), i32), by_slot, by_slot, by_slot, by_state, by_state,
+        _on(d, (1, 8192), f32), _on(d, (8192,), f32)).compile()
+    _assert_kernel(compiled)
+    assert "ssm_state_update" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 9 * 32 * (4 << 20)
+    assert m.temp_size_in_bytes < 32 * (4 << 20) / 4
