@@ -7,7 +7,10 @@ is what an expert-parallel deployment's combine adds up; on one chip the
 exchange is simply absent. No capacity and no dropped token: the (token,
 choice) pairs are sorted by expert and run through ``jax.lax.ragged_dot`` over
 the held stack, which on the TPU visits only the row tiles and the experts
-that have rows.
+that have rows. Only the rows that count have any: the pairs of an idle slot
+and of a prompt's padding join the pairs that chose an expert held elsewhere,
+past the last group, so a step reads the held experts its active slots reach
+and no other.
 
   s   = sigmoid(float32(h) Wr)
   sel = top_k(s + b)                         b: selection only
@@ -33,6 +36,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from kukeon_tpu.models.llama import mm
 
@@ -80,11 +84,20 @@ def swiglu(h: jnp.ndarray, w_gate, w_up, w_down) -> jnp.ndarray:
     return mm(gate * mm(h, w_up), w_down)
 
 
+# What a call of the layer sums beside its output, in this order: the counted
+# choices that fell on a held expert, the experts it holds, and those of them
+# whose group had a row (whose weights the three ragged products had to read).
+TALLY = ("kukeon_moe_held_hits_total", "kukeon_moe_held_experts_total",
+         "kukeon_moe_held_experts_reached_total")
+NO_TALLY = np.zeros(len(TALLY), np.int32)
+
+
 def _routed(h, w: dict, local, held, wts):
-    """The held experts' part for h [N, H]: every (token, choice) pair sorted
-    by held expert (pairs that chose an expert held elsewhere last, in no
-    group), three ragged products over the held stack, weighted, and summed
-    back per token."""
+    """The held experts' part for h [N, H], and the held experts reached: the
+    ``held`` (token, choice) pairs sorted by held expert, every other pair
+    last, in no group (it chose an expert held elsewhere, or its token does
+    not count); three ragged products over the held stack, weighted, and
+    summed back per token. A token with no pair in a group gets zeros."""
     N, K = local.shape
     count = w["e_gate"].shape[0]
     flat = jnp.where(held, local, count).reshape(N * K)
@@ -101,7 +114,8 @@ def _routed(h, w: dict, local, held, wts):
     y = jnp.where(in_group, y * jnp.take(wts.reshape(N * K), order)[:, None]
                   .astype(y.dtype), 0)
     back = jnp.argsort(order)                       # pair -> its sorted row
-    return jnp.take(y, back, axis=0).reshape(N, K, -1).sum(axis=1)
+    return (jnp.take(y, back, axis=0).reshape(N, K, -1).sum(axis=1),
+            jnp.sum(sizes > 0, dtype=jnp.int32))
 
 
 def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
@@ -110,10 +124,13 @@ def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
                  groups_kept: int = 1, scoring: str = SIGMOID,
                  counted: jnp.ndarray,
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """h [..., H] -> (y [..., H], held hits int32): the shared expert once
+    """h [..., H] -> (y [..., H], TALLY int32 [3]): the shared expert once
     plus the held experts' share of the routed sum. ``counted`` [...] bool
-    marks the tokens whose choices count as hits (real prompt tokens, active
-    slots); every token is computed either way."""
+    marks the tokens that are read afterwards (real prompt tokens, active
+    slots): only their choices are hits and only their pairs are routed, so a
+    held expert that no counted token chose is not read. Any other token gets
+    the shared expert alone back; a counted token's output does not depend on
+    which other tokens count."""
     lead, H = h.shape[:-1], h.shape[-1]
     x = h.reshape(-1, H)
     first, count = experts_held
@@ -122,10 +139,10 @@ def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
                          norm=route_norm, scale=route_scale, groups=groups,
                          groups_kept=groups_kept, scoring=scoring)
         local = sel - first
-        held = (local >= 0) & (local < count)
+        held = (local >= 0) & (local < count) & counted.reshape(-1, 1)
     with jax.named_scope("expert_layer"):
-        y = _routed(x, w, local, held, wts)
+        y, reached = _routed(x, w, local, held, wts)
     with jax.named_scope("shared_expert"):
         y = y + swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
-    mask = held & counted.reshape(-1, 1)
-    return y.reshape(*lead, H), jnp.sum(mask, dtype=jnp.int32)
+    return y.reshape(*lead, H), jnp.stack(
+        [jnp.sum(held, dtype=jnp.int32), jnp.int32(count), reached])

@@ -53,7 +53,8 @@ import jax
 import jax.numpy as jnp
 
 from kukeon_tpu.models import kv_kinds
-from kukeon_tpu.models.expert_layer import expert_layer, select, swiglu
+from kukeon_tpu.models.expert_layer import (
+    NO_TALLY, TALLY, expert_layer, select, swiglu)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops import rope
 from kukeon_tpu.ops import sparse_attention as sa
@@ -61,10 +62,10 @@ from kukeon_tpu.ops.norms import rms_norm
 
 Params = dict[str, Any]
 # Device-summed counters a forward returns beside its logits, in this order:
-# the routers' choices and those that chose a held expert (as window_moe's),
-# the token x expert-layer pairs those choices were made for, and of the decode
+# the routers' choices and the expert layers' TALLY (as window_moe's), the
+# token x expert-layer pairs those choices were made for, and of the decode
 # steps the latent rows attended and the rows that were live for them.
-COUNTERS = ("kukeon_moe_routed_total", "kukeon_moe_held_hits_total",
+COUNTERS = ("kukeon_moe_routed_total", *TALLY,
             "kukeon_moe_routed_tokens_total",
             "kukeon_sparse_rows_selected_total",
             "kukeon_sparse_rows_live_total")
@@ -432,8 +433,8 @@ def _pieces(S: int, rows: int) -> int:
 def _in_place(fn, x, rows: int):
     """x [S, ...] with ``fn`` applied to runs of ``rows`` rows, one after
     another, each run written back where it was read: one buffer of x's size
-    however many runs. ``fn(piece, first row) -> (piece', extra)``; the
-    extras are summed."""
+    however many runs. ``fn(piece, first row) -> (piece', TALLY)``; the
+    tallies are summed."""
     n = _pieces(x.shape[0], rows)
     rows = x.shape[0] // n
 
@@ -447,7 +448,7 @@ def _in_place(fn, x, rows: int):
         return (jax.lax.dynamic_update_slice_in_dim(x, piece, first, 0),
                 extra + e)
 
-    return jax.lax.fori_loop(0, n, one, (x, jnp.int32(0)))
+    return jax.lax.fori_loop(0, n, one, (x, NO_TALLY))
 
 
 def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig):
@@ -503,7 +504,7 @@ def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig):
 
         _, o = jax.lax.scan(group, None, (wq_b, wk, wv))
         o = jnp.moveaxis(o, 0, 1).reshape(rows, NH * Dv)
-        return xq + mm(o, w["wo"]), jnp.int32(0)
+        return xq + mm(o, w["wo"]), NO_TALLY
 
     x, _ = _in_place(chunk, x, ATTN_CHUNK)
     return x, row, ki
@@ -511,16 +512,16 @@ def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig):
 
 def _mlp(x, w: dict, c: SparseLatentMoEConfig, counted):
     """The dense SwiGLU or the expert layer, by the leaves the layer has, for
-    x [N, H]; returns (x', held hits)."""
+    x [N, H]; returns (x', the expert layer's TALLY)."""
     h = rms_norm(x, w["norm2"], c.rms_norm_eps)
     if "router" not in w:
-        return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), jnp.int32(0)
-    m, hits = expert_layer(
+        return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_TALLY
+    m, tally = expert_layer(
         h, w, experts_per_token=c.experts_per_token,
         experts_held=c.experts_held, route_norm=c.route_norm,
         route_scale=c.route_scale, groups=c.n_group,
         groups_kept=c.topk_group, counted=counted)
-    return x + m, hits
+    return x + m, tally
 
 
 def _head(params, c: SparseLatentMoEConfig, x):
@@ -543,10 +544,10 @@ def _through_layers(params: Params, x, layer):
     return x, jnp.stack(rows), jnp.stack(keys), sums
 
 
-def _counters(c: SparseLatentMoEConfig, counted, hits, selected=0, live=0):
+def _counters(c: SparseLatentMoEConfig, counted, tally, selected=0, live=0):
     tokens = jnp.sum(counted, dtype=jnp.int32) * (
         c.num_layers - c.num_dense_layers)
-    return jnp.stack([tokens * c.experts_per_token, hits, tokens,
+    return jnp.stack([tokens * c.experts_per_token, *tally, tokens,
                       jnp.int32(selected), jnp.int32(live)])
 
 
@@ -566,15 +567,15 @@ def prefill(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
             return _mlp(piece, w, c, jax.lax.dynamic_slice_in_dim(
                 counted, first, piece.shape[0]))
 
-        x, hits = _in_place(mlp, x, MLP_ROWS)
-        return x, row, key, hits
+        x, tally = _in_place(mlp, x, MLP_ROWS)
+        return x, row, key, tally
 
-    x, rows, keys, hits = _through_layers(
+    x, rows, keys, tally = _through_layers(
         params, embed(params, tokens, c.dtype)[0], layer)
     last = jax.lax.dynamic_index_in_dim(x, length - 1, keepdims=True)
     return (_head(params, c, last)[0],
             {"ckv": rows[:, None], "kidx": keys[:, None]},
-            _counters(c, counted, hits))
+            _counters(c, counted, tally))
 
 
 def decode(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
@@ -622,13 +623,13 @@ def decode(params: Params, cfg: SparseLatentMoEConfig, tokens: jnp.ndarray,
             topk=c.index_topk, scale=c.softmax_scale, value_dim=R)
         o = jnp.einsum("bhr,hrd->bhd", mix, w["wkv_bv"])
         x = x + mm(o.reshape(B, 1, NH * Dv), w["wo"])
-        x, hits = _mlp(x[:, 0], w, c, active)
+        x, tally = _mlp(x[:, 0], w, c, active)
         picked = jnp.sum(jnp.where(active, kept, 0), dtype=jnp.int32)
-        return x[:, None], row, ki[:, None], jnp.stack([hits, picked])
+        return x[:, None], row, ki[:, None], jnp.append(tally, picked)
 
     x, rows, keys, sums = _through_layers(
         params, embed(params, tokens[:, None], c.dtype), layer)
     live = jnp.sum(jnp.where(active, lengths + 1, 0),
                    dtype=jnp.int32) * c.num_layers
     return (_head(params, c, x)[:, 0], {"ckv": rows, "kidx": keys},
-            _counters(c, active, sums[0], sums[1], live))
+            _counters(c, active, sums[:-1], sums[-1], live))

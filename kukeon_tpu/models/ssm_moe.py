@@ -66,7 +66,8 @@ import jax.numpy as jnp
 
 from kukeon_tpu.models import kv_kinds
 from kukeon_tpu.models import ssm_hybrid as sh
-from kukeon_tpu.models.expert_layer import SOFTMAX_SELECTED, expert_layer
+from kukeon_tpu.models.expert_layer import (
+    NO_TALLY, SOFTMAX_SELECTED, TALLY, expert_layer)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops import selective_scan as ss
 from kukeon_tpu.ops import ssd_scan as ssd
@@ -75,7 +76,7 @@ from kukeon_tpu.ops.norms import rms_norm
 
 Params = dict[str, Any]
 # Device-summed counters a forward returns beside its logits, in this order.
-COUNTERS = ("kukeon_moe_routed_total", "kukeon_moe_held_hits_total",
+COUNTERS = ("kukeon_moe_routed_total", *TALLY,
             "kukeon_moe_routed_tokens_total")
 PREFILL_BLOCK = 512     # query rows a prefill attends at once (a bucket's, if fewer)
 MLP_ROWS = 2048         # rows a prefill takes through an expert layer at once
@@ -313,13 +314,13 @@ param_specs = sh.param_specs
 # --- The block ---------------------------------------------------------------
 
 def _moe(x, w: dict, c: SsmMoEConfig, counted):
-    """The second half of every layer: x [.., H] -> (x', held hits)."""
+    """The second half of every layer: x [.., H] -> (x', TALLY)."""
     h = rms_norm(x, w["norm2"], c.rms_norm_eps)
-    m, hits = expert_layer(
+    m, tally = expert_layer(
         h, w, experts_per_token=c.experts_per_token,
         experts_held=c.experts_held, scoring=SOFTMAX_SELECTED,
         counted=counted)
-    return x + m * jnp.asarray(c.residual_multiplier, x.dtype), hits
+    return x + m * jnp.asarray(c.residual_multiplier, x.dtype), tally
 
 
 def _moe_in_pieces(x, w: dict, c: SsmMoEConfig, counted):
@@ -330,15 +331,15 @@ def _moe_in_pieces(x, w: dict, c: SsmMoEConfig, counted):
     if S <= MLP_ROWS or S % MLP_ROWS:
         return _moe(x, w, c, counted)
 
-    def piece(hits, xs):
+    def piece(tally, xs):
         rows, real = xs
         rows, h = _moe(rows, w, c, real)
-        return hits + h, rows
+        return tally + h, rows
 
-    hits, out = jax.lax.scan(
-        piece, jnp.int32(0),
+    tally, out = jax.lax.scan(
+        piece, NO_TALLY,
         (x.reshape(-1, MLP_ROWS, x.shape[1]), counted.reshape(-1, MLP_ROWS)))
-    return out.reshape(S, -1), hits
+    return out.reshape(S, -1), tally
 
 
 def _projections(x, w: dict, c: SsmMoEConfig):
@@ -425,11 +426,11 @@ def _embed_scaled(params: Params, c: SsmMoEConfig, tokens):
         c.embedding_multiplier, c.dtype)
 
 
-def _counters(c: SsmMoEConfig, counted, hits) -> jnp.ndarray:
+def _counters(c: SsmMoEConfig, counted, tally) -> jnp.ndarray:
     """COUNTERS for one forward: every counted token makes
     ``experts_per_token`` choices in each layer."""
     pairs = jnp.sum(counted, dtype=jnp.int32) * c.num_layers
-    return jnp.stack([pairs * c.experts_per_token, hits, pairs])
+    return jnp.stack([pairs * c.experts_per_token, *tally, pairs])
 
 
 def prefill(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray, length):
@@ -442,28 +443,27 @@ def prefill(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray, length):
     rm = jnp.asarray(c.residual_multiplier, c.dtype)
 
     def mixer(carry, w, _i):
-        x, hits = carry
+        x, tally = carry
         x, tail, state = _mixer_prefill(x[0], w, c, length)
         x, h = _moe_in_pieces(x, w, c, counted)
-        return (x[None], hits + h), (tail, state)
+        return (x[None], tally + h), (tail, state)
 
     def attn(carry, w, _i):
-        x, hits = carry
+        x, tally = carry
         q, k, v = sh.qkv(x, w, c)
         with jax.named_scope("full_attention"):
             a = blocked_attention(q, k, v, None, PREFILL_BLOCK,
                                   scale=c.attention_multiplier)
         x = x + mm(a.reshape(*x.shape[:2], c.q_dim), w["wo"]) * rm
         x, h = _moe_in_pieces(x[0], w, c, counted)
-        return (x[None], hits + h), (k, v)
+        return (x[None], tally + h), (k, v)
 
-    (x, hits), (tails, states), (ks, vs) = _through_layers(
-        params, c, (_embed_scaled(params, c, tokens), jnp.int32(0)), mixer,
-        attn)
+    (x, tally), (tails, states), (ks, vs) = _through_layers(
+        params, c, (_embed_scaled(params, c, tokens), NO_TALLY), mixer, attn)
     last = jax.lax.dynamic_index_in_dim(x[0], length - 1, keepdims=False)
     block = {"k": ks, "v": vs, "conv": tails[:, :, None],
              "ssm": states[:, None]}
-    return _head(params, c, last), block, _counters(c, counted, hits)
+    return _head(params, c, last), block, _counters(c, counted, tally)
 
 
 def decode(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray,
@@ -486,24 +486,24 @@ def decode(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray,
     rm = jnp.asarray(c.residual_multiplier, c.dtype)
 
     def mixer(carry, w, i):
-        x, conv, ssm, hits = carry
+        x, conv, ssm, tally = carry
         x, tail, ssm = _mixer_decode(x, w, c, conv[i], ssm, i, walk)
         conv = conv.at[i].set(tail)
         x, h = _moe(x, w, c, active)
-        return (x, conv, ssm, hits + h), ()
+        return (x, conv, ssm, tally + h), ()
 
     def attn(carry, w, i):
-        x, conv, ssm, hits = carry
+        x, conv, ssm, tally = carry
         q, k, v = sh.qkv(x[:, None], w, c)
         with jax.named_scope("full_attention"):
             a = decode_gqa_attention(q, k, v, rows_of["k"], rows_of["v"], i,
                                      count, scale=c.attention_multiplier)
         x = x + mm(a.reshape(-1, c.q_dim), w["wo"]) * rm
         x, h = _moe(x, w, c, active)
-        return (x, conv, ssm, hits + h), (k, v)
+        return (x, conv, ssm, tally + h), (k, v)
 
-    (x, conv, ssm, hits), _, (ks, vs) = _through_layers(
+    (x, conv, ssm, tally), _, (ks, vs) = _through_layers(
         params, c, (_embed_scaled(params, c, tokens), state_of["conv"],
-                    state_of["ssm"], jnp.int32(0)), mixer, attn)
+                    state_of["ssm"], NO_TALLY), mixer, attn)
     return (_head(params, c, x), {"k": ks, "v": vs, "conv": conv, "ssm": ssm},
-            _counters(c, active, hits))
+            _counters(c, active, tally))

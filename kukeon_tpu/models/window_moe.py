@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 
 from kukeon_tpu.models import kv_kinds
-from kukeon_tpu.models.expert_layer import expert_layer, swiglu
+from kukeon_tpu.models.expert_layer import (
+    NO_TALLY, TALLY, expert_layer, swiglu)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops.attention import blocked_attention, decode_gqa_attention
 from kukeon_tpu.ops.norms import rms_norm
@@ -55,7 +56,7 @@ from kukeon_tpu.ops.rope import apply_rope
 Params = dict[str, Any]
 SLIDING, FULL = "sliding_attention", "full_attention"
 # Device-summed counters a forward returns beside its logits, in this order.
-COUNTERS = ("kukeon_moe_routed_total", "kukeon_moe_held_hits_total")
+COUNTERS = ("kukeon_moe_routed_total", *TALLY)
 PREFILL_BLOCK = 512     # query rows a prefill attends at once (a bucket's, if fewer)
 
 
@@ -303,16 +304,16 @@ def _attn_out(x, attn, gate, w: dict, c: WindowMoEConfig):
 
 def _mlp(x, w: dict, c: WindowMoEConfig, counted):
     """The dense SwiGLU or the expert layer, by the leaves the layer has;
-    returns (x', held hits)."""
+    returns (x', the expert layer's TALLY)."""
     h = rms_norm(x, w["norm3"], c.rms_norm_eps)
     if "router" in w:
-        m, hits = expert_layer(
+        m, tally = expert_layer(
             h, w, experts_per_token=c.experts_per_token,
             experts_held=c.experts_held, route_norm=c.route_norm,
             route_scale=c.route_scale, counted=counted)
     else:
-        m, hits = swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), jnp.int32(0)
-    return x + rms_norm(m, w["norm4"], c.rms_norm_eps), hits
+        m, tally = swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_TALLY
+    return x + rms_norm(m, w["norm4"], c.rms_norm_eps), tally
 
 
 def _scope(layer_type: str) -> str:
@@ -330,46 +331,46 @@ def _head(params, c: WindowMoEConfig, x):
                   params["lm_head"]).astype(jnp.float32)
 
 
-def _counters(c: WindowMoEConfig, counted, hits) -> jnp.ndarray:
+def _counters(c: WindowMoEConfig, counted, tally) -> jnp.ndarray:
     """COUNTERS for one forward: every counted token makes
     ``experts_per_token`` choices in each expert layer."""
     routed = (jnp.sum(counted, dtype=jnp.int32) * c.experts_per_token
               * (c.num_layers - c.num_dense_layers))
-    return jnp.stack([routed, hits])
+    return jnp.stack([routed, *tally])
 
 
 def _through_layers(params: Params, c: WindowMoEConfig, x, layer):
     """x through the unrolled head and ONE scan over the periods.
-    ``layer(x, w, layer_type, number) -> (x', k, v, hits)``; ``number`` is
+    ``layer(x, w, layer_type, number) -> (x', k, v, tally)``; ``number`` is
     the layer's place in the model (traced inside the scan). Returns (x, K, V
-    stacked over the layers in their order, the expert layers' hits)."""
+    stacked over the layers in their order, the expert layers' TALLY)."""
     ks, vs = [], []
-    hits = jnp.int32(0)
+    tally = NO_TALLY
     for number, (w, t) in enumerate(zip(params["head"], c.layer_types)):
         x, k, v, h = layer(x, w, t, number)
-        hits = hits + h
+        tally = tally + h
         ks.append(k)
         vs.append(v)
     U, p = c.num_unrolled, len(c.period)
 
     def period(carry, xs):
-        x, hits = carry
+        x, tally = carry
         ws, i = xs
         out_k, out_v = [], []
         for j, (w, t) in enumerate(zip(ws, c.period)):
             x, k, v, h = layer(x, w, t, U + i * p + j)
-            hits = hits + h
+            tally = tally + h
             out_k.append(k)
             out_v.append(v)
-        return (x, hits), (jnp.stack(out_k), jnp.stack(out_v))
+        return (x, tally), (jnp.stack(out_k), jnp.stack(out_v))
 
-    (x, hits), (pk, pv) = jax.lax.scan(
-        period, (x, hits),
+    (x, tally), (pk, pv) = jax.lax.scan(
+        period, (x, tally),
         (tuple(params["period"]), jnp.arange(c.num_periods)))
     # [P, p, ...] -> the periods' layers in their order
     ks.extend(pk.reshape(-1, *pk.shape[2:]))
     vs.extend(pv.reshape(-1, *pv.shape[2:]))
-    return x, jnp.stack(ks), jnp.stack(vs), hits
+    return x, jnp.stack(ks), jnp.stack(vs), tally
 
 
 def prefill(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
@@ -389,14 +390,14 @@ def prefill(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
             attn = blocked_attention(
                 q, k, v, c.sliding_window if sliding else None,
                 PREFILL_BLOCK)
-        x, hits = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
-        return x, k, v, hits
+        x, tally = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
+        return x, k, v, tally
 
-    x, ks, vs, hits = _through_layers(
+    x, ks, vs, tally = _through_layers(
         params, c, _embed_scaled(params, c, tokens), layer)
     last = jax.lax.dynamic_index_in_dim(x[0], length - 1, keepdims=True)
     return (_head(params, c, last)[0], {"k": ks, "v": vs},
-            _counters(c, counted, hits))
+            _counters(c, counted, tally))
 
 
 def decode(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
@@ -430,10 +431,10 @@ def decode(params: Params, cfg: WindowMoEConfig, tokens: jnp.ndarray,
             attn = decode_gqa_attention(
                 q, k, v, cache.held[kind]["k"], cache.held[kind]["v"],
                 index_of[number], count, skip=skip)
-        x, hits = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
-        return x, k, v, hits
+        x, tally = _mlp(_attn_out(x, attn, gate, w, c), w, c, counted)
+        return x, k, v, tally
 
-    x, ks, vs, hits = _through_layers(
+    x, ks, vs, tally = _through_layers(
         params, c, _embed_scaled(params, c, tokens[:, None]), layer)
     return (_head(params, c, x)[:, 0], {"k": ks, "v": vs},
-            _counters(c, counted, hits))
+            _counters(c, counted, tally))

@@ -1,0 +1,188 @@
+"""models/expert_layer.py routes the rows that count and no other: the pairs
+of an uncounted token (an idle slot's, a prompt's padding) join no held
+expert's group. The layer alone under the three routers the cells run, then
+each family's tiny preset through the engine with its other slots idle."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import plugins
+from kukeon_tpu.models import expert_layer as el
+from kukeon_tpu.models import sparse_latent_moe as slm
+from kukeon_tpu.models import ssm_moe as sm
+from kukeon_tpu.models import window_moe as wm
+from kukeon_tpu.parallel import make_mesh
+from kukeon_tpu.serving import SamplingParams, ServingEngine
+from tests import test_sparse_latent_moe as t_slm
+from tests import test_ssm_moe as t_sm
+from tests import test_window_moe as t_wm
+
+N, H, I = 32, 32, 24
+# the ways the cells route: (router width, held (first, count), the layer's
+# keywords): Trinity's, deepseek's, granite's
+ROUTERS = {
+    "sigmoid_bias": (16, (4, 8), dict(experts_per_token=4, route_scale=2.448)),
+    "sigmoid_groups": (32, (8, 16), dict(
+        experts_per_token=6, route_scale=2.5, groups=8, groups_kept=4)),
+    "softmax_selected": (24, (0, 12), dict(
+        experts_per_token=5, scoring=el.SOFTMAX_SELECTED)),
+}
+
+
+@pytest.fixture(params=sorted(ROUTERS))
+def layer(request):
+    """(h [N, H], the share's weights, the layer's keywords, every row's
+    choices [N, k] and which of them this share holds, the shared expert's
+    output)."""
+    E, (first, count), kw = ROUTERS[request.param]
+    ks = jax.random.split(jax.random.key(len(request.param)), 9)
+    n = jax.random.normal
+    w = {"router": n(ks[0], (H, E)),
+         "e_gate": n(ks[2], (count, H, I)) * H ** -.5,
+         "e_up": n(ks[3], (count, H, I)) * H ** -.5,
+         "e_down": n(ks[4], (count, I, H)) * I ** -.5,
+         "s_gate": n(ks[5], (H, I)) * H ** -.5,
+         "s_up": n(ks[6], (H, I)) * H ** -.5,
+         "s_down": n(ks[7], (I, H)) * I ** -.5}
+    if kw.get("scoring") != el.SOFTMAX_SELECTED:
+        w["bias"] = 0.05 * n(ks[1], (E,))
+    h = n(ks[8], (N, H))
+    kw = dict(kw, experts_held=(first, count))
+    route_kw = {k: v for k, v in kw.items()
+                if k in ("groups", "groups_kept", "scoring")}
+    sel, _ = el.route(h, w["router"], w.get("bias"), kw["experts_per_token"],
+                      scale=kw.get("route_scale", 1.0), **route_kw)
+    held = np.asarray((sel >= first) & (sel < first + count))
+    shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
+    return h, w, kw, np.asarray(sel), held, np.asarray(shared)
+
+
+def _some(seed=0):
+    return np.random.default_rng(seed).random(N) < 0.4
+
+
+def test_a_counted_rows_output_is_the_same_whoever_else_counts(layer):
+    h, w, kw, _sel, _held, _shared = layer
+    whole, _ = el.expert_layer(h, w, counted=jnp.ones(N, bool), **kw)
+    for seed in (0, 1):
+        counted = _some(seed)
+        y, _ = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+        np.testing.assert_allclose(np.asarray(y)[counted],
+                                   np.asarray(whole)[counted], atol=1e-6)
+    one = np.arange(N) == 7
+    y, _ = el.expert_layer(h, w, counted=jnp.asarray(one), **kw)
+    np.testing.assert_allclose(np.asarray(y)[7], np.asarray(whole)[7],
+                               atol=1e-6)
+
+
+def test_an_uncounted_row_gets_the_shared_expert_alone(layer):
+    h, w, kw, _sel, held, shared = layer
+    counted = _some()
+    y, _ = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+    np.testing.assert_allclose(np.asarray(y)[~counted], shared[~counted],
+                               atol=1e-6)
+    # and a counted row with a held choice gets more than that
+    routed = counted & held.any(axis=1)
+    assert routed.any()
+    assert (np.abs(np.asarray(y) - shared)[routed].max(axis=1) > 1e-3).all()
+
+
+def test_with_no_row_counted_every_group_is_empty_and_the_output_finite(layer):
+    h, w, kw, _sel, _held, shared = layer
+    y, tally = jax.jit(lambda h: el.expert_layer(
+        h, w, counted=jnp.zeros(N, bool), **kw))(h)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), shared, atol=1e-6)
+    assert np.asarray(tally).tolist() == [0, kw["experts_held"][1], 0]
+
+
+def test_one_counted_row_of_32_reaches_at_most_top_k_held_experts(layer):
+    h, w, kw, sel, held, _shared = layer
+    assert el.TALLY == ("kukeon_moe_held_hits_total",
+                        "kukeon_moe_held_experts_total",
+                        "kukeon_moe_held_experts_reached_total")
+    row = int(np.argmax(held.sum(axis=1)))      # a row that hits something
+    _, tally = el.expert_layer(h, w, counted=jnp.arange(N) == row, **kw)
+    hits, total, reached = (int(v) for v in tally)
+    assert total == kw["experts_held"][1]
+    assert 0 < reached <= kw["experts_per_token"]
+    # a row's choices are distinct experts: each hit reaches its own
+    assert reached == hits == len(set(sel[row][held[row]]))
+    _, tally = el.expert_layer(h, w, counted=jnp.ones(N, bool), **kw)
+    assert int(tally[2]) == len(set(sel[held])) <= total
+
+
+def test_the_hit_count_is_the_counted_rows_held_choices(layer):
+    h, w, kw, _sel, held, _shared = layer
+    for counted in (np.ones(N, bool), _some(), np.zeros(N, bool)):
+        _, tally = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+        assert int(tally[0]) == int(held[counted].sum())
+    # leading axes as a decode step's [B, 1] and a prefill's [1, S]
+    counted = _some()
+    for lead in ((N, 1), (1, N)):
+        y, tally = el.expert_layer(h.reshape(*lead, H), w,
+                                   counted=jnp.asarray(counted.reshape(lead)),
+                                   **kw)
+        assert y.shape == (*lead, H)
+        assert int(tally[0]) == int(held[counted].sum())
+
+
+# --- through the engine, each family's tiny preset ------------------------------
+
+FAMILIES = {
+    # the model, its test module (reference_config, SEED), cache rows, the
+    # gap its own engine test allows, expert layers, held experts a layer
+    "window_moe": (wm, wm.window_moe_tiny, t_wm, 64, 1e-4, 8, 4),
+    "sparse_latent_moe": (slm, slm.sparse_latent_moe_tiny, t_slm, 128, 1e-4,
+                          2, 4),
+    "ssm_moe": (sm, sm.ssm_moe_tiny, t_sm, 128, t_sm.TOL, 8, 4),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_request_beside_idle_slots_and_a_padded_prompt_through_the_engine(
+        family):
+    """One request in slot 0 of four, its prompt of 16 tokens padded to a
+    bucket of 32: every served token is the float32 reference's best at its
+    position, the first token is the one an unpadded prefill gives, and the
+    layer's tally is on the registry."""
+    model, preset, tests_of, rows, tol, expert_layers, count = FAMILIES[family]
+    cfg = preset()
+    params = model.init_params(jax.random.key(tests_of.SEED), cfg)
+    reference = plugins.load("reference", family)
+    mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
+    prompt = np.random.default_rng(4).integers(0, cfg.vocab_size, 16)
+
+    def serve(slots, buckets, new):
+        eng = ServingEngine(cfg, params, mesh, num_slots=slots,
+                            max_seq_len=rows, decode_chunk=4,
+                            prefill_buckets=buckets)
+        req = eng.submit(prompt, SamplingParams(max_new_tokens=new))
+        while not req.done.is_set():
+            eng.step()
+        return eng, list(req.generated)
+
+    eng, generated = serve(4, (32, 64), 13)
+    seq = np.concatenate([prompt, generated])
+    pos = np.arange(len(prompt) - 1, len(seq) - 1)
+    logits = reference.logits_at(tests_of.reference_config(cfg),
+                                 tests_of.SEED, [seq], [pos], rows)[0]
+    gaps = logits.max(-1) - logits[np.arange(len(pos)), seq[pos + 1]]
+    assert gaps.max() < tol
+    _, unpadded = serve(1, (16,), 1)
+    assert unpadded[0] == generated[0]
+
+    hits, total, reached = (eng.registry.get(name).value()
+                            for name in el.TALLY)
+    steps = sum(int(labels["k"]) * n for labels, n in eng.registry.get(
+        "kukeon_engine_decode_chunks_total").samples())
+    # one call a layer in the prefill and in every step of a fetched chunk
+    calls, rest = divmod(total, expert_layers * count)
+    assert rest == 0 and 1 + 12 <= calls <= 1 + steps
+    # three of four slots idle: a step reaches no more than its one token hit
+    assert 0 < reached <= min(total, hits)
+    assert reached < total

@@ -103,7 +103,10 @@ def test_a_right_padded_prefill_gives_the_reference_logits_at_its_length(
     assert np.abs(np.asarray(logits) - want).max() < 2e-4
     assert {k: v.shape for k, v in block.items()} == {
         "ckv": (3, 1, bucket, 128), "kidx": (3, 1, bucket, 16)}
-    routed, hits, routed_tokens, selected, live = np.asarray(counters)
+    routed, hits, held, reached, routed_tokens, selected, live = np.asarray(
+        counters)
+    # two expert layers of 4 held experts, one piece each
+    assert held == 2 * 4 and 0 < reached <= held
     assert routed_tokens == 2 * n and routed == 2 * n * 4
     assert 0 < hits < routed and selected == live == 0
 
@@ -130,7 +133,7 @@ def test_prefill_then_decode_through_the_cache_matches_the_full_forward(
         cache = kv_kinds.append(cache, kinds, new, active)
         worst = max(worst, float(np.abs(np.asarray(lg[1])
                                         - want[n - P + 1]).max()))
-        _routed, _hits, routed_tokens, selected, live = np.asarray(counters)
+        *_moe, routed_tokens, selected, live = np.asarray(counters)
         # one active slot: 8 of its n + 1 positions a layer
         assert (routed_tokens, selected, live) == (2, 3 * 8, 3 * (n + 1))
     assert worst < 2e-4
@@ -532,7 +535,8 @@ def test_sixteen_shares_and_the_shared_expert_once_make_the_uncut_layer():
     h = jax.random.normal(jax.random.key(9), (50, 32))
     kw = dict(experts_per_token=6, route_scale=2.5, groups=8, groups_kept=4,
               counted=jnp.ones(50, bool))
-    whole, hits = el.expert_layer(h, w, experts_held=(0, 32), **kw)
+    whole, (hits, _held, _reached) = el.expert_layer(
+        h, w, experts_held=(0, 32), **kw)
     shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
     parts, counted = shared, 0
     for share in range(16):
@@ -540,7 +544,7 @@ def test_sixteen_shares_and_the_shared_expert_once_make_the_uncut_layer():
                         for k in ("e_gate", "e_up", "e_down")}}
         y, n = el.expert_layer(h, held, experts_held=(2 * share, 2), **kw)
         parts = parts + (y - shared)
-        counted += int(n)
+        counted += int(n[0])
     assert jnp.abs(parts - whole).max() < 1e-5
     assert counted == int(hits) == 50 * 6
     sel, wts = el.route(h, w["router"], w["bias"], 6, scale=2.5, groups=8,

@@ -112,8 +112,10 @@ def test_a_right_padded_prefill_gives_the_logits_and_the_state_at_its_length(
     last, block, counted = prefill(params, _padded(seq, n, 32), n)
     assert np.abs(np.asarray(last) - want[n - 1]).max() < TOL
     # every real token makes top-2 choices in each of the 8 layers
-    routed, hits, pairs = (int(v) for v in counted)
+    routed, hits, held, reached, pairs = (int(v) for v in counted)
     assert (routed, pairs) == (n * 8 * 2, n * 8) and 0 <= hits <= routed
+    # 8 layers of 4 held experts; n tokens reach at most n of a layer's
+    assert held == 8 * 4 and reached <= min(held, hits)
     M, I, N = cfg.num_mixers, cfg.d_inner, cfg.d_state
     assert {k: v.shape for k, v in block.items()} == {
         "k": (2, 1, 32, 2, 16), "v": (2, 1, 32, 2, 16),
@@ -214,8 +216,10 @@ def test_an_idle_slots_state_is_bit_for_bit_unchanged_by_a_step(tiny, prefill):
     assert np.array_equal(before["conv"][:, :, 0], now["conv"][:, :, 0])
     assert not np.array_equal(before["ssm"][:, 1], now["ssm"][:, 1])
     assert np.asarray(after.lengths).tolist() == [11, 20]
-    # one active slot: top-2 in each of 8 layers
-    assert [int(v) for v in counted][::2] == [16, 8]
+    # one active slot: top-2 in each of 8 layers, which hold 4 experts each
+    # and read at most the two it chose
+    routed, hits, held, reached, pairs = (int(v) for v in counted)
+    assert (routed, held, pairs) == (16, 32, 8) and reached <= hits <= 16
 
 
 def test_the_check_sees_a_lost_state(tiny, reference, prefill):
@@ -324,9 +328,10 @@ def test_the_engine_admits_two_slots_at_different_steps_and_reuses_one(
     assert 0 < held.value(what="active") < held.value(what="held")
     rows_read = eng.registry.get("kukeon_engine_decode_kv_rows_total")
     assert rows_read.value(what="held") > 0
-    routed, hits, tokens = (eng.registry.get(name).value()
-                            for name in sm.COUNTERS)
+    routed, hits, held, reached, tokens = (
+        eng.registry.get(name).value() for name in sm.COUNTERS)
     assert routed == 2 * tokens and 0 < hits < routed
+    assert 0 < reached <= min(held, hits)
     # prompt tokens and decode steps of all three requests, in 8 layers
     assert tokens >= 8 * (5 + 19 + 2)
     assert np.isfinite(np.asarray(eng.state.cache.held[0]["ssm"])).all()
@@ -534,7 +539,7 @@ def test_two_shares_and_the_shared_expert_once_make_the_uncut_reference_layer(
         h = rms_norm(x, w["norm2"], cfg.rms_norm_eps)
         shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
         parts = parts + (y - x) - cfg.residual_multiplier * shared
-        hits += int(n)
+        hits += int(n[0])
     parts = parts + cfg.residual_multiplier * shared
     assert jnp.abs(parts - whole).max() < 1e-5
     assert hits == 40 * cfg.experts_per_token
