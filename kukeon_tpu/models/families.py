@@ -12,7 +12,8 @@ slot's cache holds layer by layer:
 - ``layered`` set: the layers are of several kinds (``models/kv_kinds.py``:
   a ring of a window's rows beside full stacks; a state-space layer's state,
   which has no rows at all, Mamba-1's or Mamba-2's; a latent row and an
-  indexer's key with no head axis). The family states its kinds and brings
+  indexer's key with no head axis; a ring of a window layer's own latent row
+  beside them). The family states its kinds and brings
   ``prefill`` and ``decode``; the engine owns insertion, the step's write and
   the valid rows.
 
@@ -44,7 +45,8 @@ class Layered:
     #                         -> (logits [B, V], new, counters); new: k, v
     #                         [L, B, 1, KV, D] (named rows [L, B, 1, width])
     #                         and the state stacks, replaced
-    counters: tuple[str, ...] = ()          # device-summed, by metric name
+    # (cfg) -> what that config's forwards sum on the device, by metric name
+    counters: Callable[[Any], tuple[str, ...]] = lambda cfg: ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,7 +156,7 @@ def _families() -> tuple[Family, ...]:
                layered=Layered(
                    kinds=window_moe.WindowMoEConfig.cache_kinds,
                    prefill=window_moe.prefill, decode=window_moe.decode,
-                   counters=window_moe.COUNTERS)),
+                   counters=lambda cfg: window_moe.COUNTERS)),
         Family("ssm_hybrid", ssm_hybrid.SsmHybridConfig,
                _drawn_init(ssm_hybrid),
                param_specs=ssm_hybrid.param_specs,
@@ -168,13 +170,13 @@ def _families() -> tuple[Family, ...]:
                    kinds=sparse_latent_moe.SparseLatentMoEConfig.cache_kinds,
                    prefill=sparse_latent_moe.prefill,
                    decode=sparse_latent_moe.decode,
-                   counters=sparse_latent_moe.COUNTERS)),
+                   counters=sparse_latent_moe.counters)),
         Family("ssm_moe", ssm_moe.SsmMoEConfig, _drawn_init(ssm_moe),
                param_specs=ssm_moe.param_specs,
                layered=Layered(
                    kinds=ssm_moe.SsmMoEConfig.cache_kinds,
                    prefill=ssm_moe.prefill, decode=ssm_moe.decode,
-                   counters=ssm_moe.COUNTERS)),
+                   counters=lambda cfg: ssm_moe.COUNTERS)),
     )
 
 

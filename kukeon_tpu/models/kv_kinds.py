@@ -11,7 +11,13 @@ models hold other things for a slot, and a family states them as data
   every layer (a latent attention's compressed row and its indexer's key),
   ``[layers, B, rows, width]`` held and viewed alike; a decode step may
   attend only the ``select`` best of the live rows, which it finds by reading
-  them all;
+  them all. A named array belongs to ONE kind: a block's ``block[name]`` and a
+  step's ``new[name]`` carry that kind's layers and no other, so two such
+  kinds of different widths lie side by side (a selecting latent layer's
+  ``kidx`` and ``ckv`` beside a window layer's ring of its own latent row).
+  Such a kind may be a ring too, and a ring may HOLD more rows than the
+  ``window`` it attends (whole tiles): ``ops/sparse_attention.py``
+  ``ring_keep`` says which, and the window decode kernel evaluates it;
 - **state**: named arrays WITHOUT a row axis, of a fixed size whatever the
   slot's length (a state-space layer's convolution tail and scan state). A
   prefill leaves the arrays of its last real token, ``insert`` copies them
@@ -57,6 +63,12 @@ class CacheKind:
     # costs less than a gather by row.
     arrays: tuple = ()
     select: int = 0
+    # Of a ring: the positions a step attends, its own among them; 0: as many
+    # as the ring holds rows (the K / V ring: it holds its window, and the
+    # oldest row leaves as the step's own arrives). The step's own row takes
+    # part unwritten, so ``window - 1`` positions lie in the ring, which may
+    # hold more.
+    window: int = 0
 
     @property
     def unit(self) -> str:
@@ -144,21 +156,19 @@ def _of_kind(kd: CacheKind, per_layer: jnp.ndarray) -> jnp.ndarray:
 
 def _block_rows(kd: CacheKind, block: jnp.ndarray, length) -> jnp.ndarray:
     """The rows of a prefill's block [L, 1, S, KV, D] that this kind keeps,
-    as [layers, 1, KV, rows', D] (held layout; of a named array [L, 1, S,
-    width], as it is). A ring shorter than the block
-    takes, for each of its rows, the LAST position below ``length`` that
-    lands there; rows no position reaches hold whatever the gather brings
-    and lie past the rows ``valid`` counts."""
-    part = _of_kind(kd, block)
-    if kd.arrays:       # [layers, 1, S, width]: the held layout already
-        return part[:, :, :kd.rows]
+    as [layers, 1, KV, rows', D] (held layout; a named array is the kind's
+    own, [layers, 1, S, width], the held layout already). A ring shorter than
+    the block takes, for each of its rows, the LAST position below ``length``
+    that lands there; rows no position reaches hold whatever the gather
+    brings and lie past the rows ``valid`` counts."""
+    part = block if kd.arrays else _of_kind(kd, block)
     if kd.ring and part.shape[2] > kd.rows:
         r = jnp.arange(kd.rows)
         pos = r + kd.rows * ((length - 1 - r) // kd.rows)
         part = jnp.take(part, jnp.clip(pos, 0, part.shape[2] - 1), axis=2)
     elif part.shape[2] > kd.rows:
         part = part[:, :, :kd.rows]
-    return jnp.swapaxes(part, 2, 3)
+    return part if kd.arrays else jnp.swapaxes(part, 2, 3)
 
 
 @jax.named_scope("kv_insert")
@@ -195,9 +205,17 @@ def valid(kd: CacheKind, lengths: jnp.ndarray):
     has left the window and sits in the row the new token takes, ``lengths %
     rows`` (before the wrap that row is past the rows read and excludes
     nothing)."""
-    if kd.ring:
+    if not kd.ring:
+        return lengths, None
+    behind = (kd.window or kd.rows) - 1
+    if behind == kd.rows:       # it holds the positions behind and no other
+        return jnp.minimum(lengths, kd.rows), None
+    if behind == kd.rows - 1:
         return jnp.minimum(lengths, kd.rows), lengths % kd.rows
-    return lengths, None
+    raise ValueError(
+        f"kind {kd.name!r} holds {kd.rows} rows for a window of {kd.window}: "
+        "a count and one excluded row cannot say which it attends "
+        "(``ops/sparse_attention.py`` ``ring_keep`` can)")
 
 
 def keep(active: jnp.ndarray, new: jnp.ndarray, old: jnp.ndarray,
@@ -230,7 +248,8 @@ def append(cache: LayeredKV, kinds, new: dict, active) -> LayeredKV:
         out = {name: new[name] for name, _shape, _dtype in kd.state}
         if kd.rows:
             row = cache.lengths % kd.rows if kd.ring else cache.lengths
-            rows = {name: _of_kind(kd, new[name]) for name in kd.row_names()}
+            rows = {name: new[name] if kd.arrays else _of_kind(kd, new[name])
+                    for name in kd.row_names()}
             out.update({name: held[name] for name in rows})
             for b in range(B):
                 for name, x in rows.items():

@@ -5,7 +5,7 @@ only those (DeepSeek sparse attention over a latent cache).
     I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s)),   s <= t
     S_t     = the min(topk, t + 1) positions of largest I(t, s)
 
-Four ops, each a Pallas TPU kernel where ``kernels_run`` says so and the XLA
+Five ops, each a Pallas TPU kernel where ``kernels_run`` says so and the XLA
 body below it everywhere else (the CPU, shapes the tiles do not divide); the
 XLA body is also the kernel's reference in the tests.
 
@@ -28,6 +28,10 @@ XLA body is also the kernel's reference in the tests.
   step's own row among them without having been written first). No sort, no
   index list, no gathered copy; a block past a slot's last live row moves no
   byte, and a slot of length 0 is not walked.
+- ``window_decode_attention``: the same absorbed form with NO selection, over
+  a RING of latent rows (a window layer's cache: position ``p`` in row ``p mod
+  rows``): the slot's live ring rows are streamed in place and attended where
+  they lie inside the window, the step's own row among them unwritten.
 
 One selection rule for both halves: everything at or above the ``topk``-th
 score, never a row at or past the length; rows that tie with the ``topk``-th
@@ -53,6 +57,7 @@ SELECT_ROWS = 128       # queries a step of the selection kernel
 FLASH_ROWS = 512        # queries a step of the attention kernel
 DECODE_TILE = 2048      # cache rows a step of either decode kernel
 DECODE_DEPTH = 3        # blocks the decode attention's walk keeps in flight
+WINDOW_TILE = 256       # ring rows a step of the window decode kernel
 SUBLANES = 8            # a slot's scores lie [SUBLANES, rows / SUBLANES] in VMEM
 INT_MIN = -2 ** 31
 
@@ -392,12 +397,72 @@ def decode_index_scores(q, w, keys, layer, lengths, *,
     return out[:, 0]
 
 
+def _block_walk(lat_hbm, layer, b, blocks, buf, sem):
+    """The walk both decode kernels make over a slot's first ``blocks`` blocks
+    of ``buf.shape[1]`` cache rows, read in place from the held stack
+    ``lat_hbm`` [layers, B, rows, W] at (``layer``, ``b``) through
+    ``buf.shape[0]`` buffers: ``prime()`` starts the first copies (whatever
+    follows it runs under them), ``run(step)`` calls ``step(j, rows)`` for
+    each block in turn with the next copies in flight."""
+    depth, bk, _ = buf.shape
+
+    def copy(j):
+        return pltpu.make_async_copy(
+            lat_hbm.at[layer, b, pl.ds(pl.multiple_of(j * bk, bk), bk), :],
+            buf.at[j % depth], sem.at[j % depth])
+
+    def start(j):
+        @pl.when(j < blocks)
+        def _start():
+            copy(j).start()
+
+    def prime():
+        for j in range(depth - 1):
+            start(j)
+
+    def run(step):
+        def block(j, _):
+            start(j + depth - 1)
+            copy(j).wait()
+            step(j, buf[j % depth])
+            return 0
+
+        jax.lax.fori_loop(0, blocks, block, 0)
+
+    return prime, run
+
+
+def _attend_block(q, rows, keep, R, scale, m_scr, l_scr, acc_scr):
+    """One block of latent rows [bk, W] into a slot's running softmax: the
+    heads' queries q [NH, W] against it, under ``keep`` [1, bk], the rows'
+    first ``R`` values mixed. Both decode kernels' step."""
+    f32 = jnp.float32
+    s = jax.lax.dot_general(
+        q, rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=f32) * scale                     # [NH, bk]
+    s = jnp.where(keep, s, NEG_INF)
+    m_prev = m_scr[:, :1]
+    l_prev = l_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    # a slot whose own row fell out holds NEG_INF until its first kept row:
+    # the weights of 1 it adds till then are wiped by that row's ``corr`` of
+    # 0; every walked slot keeps at least one row
+    p = jnp.exp(s - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(rows.dtype), rows[:, :R], (((1,), (0,)), ((), ())),
+        preferred_element_type=f32)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
 def _decode_attend_kernel(layer_ref, len_ref, own_ref, q_ref, new_ref, sc_ref,
                           lat_hbm, o_ref, kept_ref, buf, sem, m_scr, l_scr,
                           acc_scr, *, topk, scale):
     slots = q_ref.shape[0]
     R = o_ref.shape[2]
-    depth, bk, _ = buf.shape
+    bk = buf.shape[1]
     sub, width = sc_ref.shape[1:]
     per = width // bk                   # blocks a sublane of the scores
     layer = layer_ref[0]
@@ -412,20 +477,9 @@ def _decode_attend_kernel(layer_ref, len_ref, own_ref, q_ref, new_ref, sc_ref,
                        axis=0, keepdims=True)
 
     def walk(b, n):
-        blocks = (n + bk - 1) // bk
-
-        def copy(j):
-            return pltpu.make_async_copy(
-                lat_hbm.at[layer, b, pl.ds(pl.multiple_of(j * bk, bk), bk), :],
-                buf.at[j % depth], sem.at[j % depth])
-
-        def start(j):
-            @pl.when(j < blocks)
-            def _start():
-                copy(j).start()
-
-        for j in range(depth - 1):      # in flight under the descent
-            start(j)
+        prime, run = _block_walk(lat_hbm, layer, b, (n + bk - 1) // bk, buf,
+                                 sem)
+        prime()                         # in flight under the descent
         own = own_ref[b]
         kth = _kth_largest(
             lambda at: total(sc_ref[b] >= at) + (own >= at).astype(jnp.int32),
@@ -449,35 +503,14 @@ def _decode_attend_kernel(layer_ref, len_ref, own_ref, q_ref, new_ref, sc_ref,
         acc_scr[...] = jnp.broadcast_to(
             jnp.where(own_kept, new[:, :R], 0.0), acc_scr.shape)
 
-        def block(j, _):
-            start(j + depth - 1)
-            copy(j).wait()
-            rows = buf[j % depth]                               # [bk, W]
+        def block(j, rows):                                     # [bk, W]
             order = sc_ref[b, pl.ds(j // per, 1),
                            pl.ds(pl.multiple_of((j % per) * bk, bk), bk)]
             row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
             keep = (order >= kth) & (row < n)                   # [1, bk]
-            s = jax.lax.dot_general(
-                q, rows, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32) * scale             # [NH, bk]
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[:, :1]
-            l_prev = l_scr[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)
-            # a slot whose own row fell out holds NEG_INF until its first
-            # kept row: the weights of 1 it adds till then are wiped by that
-            # row's ``corr`` of 0; every walked slot keeps at least one row
-            p = jnp.exp(s - m_new)
-            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-                p.astype(rows.dtype), rows[:, :R], (((1,), (0,)), ((), ())),
-                preferred_element_type=f32)
-            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-            return 0
+            _attend_block(q, rows, keep, R, scale, m_scr, l_scr, acc_scr)
 
-        jax.lax.fori_loop(0, blocks, block, 0)
+        run(block)
         o_ref[b] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
     def slot(b, _):
@@ -492,16 +525,11 @@ def _decode_attend_kernel(layer_ref, len_ref, own_ref, q_ref, new_ref, sc_ref,
     jax.lax.fori_loop(0, slots, slot, 0)
 
 
-def _decode_attend_xla(q, new, own, scores, latents, layer, lengths, topk,
-                       scale, value_dim):
-    rows = scores.shape[1]
-    rows_of = jax.lax.dynamic_index_in_dim(latents, layer, keepdims=False)
-    live = jnp.arange(rows)[None] < lengths[:, None]
-    both = jnp.concatenate([jnp.where(live, scores, -jnp.inf), own[:, None]],
-                           axis=1)
-    kth = jax.lax.top_k(both, min(topk, rows + 1))[0][:, -1:]
-    keep = (both >= kth) & jnp.pad(live, ((0, 0), (0, 1)),
-                                   constant_values=True)        # [B, rows + 1]
+def _attend_kept_xla(q, new, keep, rows_of, scale, value_dim):
+    """The absorbed attention of q [B, NH, W] over the cache rows ``rows_of``
+    [B, rows, W] and the step's own row ``new`` [B, W] (the last column of
+    ``keep`` [B, rows + 1])."""
+    rows = rows_of.shape[1]
     s = jnp.concatenate(
         [jnp.einsum("bhw,bkw->bhk", q, rows_of,
                     preferred_element_type=jnp.float32),
@@ -514,7 +542,21 @@ def _decode_attend_xla(q, new, own, scores, latents, layer, lengths, topk,
                       preferred_element_type=jnp.float32)
            + p[..., rows:].astype(jnp.float32)
            * new[:, None, :value_dim].astype(jnp.float32))
-    return mix.astype(q.dtype), jnp.sum(keep, axis=1, dtype=jnp.int32)
+    return mix.astype(q.dtype)
+
+
+def _decode_attend_xla(q, new, own, scores, latents, layer, lengths, topk,
+                       scale, value_dim):
+    rows = scores.shape[1]
+    rows_of = jax.lax.dynamic_index_in_dim(latents, layer, keepdims=False)
+    live = jnp.arange(rows)[None] < lengths[:, None]
+    both = jnp.concatenate([jnp.where(live, scores, -jnp.inf), own[:, None]],
+                           axis=1)
+    kth = jax.lax.top_k(both, min(topk, rows + 1))[0][:, -1:]
+    keep = (both >= kth) & jnp.pad(live, ((0, 0), (0, 1)),
+                                   constant_values=True)        # [B, rows + 1]
+    return (_attend_kept_xla(q, new, keep, rows_of, scale, value_dim),
+            jnp.sum(keep, axis=1, dtype=jnp.int32))
 
 
 @jax.named_scope("latent_attention")
@@ -542,7 +584,10 @@ def decode_attention(q, new, own, scores, latents, layer, lengths, *,
     rows = latents.shape[2]
     lengths = lengths.astype(jnp.int32)
     width = rows // SUBLANES
-    bk = block or min(DECODE_TILE, width)
+    # the largest whole-lane block that divides a sublane's run of rows
+    bk = block or max(
+        (b for b in range(LANES, min(DECODE_TILE, width) + 1, LANES)
+         if width % b == 0), default=min(DECODE_TILE, width))
     if interpret is None:
         if not (decode_kernel_runs(rows, W) and value_dim % LANES == 0
                 and width % bk == 0):
@@ -583,3 +628,133 @@ def decode_attention(q, new, own, scores, latents, layer, lengths, *,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths, _order(own),
       q, new[:, None, :], order, latents)
     return out, kept[:, 0, 0]
+
+
+# --- decode over a ring, no selection -----------------------------------------
+
+def ring_keep(lengths, rows: int, window: int):
+    """[B, rows] bool: the rows of a ring of ``rows`` rows (position ``p`` in
+    row ``p mod rows``) that a step at position ``lengths`` [B] attends: those
+    that hold one of the ``window - 1`` positions before it (the step's own
+    row, unwritten, is the window's last). The position in row ``r`` is the
+    last one below ``lengths`` that lands there, ``(lengths - 1 - r) mod
+    rows`` steps back from ``lengths - 1``."""
+    t = lengths[:, None]
+    r = jnp.arange(rows)[None]
+    return (r < jnp.minimum(t, rows)) & ((t - 1 - r) % rows < window - 1)
+
+
+def _window_attend_kernel(layer_ref, len_ref, q_ref, new_ref, lat_hbm, o_ref,
+                          buf, sem, m_scr, l_scr, acc_scr, *, scale, behind):
+    slots = q_ref.shape[0]
+    R = o_ref.shape[2]
+    bk = buf.shape[1]
+    held = lat_hbm.shape[2]
+    layer = layer_ref[0]
+    f32 = jnp.float32
+
+    # A slot that is not walked attends to its own row alone.
+    o_ref[...] = jnp.broadcast_to(new_ref[:, :, :R], o_ref.shape)
+
+    def walk(b, t):
+        n = jnp.minimum(t, held)
+        prime, run = _block_walk(lat_hbm, layer, b, (n + bk - 1) // bk, buf,
+                                 sem)
+        prime()
+        # the step's own row starts the running softmax
+        q = q_ref[b]                                            # [NH, W]
+        new = new_ref[b].astype(f32)                            # [1, W]
+        s_own = jnp.sum(q.astype(f32) * new, axis=1, keepdims=True) * scale
+        m_scr[...] = jnp.broadcast_to(s_own, m_scr.shape)
+        l_scr[...] = jnp.ones_like(l_scr)
+        acc_scr[...] = jnp.broadcast_to(new[:, :R], acc_scr.shape)
+
+        def block(j, rows):
+            row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            keep = row < n
+            if behind < held:       # the ring holds more than the window
+                keep &= jax.lax.rem(t - 1 - row, held) < behind
+            _attend_block(q, rows, keep, R, scale, m_scr, l_scr, acc_scr)
+
+        run(block)
+        o_ref[b] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+    def slot(b, _):
+        t = len_ref[b]
+
+        @pl.when(t > 0)
+        def _live():
+            walk(b, t)
+
+        return 0
+
+    jax.lax.fori_loop(0, slots, slot, 0)
+
+
+def window_kernel_runs(rows: int, block: int, width: int,
+                       value_dim: int) -> bool:
+    return (jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1
+            and rows % block == 0 and block % SUBLANES == 0
+            and width % LANES == 0 and value_dim % LANES == 0)
+
+
+@jax.named_scope("window_latent_attention")
+@functools.partial(jax.jit, static_argnames=("window", "scale", "value_dim",
+                                             "block", "interpret"))
+def window_decode_attention(q, new, latents, layer, lengths, *, window: int,
+                            scale: float, value_dim: int,
+                            block: int | None = None,
+                            interpret: bool | None = None):
+    """One decode step's attention over a window layer's ring, the absorbed
+    form, nothing selected. q [B, NH, W] and new [B, W] as
+    ``decode_attention`` takes them; ``latents`` the held stack [layers, B,
+    rows, W] of rings read at ``layer`` (traced: a layer is read in place);
+    lengths [B] the TOKENS a slot holds (0: the slot is not walked and attends
+    to its own row alone). A slot attends the ring rows ``ring_keep`` names
+    and its own row: ``window`` positions at most; ``window - 1`` may be
+    fewer than the rows the ring holds. Returns [B, NH, value_dim] in q's
+    dtype, float32-accumulated."""
+    B, NH, W = q.shape
+    rows = latents.shape[2]
+    lengths = lengths.astype(jnp.int32)
+    behind = window - 1
+    if behind > rows:
+        raise ValueError(f"a ring of {rows} rows cannot hold the {behind} "
+                         f"positions behind a window of {window}")
+    bk = block or min(WINDOW_TILE, rows)
+    if interpret is None:
+        if not window_kernel_runs(rows, bk, W, value_dim):
+            dispatch.note("window_decode_attention", "xla")
+            keep = jnp.pad(ring_keep(lengths, rows, window), ((0, 0), (0, 1)),
+                           constant_values=True)
+            return _attend_kept_xla(
+                q, new, keep,
+                jax.lax.dynamic_index_in_dim(latents, layer, keepdims=False),
+                scale, value_dim)
+        interpret = False
+    dispatch.note("window_decode_attention", "pallas")
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    item = latents.dtype.itemsize
+    held = (B * NH * (W + value_dim) * item + DECODE_DEPTH * bk * W * item
+            + NH * (2 * LANES + value_dim + 3 * bk) * 4)
+    return pl.pallas_call(
+        functools.partial(_window_attend_kernel, scale=scale, behind=behind),
+        out_shape=jax.ShapeDtypeStruct((B, NH, value_dim), q.dtype),
+        in_specs=[smem] * 2 + [vmem] * 2
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((DECODE_DEPTH, bk, W), latents.dtype),
+            pltpu.SemaphoreType.DMA((DECODE_DEPTH,)),
+            pltpu.VMEM((NH, LANES), jnp.float32),           # running max
+            pltpu.VMEM((NH, LANES), jnp.float32),           # running sum
+            pltpu.VMEM((NH, value_dim), jnp.float32),       # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(held * 1.5) + (8 << 20)),
+        name="window_latent_decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths, q,
+      new[:, None, :], latents)

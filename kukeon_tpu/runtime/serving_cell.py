@@ -421,6 +421,7 @@ def _register_models():
         "window-moe-tiny": window_moe.window_moe_tiny,
         "ssm-hybrid-tiny": ssm_hybrid.ssm_hybrid_tiny,
         "sparse-latent-moe-tiny": sparse_latent_moe.sparse_latent_moe_tiny,
+        "mixed-latent-moe-tiny": sparse_latent_moe.mixed_latent_moe_tiny,
         "ssm-moe-tiny": ssm_moe.ssm_moe_tiny,
     })
     MOE_MODELS.update({"mixtral-tiny", "mixtral-8x7b"})

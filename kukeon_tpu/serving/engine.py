@@ -651,7 +651,8 @@ class ServingEngine:
         self._counted = tuple(
             reg.counter(name, "Summed on the device by the model's forwards "
                         "over real prompt tokens and active slots.")
-            for name in (self._layered.counters if self._layered else ()))
+            for name in (self._layered.counters(cfg) if self._layered
+                         else ()))
         # The loop's spans (on the profiler's clock while /v1/profile
         # captures) and its wall time by phase (obs/spans.py).
         self.spans = LoopSpans(reg)
@@ -885,7 +886,7 @@ class ServingEngine:
             last, block, counted = model.prefill(params, cfg, tokens, length)
             first = sample_per_slot(
                 last[None, :], key, temp[None], top_k[None], top_p[None])[0]
-            if model.counters:
+            if self._counted:
                 first = jnp.concatenate([first[None], counted])
             return (first, *(block[name] for name in names))
 

@@ -109,10 +109,29 @@ def test_a_family_is_its_configs_type_and_one_record():
     assert dense is families.of(llama.llama3_8b())
     assert families.of(moe.moe_tiny()).name == "moe_softmax_topk"
     layered = families.of(window_moe.window_moe_tiny())
-    assert layered.layered.counters == window_moe.COUNTERS
+    assert layered.layered.counters(window_moe.window_moe_tiny()) \
+        == window_moe.COUNTERS
     assert families.find(object()) is None
     with pytest.raises(SystemExit, match="no model family"):
         families.of(object())
+
+
+def test_two_configurations_of_one_type_are_one_family_with_their_own_counters():
+    """Every layer alike, or two kinds of layer with an attention each: the
+    type of the config is the family, and what its forwards sum turns on the
+    config (``Layered.counters`` as a function of it)."""
+    from kukeon_tpu.models import families
+    from kukeon_tpu.models import sparse_latent_moe as slm
+
+    alike, mixed = slm.sparse_latent_moe_tiny(), slm.mixed_latent_moe_tiny()
+    family = families.of(alike)
+    assert family is families.of(mixed) and family.name == "sparse_latent_moe"
+    assert family.layered.counters(alike) == slm.COUNTERS
+    assert family.layered.counters(mixed) == (slm.COUNTERS
+                                              + slm.WINDOW_COUNTERS)
+    assert [k.name for k in family.layered.kinds(mixed, 64)] == [
+        "latent", "window_latent"]
+    assert [k.name for k in family.layered.kinds(alike, 64)] == ["latent"]
 
 
 def test_the_engine_finds_the_family_of_a_config_by_itself():
