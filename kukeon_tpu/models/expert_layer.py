@@ -5,12 +5,17 @@ k; this chip computes the part its ``experts_held = (first, count)`` give.
 Eight such shares (and the shared expert, once) sum to the uncut layer, which
 is what an expert-parallel deployment's combine adds up; on one chip the
 exchange is simply absent. No capacity and no dropped token: the (token,
-choice) pairs are sorted by expert and run through ``jax.lax.ragged_dot`` over
-the held stack, which on the TPU visits only the row tiles and the experts
-that have rows. Only the rows that count have any: the pairs of an idle slot
-and of a prompt's padding join the pairs that chose an expert held elsewhere,
-past the last group, so a step reads the held experts its active slots reach
-and no other.
+choice) pairs are sorted by expert, and only the rows that count have a
+group: the pairs of an idle slot and of a prompt's padding join the pairs that
+chose an expert held elsewhere, past the last group, so a step reads the held
+experts its active slots reach and no other. What is walked is the FRONT of
+that order alone: blocks of ``block_rows`` sorted rows, as many as the pairs
+in a group fill, a trip count the program reads from its own data. A block
+gathers its rows of h, runs them through ``jax.lax.ragged_dot`` over the held
+stack with the group sizes clipped to the block, and adds each row, weighted,
+to its token's float32 output. Beside the index vectors of the sort nothing
+of ``tokens x top-k`` rows is made: what is allocated is a block and the
+[N, H] output.
 
   s   = sigmoid(float32(h) Wr)
   sel = top_k(s + b)                         b: selection only
@@ -33,6 +38,8 @@ router's; the other has none), ``e_gate`` /
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -89,42 +96,93 @@ def swiglu(h: jnp.ndarray, w_gate, w_up, w_down) -> jnp.ndarray:
 # whose group had a row (whose weights the three ragged products had to read).
 TALLY = ("kukeon_moe_held_hits_total", "kukeon_moe_held_experts_total",
          "kukeon_moe_held_experts_reached_total")
-NO_TALLY = np.zeros(len(TALLY), np.int32)
+# ... and after them the (token, choice) pairs the call made and the sorted
+# rows its products worked over: whole blocks, as many as the held pairs fill.
+PAIR_ROWS = ("kukeon_moe_pair_rows_total", "kukeon_moe_pair_rows_worked_total")
+COUNTS = TALLY + PAIR_ROWS
+NO_COUNTS = np.zeros(len(COUNTS), np.int32)
+
+BLOCK_ROWS = 2048
 
 
-def _routed(h, w: dict, local, held, wts):
-    """The held experts' part for h [N, H], and the held experts reached: the
-    ``held`` (token, choice) pairs sorted by held expert, every other pair
-    last, in no group (it chose an expert held elsewhere, or its token does
-    not count); three ragged products over the held stack, weighted, and
-    summed back per token. A token with no pair in a group gets zeros."""
+def block_rows(pairs: int) -> int:
+    """The sorted rows ``_routed`` works through at once, of ``pairs`` a
+    call: all of a decode step's few hundred, 2048 of a prefill piece's (on
+    the chip 1024 to 4096 rows cost the same to within 3% at every cell's
+    widths and held share; PERF.md section 6, PR 48)."""
+    return min(pairs, BLOCK_ROWS)
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int):
+    """The held experts' part for h [N, H] in float32, the held experts
+    reached and the rows worked over. The (token, choice) pairs are sorted by
+    held expert, every other pair last, in no group (it chose an expert held
+    elsewhere, or its token does not count), and only the FRONT of that order
+    is walked: blocks of ``rows`` rows, as many as the ``held`` pairs fill, a
+    count the program reads from its own data (where one block holds every
+    pair of the call, that block once and no loop). A block gathers its rows
+    of h, runs the three ragged products with the group sizes clipped to it,
+    and adds each row, weighted, to its token's output (a product with the
+    block's [N, rows] weighted one-hot: float32 sums on the MXU, no scatter).
+    Nothing of ``N * K`` rows is made but index vectors; with no held pair
+    the loop runs zero times and every token gets zeros.
+
+    Jitted on its own: a family that unrolls its layers traces and lowers
+    this body once a program, not once a layer, and a boot's second pass over
+    a program finds it traced."""
     N, K = local.shape
-    count = w["e_gate"].shape[0]
+    count = e_gate.shape[0]
     flat = jnp.where(held, local, count).reshape(N * K)
     order = jnp.argsort(flat)                       # stable: by expert
     sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
-    xs = jnp.take(h, order // K, axis=0)            # [N*K, H]
-    gate = jax.nn.silu(jax.lax.ragged_dot(xs, w["e_gate"], sizes)
-                       .astype(jnp.float32)).astype(h.dtype)
-    up = jax.lax.ragged_dot(xs, w["e_up"], sizes)
-    y = jax.lax.ragged_dot(gate * up, w["e_down"], sizes)
-    # Rows past the last group belong to no expert; what a kernel leaves
-    # there is not defined, so they are selected out, not multiplied out.
-    in_group = (jnp.take(flat, order) < count)[:, None]
-    y = jnp.where(in_group, y * jnp.take(wts.reshape(N * K), order)[:, None]
-                  .astype(y.dtype), 0)
-    back = jnp.argsort(order)                       # pair -> its sorted row
-    return (jnp.take(y, back, axis=0).reshape(N, K, -1).sum(axis=1),
-            jnp.sum(sizes > 0, dtype=jnp.int32))
+    ends = jnp.cumsum(sizes)
+    blocks = (ends[-1] + rows - 1) // rows
+    # whole blocks to slice: a pad row lies past the last group
+    order = jnp.pad(order, (0, -(N * K) % rows))
+    weight = jnp.take(wts.reshape(N * K), order).astype(h.dtype)
+
+    def block(i, out):
+        first = i * rows
+        pairs = jax.lax.dynamic_slice_in_dim(order, first, rows)
+        in_group = first + jnp.arange(rows) < ends[-1]
+        tokens = pairs // K
+        clipped = (jnp.clip(ends - first, 0, rows)
+                   - jnp.clip(ends - sizes - first, 0, rows))
+        xs = jnp.take(h, tokens, axis=0)            # [rows, H]
+        gate = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, clipped)
+                           .astype(jnp.float32)).astype(h.dtype)
+        up = jax.lax.ragged_dot(xs, e_up, clipped)
+        y = jax.lax.ragged_dot(gate * up, e_down, clipped)
+        # Rows past the last group belong to no expert; what a kernel leaves
+        # there is not defined, so they are selected out, not multiplied out.
+        y = jnp.where(in_group[:, None], y, 0)
+        to_token = jnp.where(
+            (tokens == jnp.arange(N)[:, None]) & in_group,
+            jax.lax.dynamic_slice_in_dim(weight, first, rows), 0)
+        return out + jnp.dot(to_token, y, preferred_element_type=jnp.float32)
+
+    out = jnp.zeros(h.shape, jnp.float32)
+    if rows == N * K:
+        # One block holds every pair (a decode step's, a short bucket's): it
+        # is walked once whatever it holds, with no loop. A loop nested in a
+        # decode step's keeps the compiler from appending to window_moe's
+        # held full-attention stack in place: two copies of 537 MB a step at
+        # Trinity's sizes (PERF.md section 6, PR 48).
+        blocks = 1
+        out = block(0, out)
+    else:
+        out = jax.lax.fori_loop(0, blocks, block, out)
+    return out, jnp.sum(sizes > 0, dtype=jnp.int32), blocks * rows
 
 
-def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
-                 experts_held: tuple[int, int], route_norm: bool = True,
-                 route_scale: float = 1.0, groups: int = 1,
-                 groups_kept: int = 1, scoring: str = SIGMOID,
-                 counted: jnp.ndarray,
-                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """h [..., H] -> (y [..., H], TALLY int32 [3]): the shared expert once
+def expert_layer_counts(h: jnp.ndarray, w: dict, *, experts_per_token: int,
+                        experts_held: tuple[int, int],
+                        route_norm: bool = True, route_scale: float = 1.0,
+                        groups: int = 1, groups_kept: int = 1,
+                        scoring: str = SIGMOID, counted: jnp.ndarray,
+                        ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """h [..., H] -> (y [..., H], COUNTS int32 [5]): the shared expert once
     plus the held experts' share of the routed sum. ``counted`` [...] bool
     marks the tokens that are read afterwards (real prompt tokens, active
     slots): only their choices are hits and only their pairs are routed, so a
@@ -141,8 +199,19 @@ def expert_layer(h: jnp.ndarray, w: dict, *, experts_per_token: int,
         local = sel - first
         held = (local >= 0) & (local < count) & counted.reshape(-1, 1)
     with jax.named_scope("expert_layer"):
-        y, reached = _routed(x, w, local, held, wts)
+        y, reached, worked = _routed(x, w["e_gate"], w["e_up"], w["e_down"],
+                                     local, held, wts,
+                                     rows=block_rows(held.size))
     with jax.named_scope("shared_expert"):
-        y = y + swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
+        y = (y + swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
+             ).astype(h.dtype)
     return y.reshape(*lead, H), jnp.stack(
-        [jnp.sum(held, dtype=jnp.int32), jnp.int32(count), reached])
+        [jnp.sum(held, dtype=jnp.int32), jnp.int32(count), reached,
+         jnp.int32(held.size), jnp.int32(worked)])
+
+
+def expert_layer(h: jnp.ndarray, w: dict, **how
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``expert_layer_counts`` with the TALLY alone, int32 [3]."""
+    y, counts = expert_layer_counts(h, w, **how)
+    return y, counts[:len(TALLY)]

@@ -73,7 +73,7 @@ import jax.numpy as jnp
 
 from kukeon_tpu.models import kv_kinds
 from kukeon_tpu.models.expert_layer import (
-    NO_TALLY, TALLY, expert_layer, select, swiglu)
+    COUNTS, NO_COUNTS, expert_layer_counts, select, swiglu)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops import rope
 from kukeon_tpu.ops import sparse_attention as sa
@@ -81,10 +81,10 @@ from kukeon_tpu.ops.norms import rms_norm
 
 Params = dict[str, Any]
 # Device-summed counters a forward returns beside its logits, in this order:
-# the routers' choices and the expert layers' TALLY (as window_moe's), the
+# the routers' choices and the expert layers' COUNTS (as window_moe's), the
 # token x expert-layer pairs those choices were made for, and of the decode
 # steps the latent rows attended and the rows that were live for them.
-COUNTERS = ("kukeon_moe_routed_total", *TALLY,
+COUNTERS = ("kukeon_moe_routed_total", *COUNTS,
             "kukeon_moe_routed_tokens_total",
             "kukeon_sparse_rows_selected_total",
             "kukeon_sparse_rows_live_total")
@@ -610,7 +610,7 @@ def _pieces(S: int, rows: int) -> int:
 def _in_place(fn, x, rows: int):
     """x [S, ...] with ``fn`` applied to runs of ``rows`` rows, one after
     another, each run written back where it was read: one buffer of x's size
-    however many runs. ``fn(piece, first row) -> (piece', TALLY)``; the
+    however many runs. ``fn(piece, first row) -> (piece', COUNTS)``; the
     tallies are summed."""
     n = _pieces(x.shape[0], rows)
     rows = x.shape[0] // n
@@ -625,7 +625,7 @@ def _in_place(fn, x, rows: int):
         return (jax.lax.dynamic_update_slice_in_dim(x, piece, first, 0),
                 extra + e)
 
-    return jax.lax.fori_loop(0, n, one, (x, NO_TALLY))
+    return jax.lax.fori_loop(0, n, one, (x, NO_COUNTS))
 
 
 def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig,
@@ -719,7 +719,7 @@ def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig,
         _, o = jax.lax.scan(group, None, (wq_b, wk, wv))
         o = _gated(jnp.moveaxis(o, 0, 1).reshape(rows, NH, Dv),
                    part(made["gate"]) if c.head_gate else None)
-        return xq + mm(o.reshape(rows, NH * Dv), w["wo"]), NO_TALLY
+        return xq + mm(o.reshape(rows, NH * Dv), w["wo"]), NO_COUNTS
 
     x, _ = _in_place(chunk, x, rows)
     if a.window:
@@ -729,11 +729,11 @@ def _prefill_attention(x, w: dict, c: SparseLatentMoEConfig,
 
 def _mlp(x, w: dict, c: SparseLatentMoEConfig, counted):
     """The dense SwiGLU or the expert layer, by the leaves the layer has, for
-    x [N, H]; returns (x', the expert layer's TALLY)."""
+    x [N, H]; returns (x', the expert layer's COUNTS)."""
     h = rms_norm(x, w["norm2"], c.rms_norm_eps)
     if "router" not in w:
-        return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_TALLY
-    m, tally = expert_layer(
+        return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_COUNTS
+    m, tally = expert_layer_counts(
         h, w, experts_per_token=c.experts_per_token,
         experts_held=c.experts_held, route_norm=c.route_norm,
         route_scale=c.route_scale, groups=c.n_group,

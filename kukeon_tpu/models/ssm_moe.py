@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from kukeon_tpu.models import kv_kinds
 from kukeon_tpu.models import ssm_hybrid as sh
 from kukeon_tpu.models.expert_layer import (
-    NO_TALLY, SOFTMAX_SELECTED, TALLY, expert_layer)
+    COUNTS, NO_COUNTS, SOFTMAX_SELECTED, expert_layer_counts)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops import selective_scan as ss
 from kukeon_tpu.ops import ssd_scan as ssd
@@ -76,7 +76,7 @@ from kukeon_tpu.ops.norms import rms_norm
 
 Params = dict[str, Any]
 # Device-summed counters a forward returns beside its logits, in this order.
-COUNTERS = ("kukeon_moe_routed_total", *TALLY,
+COUNTERS = ("kukeon_moe_routed_total", *COUNTS,
             "kukeon_moe_routed_tokens_total")
 PREFILL_BLOCK = 512     # query rows a prefill attends at once (a bucket's, if fewer)
 MLP_ROWS = 2048         # rows a prefill takes through an expert layer at once
@@ -314,9 +314,9 @@ param_specs = sh.param_specs
 # --- The block ---------------------------------------------------------------
 
 def _moe(x, w: dict, c: SsmMoEConfig, counted):
-    """The second half of every layer: x [.., H] -> (x', TALLY)."""
+    """The second half of every layer: x [.., H] -> (x', COUNTS)."""
     h = rms_norm(x, w["norm2"], c.rms_norm_eps)
-    m, tally = expert_layer(
+    m, tally = expert_layer_counts(
         h, w, experts_per_token=c.experts_per_token,
         experts_held=c.experts_held, scoring=SOFTMAX_SELECTED,
         counted=counted)
@@ -337,7 +337,7 @@ def _moe_in_pieces(x, w: dict, c: SsmMoEConfig, counted):
         return tally + h, rows
 
     tally, out = jax.lax.scan(
-        piece, NO_TALLY,
+        piece, NO_COUNTS,
         (x.reshape(-1, MLP_ROWS, x.shape[1]), counted.reshape(-1, MLP_ROWS)))
     return out.reshape(S, -1), tally
 
@@ -459,7 +459,7 @@ def prefill(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray, length):
         return (x[None], tally + h), (k, v)
 
     (x, tally), (tails, states), (ks, vs) = _through_layers(
-        params, c, (_embed_scaled(params, c, tokens), NO_TALLY), mixer, attn)
+        params, c, (_embed_scaled(params, c, tokens), NO_COUNTS), mixer, attn)
     last = jax.lax.dynamic_index_in_dim(x[0], length - 1, keepdims=False)
     block = {"k": ks, "v": vs, "conv": tails[:, :, None],
              "ssm": states[:, None]}
@@ -504,6 +504,6 @@ def decode(params: Params, cfg: SsmMoEConfig, tokens: jnp.ndarray,
 
     (x, conv, ssm, tally), _, (ks, vs) = _through_layers(
         params, c, (_embed_scaled(params, c, tokens), state_of["conv"],
-                    state_of["ssm"], NO_TALLY), mixer, attn)
+                    state_of["ssm"], NO_COUNTS), mixer, attn)
     return (_head(params, c, x), {"k": ks, "v": vs, "conv": conv, "ssm": ssm},
             _counters(c, active, tally))

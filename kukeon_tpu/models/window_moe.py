@@ -47,7 +47,7 @@ import jax.numpy as jnp
 
 from kukeon_tpu.models import kv_kinds
 from kukeon_tpu.models.expert_layer import (
-    NO_TALLY, TALLY, expert_layer, swiglu)
+    COUNTS, NO_COUNTS, expert_layer_counts, swiglu)
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops.attention import blocked_attention, decode_gqa_attention
 from kukeon_tpu.ops.norms import rms_norm
@@ -56,7 +56,7 @@ from kukeon_tpu.ops.rope import apply_rope
 Params = dict[str, Any]
 SLIDING, FULL = "sliding_attention", "full_attention"
 # Device-summed counters a forward returns beside its logits, in this order.
-COUNTERS = ("kukeon_moe_routed_total", *TALLY)
+COUNTERS = ("kukeon_moe_routed_total", *COUNTS)
 PREFILL_BLOCK = 512     # query rows a prefill attends at once (a bucket's, if fewer)
 
 
@@ -304,15 +304,15 @@ def _attn_out(x, attn, gate, w: dict, c: WindowMoEConfig):
 
 def _mlp(x, w: dict, c: WindowMoEConfig, counted):
     """The dense SwiGLU or the expert layer, by the leaves the layer has;
-    returns (x', the expert layer's TALLY)."""
+    returns (x', the expert layer's COUNTS)."""
     h = rms_norm(x, w["norm3"], c.rms_norm_eps)
     if "router" in w:
-        m, tally = expert_layer(
+        m, tally = expert_layer_counts(
             h, w, experts_per_token=c.experts_per_token,
             experts_held=c.experts_held, route_norm=c.route_norm,
             route_scale=c.route_scale, counted=counted)
     else:
-        m, tally = swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_TALLY
+        m, tally = swiglu(h, w["w_gate"], w["w_up"], w["w_down"]), NO_COUNTS
     return x + rms_norm(m, w["norm4"], c.rms_norm_eps), tally
 
 
@@ -343,9 +343,9 @@ def _through_layers(params: Params, c: WindowMoEConfig, x, layer):
     """x through the unrolled head and ONE scan over the periods.
     ``layer(x, w, layer_type, number) -> (x', k, v, tally)``; ``number`` is
     the layer's place in the model (traced inside the scan). Returns (x, K, V
-    stacked over the layers in their order, the expert layers' TALLY)."""
+    stacked over the layers in their order, the expert layers' COUNTS)."""
     ks, vs = [], []
-    tally = NO_TALLY
+    tally = NO_COUNTS
     for number, (w, t) in enumerate(zip(params["head"], c.layer_types)):
         x, k, v, h = layer(x, w, t, number)
         tally = tally + h

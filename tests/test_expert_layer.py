@@ -5,6 +5,8 @@ each family's tiny preset through the engine with its other slots idle."""
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,7 @@ from kukeon_tpu.models import expert_layer as el
 from kukeon_tpu.models import sparse_latent_moe as slm
 from kukeon_tpu.models import ssm_moe as sm
 from kukeon_tpu.models import window_moe as wm
+from kukeon_tpu.obs import expo
 from kukeon_tpu.parallel import make_mesh
 from kukeon_tpu.serving import SamplingParams, ServingEngine
 from tests import test_sparse_latent_moe as t_slm
@@ -33,13 +36,14 @@ ROUTERS = {
 }
 
 
-@pytest.fixture(params=sorted(ROUTERS))
-def layer(request):
-    """(h [N, H], the share's weights, the layer's keywords, every row's
-    choices [N, k] and which of them this share holds, the shared expert's
-    output)."""
-    E, (first, count), kw = ROUTERS[request.param]
-    ks = jax.random.split(jax.random.key(len(request.param)), 9)
+def _layer(router: str, rows: int = N, held=None):
+    """(h [rows, H], the share's weights, the layer's keywords, every row's
+    choices [rows, k] and which of them this share holds, the shared expert's
+    output), the share ``held`` (first, count) where the router's own is not
+    wanted."""
+    E, (first, count), kw = ROUTERS[router]
+    first, count = held or (first, count)
+    ks = jax.random.split(jax.random.key(len(router)), 9)
     n = jax.random.normal
     w = {"router": n(ks[0], (H, E)),
          "e_gate": n(ks[2], (count, H, I)) * H ** -.5,
@@ -50,7 +54,7 @@ def layer(request):
          "s_down": n(ks[7], (I, H)) * I ** -.5}
     if kw.get("scoring") != el.SOFTMAX_SELECTED:
         w["bias"] = 0.05 * n(ks[1], (E,))
-    h = n(ks[8], (N, H))
+    h = n(ks[8], (rows, H))
     kw = dict(kw, experts_held=(first, count))
     route_kw = {k: v for k, v in kw.items()
                 if k in ("groups", "groups_kept", "scoring")}
@@ -59,6 +63,11 @@ def layer(request):
     held = np.asarray((sel >= first) & (sel < first + count))
     shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
     return h, w, kw, np.asarray(sel), held, np.asarray(shared)
+
+
+@pytest.fixture(params=sorted(ROUTERS))
+def layer(request):
+    return _layer(request.param)
 
 
 def _some(seed=0):
@@ -131,6 +140,101 @@ def test_the_hit_count_is_the_counted_rows_held_choices(layer):
         assert int(tally[0]) == int(held[counted].sum())
 
 
+# --- the routed products in blocks, against a plain sum -------------------------
+
+def _plain(h, w, kw, sel, held, counted, shared):
+    """float32, no sort and no ragged product: each counted row's own sum
+    over its chosen held experts, weighted as the router weighs them."""
+    route_kw = {k: v for k, v in kw.items()
+                if k in ("groups", "groups_kept", "scoring")}
+    _, wts = el.route(h, w["router"], w.get("bias"), kw["experts_per_token"],
+                      scale=kw.get("route_scale", 1.0), **route_kw)
+    h, wts = np.asarray(h, np.float64), np.asarray(wts, np.float64)
+    first = kw["experts_held"][0]
+    gate, up, down = (np.asarray(w[k], np.float64)
+                      for k in ("e_gate", "e_up", "e_down"))
+    out = np.asarray(shared, np.float64).copy()
+    for row in np.flatnonzero(counted):
+        for k in np.flatnonzero(held[row]):
+            e = sel[row, k] - first
+            g = h[row] @ gate[e]
+            out[row] += wts[row, k] * ((g / (1 + np.exp(-g)) * (h[row] @ up[e]))
+                                       @ down[e])
+    return out
+
+
+def _counted_with(held, pairs: int):
+    """Rows whose held choices number ``pairs`` exactly."""
+    counted, left = np.zeros(len(held), bool), pairs
+    for row in np.argsort(-held.sum(axis=1), kind="stable"):
+        if 0 < held[row].sum() <= left:
+            counted[row] = True
+            left -= held[row].sum()
+    assert left == 0, (pairs, held.sum())
+    return counted
+
+
+# rows, the block's rows (None: the rule's own), every expert held, and the
+# counted rows from which of them hold a chosen expert
+BLOCKS = {
+    "no pair held: zero trips": (32, 16, False, lambda held: np.zeros(32, bool)),
+    "every pair held, the last block past them": (
+        32, 28, True, lambda held: np.ones(32, bool)),
+    "a multiple of the block": (32, 16, False, lambda held: _counted_with(held, 32)),
+    "one more than a multiple": (32, 16, False, lambda held: _counted_with(held, 33)),
+    "one counted row of 32": (
+        32, 16, False, lambda held: np.arange(32) == np.argmax(held.sum(1))),
+    "a decode step: the pairs under one block": (
+        32, None, False, lambda held: np.arange(32) % 3 > 0),
+    "a decode step that holds no pair: its one block all the same": (
+        32, None, False, lambda held: np.zeros(32, bool)),
+    "a piece of several blocks that do not divide it": (
+        200, 56, False, lambda held: np.arange(200) % 5 > 0),
+}
+
+
+@pytest.mark.parametrize("router", ["sigmoid_groups", "softmax_selected"])
+@pytest.mark.parametrize("case", sorted(BLOCKS))
+def test_the_blocked_products_are_the_plain_sum_and_count_what_they_walk(
+        monkeypatch, router, case):
+    rows, block, every, counted_of = BLOCKS[case]
+    if block:
+        monkeypatch.setattr(el, "BLOCK_ROWS", block)
+    h, w, kw, sel, held, shared = _layer(
+        router, rows, held=(0, ROUTERS[router][0]) if every else None)
+    counted = counted_of(held)
+    y, counts = el.expert_layer_counts(h, w, counted=jnp.asarray(counted),
+                                       **kw)
+    np.testing.assert_allclose(
+        np.asarray(y), _plain(h, w, kw, sel, held, counted, shared),
+        atol=2e-5)
+    pairs = rows * kw["experts_per_token"]
+    rows_a_block = el.block_rows(pairs)
+    assert rows_a_block == min(block or el.BLOCK_ROWS, pairs)
+    hits = int(held[counted].sum())
+    assert el.COUNTS == el.TALLY + ("kukeon_moe_pair_rows_total",
+                                    "kukeon_moe_pair_rows_worked_total")
+    # whole blocks, as many as the held pairs fill; the one block that holds
+    # every pair of a call is walked once whatever it holds
+    worked = (pairs if rows_a_block == pairs
+              else -(-hits // rows_a_block) * rows_a_block)
+    assert np.asarray(counts).tolist() == [
+        hits, kw["experts_held"][1], len(set(sel[counted][held[counted]])),
+        pairs, worked]
+    if "zero trips" in case:
+        assert hits == 0
+    if every:
+        assert hits == pairs < int(counts[4])
+    if "multiple" in case:
+        assert hits % rows_a_block == ("one more" in case) and hits > block
+    if "under one block" in case:
+        assert 0 < hits < rows_a_block == pairs
+    if "holds no pair" in case:
+        assert hits == 0 and rows_a_block == pairs
+    if "several blocks" in case:
+        assert hits > 2 * rows_a_block and pairs % rows_a_block
+
+
 # --- through the engine, each family's tiny preset ------------------------------
 
 FAMILIES = {
@@ -186,3 +290,59 @@ def test_a_request_beside_idle_slots_and_a_padded_prompt_through_the_engine(
     # three of four slots idle: a step reaches no more than its one token hit
     assert 0 < reached <= min(total, hits)
     assert reached < total
+
+
+# --- what a boot pays for the blocked products ----------------------------------
+
+SETUP = {"sparse_latent_moe": (slm.sparse_latent_moe_tiny, 2),
+         "mixed_latent_moe": (slm.mixed_latent_moe_tiny, 3)}
+
+
+@pytest.mark.parametrize("preset", sorted(SETUP))
+def test_a_boot_holds_the_programs_it_held_and_one_loop_an_expert_layer(
+        monkeypatch, preset):
+    """The families that unroll their layers pay for what ``_routed`` traces
+    once a layer, bucket and boot pass. A boot and one request a bucket compile
+    the programs 9e503f6 compiled there, as often (its own readings); and a
+    lowered prefill whose pieces make more pairs than a block holds ONE loop
+    more than the same program around a routed part without one, however
+    many expert layers it unrolls (the routed part is a jitted function of
+    its own: no copy a layer, no branch a capacity), a decode chunk none."""
+    make, expert_layers = SETUP[preset]
+    monkeypatch.setattr(el, "BLOCK_ROWS", 64)   # of a 32-row piece's 128 pairs
+    cfg = make()
+    params = slm.init_params(jax.random.key(0), cfg)
+    mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
+
+    def engine():
+        return ServingEngine(cfg, params, mesh, num_slots=2, max_seq_len=64,
+                             decode_chunk=4, prefill_buckets=(16, 32))
+
+    def loops(eng):
+        key = jax.random.key(0)
+        with jax.set_mesh(mesh):
+            yield eng._prefill.lower(
+                eng._abstract_params, jax.ShapeDtypeStruct((1, 32), jnp.int32),
+                16, key, jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
+            ).as_text().count("stablehlo.while")
+            yield eng._decode_chunk.lower(
+                eng._abstract_params, eng._abstract_state(), key,
+                jnp.zeros(2, jnp.float32), jnp.zeros(2, jnp.int32),
+                jnp.ones(2, jnp.float32), 4).as_text().count("stablehlo.while")
+
+    eng = engine()
+    eng.precompile((16, 32))
+    for n in (9, 20):
+        req = eng.submit(np.arange(1, n + 1), SamplingParams(max_new_tokens=6))
+        while not req.done.is_set():
+            eng.step()
+    timed = dict(re.findall(
+        r'kukeon_compile_seconds_count\{program="(\w+)"\} (\d+)',
+        expo.render(eng.registry)))
+    assert timed == {"prefill": "2", "insert": "2", "decode": "1"}
+    with_loop = list(loops(eng))
+    monkeypatch.setattr(el, "_routed", lambda h, *_, **__: (
+        jnp.zeros(h.shape, jnp.float32), jnp.int32(0), jnp.int32(0)))
+    without = list(loops(engine()))
+    assert expert_layers > 1
+    assert [a - b for a, b in zip(with_loop, without)] == [1, 0]

@@ -117,10 +117,11 @@ def test_a_right_padded_prefill_gives_the_reference_logits_at_its_length(
         "ckv": (2, 1, bucket, 128), "kidx": (2, 1, bucket, 16),
         "wckv": (2, 1, bucket, 128)}
     assert slm.counters(cfg) == slm.COUNTERS + slm.WINDOW_COUNTERS
-    (routed, hits, held, reached, routed_tokens, selected, live, read,
-     rows_held) = np.asarray(counters)
+    (routed, hits, held, reached, pair_rows, worked, routed_tokens, selected,
+     live, read, rows_held) = np.asarray(counters)
     # three expert layers of 4 held experts, one piece each
     assert held == 3 * 4 and 0 < reached <= held
+    assert pair_rows == 3 * bucket * 4 and hits <= worked <= pair_rows
     assert routed_tokens == 3 * n and routed == 3 * n * 4
     assert 0 < hits < routed and selected == live == read == rows_held == 0
 

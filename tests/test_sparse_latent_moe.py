@@ -103,10 +103,13 @@ def test_a_right_padded_prefill_gives_the_reference_logits_at_its_length(
     assert np.abs(np.asarray(logits) - want).max() < 2e-4
     assert {k: v.shape for k, v in block.items()} == {
         "ckv": (3, 1, bucket, 128), "kidx": (3, 1, bucket, 16)}
-    routed, hits, held, reached, routed_tokens, selected, live = np.asarray(
-        counters)
-    # two expert layers of 4 held experts, one piece each
+    (routed, hits, held, reached, pair_rows, worked, routed_tokens, selected,
+     live) = np.asarray(counters)
+    # two expert layers of 4 held experts, one piece each: the bucket's rows
+    # make top-4 pairs, one block of them, walked where a pair is held
     assert held == 2 * 4 and 0 < reached <= held
+    assert pair_rows == 2 * bucket * 4 and hits <= worked <= pair_rows
+    assert worked % (bucket * 4) == 0
     assert routed_tokens == 2 * n and routed == 2 * n * 4
     assert 0 < hits < routed and selected == live == 0
 

@@ -112,8 +112,11 @@ def test_a_right_padded_prefill_gives_the_logits_and_the_state_at_its_length(
     last, block, counted = prefill(params, _padded(seq, n, 32), n)
     assert np.abs(np.asarray(last) - want[n - 1]).max() < TOL
     # every real token makes top-2 choices in each of the 8 layers
-    routed, hits, held, reached, pairs = (int(v) for v in counted)
+    routed, hits, held, reached, pair_rows, worked, pairs = (
+        int(v) for v in counted)
     assert (routed, pairs) == (n * 8 * 2, n * 8) and 0 <= hits <= routed
+    # the bucket's 32 rows make top-2 pairs in each layer: one block a layer
+    assert pair_rows == 8 * 32 * 2 and hits <= worked <= pair_rows
     # 8 layers of 4 held experts; n tokens reach at most n of a layer's
     assert held == 8 * 4 and reached <= min(held, hits)
     M, I, N = cfg.num_mixers, cfg.d_inner, cfg.d_state
@@ -218,8 +221,11 @@ def test_an_idle_slots_state_is_bit_for_bit_unchanged_by_a_step(tiny, prefill):
     assert np.asarray(after.lengths).tolist() == [11, 20]
     # one active slot: top-2 in each of 8 layers, which hold 4 experts each
     # and read at most the two it chose
-    routed, hits, held, reached, pairs = (int(v) for v in counted)
+    routed, hits, held, reached, pair_rows, worked, pairs = (
+        int(v) for v in counted)
     assert (routed, held, pairs) == (16, 32, 8) and reached <= hits <= 16
+    # two slots' top-2 pairs a layer are one block, walked where one is held
+    assert pair_rows == 8 * 4 and worked in range(0, pair_rows + 1, 4)
 
 
 def test_the_check_sees_a_lost_state(tiny, reference, prefill):
@@ -328,9 +334,10 @@ def test_the_engine_admits_two_slots_at_different_steps_and_reuses_one(
     assert 0 < held.value(what="active") < held.value(what="held")
     rows_read = eng.registry.get("kukeon_engine_decode_kv_rows_total")
     assert rows_read.value(what="held") > 0
-    routed, hits, held, reached, tokens = (
+    routed, hits, held, reached, pair_rows, worked, tokens = (
         eng.registry.get(name).value() for name in sm.COUNTERS)
     assert routed == 2 * tokens and 0 < hits < routed
+    assert hits <= worked <= pair_rows
     assert 0 < reached <= min(held, hits)
     # prompt tokens and decode steps of all three requests, in 8 layers
     assert tokens >= 8 * (5 + 19 + 2)
