@@ -11,11 +11,22 @@ chose an expert held elsewhere, past the last group, so a step reads the held
 experts its active slots reach and no other. What is walked is the FRONT of
 that order alone: blocks of ``block_rows`` sorted rows, as many as the pairs
 in a group fill, a trip count the program reads from its own data. A block
-gathers its rows of h, runs them through ``jax.lax.ragged_dot`` over the held
-stack with the group sizes clipped to the block, and adds each row, weighted,
+gathers its rows of h, runs them through the three products over the held
+stack with the groups clipped to the block, and adds each row, weighted,
 to its token's float32 output. Beside the index vectors of the sort nothing
 of ``tokens x top-k`` rows is made: what is allocated is a block and the
 [N, H] output.
+
+What runs the three products is chosen from what the call observes
+(``kernel_runs``; counted as ``kukeon_op_impl_traces_total{op=
+"expert_products"}``): on one TPU, with widths in lane tiles, the Pallas
+kernels of ``ops/expert_products.py``, two calls a block (``gate_up`` reads
+both stacks and emits ``silu(x Wg) * (x Wu)``, ``down`` the third product),
+each a walk over the experts that HAVE a row in the block with every reached
+expert's matrix streamed once; on any other backend, and on a mesh of several
+devices, ``jax.lax.ragged_dot``, three calls a block. The roundings are the
+same in both (a product rounds to the activations' dtype, ``silu`` runs in
+float32), the counts are the same counts.
 
   s   = sigmoid(float32(h) Wr)
   sel = top_k(s + b)                         b: selection only
@@ -46,6 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from kukeon_tpu.models.llama import mm
+from kukeon_tpu.ops import dispatch
+from kukeon_tpu.ops import expert_products as products
 
 
 def select(biased: jnp.ndarray, k: int, groups: int = 1,
@@ -113,8 +126,19 @@ def block_rows(pairs: int) -> int:
     return min(pairs, BLOCK_ROWS)
 
 
-@functools.partial(jax.jit, static_argnames="rows")
-def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int):
+def kernel_runs(rows: int, H: int, I: int, dtype, devices: int) -> bool:
+    """Whether a block's three products run as the Pallas kernels of
+    ``ops/expert_products.py`` (else as ``jax.lax.ragged_dot``): a TPU, no
+    ambient mesh or one of a single device (GSPMD does not partition a
+    ``pallas_call``), and shapes the kernel tiles."""
+    return (jax.default_backend() == "tpu" and devices <= 1
+            and products.supports(rows, H, I, dtype)
+            and products.supports(rows, I, H, dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "kernel"))
+def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int,
+            kernel: bool = False):
     """The held experts' part for h [N, H] in float32, the held experts
     reached and the rows worked over. The (token, choice) pairs are sorted by
     held expert, every other pair last, in no group (it chose an expert held
@@ -122,8 +146,10 @@ def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int):
     is walked: blocks of ``rows`` rows, as many as the ``held`` pairs fill, a
     count the program reads from its own data (where one block holds every
     pair of the call, that block once and no loop). A block gathers its rows
-    of h, runs the three ragged products with the group sizes clipped to it,
-    and adds each row, weighted, to its token's output (a product with the
+    of h, runs the three products over the held stacks with the groups
+    clipped to it (``kernel``: the two Pallas calls of
+    ``ops/expert_products.py``; else three ``jax.lax.ragged_dot``), and adds
+    each row, weighted, to its token's output (a product with the
     block's [N, rows] weighted one-hot: float32 sums on the MXU, no scatter).
     Nothing of ``N * K`` rows is made but index vectors; with no held pair
     the loop runs zero times and every token gets zeros.
@@ -137,6 +163,7 @@ def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int):
     order = jnp.argsort(flat)                       # stable: by expert
     sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
     ends = jnp.cumsum(sizes)
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
     blocks = (ends[-1] + rows - 1) // rows
     # whole blocks to slice: a pad row lies past the last group
     order = jnp.pad(order, (0, -(N * K) % rows))
@@ -147,13 +174,18 @@ def _routed(h, e_gate, e_up, e_down, local, held, wts, *, rows: int):
         pairs = jax.lax.dynamic_slice_in_dim(order, first, rows)
         in_group = first + jnp.arange(rows) < ends[-1]
         tokens = pairs // K
-        clipped = (jnp.clip(ends - first, 0, rows)
-                   - jnp.clip(ends - sizes - first, 0, rows))
         xs = jnp.take(h, tokens, axis=0)            # [rows, H]
-        gate = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, clipped)
-                           .astype(jnp.float32)).astype(h.dtype)
-        up = jax.lax.ragged_dot(xs, e_up, clipped)
-        y = jax.lax.ragged_dot(gate * up, e_down, clipped)
+        # the block's part of each group: its rows are offsets[e]:offsets[e+1]
+        offsets = jnp.clip(starts - first, 0, rows)
+        if kernel:
+            y = products.down(products.gate_up(xs, e_gate, e_up, offsets),
+                              e_down, offsets)
+        else:
+            clipped = jnp.diff(offsets)
+            gate = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, clipped)
+                               .astype(jnp.float32)).astype(h.dtype)
+            up = jax.lax.ragged_dot(xs, e_up, clipped)
+            y = jax.lax.ragged_dot(gate * up, e_down, clipped)
         # Rows past the last group belong to no expert; what a kernel leaves
         # there is not defined, so they are selected out, not multiplied out.
         y = jnp.where(in_group[:, None], y, 0)
@@ -198,10 +230,15 @@ def expert_layer_counts(h: jnp.ndarray, w: dict, *, experts_per_token: int,
                          groups_kept=groups_kept, scoring=scoring)
         local = sel - first
         held = (local >= 0) & (local < count) & counted.reshape(-1, 1)
+    rows = block_rows(held.size)
+    kernel = (w["e_gate"].dtype == x.dtype and kernel_runs(
+        rows, H, w["e_gate"].shape[2], x.dtype,
+        jax.sharding.get_abstract_mesh().size))
+    dispatch.note("expert_products", "pallas" if kernel else "xla")
     with jax.named_scope("expert_layer"):
         y, reached, worked = _routed(x, w["e_gate"], w["e_up"], w["e_down"],
-                                     local, held, wts,
-                                     rows=block_rows(held.size))
+                                     local, held, wts, rows=rows,
+                                     kernel=kernel)
     with jax.named_scope("shared_expert"):
         y = (y + swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
              ).astype(h.dtype)

@@ -337,10 +337,18 @@ def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
 
     mesh, eng, args = _abstract_cell(v5e, config)
     before = dispatch.counts().get(("decode_gqa_attention", "pallas"), 0)
+    products = dispatch.counts().get(("expert_products", "pallas"), 0)
     with jax.set_mesh(mesh):
         compiled = eng._decode_chunk.lower(*args, k).compile()
     assert dispatch.counts()[("decode_gqa_attention", "pallas")] > before
     text = compiled.as_text()
+    # a cell with an expert layer runs its routed products as the kernels of
+    # ops/expert_products.py (Trinity: 128 rows a step, 3072 x 3072 a held
+    # expert); a dense cell has neither them nor a ragged product
+    routed = "e_gate" in str(jax.tree_util.tree_structure(args[0]))
+    assert (dispatch.counts().get(("expert_products", "pallas"), 0)
+            > products) is routed
+    assert ("expert_products" in text) is routed and "ragged-dot" not in text
     assert "decode_attention" in text and "tpu_custom_call" in text
     held = args[1].cache.k           # one stack, or one a kind
     smallest = min(x.size // x.shape[0]
@@ -539,7 +547,9 @@ def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
     place), the prefill runs the selection and the masked attention and never
     a [S, S] array of scores."""
     from benchmark import rehearse_compile as rc
+    from kukeon_tpu.ops import dispatch
 
+    chosen = dispatch.counts().get(("expert_products", "pallas"), 0)
     mesh, eng, args = _abstract_cell(v5e, "deepseek-v3.2-exp-ep16-bf16")
     repl = NamedSharding(mesh, PartitionSpec())
     held, = args[1].cache.held
@@ -561,6 +571,13 @@ def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
     text = compiled.as_text()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < temp_gb * 1e9
+    # the expert layers' routed products (128 rows a decode step, blocks of
+    # 2048 a prefill; 7168 and 2048 wide) are ops/expert_products.py's, the
+    # held stacks operands where they lie
+    assert dispatch.counts()[("expert_products", "pallas")] > chosen
+    assert "expert_products" in text and "ragged-dot" not in text
+    assert not re.search(r"bf16\[16,(7168,2048|2048,7168)\]\S* (copy|fusion)\(",
+                         text)
     if program == "decode_chunk":
         assert "sparse_decode_index_scores" in text
         assert "sparse_decode_attention" in text
@@ -619,3 +636,66 @@ def test_state_update_kernel_compiles_with_a_decay_of_one_row_for_v5e(v5e):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 9 * 32 * (4 << 20)
     assert m.temp_size_in_bytes < 32 * (4 << 20) / 4
+
+
+# An expert layer's routed products at the four cells' widths (held experts,
+# H, I, a decode step's slots x top-k rows): the decode step's one block and
+# a prefill's block of 2048 rows, both forms. A width that does not tile, a
+# window that does not align or chunks that do not fit VMEM fail here and not
+# at a cell's boot.
+@pytest.mark.parametrize("rows", ["decode", 2048])
+@pytest.mark.parametrize("count, H, I, decode_rows", [
+    (36, 4096, 768, 320),       # granite-4.0-h-small-ep2-bf16
+    (32, 5120, 1536, 256),      # dots3-note-prev-ep8-bf16
+    (32, 3072, 3072, 128),      # trinity-large-preview-ep8-bf16
+    (16, 7168, 2048, 128),      # deepseek-v3.2-exp-ep16-bf16
+])
+def test_expert_products_compile_for_v5e(v5e, count, H, I, decode_rows, rows):
+    from kukeon_tpu.models import expert_layer as el
+    from kukeon_tpu.ops import expert_products as ep
+
+    d = v5e.devices[0]
+    rows = decode_rows if rows == "decode" else rows
+    bf16 = jnp.bfloat16
+    assert el.kernel_runs(rows, H, I, bf16, 1)
+    assert not el.kernel_runs(rows, H, I, bf16, 4)  # GSPMD partitions no kernel
+    offsets = _on(d, (count + 1,), jnp.int32)
+    for fn, operands in (
+            (ep.gate_up, (_on(d, (rows, H), bf16), _on(d, (count, H, I), bf16),
+                          _on(d, (count, H, I), bf16))),
+            (ep.down, (_on(d, (rows, I), bf16), _on(d, (count, I, H), bf16)))):
+        compiled = jax.jit(fn).lower(*operands, offsets).compile()
+        _assert_kernel(compiled)
+        text = compiled.as_text()
+        assert "expert_products" in text
+        # the stacks are operands where they lie: nothing of one's size is
+        # made, and beside the rows in and out nothing is allocated
+        assert _cache_sized_values(text, count * H * I) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_window_moe_cells_largest_prefill_runs_the_expert_kernels(v5e):
+    """trinity-large-preview-ep8-bf16's 8192 bucket through the cell's
+    launcher: 32768 (token, choice) pairs in blocks of 2048 under one loop an
+    expert layer, each block the two kernels of ``ops/expert_products.py``
+    over the held 32 x 3072 x 3072 stacks in place; no ``ragged-dot`` left,
+    nothing of a stack's size made, and the program fits beside the cache."""
+    from benchmark import rehearse_compile as rc
+    from kukeon_tpu.ops import dispatch
+
+    mesh, eng, args = _abstract_cell(v5e, "trinity-large-preview-ep8-bf16")
+    repl = NamedSharding(mesh, PartitionSpec())
+    chosen = dispatch.counts().get(("expert_products", "pallas"), 0)
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)  # noqa: E731
+    with jax.set_mesh(mesh):
+        compiled = eng._prefill.lower(
+            args[0], jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=repl),
+            scalar(jnp.int32), args[2], scalar(jnp.float32),
+            scalar(jnp.int32), scalar(jnp.float32)).compile()
+    assert dispatch.counts()[("expert_products", "pallas")] > chosen
+    text = compiled.as_text()
+    assert "expert_products" in text and "ragged-dot" not in text
+    assert not re.search(r"bf16\[32,3072,3072\]\S* (copy|fusion)\(", text)
+    cache = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(args[1].cache))
+    assert rc.resident(compiled) + cache < V5E_HBM_BYTES

@@ -57,7 +57,9 @@ def test_the_mixed_latent_cells_programs_fit_beside_its_caches(
     and the masked attention on the full layers and a band on the window
     layers, never ``[S, S]`` scores."""
     from benchmark import rehearse_compile as rc
+    from kukeon_tpu.ops import dispatch
 
+    chosen = dispatch.counts().get(("expert_products", "pallas"), 0)
     mesh, eng, args = _abstract_cell(v5e, CONFIG)
     repl = NamedSharding(mesh, PartitionSpec())
     latent, ring = args[1].cache.held
@@ -81,6 +83,12 @@ def test_the_mixed_latent_cells_programs_fit_beside_its_caches(
     text = compiled.as_text()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < temp_gb * 1e9
+    # the four expert layers' routed products (256 rows a decode step, blocks
+    # of 2048 a prefill; 5120 and 1536 wide) are ops/expert_products.py's
+    assert dispatch.counts()[("expert_products", "pallas")] > chosen
+    assert "expert_products" in text and "ragged-dot" not in text
+    assert not re.search(r"bf16\[32,(5120,1536|1536,5120)\]\S* (copy|fusion)\(",
+                         text)
     if program == "decode_chunk":
         for kernel in ("sparse_decode_index_scores", "sparse_decode_attention",
                        "window_latent_decode_attention"):

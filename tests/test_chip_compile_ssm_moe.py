@@ -32,7 +32,10 @@ def test_the_ssm_moe_cells_programs_copy_no_stack_and_fit_beside_its_cache(
     are unrolled: a weight is read where it lies, where a slice of a stack by
     a traced index was copied on its way into every ragged product, 0.9 GB a
     mixer and step). The prefill runs the chunked scan's kernel once a mixer
-    and fits beside the cache with more than 1 GB to spare."""
+    and fits beside the cache with more than 1 GB to spare. Every expert
+    layer's routed products are the kernels of ``ops/expert_products.py`` (a
+    decode step's 320 rows, a prefill's blocks of 2048; 4096 and 768 wide):
+    no ``ragged-dot`` is left in either program."""
     from benchmark import rehearse_compile as rc
     from kukeon_tpu.ops import dispatch
 
@@ -68,6 +71,8 @@ def test_the_ssm_moe_cells_programs_copy_no_stack_and_fit_beside_its_cache(
     assert m.temp_size_in_bytes < temp_gb * 1e9
     experts = args[0]["layers"][0]["e_gate"]
     assert experts.shape == (36, 4096, 768)
+    assert noted("expert_products") >= 1
+    assert "expert_products" in text and "ragged-dot" not in text
     if program == "decode_chunk":
         assert noted("state_update") == 9 and noted("decode_gqa_attention") == 1
         assert "decode_attention" in text and "ssm_state_update" in text

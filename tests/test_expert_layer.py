@@ -36,12 +36,15 @@ ROUTERS = {
 }
 
 
-def _layer(router: str, rows: int = N, held=None):
+def _layer(router: str, rows: int = N, held=None, widths=(H, I),
+           dtype=jnp.float32):
     """(h [rows, H], the share's weights, the layer's keywords, every row's
     choices [rows, k] and which of them this share holds, the shared expert's
     output), the share ``held`` (first, count) where the router's own is not
-    wanted."""
+    wanted; ``widths`` (H, I) and the activations' and experts' ``dtype``
+    where the module's are not."""
     E, (first, count), kw = ROUTERS[router]
+    H, I = widths
     first, count = held or (first, count)
     ks = jax.random.split(jax.random.key(len(router)), 9)
     n = jax.random.normal
@@ -54,7 +57,9 @@ def _layer(router: str, rows: int = N, held=None):
          "s_down": n(ks[7], (I, H)) * I ** -.5}
     if kw.get("scoring") != el.SOFTMAX_SELECTED:
         w["bias"] = 0.05 * n(ks[1], (E,))
-    h = n(ks[8], (rows, H))
+    h = n(ks[8], (rows, H)).astype(dtype)
+    w = {k: v if k in ("router", "bias") else v.astype(dtype)
+         for k, v in w.items()}
     kw = dict(kw, experts_held=(first, count))
     route_kw = {k: v for k, v in kw.items()
                 if k in ("groups", "groups_kept", "scoring")}
@@ -233,6 +238,65 @@ def test_the_blocked_products_are_the_plain_sum_and_count_what_they_walk(
         assert hits == 0 and rows_a_block == pairs
     if "several blocks" in case:
         assert hits > 2 * rows_a_block and pairs % rows_a_block
+
+
+# --- the same blocks through the Pallas products ---------------------------------
+
+KERNEL_BLOCKS = {
+    # rows, the block's rows (None: the rule's own), counted rows
+    "a decode step": (32, None, lambda rows: np.arange(rows) % 3 > 0),
+    "a decode step that holds no pair": (
+        32, None, lambda rows: np.zeros(rows, bool)),
+    "a piece of several blocks": (96, 144, lambda rows: np.arange(rows) % 5 > 0),
+}
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+@pytest.mark.parametrize("case", sorted(KERNEL_BLOCKS))
+def test_the_kernels_and_the_ragged_products_agree_and_count_the_same(
+        monkeypatch, router, case):
+    """What a TPU runs (``ops/expert_products.py``, here interpreted) beside
+    what every other backend runs, on bf16 rows at widths the kernel tiles:
+    within one bf16 step of the output, every count the same."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from kukeon_tpu.ops import dispatch
+
+    rows, block, counted_of = KERNEL_BLOCKS[case]
+    if block:
+        monkeypatch.setattr(el, "BLOCK_ROWS", block)
+    h, w, kw, _, _, _ = _layer(router, rows, widths=(256, 128),
+                               dtype=jnp.bfloat16)
+    counted = jnp.asarray(counted_of(rows))
+    before = dispatch.counts()
+    y_xla, counts_xla = el.expert_layer_counts(h, w, counted=counted, **kw)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        y, counts = el.expert_layer_counts(h, w, counted=counted, **kw)
+    after = dispatch.counts()
+    for impl in ("xla", "pallas"):
+        assert (after[("expert_products", impl)]
+                == before.get(("expert_products", impl), 0) + 1)
+    assert y.dtype == y_xla.dtype == jnp.bfloat16
+    assert np.asarray(counts).tolist() == np.asarray(counts_xla).tolist()
+    y, y_xla = (np.asarray(v, np.float32) for v in (y, y_xla))
+    assert np.all(np.isfinite(y))
+    assert np.abs(y - y_xla).max() <= 2.0 ** -7 * max(1.0, np.abs(y_xla).max())
+    if "no pair" in case:
+        assert int(counts[0]) == 0
+        np.testing.assert_array_equal(y, y_xla)
+    if "several" in case:
+        assert int(counts[4]) > el.block_rows(rows * kw["experts_per_token"])
+
+
+def test_off_a_tpu_and_on_a_mesh_of_several_the_ragged_products_stay(
+        monkeypatch):
+    assert not el.kernel_runs(320, 4096, 768, jnp.bfloat16, 1)      # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert el.kernel_runs(320, 4096, 768, jnp.bfloat16, 1)
+    assert el.kernel_runs(2048, 5120, 1536, jnp.bfloat16, 0)   # no mesh set
+    assert not el.kernel_runs(320, 4096, 768, jnp.bfloat16, 4)
+    assert not el.kernel_runs(320, H, I, jnp.float32, 1)    # the tiny presets
 
 
 # --- through the engine, each family's tiny preset ------------------------------
