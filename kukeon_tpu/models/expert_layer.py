@@ -245,10 +245,3 @@ def expert_layer_counts(h: jnp.ndarray, w: dict, *, experts_per_token: int,
     return y.reshape(*lead, H), jnp.stack(
         [jnp.sum(held, dtype=jnp.int32), jnp.int32(count), reached,
          jnp.int32(held.size), jnp.int32(worked)])
-
-
-def expert_layer(h: jnp.ndarray, w: dict, **how
-                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``expert_layer_counts`` with the TALLY alone, int32 [3]."""
-    y, counts = expert_layer_counts(h, w, **how)
-    return y, counts[:len(TALLY)]

@@ -46,13 +46,6 @@ class LlamaConfig:
     max_seq_len: int = 8192
     tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16
-    # Route quantized decode matmuls through the Pallas int8 kernel
-    # (ops/int8_matmul.py) instead of XLA's dequant-fused dot. Measured at
-    # parity with XLA 0.9's fusion on v5e (both stream int8 at the HBM roof);
-    # kept as an explicit switch so the kernel path stays exercised and the
-    # win is guaranteed on XLA versions whose fusion regresses. Enable via
-    # ServingEngine(int8_pallas=...) or directly; ignored for bf16 params.
-    int8_pallas: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -350,20 +343,9 @@ def _is_q(w) -> bool:
     return isinstance(w, dict) and "q" in w
 
 
-def mm(h: jnp.ndarray, w, pallas: bool = False) -> jnp.ndarray:
-    """h @ w for plain or quantized weights (dequant fused into the dot).
-
-    ``pallas=True`` routes int8 weights through the Pallas kernel (decode
-    path); the kernel itself falls back to the XLA fused dot for odd shapes
-    or large batches (prefill), so callers can pass the flag unconditionally.
-    """
+def mm(h: jnp.ndarray, w) -> jnp.ndarray:
+    """h @ w for plain or quantized weights (dequant fused into the dot)."""
     if _is_q(w):
-        if pallas:
-            from kukeon_tpu.ops.int8_matmul import int8_matmul
-
-            lead = h.shape[:-1]
-            out = int8_matmul(h.reshape(-1, h.shape[-1]), w["q"], w["s"])
-            return out.reshape(*lead, out.shape[-1])
         dispatch.note("int8_matmul", "xla")
         return (h @ w["q"].astype(h.dtype)) * w["s"].astype(h.dtype)
     return h @ w
@@ -379,23 +361,14 @@ def embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
 
 
 @jax.named_scope("lm_head")
-def _logits(params: Params, c: LlamaConfig, x: jnp.ndarray,
-            pallas: bool = False) -> jnp.ndarray:
+def _logits(params: Params, c: LlamaConfig, x: jnp.ndarray) -> jnp.ndarray:
     if c.tie_embeddings:
         e = params["embed"]
         if _is_q(e):
-            if pallas:
-                from kukeon_tpu.ops.int8_matmul import int8_matmul
-
-                lead = x.shape[:-1]
-                out = int8_matmul(
-                    x.reshape(-1, x.shape[-1]), e["q"], e["s"], transpose=True
-                )
-                return out.reshape(*lead, out.shape[-1]).astype(jnp.float32)
             raw = jnp.einsum("bsh,vh->bsv", x, e["q"].astype(x.dtype))
             return (raw * e["s"].astype(x.dtype)).astype(jnp.float32)
         return jnp.einsum("bsh,vh->bsv", x, e).astype(jnp.float32)
-    return _mm(x, params["lm_head"], pallas).astype(jnp.float32)
+    return _mm(x, params["lm_head"]).astype(jnp.float32)
 
 
 # The names this file's own forward and older callers use; ``mm``, ``embed``
@@ -409,7 +382,7 @@ _mm, _embed, _cache_insert = mm, embed, cache_insert
 # is metadata on the operations (the compiled code is the same), and it is
 # what a device trace can name a fused operation by after a refactor.
 
-def _qkv(x, w: dict, c: LlamaConfig, positions, pallas: bool = False):
+def _qkv(x, w: dict, c: LlamaConfig, positions):
     """x [B, S, H] -> rotated q [B, S, NH, D] and k, v [B, S, KV, D]."""
     B, S = x.shape[:2]
     with jax.named_scope("attn_norm"):
@@ -427,8 +400,7 @@ def _qkv(x, w: dict, c: LlamaConfig, positions, pallas: bool = False):
         # (tests/test_chip_compile.py,
         # test_a_dense_cells_programs_make_no_value_of_a_weights_size).
         q, k, v = jax.lax.optimization_barrier((
-            _mm(h, w["wq"], pallas), _mm(h, w["wk"], pallas),
-            _mm(h, w["wv"], pallas)))
+            _mm(h, w["wq"]), _mm(h, w["wk"]), _mm(h, w["wv"])))
         q = q.reshape(B, S, c.num_heads, c.head_dim)
         k = k.reshape(B, S, c.num_kv_heads, c.head_dim)
         v = v.reshape(B, S, c.num_kv_heads, c.head_dim)
@@ -438,22 +410,22 @@ def _qkv(x, w: dict, c: LlamaConfig, positions, pallas: bool = False):
     return q, k, v
 
 
-def _wo(x, attn, w: dict, c: LlamaConfig, pallas: bool = False):
+def _wo(x, attn, w: dict, c: LlamaConfig):
     """The attention block's output projection and residual."""
     B, S = x.shape[:2]
     with jax.named_scope("wo"):
-        return x + _mm(attn.reshape(B, S, c.q_dim), w["wo"], pallas)
+        return x + _mm(attn.reshape(B, S, c.q_dim), w["wo"])
 
 
-def _mlp(x, w: dict, c: LlamaConfig, pallas: bool = False):
+def _mlp(x, w: dict, c: LlamaConfig):
     """The SwiGLU block and its residual."""
     with jax.named_scope("mlp_norm"):
         h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
     with jax.named_scope("mlp"):
         gate = jax.nn.silu(
-            _mm(h, w["w_gate"], pallas).astype(jnp.float32)).astype(c.dtype)
-        up = _mm(h, w["w_up"], pallas)
-        return x + _mm(gate * up, w["w_down"], pallas)
+            _mm(h, w["w_gate"]).astype(jnp.float32)).astype(c.dtype)
+        up = _mm(h, w["w_up"])
+        return x + _mm(gate * up, w["w_down"])
 
 
 def transformer_block(
@@ -614,15 +586,14 @@ def _decode_forward(
 
     offsets = cache.lengths
     reads = offsets if active is None else jnp.where(active, offsets, 0)
-    pl8 = c.int8_pallas
 
     def layer_step(x, layer):
         w, i = layer
-        q, k, v = _qkv(x, w, c, positions, pl8)
+        q, k, v = _qkv(x, w, c, positions)
         attn = decode_gqa_attention(
             q, k, v, cache.k, cache.v, i, reads, k_scale=cache.k_scale,
             v_scale=cache.v_scale)
-        return _mlp(_wo(x, attn, w, c, pl8), w, c, pl8), (k, v)
+        return _mlp(_wo(x, attn, w, c), w, c), (k, v)
 
     # The stacks are read at the layer's index, not scanned over: a kernel
     # takes the whole stack as its operand and reads the layer in place.
@@ -649,4 +620,4 @@ def _decode_forward(
                         k_scale=ks_upd, v_scale=vs_upd)
 
     x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    return _logits(params, c, x, pl8), new_cache
+    return _logits(params, c, x), new_cache

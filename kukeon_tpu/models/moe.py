@@ -61,12 +61,6 @@ class MoEConfig:
     dtype: Any = jnp.bfloat16
     router_z_coef: float = 1e-3
     load_balance_coef: float = 1e-2
-    # Route quantized decode matmuls (attention trunk via llama.mm, expert
-    # stacks via ops.int8_matmul.int8_matmul_expert) through the Pallas
-    # int8 kernel — same contract as LlamaConfig.int8_pallas, same engine
-    # auto-routing, XLA fallback off-TPU. Prefill always keeps XLA's
-    # dequant-fused dots (MXU-bound there).
-    int8_pallas: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -209,19 +203,10 @@ def init_quantized_params_host(cfg: MoEConfig, seed: int = 0) -> Params:
     return params
 
 
-def _expert_mm(x: jnp.ndarray, w, eq: str, pallas: bool = False) -> jnp.ndarray:
+def _expert_mm(x: jnp.ndarray, w, eq: str) -> jnp.ndarray:
     """Per-expert batched matmul ('ech,ehi->eci' or 'eci,eih->ech') for
-    plain or int8 ({"q","s"}) expert stacks; dequant fuses into the dot.
-
-    ``pallas=True`` routes int8 stacks through the Pallas decode kernel
-    (both einsums above are x [E, C, K] @ w [E, K, N], so one helper covers
-    them); the helper itself falls back to the XLA fused einsum for odd
-    shapes, prefill-sized C, or non-TPU backends."""
+    plain or int8 ({"q","s"}) expert stacks; dequant fuses into the dot."""
     if llama._is_q(w):
-        if pallas:
-            from kukeon_tpu.ops.int8_matmul import int8_matmul_expert
-
-            return int8_matmul_expert(x, w["q"], w["s"])
         raw = jnp.einsum(eq, x, w["q"].astype(x.dtype))
         return raw * w["s"][:, None, :].astype(x.dtype)
     return jnp.einsum(eq, x, w)
@@ -249,8 +234,7 @@ def _capacity(cfg: MoEConfig, n_tokens: int, inference: bool = False) -> int:
 
 
 def moe_block(h: jnp.ndarray, w: dict, cfg: MoEConfig,
-              inference: bool = False,
-              pallas: bool = False) -> tuple[jnp.ndarray, dict]:
+              inference: bool = False) -> tuple[jnp.ndarray, dict]:
     """Sparse-MoE SwiGLU over [B, S, H] -> ([B, S, H], aux losses).
 
     GShard dense-dispatch: top-k routing -> static-capacity one-hot dispatch
@@ -290,10 +274,10 @@ def moe_block(h: jnp.ndarray, w: dict, cfg: MoEConfig,
     # Dispatch -> per-expert batches -> SwiGLU -> combine.
     xe = jnp.einsum("nec,nh->ech", dispatch, x).astype(c.dtype)  # [E, C, H]
     gate = jax.nn.silu(
-        _expert_mm(xe, w["w_gate"], "ech,ehi->eci", pallas).astype(jnp.float32)
+        _expert_mm(xe, w["w_gate"], "ech,ehi->eci").astype(jnp.float32)
     ).astype(c.dtype)
-    up = _expert_mm(xe, w["w_up"], "ech,ehi->eci", pallas)
-    ye = _expert_mm(gate * up, w["w_down"], "eci,eih->ech", pallas)  # [E, C, H]
+    up = _expert_mm(xe, w["w_up"], "ech,ehi->eci")
+    ye = _expert_mm(gate * up, w["w_down"], "eci,eih->ech")  # [E, C, H]
     y = jnp.einsum("nec,ech->nh", combine.astype(c.dtype), ye)
 
     # Aux losses (f32): Switch load-balance (E * sum_e f_e * P_e; 1.0 at
@@ -319,30 +303,26 @@ def _decode_forward(
     per-layer new K/V; the cache is updated once per step with per-slot
     in-place slice writes — cache bytes stream through HBM exactly once).
     The MoE block runs at N = B tokens, where dense dispatch is a few KB
-    and capacity is exact (no drops). With ``cfg.int8_pallas`` every
-    quantized matmul — attention trunk and expert stacks — reads int8
-    straight from HBM through the Pallas kernel instead of materializing a
-    dequantized copy per step."""
+    and capacity is exact (no drops)."""
     from kukeon_tpu.ops.attention import decode_gqa_attention
 
     offsets = cache.lengths
     reads = offsets if active is None else jnp.where(active, offsets, 0)
-    pl8 = c.int8_pallas
 
     def layer_step(x, layer):
         w, i = layer
         h = rms_norm(x, w["attn_norm"], c.rms_norm_eps)
-        q = mm(h, w["wq"], pl8).reshape(B, 1, c.num_heads, c.head_dim)
-        k = mm(h, w["wk"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        v = mm(h, w["wv"], pl8).reshape(B, 1, c.num_kv_heads, c.head_dim)
+        q = mm(h, w["wq"]).reshape(B, 1, c.num_heads, c.head_dim)
+        k = mm(h, w["wk"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
+        v = mm(h, w["wv"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)
 
         attn = decode_gqa_attention(q, k, v, cache.k, cache.v, i, reads)
-        x = x + mm(attn.reshape(B, 1, c.q_dim), w["wo"], pl8)
+        x = x + mm(attn.reshape(B, 1, c.q_dim), w["wo"])
 
         h = rms_norm(x, w["mlp_norm"], c.rms_norm_eps)
-        y, _ = moe_block(h, w, c, inference=True, pallas=pl8)
+        y, _ = moe_block(h, w, c, inference=True)
         return x + y, (k, v)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -355,7 +335,7 @@ def _decode_forward(
     new_cache = KVCache(k=k_upd, v=v_upd, lengths=cache.lengths + 1)
 
     x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    return llama._logits(params, c, x, pl8), new_cache
+    return llama._logits(params, c, x), new_cache
 
 
 def forward_with_aux(

@@ -66,12 +66,13 @@ of more than one chip, the multi-token-prediction module, training.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from kukeon_tpu.models import kv_kinds
+from kukeon_tpu.models import drawn, kv_kinds
 from kukeon_tpu.models.expert_layer import (
     COUNTS, NO_COUNTS, expert_layer_counts, select, swiglu)
 from kukeon_tpu.models.llama import embed, mm
@@ -333,8 +334,7 @@ def sparse_latent_moe_tiny() -> SparseLatentMoEConfig:
 
 # --- Init --------------------------------------------------------------------
 #
-# The weights ARE their recipe, as in ``models/window_moe.py``: a leaf is a
-# seeded Gaussian under a key folded from (seed, leaf name, layer, expert), and
+# The weights ARE their recipe (``models/drawn.py``): this family's table, and
 # ``benchmark/reference/sparse_latent_moe.py`` draws the same values without
 # importing this file (tests/bench pins the two).
 
@@ -348,30 +348,12 @@ LEAVES = ("embed", "lm_head", "final_norm", "norm1", "norm2", "wq_a",
           "e_down")
 # Leaves only a config with ``head_gate`` has; their keys follow LEAVES'.
 GATE_LEAVES = ("wg",)
-GAIN_STD = 0.1
 SHIFT_STD = 0.1
 BIAS_SAMPLES = 1 << 16
 BIAS_STEPS = 32
 BIAS_STEP = 0.02
 
-
-def _leaf_key(key, name: str, layer=None, expert=None):
-    key = jax.random.fold_in(key, (LEAVES + GATE_LEAVES).index(name))
-    if layer is not None:
-        key = jax.random.fold_in(key, layer)
-    if expert is not None:
-        key = jax.random.fold_in(key, expert)
-    return key
-
-
-def _matrix(key, shape, fan_in, dtype):
-    return (jax.random.normal(key, shape, jnp.float32)
-            * fan_in ** -0.5).astype(dtype)
-
-
-def _gain(key, shape, dtype):
-    return (1.0 + GAIN_STD * jax.random.normal(key, shape, jnp.float32)
-            ).astype(dtype)
+_key = functools.partial(drawn.leaf_key, LEAVES + GATE_LEAVES)
 
 
 def _bias(key, router, gain, c: SparseLatentMoEConfig) -> jnp.ndarray:
@@ -456,29 +438,21 @@ def _layer_leaves(c: SparseLatentMoEConfig, dense: bool,
 def _draw(key, c, name, kind, shape, fan_in, layer):
     if kind in ("up_k", "up_v"):
         a = c.attention(layer)
-        both = _matrix(_leaf_key(key, "wkv_b", layer), shape, fan_in, c.dtype
-                       ).reshape(shape[0], a.num_heads, -1)
+        both = drawn.matrix(_key(key, "wkv_b", layer), shape, fan_in, c.dtype
+                            ).reshape(shape[0], a.num_heads, -1)
         n = a.qk_nope_head_dim
         return (jnp.transpose(both[..., :n], (1, 2, 0)) if kind == "up_k"
                 else jnp.transpose(both[..., n:], (1, 0, 2)))
-    k = _leaf_key(key, name, layer)
-    if kind == "gain":
-        return _gain(k, shape, c.dtype)
     if kind == "shift":
-        return (SHIFT_STD * jax.random.normal(k, shape, jnp.float32)
-                ).astype(c.dtype)
-    if kind == "router":
-        return _matrix(k, shape, fan_in, jnp.float32)
+        return (SHIFT_STD * jax.random.normal(_key(key, name, layer), shape,
+                                              jnp.float32)).astype(c.dtype)
     if kind == "bias":      # fitted to the layer's own router and norm
         spec = _layer_leaves(c, False)
-        return _bias(k, _draw(key, c, "router", *spec["router"], layer),
+        return _bias(_key(key, name, layer),
+                     _draw(key, c, "router", *spec["router"], layer),
                      _draw(key, c, "norm2", *spec["norm2"], layer), c)
-    if kind == "experts":
-        first, count = c.experts_held
-        return jax.lax.map(
-            lambda e: _matrix(_leaf_key(key, name, layer, e), shape, fan_in,
-                              c.dtype), first + jnp.arange(count))
-    return _matrix(k, shape, fan_in, c.dtype)
+    return drawn.draw(LEAVES + GATE_LEAVES, key, c, name, kind, shape, fan_in,
+                      layer)
 
 
 def _draw_params(key: jax.Array, c: SparseLatentMoEConfig) -> Params:
@@ -490,9 +464,9 @@ def _draw_params(key: jax.Array, c: SparseLatentMoEConfig) -> Params:
         # the token (0.022 against 0.012 a value at the published widths),
         # every router then sees one direction a sequence, and a seed's
         # prompts decide which experts work
-        "embed": _matrix(_leaf_key(key, "embed"), (V, H), 1, c.dtype),
-        "lm_head": _matrix(_leaf_key(key, "lm_head"), (H, V), H, c.dtype),
-        "final_norm": _gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
+        "embed": drawn.matrix(_key(key, "embed"), (V, H), 1, c.dtype),
+        "lm_head": drawn.matrix(_key(key, "lm_head"), (H, V), H, c.dtype),
+        "final_norm": drawn.gain(_key(key, "final_norm"), (H,), c.dtype),
         "layers": [
             {name: _draw(key, c, name, *spec, i)
              for name, spec in _layer_leaves(
@@ -501,19 +475,8 @@ def _draw_params(key: jax.Array, c: SparseLatentMoEConfig) -> Params:
     }
 
 
-def init_params(key: jax.Array, cfg: SparseLatentMoEConfig,
-                shardings: Any = None) -> Params:
-    """Checkpoint-less init on the device in ONE jitted program that takes
-    the key as its argument (``window_moe.init_params`` says why)."""
-    return jax.jit(lambda k: _draw_params(k, cfg),
-                   out_shardings=shardings)(key)
-
-
-def param_specs(params: Params):
-    """Everything whole on the one chip."""
-    from jax.sharding import PartitionSpec
-
-    return jax.tree.map(lambda _: PartitionSpec(), params)
+init_params = functools.partial(drawn.init, _draw_params)
+param_specs = drawn.whole
 
 
 # --- The block ---------------------------------------------------------------

@@ -44,6 +44,7 @@ training (the scan has no backward pass).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -51,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kukeon_tpu.models import kv_kinds
+from kukeon_tpu.models import drawn, kv_kinds
 from kukeon_tpu.models.expert_layer import swiglu
 from kukeon_tpu.models.llama import embed, mm
 from kukeon_tpu.ops import selective_scan as ss
@@ -154,9 +155,8 @@ def ssm_hybrid_tiny() -> SsmHybridConfig:
 
 # --- Init --------------------------------------------------------------------
 #
-# The weights ARE their recipe, as in ``models/window_moe.py``: a leaf is drawn
-# under a key folded from (seed, leaf name, the layer's number in the model),
-# and ``benchmark/reference/ssm_hybrid.py`` draws the same values without
+# The weights ARE their recipe (``models/drawn.py``): this family's table, and
+# ``benchmark/reference/ssm_hybrid.py`` draws the same values without
 # importing this file (tests/bench pins the two). The recipe leaves the state a
 # long memory (a channel's decay a step runs from 0.999 to 0.2), or a check
 # against the reference could not see a lost state: A = -(1 .. d_state) in
@@ -166,25 +166,11 @@ def ssm_hybrid_tiny() -> SsmHybridConfig:
 LEAVES = ("embed", "final_norm", "norm1", "norm2", "w_gate", "w_up", "w_down",
           "wq", "wk", "wv", "wo", "w_in", "conv_w", "conv_b", "w_x", "dt_norm",
           "b_norm", "c_norm", "w_dt", "b_dt", "w_out")
-GAIN_STD = 0.1
 CONV_BIAS_STD = 0.1
 DT_SCALE = 0.1
 DT_MIN, DT_MAX = 1e-3, 1e-1
 
-
-def _leaf_key(key, name: str, layer=None):
-    key = jax.random.fold_in(key, LEAVES.index(name))
-    return key if layer is None else jax.random.fold_in(key, layer)
-
-
-def matrix(key, shape, fan_in, dtype, scale=1.0):
-    return (jax.random.normal(key, shape, jnp.float32)
-            * (fan_in ** -0.5 * scale)).astype(dtype)
-
-
-def gain(key, shape, dtype):
-    return (1.0 + GAIN_STD * jax.random.normal(key, shape, jnp.float32)
-            ).astype(dtype)
+_key = functools.partial(drawn.leaf_key, LEAVES)
 
 
 def dt_bias(key, shape):
@@ -221,19 +207,17 @@ def _layer_leaves(c: SsmHybridConfig, mixer: bool) -> dict:
 
 
 def _draw(key, c: SsmHybridConfig, name, kind, shape, fan_in, layer):
-    k = _leaf_key(key, name, layer)
-    if kind == "gain":
-        return gain(k, shape, c.dtype)
+    k = _key(key, name, layer)
     if kind == "taps":
-        return matrix(k, shape, fan_in, c.dtype).T
+        return drawn.matrix(k, shape, fan_in, c.dtype).T
     if kind == "conv_bias":
         return (CONV_BIAS_STD * jax.random.normal(k, shape, jnp.float32)
                 ).astype(c.dtype)
     if kind == "dt":
-        return matrix(k, shape, fan_in, c.dtype, DT_SCALE)
+        return drawn.matrix(k, shape, fan_in, c.dtype, DT_SCALE)
     if kind == "dt_bias":
         return dt_bias(k, shape)
-    return matrix(k, shape, fan_in, c.dtype)
+    return drawn.draw(LEAVES, key, c, name, kind, shape, fan_in, layer)
 
 
 def _draw_params(key: jax.Array, c: SsmHybridConfig) -> Params:
@@ -255,25 +239,14 @@ def _draw_params(key: jax.Array, c: SsmHybridConfig) -> Params:
         (M, N, I))
     mamba["d_skip"] = jnp.ones((M, I), jnp.float32)
     return {
-        "embed": matrix(_leaf_key(key, "embed"), (c.vocab_size, H), H,
-                        c.dtype),
-        "final_norm": gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
+        "embed": drawn.matrix(_key(key, "embed"), (c.vocab_size, H), H,
+                              c.dtype),
+        "final_norm": drawn.gain(_key(key, "final_norm"), (H,), c.dtype),
         "mamba": mamba, "attn": stack(False)}
 
 
-def init_params(key: jax.Array, cfg: SsmHybridConfig,
-                shardings: Any = None) -> Params:
-    """Checkpoint-less init on the device in ONE jitted program that takes
-    the key as its argument (``window_moe.init_params`` says why)."""
-    return jax.jit(lambda k: _draw_params(k, cfg),
-                   out_shardings=shardings)(key)
-
-
-def param_specs(params: Params):
-    """Everything whole on the one chip."""
-    from jax.sharding import PartitionSpec
-
-    return jax.tree.map(lambda _: PartitionSpec(), params)
+init_params = functools.partial(drawn.init, _draw_params)
+param_specs = drawn.whole
 
 
 # --- The block ---------------------------------------------------------------

@@ -59,12 +59,13 @@ training.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from kukeon_tpu.models import kv_kinds
+from kukeon_tpu.models import drawn, kv_kinds
 from kukeon_tpu.models import ssm_hybrid as sh
 from kukeon_tpu.models.expert_layer import (
     COUNTS, NO_COUNTS, SOFTMAX_SELECTED, expert_layer_counts)
@@ -176,10 +177,7 @@ def ssm_moe_tiny() -> SsmMoEConfig:
 
 # --- Init --------------------------------------------------------------------
 #
-# The weights ARE their recipe, as in the other layered families: a leaf is
-# drawn under a key folded from (seed, leaf name, the layer's number in the
-# model, the expert's number among all the router scores), so a chip that
-# holds experts 36-71 draws exactly those, and
+# The weights ARE their recipe (``models/drawn.py``): this family's table, and
 # ``benchmark/reference/ssm_moe.py`` draws the same values without importing
 # this file (tests/bench pins the two). The scan's leaves leave the state a
 # long memory, or a check against the reference could not see a lost state:
@@ -211,13 +209,7 @@ BC_SCALE = 2.0      # a power of two: exact in every dtype
 A_MIN, A_MAX = 1.0, 16.0
 
 
-def _leaf_key(key, name: str, layer=None, expert=None):
-    key = jax.random.fold_in(key, LEAVES.index(name))
-    if layer is not None:
-        key = jax.random.fold_in(key, layer)
-    if expert is not None:
-        key = jax.random.fold_in(key, expert)
-    return key
+_key = functools.partial(drawn.leaf_key, LEAVES)
 
 
 def _layer_leaves(c: SsmMoEConfig, mixer: bool) -> dict:
@@ -250,34 +242,25 @@ def _layer_leaves(c: SsmMoEConfig, mixer: bool) -> dict:
 
 
 def _draw(key, c: SsmMoEConfig, name, kind, shape, fan_in, layer):
-    k = _leaf_key(key, name, layer)
-    if kind == "gain":
-        return sh.gain(k, shape, c.dtype)
-    if kind == "router":
-        return sh.matrix(k, shape, fan_in, jnp.float32)
-    if kind == "experts":
-        first, count = c.experts_held
-        return jax.lax.map(
-            lambda e: sh.matrix(_leaf_key(key, name, layer, e), shape, fan_in,
-                                c.dtype), first + jnp.arange(count))
+    k = _key(key, name, layer)
     if kind == "wide":
-        return sh.matrix(k, shape, fan_in, c.dtype, c.head_dim ** 0.25)
+        return drawn.matrix(k, shape, fan_in, c.dtype, c.head_dim ** 0.25)
     if kind == "in_proj":
         wide = jnp.arange(shape[1]) >= 2 * c.d_inner        # B and C
-        return sh.matrix(k, shape, fan_in, c.dtype) * jnp.where(
+        return drawn.matrix(k, shape, fan_in, c.dtype) * jnp.where(
             wide, BC_SCALE, 1.0).astype(c.dtype)
     if kind == "taps":
-        return sh.matrix(k, shape, fan_in, c.dtype).T
+        return drawn.matrix(k, shape, fan_in, c.dtype).T
     if kind == "conv_bias":
         return (CONV_BIAS_STD * jax.random.normal(k, shape, jnp.float32)
                 ).astype(c.dtype)
     if kind == "dt":
-        return sh.matrix(k, shape, fan_in, c.dtype, DT_SCALE)
+        return drawn.matrix(k, shape, fan_in, c.dtype, DT_SCALE)
     if kind == "dt_bias":
         return sh.dt_bias(k, shape)
     if kind == "a_log":
         return jnp.log(jax.random.uniform(k, shape, jnp.float32, A_MIN, A_MAX))
-    return sh.matrix(k, shape, fan_in, c.dtype)
+    return drawn.draw(LEAVES, key, c, name, kind, shape, fan_in, layer)
 
 
 def _draw_params(key: jax.Array, c: SsmMoEConfig) -> Params:
@@ -290,25 +273,18 @@ def _draw_params(key: jax.Array, c: SsmMoEConfig) -> Params:
             w["d_skip"] = jnp.ones((c.mamba_heads,), jnp.float32)
         return w
 
-    final = sh.gain(_leaf_key(key, "final_norm"), (H,), jnp.float32)
+    final = drawn.gain(_key(key, "final_norm"), (H,), jnp.float32)
     return {
-        "embed": sh.matrix(_leaf_key(key, "embed"), (c.vocab_size, H), H,
-                           c.dtype, 1.0 / c.embedding_multiplier),
+        "embed": drawn.matrix(_key(key, "embed"), (c.vocab_size, H), H,
+                              c.dtype, 1.0 / c.embedding_multiplier),
         "final_norm": (final * (c.logits_scaling * c.embedding_multiplier)
                        ).astype(c.dtype),
         "layers": [layer(i, t == "mamba")
                    for i, t in enumerate(c.layer_types)]}
 
 
-def init_params(key: jax.Array, cfg: SsmMoEConfig,
-                shardings: Any = None) -> Params:
-    """Checkpoint-less init on the device in ONE jitted program that takes
-    the key as its argument (``window_moe.init_params`` says why)."""
-    return jax.jit(lambda k: _draw_params(k, cfg),
-                   out_shardings=shardings)(key)
-
-
-param_specs = sh.param_specs
+init_params = functools.partial(drawn.init, _draw_params)
+param_specs = drawn.whole
 
 
 # --- The block ---------------------------------------------------------------

@@ -40,12 +40,13 @@ a mesh of more than one chip, multi-token prediction, training.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from kukeon_tpu.models import kv_kinds
+from kukeon_tpu.models import drawn, kv_kinds
 from kukeon_tpu.models.expert_layer import (
     COUNTS, NO_COUNTS, expert_layer_counts, swiglu)
 from kukeon_tpu.models.llama import embed, mm
@@ -159,36 +160,17 @@ def window_moe_tiny() -> WindowMoEConfig:
 
 # --- Init --------------------------------------------------------------------
 #
-# The weights ARE their recipe: a leaf is a seeded Gaussian under a key folded
-# from (seed, leaf name, layer, expert), so a chip that holds experts 32-63
-# draws exactly those, and ``benchmark/reference/window_moe.py`` draws the
-# same values without importing this file (tests/bench pins the two).
+# The weights ARE their recipe (``models/drawn.py``): this family's table, and
+# ``benchmark/reference/window_moe.py`` draws the same values without
+# importing this file (tests/bench pins the two).
 
 LEAVES = ("embed", "lm_head", "final_norm", "norm1", "norm2", "norm3",
           "norm4", "q_norm", "k_norm", "wq", "wk", "wv", "wg", "wo",
           "w_gate", "w_up", "w_down", "router", "bias", "s_gate", "s_up",
           "s_down", "e_gate", "e_up", "e_down")
 BIAS_STD = 0.02     # a tenth of the sigmoid scores' spread (0.21)
-GAIN_STD = 0.1
 
-
-def _leaf_key(key, name: str, layer=None, expert=None):
-    key = jax.random.fold_in(key, LEAVES.index(name))
-    if layer is not None:
-        key = jax.random.fold_in(key, layer)
-    if expert is not None:
-        key = jax.random.fold_in(key, expert)
-    return key
-
-
-def _matrix(key, shape, fan_in, dtype):
-    return (jax.random.normal(key, shape, jnp.float32)
-            * fan_in ** -0.5).astype(dtype)
-
-
-def _gain(key, shape, dtype):
-    return (1.0 + GAIN_STD * jax.random.normal(key, shape, jnp.float32)
-            ).astype(dtype)
+_key = functools.partial(drawn.leaf_key, LEAVES)
 
 
 def _layer_leaves(cfg: WindowMoEConfig, dense: bool) -> dict:
@@ -218,19 +200,10 @@ def _layer_leaves(cfg: WindowMoEConfig, dense: bool) -> dict:
 
 
 def _draw(key, cfg, name, kind, shape, fan_in, layer):
-    if kind == "gain":
-        return _gain(_leaf_key(key, name, layer), shape, cfg.dtype)
-    if kind == "router":
-        return _matrix(_leaf_key(key, name, layer), shape, fan_in, jnp.float32)
     if kind == "bias":
         return BIAS_STD * jax.random.normal(
-            _leaf_key(key, name, layer), shape, jnp.float32)
-    if kind == "experts":
-        first, count = cfg.experts_held
-        return jax.lax.map(
-            lambda e: _matrix(_leaf_key(key, name, layer, e), shape, fan_in,
-                              cfg.dtype), first + jnp.arange(count))
-    return _matrix(_leaf_key(key, name, layer), shape, fan_in, cfg.dtype)
+            _key(key, name, layer), shape, jnp.float32)
+    return drawn.draw(LEAVES, key, cfg, name, kind, shape, fan_in, layer)
 
 
 def _draw_params(key: jax.Array, cfg: WindowMoEConfig) -> Params:
@@ -239,9 +212,9 @@ def _draw_params(key: jax.Array, cfg: WindowMoEConfig) -> Params:
                    len(c.period))
     H, V = c.hidden_size, c.vocab_size
     return {
-        "embed": _matrix(_leaf_key(key, "embed"), (V, H), H, c.dtype),
-        "lm_head": _matrix(_leaf_key(key, "lm_head"), (H, V), H, c.dtype),
-        "final_norm": _gain(_leaf_key(key, "final_norm"), (H,), c.dtype),
+        "embed": drawn.matrix(_key(key, "embed"), (V, H), H, c.dtype),
+        "lm_head": drawn.matrix(_key(key, "lm_head"), (H, V), H, c.dtype),
+        "final_norm": drawn.gain(_key(key, "final_norm"), (H,), c.dtype),
         "head": [
             {name: _draw(key, c, name, *spec, i)
              for name, spec in _layer_leaves(c, i < Ld).items()}
@@ -255,25 +228,8 @@ def _draw_params(key: jax.Array, cfg: WindowMoEConfig) -> Params:
     }
 
 
-def init_params(key: jax.Array, cfg: WindowMoEConfig,
-                shardings: Any = None) -> Params:
-    """Checkpoint-less init on the device(s) in ONE jitted program that takes
-    the key as its argument, every leaf born in its serving sharding
-    (``shardings``: the tree ``parallel.sharding.param_shardings`` gives for
-    this function's ``jax.eval_shape``). One program whatever the seed, so
-    the persistent compile cache finds it again at the next boot (a program
-    a leaf, each under the cache's one-second floor and with the key baked
-    in, compiled anew at every boot: 75-80 s of a 126 s set-up, my chip
-    runs, PR 30); the float32 transient of a stack is one expert's matrix."""
-    return jax.jit(lambda k: _draw_params(k, cfg),
-                   out_shardings=shardings)(key)
-
-
-def param_specs(params: Params):
-    """Everything whole on the one chip."""
-    from jax.sharding import PartitionSpec
-
-    return jax.tree.map(lambda _: PartitionSpec(), params)
+init_params = functools.partial(drawn.init, _draw_params)
+param_specs = drawn.whole
 
 
 # --- The block ---------------------------------------------------------------

@@ -1,10 +1,11 @@
 """Which implementation each dispatching op chose.
 
-``int8_matmul`` and ``gqa_attention`` pick a Pallas kernel or the XLA
-path from what they can observe (backend, shapes). That choice is made
-once per trace and is invisible afterwards — the compiled program just
-runs — so the dispatchers count it here where they make it, and the
-serving engine exports the counts as
+``gqa_attention``, ``decode_gqa_attention``, the scans and the expert
+products pick a Pallas kernel or the XLA path from what they can observe
+(backend, devices, shapes); the int8 product has the one XLA path and is
+counted with them. That choice is made once per trace and is invisible
+afterwards — the compiled program just runs — so the dispatchers count it
+here where they make it, and the serving engine exports the counts as
 ``kukeon_op_impl_traces_total{op=,impl=}``: a scrape (and
 ``chip_smoke.py``) can say which path the programs on this device took
 without lowering anything again.

@@ -291,7 +291,6 @@ class ServingEngine:
         eos_ids: tuple[int, ...] = (),
         decode_chunk: int | None = None,
         seed: int = 0,
-        int8_pallas: bool | None = None,
         kv_cache_int8: bool | None = None,
         async_load: bool = False,
         forward_fn=None,
@@ -302,7 +301,6 @@ class ServingEngine:
         model_name: str | None = None,
         max_pending: int | None = None,
         registry: Registry | None = None,
-        trace_capacity: int = 512,
         kv_page_tokens: int | None = None,
         kv_pool_pages: int | None = None,
         kv_shard: bool | None = None,
@@ -384,36 +382,6 @@ class ServingEngine:
             tuple(sorted({int(b) for b in prefill_buckets}))
             if prefill_buckets else PREFILL_BUCKETS
         )
-        # int8_pallas=None -> auto: route quantized decode matmuls through
-        # the Pallas kernel on a single-chip TPU mesh when the operator opts
-        # in (KUKEON_INT8_PALLAS=1). Microbenchmarks on v5e measured the
-        # kernel at parity with XLA 0.9's dequant-fused dot (both at the
-        # HBM roof), so the default stays on the XLA path; the env knob
-        # exists for XLA versions whose fusion regresses. Multi-chip meshes
-        # always keep XLA's dot: GSPMD partitions it, while a pallas_call
-        # would force all-gathers of the sharded weights. Explicit
-        # True/False is authoritative either way — False must clear a flag
-        # already set on cfg.
-        if int8_pallas is None:
-            import os as _os
-
-            env_wants = (
-                _os.environ.get("KUKEON_INT8_PALLAS", "").lower()
-                in ("1", "true", "yes", "on")
-                and jax.default_backend() == "tpu"
-                and llama._is_q(ptree.get("layers", {}).get("wq"))
-            )
-            # The mesh guard applies to BOTH triggers: auto mode must clear
-            # a pallas-enabled cfg on a multi-chip mesh (per-layer weight
-            # all-gathers), not just decline to set it.
-            int8_pallas = (
-                (getattr(cfg, "int8_pallas", False) or env_wants)
-                and mesh is not None
-                and mesh.size == 1
-            )
-        # (a family with no int8 path has no such lever on its config)
-        if getattr(cfg, "int8_pallas", int8_pallas) != int8_pallas:
-            cfg = dataclasses.replace(cfg, int8_pallas=int8_pallas)
         self.cfg = cfg
         self.mesh = mesh
         # KV-shard lever: None = shard over the mesh's
@@ -613,7 +581,7 @@ class ServingEngine:
         # must never cross-pollute; the serving cell injects its own so
         # cell-level and engine-level metrics share one /metrics scrape.
         self.registry = registry or Registry()
-        self.tracer = Tracer(capacity=trace_capacity)
+        self.tracer = Tracer(capacity=512)
         reg = self.registry
         self._m_queue_wait = reg.histogram(
             "kukeon_engine_queue_wait_seconds",

@@ -60,55 +60,32 @@ def pytest_configure(config):
         "pytest-xdist worker")
 
 
-def _host_lock(name: str, how: int):
-    import fcntl
-    import tempfile
-
-    f = open(os.path.join(tempfile.gettempdir(), f"kukeon-tests-{name}.lock"),
-             "w")
-    fcntl.flock(f, how)
-    return f
-
-
 @pytest.fixture(scope="module", autouse=True)
 def _host_exclusive_module(request):
     """A module marked ``host_exclusive`` holds the host-state lock
-    exclusively for its whole stay on a pytest-xdist worker; every other
-    test holds it shared (below). The gate makes waiting writers win: a
-    writer keeps it while it waits, so no new sharer slips in. A serial
-    run never waits."""
-    import fcntl
+    exclusively for its whole stay on a pytest-xdist worker
+    (tests/host_lock.py says who holds it shared, and when)."""
+    import host_lock
 
     if request.node.get_closest_marker("host_exclusive") is None:
         yield
         return
-    gate = _host_lock("gate", fcntl.LOCK_EX)
-    try:
-        held = _host_lock("host", fcntl.LOCK_EX)
-    finally:
-        gate.close()
+    host_lock.exclusive()
     try:
         yield
     finally:
-        held.close()
+        host_lock.release()
 
 
 @pytest.fixture(autouse=True)
-def _host_shared_test(request, _host_exclusive_module):
-    import fcntl
+def _host_lock_ends_with_the_test(request):
+    """A test that took the host lock shared (it started a daemon) gives it
+    back when it ends, whatever became of the daemon."""
+    import host_lock
 
-    if request.node.get_closest_marker("host_exclusive") is not None:
-        yield
-        return
-    gate = _host_lock("gate", fcntl.LOCK_EX)
-    try:
-        held = _host_lock("host", fcntl.LOCK_SH)
-    finally:
-        gate.close()
-    try:
-        yield
-    finally:
-        held.close()
+    yield
+    if request.node.get_closest_marker("host_exclusive") is None:
+        host_lock.release()
 
 
 @pytest.fixture

@@ -1,6 +1,5 @@
-"""Checkpoint tooling: HF-layout synthesis, streaming int8 load, quantized
-checkpoint save/load, and the int8_pallas flag plumbing (VERDICT r3 items
-1 & 4)."""
+"""Checkpoint tooling: HF-layout synthesis, streaming int8 load and quantized
+checkpoint save/load (VERDICT r3 item 1)."""
 
 from __future__ import annotations
 
@@ -169,77 +168,6 @@ class TestServingCellLoaders:
         out = cell.generate({"promptTokens": [3, 1, 4], "maxNewTokens": 4,
                              "temperature": 0.0})
         assert out["numTokens"] == 4
-
-
-class TestInt8PallasFlag:
-    def test_flag_plumbing_cpu_fallback(self):
-        """int8_pallas=True must be a no-op numerically (CPU backend routes
-        through the XLA fallback inside int8_matmul)."""
-        cfg = _tiny_cfg()
-        qp = llama.quantize_params(llama.init_params(jax.random.key(0), cfg))
-        cfg8 = dataclasses.replace(cfg, int8_pallas=True)
-        B = 2
-        cache = llama.KVCache.create(cfg, B, 32)
-        cache8 = llama.KVCache.create(cfg, B, 32)
-        prompt = jax.random.randint(jax.random.key(1), (B, 8), 0, cfg.vocab_size)
-        pos = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32)[None, :], (B, 8))
-        _, cache = llama.forward(qp, cfg, prompt, pos, cache=cache)
-        _, cache8 = llama.forward(qp, cfg8, prompt, pos, cache=cache8)
-        t = jnp.array([[5], [7]], jnp.int32)
-        lg, _ = llama.forward(qp, cfg, t, cache.lengths[:, None], cache=cache)
-        lg8, _ = llama.forward(qp, cfg8, t, cache8.lengths[:, None], cache=cache8)
-        np.testing.assert_allclose(np.asarray(lg), np.asarray(lg8),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_engine_auto_flag_off_on_cpu(self):
-        from kukeon_tpu.parallel import make_mesh
-        from kukeon_tpu.serving import ServingEngine
-
-        cfg = _tiny_cfg()
-        qp = llama.quantize_params(llama.init_params(jax.random.key(0), cfg))
-        mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
-        eng = ServingEngine(cfg, qp, mesh, num_slots=2, max_seq_len=64)
-        assert eng.cfg.int8_pallas is False   # cpu backend -> auto stays off
-
-    def test_env_knob_requires_tpu_and_auto_clears_on_multichip(self, monkeypatch):
-        """KUKEON_INT8_PALLAS=true must not enable pallas on CPU, and auto
-        mode must CLEAR a pallas-enabled cfg on a multi-chip mesh (the
-        per-layer all-gather hazard)."""
-        import dataclasses
-
-        from kukeon_tpu.parallel import make_mesh
-        from kukeon_tpu.serving import ServingEngine
-
-        cfg = _tiny_cfg()
-        qp = llama.quantize_params(llama.init_params(jax.random.key(0), cfg))
-        monkeypatch.setenv("KUKEON_INT8_PALLAS", "true")
-        mesh1 = make_mesh(tensor=1, devices=jax.devices()[:1])
-        eng = ServingEngine(cfg, qp, mesh1, num_slots=2, max_seq_len=64)
-        assert eng.cfg.int8_pallas is False   # cpu backend blocks the env knob
-
-        cfg8 = dataclasses.replace(cfg, int8_pallas=True)
-        mesh2 = make_mesh(tensor=2, devices=jax.devices()[:2])
-        eng = ServingEngine(cfg8, qp, mesh2, num_slots=2, max_seq_len=64)
-        assert eng.cfg.int8_pallas is False   # multi-chip auto-clears
-
-        mesh1b = make_mesh(tensor=1, devices=jax.devices()[:1])
-        eng = ServingEngine(cfg8, qp, mesh1b, num_slots=2, max_seq_len=64)
-        assert eng.cfg.int8_pallas is True    # single-device cfg flag honored
-
-    def test_engine_explicit_false_clears_cfg_flag(self):
-        """int8_pallas=False must override a flag already set on cfg (a
-        multi-chip engine handed a pallas cfg would all-gather weights)."""
-        import dataclasses
-
-        from kukeon_tpu.parallel import make_mesh
-        from kukeon_tpu.serving import ServingEngine
-
-        cfg = dataclasses.replace(_tiny_cfg(), int8_pallas=True)
-        qp = llama.quantize_params(llama.init_params(jax.random.key(0), cfg))
-        mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
-        eng = ServingEngine(cfg, qp, mesh, num_slots=2, max_seq_len=64,
-                            int8_pallas=False)
-        assert eng.cfg.int8_pallas is False
 
 
 class TestTokenizerRobustness:

@@ -4,11 +4,13 @@ The TPU compiler is installed here and compiles for a v5e that is described
 (`jax.experimental.topologies`), not attached: it refuses what the chip's
 compiler would refuse — a kernel tile that does not align, a program that
 does not fit 16 GB — and interpret-mode tests on the CPU cannot. These are
-the main path's kernels at the real widths and the whole decode programs of
-the flagship cell and of the benchmark's configurations; they guard every
-later PR at no chip time. A compile that
-passes is not a chip run: nothing executes, no number here is a device
-metric.
+the main path's kernels at the real widths and the whole programs of the
+flagship cell and of the benchmark's dense configuration; each layered
+configuration's whole programs are in a file of its own
+(``test_chip_compile_<family>.py``, which use this file's fixtures) so that
+the test runner's workers share the minutes. They guard every later PR at no
+chip time. A compile that passes is not a chip run: nothing executes, no
+number here is a device metric.
 
 The dispatchers ask `jax.default_backend()` and here that says "cpu"; the
 tests steer it themselves (monkeypatch), not through an option of the
@@ -35,7 +37,6 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding  # n
 from kukeon_tpu.models import llama  # noqa: E402
 from kukeon_tpu.ops import decode_attention as da  # noqa: E402
 from kukeon_tpu.ops import flash_attention as fa  # noqa: E402
-from kukeon_tpu.ops import int8_matmul as i8  # noqa: E402
 from kukeon_tpu.ops import selective_scan as ss  # noqa: E402
 from kukeon_tpu.ops import ssd_scan as sd  # noqa: E402
 
@@ -78,43 +79,6 @@ def _on(dev, shape, dtype):
 def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text(), \
         "the Pallas kernel is not in the compiled program (XLA path taken)"
-
-
-# llama3-8b: hidden 4096, q 4096, kv 1024, ffn 14336, vocab 128256.
-# mixtral-8x7b shares those widths; its vocab is 32000.
-@pytest.mark.parametrize("k,n", [
-    (4096, 4096),      # wq / wo
-    (4096, 1024),      # wk / wv
-    (4096, 14336),     # w_gate / w_up
-    (14336, 4096),     # w_down
-    (4096, 128256),    # llama3-8b lm_head
-    (4096, 32000),     # mixtral-8x7b lm_head
-])
-def test_int8_matmul_compiles_for_v5e(v5e, k, n):
-    d = v5e.devices[0]
-    compiled = i8.int8_matmul.lower(
-        _on(d, (4, k), jnp.bfloat16), _on(d, (k, n), jnp.int8),
-        _on(d, (n,), jnp.float32)).compile()
-    _assert_kernel(compiled)
-
-
-def test_int8_matmul_transposed_compiles_for_v5e(v5e):
-    """The tied-embedding LM head orientation: q [N, K], vocab rows."""
-    d = v5e.devices[0]
-    compiled = i8.int8_matmul.lower(
-        _on(d, (4, 4096), jnp.bfloat16), _on(d, (128256, 4096), jnp.int8),
-        _on(d, (128256,), jnp.float32), transpose=True).compile()
-    _assert_kernel(compiled)
-
-
-@pytest.mark.parametrize("k,n", [(4096, 14336), (14336, 4096)])
-def test_int8_matmul_expert_compiles_for_v5e(v5e, k, n):
-    """Mixtral's 8 expert stacks at decode-sized capacity."""
-    d = v5e.devices[0]
-    compiled = jax.jit(i8.int8_matmul_expert).lower(
-        _on(d, (8, 4, k), jnp.bfloat16), _on(d, (8, k, n), jnp.int8),
-        _on(d, (8, n), jnp.float32)).compile()
-    _assert_kernel(compiled)
 
 
 @pytest.mark.parametrize("s", [1024, 8192])
@@ -318,50 +282,11 @@ def _cache_sized_values(text: str, cache_elements: int,
     return made
 
 
-@pytest.mark.parametrize("config, k, gb", [
-    ("mistral-7b-v0.3-int8", 16, 9.41),
-    ("mistral-7b-v0.3-int8", 4, 9.41),
-    ("trinity-large-preview-ep8-bf16", 4, 12.12),
-])
-def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
-        v5e, config, k, gb):
-    """A benchmark cell's whole decode program, built by the engine from
-    shapes alone through the cell's launcher: the decode kernel is in it
-    (one call a layer kind the scan holds), nothing in it makes a value the
-    size of one layer's K or V of one kind (a Pallas operand takes its
-    default layout, so a kernel fed a slice or a transposed view of the
-    stack would bring a copy of it back), and what it keeps resident is what
-    it kept with the XLA body."""
-    from benchmark import rehearse_compile as rc
-    from kukeon_tpu.ops import dispatch
-
-    mesh, eng, args = _abstract_cell(v5e, config)
-    before = dispatch.counts().get(("decode_gqa_attention", "pallas"), 0)
-    products = dispatch.counts().get(("expert_products", "pallas"), 0)
-    with jax.set_mesh(mesh):
-        compiled = eng._decode_chunk.lower(*args, k).compile()
-    assert dispatch.counts()[("decode_gqa_attention", "pallas")] > before
-    text = compiled.as_text()
-    # a cell with an expert layer runs its routed products as the kernels of
-    # ops/expert_products.py (Trinity: 128 rows a step, 3072 x 3072 a held
-    # expert); a dense cell has neither them nor a ragged product
-    routed = "e_gate" in str(jax.tree_util.tree_structure(args[0]))
-    assert (dispatch.counts().get(("expert_products", "pallas"), 0)
-            > products) is routed
-    assert ("expert_products" in text) is routed and "ragged-dot" not in text
-    assert "decode_attention" in text and "tpu_custom_call" in text
-    held = args[1].cache.k           # one stack, or one a kind
-    smallest = min(x.size // x.shape[0]
-                   for x in (held if isinstance(held, tuple) else (held,)))
-    assert _cache_sized_values(text, smallest) == []
-    assert rc.resident(compiled) / 1e9 == pytest.approx(gb, rel=0.01)
-
-
-def _lower_dense_program(mesh, eng, args, kind: str, sizes: tuple):
-    """One of a dense cell's programs with the arguments
+def _lower_program(mesh, eng, args, kind: str, sizes: tuple):
+    """One of a cell's programs with the arguments
     ``benchmark/rehearse_compile.py`` states for it: a decode chunk of
-    ``sizes[0]`` steps, a prefill of ``sizes[0]`` tokens, or a ``prefill_ext``
-    of ``sizes[1]`` tokens behind ``sizes[0]`` cached rows."""
+    ``sizes[0]`` steps, a prefill of ``sizes[0]`` tokens, or a dense cell's
+    ``prefill_ext`` of ``sizes[1]`` tokens behind ``sizes[0]`` cached rows."""
     cfg, repl = eng.cfg, NamedSharding(mesh, PartitionSpec())
 
     def sds(shape, dtype, sh=repl):
@@ -382,13 +307,80 @@ def _lower_dense_program(mesh, eng, args, kind: str, sizes: tuple):
                                   tokens, i32, key, f32, i32, f32)
 
 
+@pytest.fixture(scope="module")
+def programs(v5e):
+    """``programs(config, kind, *sizes)`` -> (eng, args, compiled, noted): a
+    benchmark configuration's engine over shapes alone and one of its
+    programs compiled for the described chip, each built ONCE a module
+    however many tests read it (a whole program is 25-65 s of compiling);
+    ``noted`` is what the dispatchers counted while the program was traced."""
+    from kukeon_tpu.ops import dispatch
+
+    cells, built = {}, {}
+
+    def get(config, kind, *sizes):
+        if config not in cells:
+            cells[config] = _abstract_cell(v5e, config)
+        mesh, eng, args = cells[config]
+        if (config, kind, sizes) not in built:
+            before = dispatch.counts()
+            with jax.set_mesh(mesh):
+                compiled = _lower_program(mesh, eng, args, kind,
+                                          sizes).compile()
+            built[config, kind, sizes] = compiled, {
+                op: n - before.get(op, 0)
+                for op, n in dispatch.counts().items()}
+        return (eng, args, *built[config, kind, sizes])
+
+    return get
+
+
+def a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
+        programs, config, k, gb):
+    """A benchmark cell's whole decode program, built by the engine from
+    shapes alone through the cell's launcher: the decode kernel is in it
+    (one call a layer kind the scan holds), nothing in it makes a value the
+    size of one layer's K or V of one kind (a Pallas operand takes its
+    default layout, so a kernel fed a slice or a transposed view of the
+    stack would bring a copy of it back), and what it keeps resident is what
+    it kept with the XLA body."""
+    from benchmark import rehearse_compile as rc
+
+    _eng, args, compiled, noted = programs(config, "decode_chunk", k)
+    assert noted[("decode_gqa_attention", "pallas")] > 0
+    text = compiled.as_text()
+    # a cell with an expert layer runs its routed products as the kernels of
+    # ops/expert_products.py (Trinity: 128 rows a step, 3072 x 3072 a held
+    # expert); a dense cell has neither them nor a ragged product
+    routed = "e_gate" in str(jax.tree_util.tree_structure(args[0]))
+    assert (noted.get(("expert_products", "pallas"), 0) > 0) is routed
+    assert ("expert_products" in text) is routed and "ragged-dot" not in text
+    assert "decode_attention" in text and "tpu_custom_call" in text
+    held = args[1].cache.k           # one stack, or one a kind
+    smallest = min(x.size // x.shape[0]
+                   for x in (held if isinstance(held, tuple) else (held,)))
+    assert _cache_sized_values(text, smallest) == []
+    assert rc.resident(compiled) / 1e9 == pytest.approx(gb, rel=0.01)
+
+
+# (the window cell's case is in tests/test_chip_compile_window_moe.py)
+@pytest.mark.parametrize("config, k, gb", [
+    ("mistral-7b-v0.3-int8", 16, 9.41),
+    ("mistral-7b-v0.3-int8", 4, 9.41),
+])
+def test_a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
+        programs, config, k, gb):
+    a_cells_decode_chunk_runs_the_kernel_and_copies_no_cache(
+        programs, config, k, gb)
+
+
 @pytest.mark.parametrize("kind, sizes", [
     ("decode_chunk", (4,)), ("decode_chunk", (16,)),
     ("prefill", (256,)), ("prefill", (2048,)),
     ("prefill_ext", (1024, 256)),   # a turn of agent-sessions.json's warm-up
 ], ids=lambda v: v if isinstance(v, str) else "+".join(map(str, v)))
 def test_a_dense_cells_programs_make_no_value_of_a_weights_size(
-        v5e, kind, sizes):
+        programs, kind, sizes):
     """Every quantized product of `mistral-7b-v0.3-int8` takes its stack and
     the layer's index and reads one layer in place: no instruction of a
     decode chunk or a prefill MAKES an int8 value of one layer of `wk`
@@ -402,9 +394,8 @@ def test_a_dense_cells_programs_make_no_value_of_a_weights_size(
     transposes a layer of each. The one int8 value a long prefill does make
     is the prompt's own rows of the embedding table, `s8[tokens, hidden]`:
     a lookup's result, not a weight."""
-    mesh, eng, args = _abstract_cell(v5e, "mistral-7b-v0.3-int8")
-    with jax.set_mesh(mesh):
-        compiled = _lower_dense_program(mesh, eng, args, kind, sizes).compile()
+    eng, args, compiled, _noted = programs(
+        "mistral-7b-v0.3-int8", kind, *sizes)
     wk = args[0]["layers"]["wk"]["q"]
     assert wk.dtype == jnp.int8 and wk.shape[1:] == (4096, 1024)
     made = _cache_sized_values(compiled.as_text(), wk.size // wk.shape[0], "s8")
@@ -412,55 +403,6 @@ def test_a_dense_cells_programs_make_no_value_of_a_weights_size(
     assert [v for v in made if not v.endswith(embedded)] == []
     if kind == "decode_chunk":
         assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
-
-
-@pytest.mark.parametrize("k", [4, 16])
-def test_the_state_space_cells_decode_chunk_holds_one_copy_of_each_state_stack(
-        v5e, k):
-    """jamba2-3b's whole decode program, built by the engine from shapes alone
-    through the cell's launcher: 26 mixers' scan state for 64 slots is 545 MB
-    of float32 and their convolution tails 51 MB. The chunk donates and
-    carries both, a layer's state is written back where it was read, and so
-    no instruction makes a second array of either stack's size and the
-    temporaries stay under half the scan state; the decode kernel reads the
-    two attention layers' rows (20 query heads on one KV head) in place.
-    Since PR 41 the scan states are updated by the kernel of
-    ``selective_scan.update_held`` in the stack itself (one call in each run
-    of mixers): no fusion writes the stack any more."""
-    from benchmark import rehearse_compile as rc
-    from kukeon_tpu.ops import dispatch
-
-    mesh, eng, args = _abstract_cell(v5e, "ai21-jamba2-3b-bf16")
-    before = dispatch.counts().get(("state_update", "pallas"), 0)
-    with jax.set_mesh(mesh):
-        compiled = eng._decode_chunk.lower(*args, k).compile()
-    assert dispatch.counts()[("state_update", "pallas")] == before + 2
-    text = compiled.as_text()
-    assert "decode_attention" in text and "tpu_custom_call" in text
-    assert len(re.findall(r" = \(.*f32\[26,64,16,5120\]\S*\) custom-call\(",
-                          text)) == 2 and "ssm_state_update" in text
-    assert not re.search(r" = f32\[26,64,16,5120\]\S* fusion\(", text)
-    state, rows = args[1].cache.held
-    assert state["ssm"].shape == (26, 64, 16, 5120)
-    assert state["conv"].shape == (26, 3, 64, 5120)
-    assert _cache_sized_values(text, state["ssm"].size, "f32") == []
-    # (the weights are bf16 too and larger than these stacks, and the
-    # compiler prefetches some of them whole once a chunk: tell the cache's
-    # own arrays by their dimensions, a stack's or one layer's)
-    def dims(v):
-        return sorted(int(n) for n in v[v.index("[") + 1:-1].split(",")
-                      if n != "1")
-
-    bf16 = _cache_sized_values(text, state["conv"].size)
-    for stack in (state["conv"].shape, rows["k"].shape):
-        ours = [sorted(n for n in shape if n != 1)
-                for shape in (stack, stack[1:])]
-        assert [v for v in bf16 if dims(v) in ours] == []
-    m = compiled.memory_analysis()
-    assert m.temp_size_in_bytes < state["ssm"].size * 4 / 2
-    assert 6.0e9 < sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
-        args[0])) < 6.1e9
-    assert rc.resident(compiled) / 1e9 == pytest.approx(7.07, rel=0.01)
 
 
 # The selecting attention's kernels (ops/sparse_attention.py) at the
@@ -529,69 +471,6 @@ def test_sparse_decode_attention_compiles_for_v5e(v5e):
     # the stack is an operand in place; what is made beside it is the scores
     # in the floats' order (2 MB), never a gathered copy of the selection
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
-
-
-@pytest.mark.parametrize("program, size, temp_gb", [
-    ("decode_chunk", 1, 0.1), ("decode_chunk", 4, 0.1),
-    ("decode_chunk", 16, 0.1), ("prefill", 32768, 2.4)])
-def test_the_sparse_latent_cells_programs_fit_beside_its_cache(
-        v5e, program, size, temp_gb):
-    """deepseek-v3.2-exp-ep16-bf16's decode chunk and its largest prefill,
-    built by the engine from shapes alone through the cell's launcher: 9.29 GB
-    of weights and 4.03 GB of cache (16 slots x 32768 rows x five layers of
-    640 + 128 values) stay resident, so a program's temporaries have to fit
-    what is left of the chip; a decode chunk of each length the cell warms
-    runs the index kernel and the selecting attention's, sorts no scores,
-    gathers no copy of the selection (16 slots x 2048 rows x 640) and makes no
-    value of a cache layer's size (both kernels read the held stacks in
-    place), the prefill runs the selection and the masked attention and never
-    a [S, S] array of scores."""
-    from benchmark import rehearse_compile as rc
-    from kukeon_tpu.ops import dispatch
-
-    chosen = dispatch.counts().get(("expert_products", "pallas"), 0)
-    mesh, eng, args = _abstract_cell(v5e, "deepseek-v3.2-exp-ep16-bf16")
-    repl = NamedSharding(mesh, PartitionSpec())
-    held, = args[1].cache.held
-    assert held["ckv"].shape == (5, 16, 32768, 640)
-    assert held["kidx"].shape == (5, 16, 32768, 128)
-    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(args[0]))
-    cache = sum(x.size * x.dtype.itemsize for x in held.values())
-    assert 9.25e9 < weights < 9.30e9 and 4.0e9 < cache < 4.05e9
-    with jax.set_mesh(mesh):
-        if program == "decode_chunk":
-            compiled = eng._decode_chunk.lower(*args, size).compile()
-        else:
-            scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)  # noqa: E731
-            compiled = eng._prefill.lower(
-                args[0], jax.ShapeDtypeStruct((1, size), jnp.int32,
-                                              sharding=repl),
-                scalar(jnp.int32), args[2], scalar(jnp.float32),
-                scalar(jnp.int32), scalar(jnp.float32)).compile()
-    text = compiled.as_text()
-    m = compiled.memory_analysis()
-    assert m.temp_size_in_bytes < temp_gb * 1e9
-    # the expert layers' routed products (128 rows a decode step, blocks of
-    # 2048 a prefill; 7168 and 2048 wide) are ops/expert_products.py's, the
-    # held stacks operands where they lie
-    assert dispatch.counts()[("expert_products", "pallas")] > chosen
-    assert "expert_products" in text and "ragged-dot" not in text
-    assert not re.search(r"bf16\[16,(7168,2048|2048,7168)\]\S* (copy|fusion)\(",
-                         text)
-    if program == "decode_chunk":
-        assert "sparse_decode_index_scores" in text
-        assert "sparse_decode_attention" in text
-        # the only sort left is the sampler's, over the vocabulary
-        assert not re.search(r"\[16,3276[89]\]\S* sort\(", text)
-        assert "[16,32769]" not in text
-        assert not re.search(r"bf16\[(16,2048|32768),640\]", text)
-        assert _cache_sized_values(text, held["kidx"].size // 5) == []
-        assert rc.resident(compiled) < V5E_HBM_BYTES
-    else:
-        assert "sparse_select_rows" in text
-        assert "sparse_masked_attention" in text
-        # beside the cache, which a prefill does not take as an argument
-        assert rc.resident(compiled) + cache < V5E_HBM_BYTES
 
 
 # The chunked state-space scan at granite-4.0-h-small's widths (128 heads of
@@ -672,30 +551,3 @@ def test_expert_products_compile_for_v5e(v5e, count, H, I, decode_rows, rows):
         # made, and beside the rows in and out nothing is allocated
         assert _cache_sized_values(text, count * H * I) == []
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
-
-
-def test_the_window_moe_cells_largest_prefill_runs_the_expert_kernels(v5e):
-    """trinity-large-preview-ep8-bf16's 8192 bucket through the cell's
-    launcher: 32768 (token, choice) pairs in blocks of 2048 under one loop an
-    expert layer, each block the two kernels of ``ops/expert_products.py``
-    over the held 32 x 3072 x 3072 stacks in place; no ``ragged-dot`` left,
-    nothing of a stack's size made, and the program fits beside the cache."""
-    from benchmark import rehearse_compile as rc
-    from kukeon_tpu.ops import dispatch
-
-    mesh, eng, args = _abstract_cell(v5e, "trinity-large-preview-ep8-bf16")
-    repl = NamedSharding(mesh, PartitionSpec())
-    chosen = dispatch.counts().get(("expert_products", "pallas"), 0)
-    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)  # noqa: E731
-    with jax.set_mesh(mesh):
-        compiled = eng._prefill.lower(
-            args[0], jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=repl),
-            scalar(jnp.int32), args[2], scalar(jnp.float32),
-            scalar(jnp.int32), scalar(jnp.float32)).compile()
-    assert dispatch.counts()[("expert_products", "pallas")] > chosen
-    text = compiled.as_text()
-    assert "expert_products" in text and "ragged-dot" not in text
-    assert not re.search(r"bf16\[32,3072,3072\]\S* (copy|fusion)\(", text)
-    cache = sum(x.size * x.dtype.itemsize
-                for x in jax.tree.leaves(args[1].cache))
-    assert rc.resident(compiled) + cache < V5E_HBM_BYTES

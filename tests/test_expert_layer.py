@@ -81,14 +81,14 @@ def _some(seed=0):
 
 def test_a_counted_rows_output_is_the_same_whoever_else_counts(layer):
     h, w, kw, _sel, _held, _shared = layer
-    whole, _ = el.expert_layer(h, w, counted=jnp.ones(N, bool), **kw)
+    whole, _ = el.expert_layer_counts(h, w, counted=jnp.ones(N, bool), **kw)
     for seed in (0, 1):
         counted = _some(seed)
-        y, _ = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+        y, _ = el.expert_layer_counts(h, w, counted=jnp.asarray(counted), **kw)
         np.testing.assert_allclose(np.asarray(y)[counted],
                                    np.asarray(whole)[counted], atol=1e-6)
     one = np.arange(N) == 7
-    y, _ = el.expert_layer(h, w, counted=jnp.asarray(one), **kw)
+    y, _ = el.expert_layer_counts(h, w, counted=jnp.asarray(one), **kw)
     np.testing.assert_allclose(np.asarray(y)[7], np.asarray(whole)[7],
                                atol=1e-6)
 
@@ -96,7 +96,7 @@ def test_a_counted_rows_output_is_the_same_whoever_else_counts(layer):
 def test_an_uncounted_row_gets_the_shared_expert_alone(layer):
     h, w, kw, _sel, held, shared = layer
     counted = _some()
-    y, _ = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+    y, _ = el.expert_layer_counts(h, w, counted=jnp.asarray(counted), **kw)
     np.testing.assert_allclose(np.asarray(y)[~counted], shared[~counted],
                                atol=1e-6)
     # and a counted row with a held choice gets more than that
@@ -107,11 +107,12 @@ def test_an_uncounted_row_gets_the_shared_expert_alone(layer):
 
 def test_with_no_row_counted_every_group_is_empty_and_the_output_finite(layer):
     h, w, kw, _sel, _held, shared = layer
-    y, tally = jax.jit(lambda h: el.expert_layer(
+    y, tally = jax.jit(lambda h: el.expert_layer_counts(
         h, w, counted=jnp.zeros(N, bool), **kw))(h)
     assert np.isfinite(np.asarray(y)).all()
     np.testing.assert_allclose(np.asarray(y), shared, atol=1e-6)
-    assert np.asarray(tally).tolist() == [0, kw["experts_held"][1], 0]
+    assert np.asarray(tally)[:len(el.TALLY)].tolist() == [
+        0, kw["experts_held"][1], 0]
 
 
 def test_one_counted_row_of_32_reaches_at_most_top_k_held_experts(layer):
@@ -120,25 +121,25 @@ def test_one_counted_row_of_32_reaches_at_most_top_k_held_experts(layer):
                         "kukeon_moe_held_experts_total",
                         "kukeon_moe_held_experts_reached_total")
     row = int(np.argmax(held.sum(axis=1)))      # a row that hits something
-    _, tally = el.expert_layer(h, w, counted=jnp.arange(N) == row, **kw)
-    hits, total, reached = (int(v) for v in tally)
+    _, tally = el.expert_layer_counts(h, w, counted=jnp.arange(N) == row, **kw)
+    hits, total, reached = (int(v) for v in tally[:len(el.TALLY)])
     assert total == kw["experts_held"][1]
     assert 0 < reached <= kw["experts_per_token"]
     # a row's choices are distinct experts: each hit reaches its own
     assert reached == hits == len(set(sel[row][held[row]]))
-    _, tally = el.expert_layer(h, w, counted=jnp.ones(N, bool), **kw)
+    _, tally = el.expert_layer_counts(h, w, counted=jnp.ones(N, bool), **kw)
     assert int(tally[2]) == len(set(sel[held])) <= total
 
 
 def test_the_hit_count_is_the_counted_rows_held_choices(layer):
     h, w, kw, _sel, held, _shared = layer
     for counted in (np.ones(N, bool), _some(), np.zeros(N, bool)):
-        _, tally = el.expert_layer(h, w, counted=jnp.asarray(counted), **kw)
+        _, tally = el.expert_layer_counts(h, w, counted=jnp.asarray(counted), **kw)
         assert int(tally[0]) == int(held[counted].sum())
     # leading axes as a decode step's [B, 1] and a prefill's [1, S]
     counted = _some()
     for lead in ((N, 1), (1, N)):
-        y, tally = el.expert_layer(h.reshape(*lead, H), w,
+        y, tally = el.expert_layer_counts(h.reshape(*lead, H), w,
                                    counted=jnp.asarray(counted.reshape(lead)),
                                    **kw)
         assert y.shape == (*lead, H)

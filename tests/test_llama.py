@@ -126,29 +126,6 @@ def test_int8_kv_roundtrip_error_bounded(tiny):
     assert (err <= np.asarray(s)[..., None] / 2 + 1e-6).all()
 
 
-def test_int8_pallas_decode_parity(tiny):
-    """cfg.int8_pallas routes the fused decode's quantized matmuls through
-    ops/int8_matmul (XLA fallback off-TPU); the decode logits must match
-    the dequant-in-dot path (ISSUE 1 parity criterion)."""
-    import dataclasses
-
-    cfg, params = tiny
-    qp = llama.quantize_params(params)
-    cfg_pl = dataclasses.replace(cfg, int8_pallas=True)
-    B, S = 2, 8
-    tokens = jax.random.randint(jax.random.key(3), (B, S), 0, cfg.vocab_size)
-    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    cache = llama.KVCache.create(cfg, B, 32)
-    _, cache = llama.forward(qp, cfg, tokens, positions, cache)
-
-    step = jax.random.randint(jax.random.key(4), (B, 1), 0, cfg.vocab_size)
-    step_pos = cache.lengths[:, None]
-    want, _ = llama.forward(qp, cfg, step, step_pos, cache)
-    got, _ = llama.forward(qp, cfg_pl, step, step_pos, cache)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_logit_positions_matches_full_head(tiny):
     """logit_positions computes the LM head at one position per sequence;
     the row must equal the same row of the full-head logits (the prefill

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kukeon_tpu.models import moe
+from kukeon_tpu.models import llama, moe
 from kukeon_tpu.parallel import make_mesh
 
 
@@ -362,15 +362,23 @@ def test_quantized_moe_serving_cell():
     assert out["numTokens"] == 3
 
 
-def test_int8_pallas_moe_decode_parity(tiny):
-    """MoE fused int8 decode (attention trunk via llama.mm, expert stacks
-    via int8_matmul_expert) must match the dequant-in-einsum path
-    numerically — the ISSUE 1 parity criterion for the MoE family."""
-    import dataclasses
-
+def test_a_quantized_decode_step_is_the_step_over_the_dequantized_weights(tiny):
+    """The decode forward over int8 leaves (attention trunk through
+    ``llama.mm``, expert stacks through ``_expert_mm``: the dequant fused into
+    each dot) gives the logits of the same step over ``q * s`` held as plain
+    matrices."""
     cfg, params = tiny
     qp = moe.quantize_params(params)
-    cfg_pl = dataclasses.replace(cfg, int8_pallas=True)
+    axis = {"embed": 1, "lm_head": 0, "wq": 1, "wk": 1, "wv": 1, "wo": 1,
+            "w_gate": 2, "w_up": 2, "w_down": 2}
+
+    def plain(tree):
+        return {name: (w["q"].astype(jnp.float32)
+                       * jnp.expand_dims(w["s"], axis[name])
+                       if llama._is_q(w) else w) for name, w in tree.items()}
+
+    fp = {**plain({k: w for k, w in qp.items() if k != "layers"}),
+          "layers": plain(qp["layers"])}
     B, S = 2, 8
     tokens = jax.random.randint(jax.random.key(3), (B, S), 0, cfg.vocab_size)
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
@@ -379,33 +387,7 @@ def test_int8_pallas_moe_decode_parity(tiny):
 
     step = jax.random.randint(jax.random.key(4), (B, 1), 0, cfg.vocab_size)
     step_pos = cache.lengths[:, None]
-    want, _ = moe.forward(qp, cfg, step, step_pos, cache)
-    got, _ = moe.forward(qp, cfg_pl, step, step_pos, cache)
+    got, _ = moe.forward(qp, cfg, step, step_pos, cache)
+    want, _ = moe.forward(fp, cfg, step, step_pos, cache)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_int8_pallas_moe_engine_generation(tiny):
-    """End-to-end: a quantized MoE engine with int8_pallas=True generates
-    the same greedy tokens as the default routing."""
-    import dataclasses
-
-    from kukeon_tpu.parallel import moe_specs_for_params
-    from kukeon_tpu.serving import SamplingParams, ServingEngine
-
-    cfg, params = tiny
-    qp = moe.quantize_params(params)
-    specs = moe_specs_for_params(qp)
-    mesh = make_mesh(tensor=1, devices=jax.devices()[:1])
-    prompt = np.arange(1, 20, dtype=np.int32) % cfg.vocab_size
-    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
-
-    eng = ServingEngine(cfg, qp, mesh, num_slots=2, max_seq_len=64,
-                        forward_fn=moe.forward, param_specs=specs)
-    want = eng.generate(prompt, sp)
-    eng_pl = ServingEngine(cfg, qp, mesh, num_slots=2, max_seq_len=64,
-                           forward_fn=moe.forward, param_specs=specs,
-                           int8_pallas=True)
-    assert eng_pl.cfg.int8_pallas
-    got = eng_pl.generate(prompt, sp)
-    assert got == want
+                               rtol=1e-4, atol=1e-4)

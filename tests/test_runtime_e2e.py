@@ -19,6 +19,8 @@ import uuid
 
 import pytest
 
+import host_lock
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLI = [sys.executable, "-m", "kukeon_tpu.runtime.cli"]
 
@@ -26,6 +28,8 @@ CLI = [sys.executable, "-m", "kukeon_tpu.runtime.cli"]
 class Daemon:
     def __init__(self, chips: str = "0,1", env_overrides: dict | None = None,
                  run_path: str | None = None):
+        # its cells are what tests/test_netpolicy_e2e.py would kill
+        host_lock.share()
         self.run_path = run_path or tempfile.mkdtemp(prefix="kuke-e2e-")
         self.socket_path = f"/tmp/kuked-{uuid.uuid4().hex[:8]}.sock"
         env = dict(os.environ)

@@ -538,14 +538,15 @@ def test_sixteen_shares_and_the_shared_expert_once_make_the_uncut_layer():
     h = jax.random.normal(jax.random.key(9), (50, 32))
     kw = dict(experts_per_token=6, route_scale=2.5, groups=8, groups_kept=4,
               counted=jnp.ones(50, bool))
-    whole, (hits, _held, _reached) = el.expert_layer(
-        h, w, experts_held=(0, 32), **kw)
+    whole, counts = el.expert_layer_counts(h, w, experts_held=(0, 32), **kw)
+    hits = counts[:len(el.TALLY)][0]
     shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
     parts, counted = shared, 0
     for share in range(16):
         held = {**w, **{k: w[k][2 * share:2 * share + 2]
                         for k in ("e_gate", "e_up", "e_down")}}
-        y, n = el.expert_layer(h, held, experts_held=(2 * share, 2), **kw)
+        y, n = el.expert_layer_counts(h, held, experts_held=(2 * share, 2),
+                                      **kw)
         parts = parts + (y - shared)
         counted += int(n[0])
     assert jnp.abs(parts - whole).max() < 1e-5

@@ -165,14 +165,14 @@ def test_eight_shares_and_the_shared_expert_once_make_the_uncut_layer():
     h = jax.random.normal(jax.random.key(9), (50, 32))
     kw = dict(experts_per_token=4, route_scale=2.448,
               counted=jnp.ones(50, bool))
-    whole, (hits, held, reached) = el.expert_layer(
-        h, w, experts_held=(0, 16), **kw)
+    whole, counts = el.expert_layer_counts(h, w, experts_held=(0, 16), **kw)
+    hits, held, reached = counts[:len(el.TALLY)]
     assert held == 16 and 0 < reached <= 16
     shared = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
     parts, counted = shared, 0
     for share in range(8):
-        y, n = el.expert_layer(h, _held(w, 2 * share, 2),
-                               experts_held=(2 * share, 2), **kw)
+        y, n = el.expert_layer_counts(h, _held(w, 2 * share, 2),
+                                      experts_held=(2 * share, 2), **kw)
         parts = parts + (y - shared)
         counted += int(n[0])
     assert jnp.abs(parts - whole).max() < 1e-5
@@ -203,9 +203,10 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     w["router"] = jnp.zeros_like(w["router"])
     w["bias"] = jnp.where(jnp.arange(16) < 4, 1.0, 0.0)
     h = jax.random.normal(jax.random.key(5), (64, 32))
-    y, (hits, held, reached) = el.expert_layer(
+    y, counts = el.expert_layer_counts(
         h, _held(w, 0, 8), experts_per_token=4, experts_held=(0, 8),
         route_scale=1.0, counted=jnp.ones(64, bool))
+    hits, held, reached = counts[:len(el.TALLY)]
     want = el.swiglu(h, w["s_gate"], w["s_up"], w["s_down"]) + sum(
         0.25 * el.swiglu(h, w["e_gate"][e], w["e_up"][e], w["e_down"][e])
         for e in range(4))
